@@ -11,12 +11,20 @@ Cold (scan-inclusive: Parquet parse, dictionary encode, H2D, kernel,
 D2H) is reported separately with a per-phase breakdown under
 `configs.tpch_q1_parquet`.
 
+A device run needs the chip: with no TPU and no explicit
+`JAX_PLATFORMS=cpu` the driver exits non-zero instead of timing the
+CPU under the device's names.  An explicit CPU pin is a logic check —
+every config's gates run, and each reports only that it passed.  Every
+config's output names the platform, device kind and device count it
+ran on.  This process holds the chip; the legs that start children
+(config 5's virtual mesh, the adaptive legs) pin them to the CPU, and
+the worker leg serves from a thread here.
+
 Env knobs: BENCH_SF (lineitem scale factor for config 3, default 1),
 BENCH_CONFIGS (comma list, default
 "1,2,3,4,5,3sf10,worker,cache,conc,ingest,joins,adaptive" —
 "3sf10" runs Q1 at the north-star SF-10 scale, "worker" runs the
-coordinator->worker-on-chip parity smoke and writes
-artifacts/TPU_WORKER_SMOKE.json, "cache" runs the result-cache
+coordinator->worker-on-chip parity smoke, "cache" runs the result-cache
 warm-repeat phase, "joins" runs the TPC-H Q3/Q5/Q10/Q12 join shapes
 against a pandas-merge oracle, "adaptive" runs the cost-store
 cold-vs-trained planning comparison), BENCH_RUNS / BENCH_COLD_RUNS.
@@ -33,9 +41,25 @@ def main():
 
     from benchmarks import suite
 
-    platforms = {d.platform for d in jax.devices()}
-    suite.log(f"devices: {jax.devices()}")
-    device_kind = "cpu" if platforms == {"cpu"} else "tpu"
+    devices = jax.devices()
+    suite.log(f"devices: {devices}")
+    device_kind = devices[0].platform
+    cpu_pinned = os.environ.get("JAX_PLATFORMS", "").lower() == "cpu"
+    if device_kind != "tpu" and not cpu_pinned:
+        print(
+            f"bench.py: no TPU (jax.devices()[0].platform == "
+            f"{device_kind!r}); set JAX_PLATFORMS=cpu for a logic check",
+            file=sys.stderr,
+        )
+        sys.exit(1)
+    device = {
+        "platform": device_kind,
+        "kind": devices[0].device_kind,
+        "count": len(devices),
+    }
+    # legs whose children are pinned to the CPU whatever this process holds
+    cpu_legs = {"5": {"platform": "cpu", "kind": "cpu", "count": 8},
+                "adaptive": {"platform": "cpu", "kind": "cpu", "count": 1}}
 
     wanted = os.environ.get(
         "BENCH_CONFIGS",
@@ -84,6 +108,13 @@ def main():
         if key not in runners:
             continue
         result = runners[key](device_kind)
+        result["device"] = cpu_legs.get(key, device)
+        if device_kind == "cpu":
+            # logic check: the gates ran; no number of a CPU run is
+            # written under a device metric's name
+            result = {k: result[k] for k in ("name", "device", "skipped",
+                                             "error") if k in result}
+            result["passed"] = "error" not in result
         configs[result["name"]] = result
 
     if not configs:
@@ -100,12 +131,16 @@ def main():
         headline = next(iter(configs.values()))
     print(json.dumps({
         "metric": headline["name"] + "_throughput",
-        "value": headline["value"],
-        "unit": headline["unit"],
-        "vs_baseline": headline["vs_baseline"],
-        "device": device_kind,
+        "value": headline.get("value"),
+        "unit": headline.get("unit"),
+        "vs_baseline": headline.get("vs_baseline"),
+        "device": device,
         "configs": configs,
     }))
+    failed = sorted(n for n, r in configs.items() if "error" in r)
+    if failed:
+        print(f"bench.py: failed legs: {failed}", file=sys.stderr)
+        sys.exit(1)
 
 
 if __name__ == "__main__":

@@ -4,7 +4,7 @@ The reference pipeline (PAPER.md) plans purely syntactically — every
 query lowers the same way regardless of what earlier queries measured.
 This package closes that loop: the engine's existing measurement seams
 (per-table scan histograms, aggregate group encoders, the join build
-path, Pallas compile probes, the serving loop's arrival stream) feed a
+path, the serving loop's arrival stream) feed a
 persistent :class:`~datafusion_tpu.cost.store.CostStore`, and the
 planner reads it back at the next lowering:
 
@@ -13,7 +13,7 @@ decision               driven by
 =====================  ==============================================
 aggregation capacity   observed group cardinality per (table, keys):
 / route                the accumulator pre-sizes to the learned group
-                       count, picking dense / Pallas / sort-merge up
+                       count, picking dense / sort-merge up
                        front instead of climbing the regrow ladder
                        (each rung past the dense bound recompiles)
 scan chunk rows        measured link rate vs learned bytes/row — keep
@@ -22,8 +22,6 @@ scan chunk rows        measured link rate vs learned bytes/row — keep
 join build side /      learned table row counts: build the smaller
 order                  input, probe the larger; left-deep dimension
                        joins reorder cheapest-build-first
-Pallas engagement      compile-probe + runtime history widen or
-windows                shrink the static env thresholds
 megabatch window       observed arrival spacing vs the configured
                        wait — don't hold a query for peers that
                        aren't coming
@@ -50,8 +48,7 @@ from typing import Optional
 
 from datafusion_tpu.cost.store import CostStore
 
-# special table keys for engine-global (not per-table) observations
-PALLAS_KEY = "__pallas__"
+# special table key for engine-global (not per-table) observations
 SERVE_KEY = "__serve__"
 
 _STORE: Optional[CostStore] = None

@@ -86,112 +86,6 @@ def scan_chunk_rows(store, tkey: str, device,
     return chosen
 
 
-# -- Pallas engagement windows ---------------------------------------
-# Learned from probe + runtime history under the engine-global
-# PALLAS_KEY: each aggregate/sort records which route ran, at what
-# size, and its device wall.  The learned window subsumes the static
-# DATAFUSION_TPU_PALLAS_AGG_GROUPS / _SORT_ROWS thresholds, which
-# remain the fallback whenever history is thin or contradictory.
-
-_MIN_ROUTE_SAMPLES = 3
-_WINDOW_CAP = 1 << 16
-
-
-def observe_agg_route(store, route: str, group_cap: int,
-                      exec_s: float, rows: float) -> None:
-    if rows <= 0:
-        return
-    store.observe(
-        _cost.PALLAS_KEY, f"agg:{route}",
-        cap=group_cap, exec_s=exec_s, s_per_row=exec_s / rows,
-    )
-
-
-def pallas_agg_window(store=None) -> int:
-    """Max group capacity routed to the Pallas hash-agg kernel.
-    Static threshold unless runtime history says otherwise: if Pallas
-    runs have been slower per row than sort-merge runs, shrink the
-    window to zero (the dense path bound takes over); if Pallas has
-    been winning at its current ceiling, double the window."""
-    from datafusion_tpu.exec.pallas import agg_max_groups
-
-    static = agg_max_groups()
-    if store is None:
-        if not _cost.enabled():
-            return static
-        store = _cost.store()
-    pal = store.lookup(_cost.PALLAS_KEY, "agg:pallas")
-    srt = store.lookup(_cost.PALLAS_KEY, "agg:sortmerge")
-    if pal is None or pal.get("n", 0) < _MIN_ROUTE_SAMPLES:
-        return static
-    if srt is not None and srt.get("n", 0) >= _MIN_ROUTE_SAMPLES:
-        if pal.get("s_per_row", 0) > 1.5 * srt.get("s_per_row", 0) > 0:
-            store.note_decision(
-                "pallas.agg_window", 0, static,
-                f"pallas {pal['s_per_row']:.2e} s/row vs sort-merge "
-                f"{srt['s_per_row']:.2e} over {int(pal['n'])} runs",
-            )
-            return 0
-        if (
-            pal.get("cap_max", 0) >= static
-            and 0 < pal.get("s_per_row", 0) < srt.get("s_per_row", 0)
-        ):
-            widened = min(2 * static, _WINDOW_CAP)
-            if widened > static:
-                store.note_decision(
-                    "pallas.agg_window", widened, static,
-                    f"pallas faster per row at cap {int(pal['cap_max'])}",
-                )
-            return widened
-    return static
-
-
-def observe_sort_route(store, route: str, rows: float,
-                       exec_s: float) -> None:
-    if rows <= 0:
-        return
-    store.observe(
-        _cost.PALLAS_KEY, f"sort:{route}",
-        rows=rows, exec_s=exec_s, s_per_row=exec_s / rows,
-    )
-
-
-def pallas_sort_window(store=None) -> int:
-    """Max row count routed to the Pallas bitonic sort (same learning
-    rule as `pallas_agg_window`, over sort runs)."""
-    from datafusion_tpu.exec.pallas import sort_max_rows
-
-    static = sort_max_rows()
-    if store is None:
-        if not _cost.enabled():
-            return static
-        store = _cost.store()
-    pal = store.lookup(_cost.PALLAS_KEY, "sort:pallas")
-    xla = store.lookup(_cost.PALLAS_KEY, "sort:xla")
-    if pal is None or pal.get("n", 0) < _MIN_ROUTE_SAMPLES:
-        return static
-    if xla is not None and xla.get("n", 0) >= _MIN_ROUTE_SAMPLES:
-        if pal.get("s_per_row", 0) > 1.5 * xla.get("s_per_row", 0) > 0:
-            store.note_decision(
-                "pallas.sort_window", 0, static,
-                f"pallas {pal['s_per_row']:.2e} s/row vs XLA "
-                f"{xla['s_per_row']:.2e} over {int(pal['n'])} runs",
-            )
-            return 0
-        if (
-            pal.get("rows_max", 0) >= static
-            and 0 < pal.get("s_per_row", 0) < xla.get("s_per_row", 0)
-        ):
-            widened = min(2 * static, 1 << 22)
-            if widened > static:
-                store.note_decision(
-                    "pallas.sort_window", widened, static,
-                    f"pallas faster per row at {int(pal['rows_max'])} rows",
-                )
-            return widened
-    return static
-
-
 # -- serving megabatch window ----------------------------------------
 
 def serve_window_s(store, configured_s: float) -> float:

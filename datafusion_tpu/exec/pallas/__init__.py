@@ -1,124 +1,58 @@
-"""Hand-written Pallas kernels for the operators where XLA's stock
-lowering loses to the CPU baseline (ROADMAP item 4):
+"""Hand-written Pallas kernels.
 
-- `hash_agg`: dense-id grouped aggregation — per-block partials built
-  in VMEM (one-hot tile accumulate; the dense group ids the host
-  encoder assigns ARE a perfect hash) and combined across row blocks,
-  spilling to HBM-resident group tiles above the VMEM threshold.
-- `sort_kernel`: segmented bitonic sort — whole-block compare-exchange
-  networks run in VMEM, multi-key orders compose as chained stable
-  passes, all inside one launch.
-- `hash_build`: hash-join build over dense-int keys — per-slot row
-  index and key count accumulated in VMEM slot tiles (the same one-hot
-  tile sweep as hash_agg; XLA's scatter alternative is serial on TPU).
+One kernel lives here: `hash_build`, the hash-join build over
+dense-int keys — per-slot row index and key count accumulated in VMEM
+slot tiles by a one-hot tile sweep (XLA's scatter alternative is serial
+on TPU).  It runs in 32-bit lanes only; Mosaic lowers no 64-bit type,
+which is why the engine's f64/int64 aggregates and int64 sort keys have
+no kernel here and take the stock XLA lowerings.
 
-Engagement policy (``DATAFUSION_TPU_PALLAS``):
+The kernel engages by a stated rule, never by trying and catching:
+the slot table fits `BUILD_MAX_SLOTS` and `enabled_for(...)` holds.
+``DATAFUSION_TPU_PALLAS`` selects the mode:
 
-- ``auto`` (default): kernels engage only when batches execute on an
-  accelerator backend — the CPU tier-1 path never sees them.
-- ``1``: force on (current backend must lower Pallas).
-- ``interpret``: run kernels through the Pallas interpreter — slow but
-  correct anywhere; this is how the CPU test suite proves kernel
-  parity against the numpy fallbacks.
-- ``0``: off everywhere.
+- ``auto`` (default): engage when batches execute on a TPU.  The
+  kernel accumulates into a revisited output tile, which relies on
+  TPU's sequential grid iteration; a backend that runs grid steps in
+  parallel would race.
+- ``interpret``: run through the Pallas interpreter — slow but correct
+  anywhere; this is how the CPU test suite proves kernel parity
+  against the numpy oracle.
+- ``0``: off everywhere (stock XLA scatter build).
 
-Every kernel has a numpy-parity fallback in its module, and callers
-gate on `enabled_for(...)` plus a one-shot compile probe
-(`probe_ok`) so a backend that can't lower a kernel falls back to the
-stock XLA path instead of failing the query.
+A kernel the compiler refuses is a query error, not a fallback:
+`chip_smoke.py` compiles it on the chip at its window-edge shape.
 """
 
 from __future__ import annotations
 
 import os
 
+# Largest direct-address slot table the hash-build kernel fills; above
+# it the stock-XLA scatter build keeps the job (the one-hot tile sweep
+# is linear in the slot count).
+BUILD_MAX_SLOTS = 8192
+
 
 def _mode() -> str:
     return os.environ.get("DATAFUSION_TPU_PALLAS", "auto")
-
-
-def available() -> bool:
-    try:
-        from jax.experimental import pallas  # noqa: F401
-    except Exception:  # noqa: BLE001 — any import failure means "no"
-        return False
-    return True
 
 
 def interpret_mode() -> bool:
     return _mode() == "interpret"
 
 
-def enabled_for(accel: bool) -> bool:
-    """Should Pallas kernels engage for an operator whose batches run
-    on an accelerator (`accel`)?  See the module docstring's policy.
-
-    `auto` additionally requires a TPU default backend: the hash-agg
-    kernel's revisited-output-tile accumulation relies on TPU's
-    sequential grid iteration, which a parallel-grid backend (GPU)
-    would race — and a compile probe can't detect that.  `1` is the
-    explicit override for backends known to iterate sequentially."""
+def enabled_for(device) -> bool:
+    """Should the Pallas kernel engage for an operator whose batches
+    run on `device` (a jax Device, or None = the JAX default backend)?
+    See the module docstring."""
     mode = _mode()
-    if mode == "0" or not available():
+    if mode == "0":
         return False
-    if mode in ("1", "interpret"):
+    if mode == "interpret":
         return True
-    if not accel:
-        return False
+    if device is not None:
+        return device.platform == "tpu"
     import jax
 
     return jax.default_backend() == "tpu"
-
-
-def config_signature() -> tuple:
-    """Folds into operator-core cache keys: a core built with kernels
-    off must not be reused by a query that enabled them (cores are
-    process-wide and LRU-bounded, exec/kernels.py)."""
-    return (_mode(), available())
-
-
-def agg_max_groups() -> int:
-    """Largest group capacity the hash-agg kernel serves; above it the
-    sort-merge path keeps the job (the one-hot tile sweep is linear in
-    G, so past this point sorting wins)."""
-    return int(os.environ.get("DATAFUSION_TPU_PALLAS_AGG_GROUPS", 8192))
-
-
-def build_max_slots() -> int:
-    """Largest direct-address slot table the hash-build kernel fills;
-    above it the stock-XLA scatter build keeps the job (the one-hot
-    tile sweep is linear in K, same trade as `agg_max_groups`)."""
-    return int(os.environ.get("DATAFUSION_TPU_PALLAS_BUILD_SLOTS", 8192))
-
-
-def sort_max_rows() -> int:
-    """Largest run the bitonic kernel sorts (the network runs on a
-    VMEM-resident block; larger runs take lax.sort)."""
-    return int(os.environ.get("DATAFUSION_TPU_PALLAS_SORT_ROWS", 1 << 18))
-
-
-_PROBES: dict = {}
-
-
-def probe_ok(name: str, fn) -> bool:
-    """One-shot compile probe: run `fn` (a tiny kernel invocation)
-    once; on any failure the kernel family `name` is disabled for the
-    process and callers use the stock lowering.  Keeps 'this backend
-    can't lower that op' a fallback, never a query error."""
-    hit = _PROBES.get(name)
-    if hit is not None:
-        return hit
-    try:
-        fn()
-        _PROBES[name] = True
-    except Exception:  # noqa: BLE001 — any lowering failure disables
-        from datafusion_tpu.utils.metrics import METRICS
-
-        METRICS.add(f"pallas.{name}.probe_failed")
-        _PROBES[name] = False
-    return _PROBES[name]
-
-
-def reset_probes() -> None:
-    """Test hook: forget probe outcomes (mode changes mid-process)."""
-    _PROBES.clear()

@@ -253,11 +253,14 @@ class HashJoinRelation(Relation):
                 vi += 1
         art.dev_valids = tuple(dvalids)
 
+        # the stated engagement rule (exec/pallas): TPU batches and a
+        # slot table within the kernel's window; operands are int32
         use_pallas = (
-            _pallas.enabled_for(_accel(self.device))
-            and num_slots <= _pallas.build_max_slots()
-            and _pallas.probe_ok("hash_build", _probe_build_kernel)
+            _pallas.enabled_for(self.device)
+            and num_slots <= _pallas.BUILD_MAX_SLOTS
         )
+        if use_pallas:
+            METRICS.add("join.build.pallas_runs")
         art.dev_slot_row = device_call(
             _build_jit(num_slots, use_pallas, _pallas.interpret_mode()),
             pos_d, live_d, _tag="join.build",
@@ -333,27 +336,6 @@ class HashJoinRelation(Relation):
                 self._schema, out_cols, out_valids,
                 list(dicts) + list(art.dicts),
             )
-
-
-def _accel(device) -> bool:
-    from datafusion_tpu.exec.relation import _is_accelerator
-
-    return _is_accelerator(device)
-
-
-def _probe_build_kernel():
-    """Tiny compile probe for the Pallas build kernel (one-shot per
-    process; see exec/pallas.probe_ok)."""
-    import jax.numpy as jnp
-
-    from datafusion_tpu.exec.pallas import hash_build
-
-    pos = jnp.zeros(8, jnp.int32)
-    live = jnp.ones(8, bool)
-    row, _ = hash_build.build_slot_table(
-        pos, live, 8, interpret=_pallas.interpret_mode()
-    )
-    np.asarray(row)
 
 
 _BUILD_JITS: dict = {}

@@ -11,8 +11,8 @@ The TPU-native equivalent implemented here:
 - partitions of a table shard round-robin over a `jax.sharding.Mesh`;
 - each device runs the *same* fused filter+aggregate kernel on its
   shard (partial aggregation), via `shard_map`;
-- partials combine with XLA collectives (`psum`/`pmin`/`pmax`) riding
-  ICI — replacing Arrow-IPC-over-HTTP result exchange;
+- partials combine with XLA collectives (`psum`, all-gather +
+  min/max) riding ICI — replacing Arrow-IPC-over-HTTP result exchange;
 - plan fragments still travel as the JSON wire format the reference
   intended (`PlanFragment`), which is what the multi-host mode ships:
   `DistributedContext` sends fragments over TCP to worker processes

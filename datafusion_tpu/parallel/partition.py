@@ -12,7 +12,7 @@ combining them — `README.md:33-35`, `physicalplan.rs`,
   per-shard filter+aggregate update in parallel across devices
   (partial aggregation = data parallelism over rows);
 - a second `shard_map` kernel combines partials with `psum` (SUM,
-  COUNT, AVG) / `pmin` / `pmax` over the mesh axis — the collective
+  COUNT, AVG) / all-gather + min/max over the mesh axis — the collective
   replaces the planned Arrow-IPC-over-HTTP partial exchange;
 - group ids are dense, global, host-assigned (`GroupKeyEncoder`), and
   partition readers share string dictionaries, so every shard's
@@ -34,28 +34,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax import lax
+from jax import shard_map as _raw_shard_map
 from jax.sharding import NamedSharding, PartitionSpec as P
-
-try:  # jax>=0.8 spelling
-    from jax import shard_map as _raw_shard_map
-except ImportError:  # pragma: no cover
-    from jax.experimental.shard_map import shard_map as _raw_shard_map  # type: ignore
-
-import inspect as _inspect
-
-_CHECK_KW = (
-    "check_vma"
-    if "check_vma" in _inspect.signature(_raw_shard_map).parameters
-    else "check_rep"
-)
-
-
-def shard_map(f, mesh, in_specs, out_specs):
-    # replication checking off: the combine kernel indexes [0] out of
-    # psum results, which the checker can't see is replicated
-    return _raw_shard_map(
-        f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, **{_CHECK_KW: False}
-    )
 
 from datafusion_tpu.datatypes import Schema
 from datafusion_tpu.errors import ExecutionError, PlanError
@@ -80,6 +60,14 @@ from datafusion_tpu.plan.logical import Aggregate, LogicalPlan, Selection, Table
 from datafusion_tpu.utils.deadline import Deadline, current_deadline, deadline_scope
 from datafusion_tpu.utils.metrics import METRICS
 from datafusion_tpu.utils.retry import device_call
+
+
+def shard_map(f, mesh, in_specs, out_specs):
+    # replication checking off: the combine kernel indexes [0] out of
+    # psum results, which the checker can't see is replicated
+    return _raw_shard_map(
+        f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_vma=False
+    )
 
 
 def _share_dictionaries(partitions: Sequence[DataSource]) -> None:
@@ -289,7 +277,7 @@ def _partitioned_pipeline_jit(core, mesh):
     core on a mesh (cached on the core like _partitioned_jits)."""
     key = (
         "pipe",
-        tuple(d.id for d in mesh.devices.flat),
+        tuple((d.platform, d.id) for d in mesh.devices.flat),
         tuple(getattr(mesh, "axis_names", ())),
     )
     cache = getattr(core, "_part_jits", None)
@@ -538,7 +526,7 @@ def _partitioned_jits(core, mesh):
     over the core only; everything per-query (literals, encoder state)
     arrives as runtime operands."""
     key = (
-        tuple(d.id for d in mesh.devices.flat),
+        tuple((d.platform, d.id) for d in mesh.devices.flat),
         tuple(getattr(mesh, "axis_names", ())),
     )
     cache = getattr(core, "_part_jits", None)
@@ -576,6 +564,17 @@ def _partitioned_jits(core, mesh):
         oc, oa = out
         return ex(oc), jax.tree.map(ex, oa)
 
+    # MIN/MAX meet as all-gather + local reduce, not pmin/pmax: the TPU
+    # compiler lowers only the SUM all-reduce for 64-bit operands
+    # ("Supported lowering only of Sum all reduce"), and the engine's
+    # accumulators are int64/f64.  Gathering is exact; the type is
+    # never narrowed.
+    def all_min(x):
+        return jnp.min(lax.all_gather(x, MESH_AXIS), axis=0)
+
+    def all_max(x):
+        return jnp.max(lax.all_gather(x, MESH_AXIS), axis=0)
+
     def combine(state, str_aux):
         counts, accs = state
         fin_counts = lax.psum(counts, MESH_AXIS)[0]
@@ -584,9 +583,9 @@ def _partitioned_jits(core, mesh):
             if sl.kind in ("sum", "cnt"):
                 fin_accs.append(lax.psum(acc, MESH_AXIS)[0])
             elif sl.kind == "min":
-                fin_accs.append(lax.pmin(acc, MESH_AXIS)[0])
+                fin_accs.append(all_min(acc)[0])
             elif sl.kind == "max":
-                fin_accs.append(lax.pmax(acc, MESH_AXIS)[0])
+                fin_accs.append(all_max(acc)[0])
             else:
                 # Utf8 MIN/MAX: partitions share dictionaries in mesh
                 # mode (_share_dictionaries), so codes are globally
@@ -594,9 +593,9 @@ def _partitioned_jits(core, mesh):
                 # map the winning rank back to its code
                 ranks = _AggCore._codes_to_ranks(sl.kind, acc[0], str_aux[i])
                 if sl.kind == "smin":
-                    best = lax.pmin(ranks, MESH_AXIS)
+                    best = all_min(ranks)
                 else:
-                    best = lax.pmax(ranks, MESH_AXIS)
+                    best = all_max(ranks)
                 fin_accs.append(
                     _AggCore._ranks_to_codes(sl.kind, best, str_aux[i])
                 )
@@ -642,10 +641,6 @@ class PartitionedAggregateRelation(AggregateRelation):
     Reuses the single-device kernel (`AggregateRelation._kernel`) as the
     per-shard body of a `shard_map`; adds the collective final combine.
     """
-
-    # per-shard kernels run inside shard_map bodies: keep the Pallas
-    # hash-agg path (a per-device kernel) out of the traced collective
-    _pallas_ok = False
 
     def __init__(
         self,

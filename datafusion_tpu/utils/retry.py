@@ -1,9 +1,9 @@
 """Transient device-failure retry.
 
-Tunneled/remote accelerators (and remote XLA compile services) can
-drop a request mid-flight; the reference never faced this (CPU-only),
-but SURVEY §5.3 names failure detection/recovery as a rebuild target
-and the query engine's natural recovery unit is the *device call*:
+A device runtime can fail a request with a retryable status
+mid-flight; the reference never faced this (CPU-only), but SURVEY §5.3
+names failure detection/recovery as a rebuild target and the query
+engine's natural recovery unit is the *device call*:
 dispatches are functionally pure (accumulator state in, state out), so
 a failed call simply replays.  Genuine programming errors (trace
 errors, shape mismatches) are not transient and re-raise immediately.

@@ -1,0 +1,146 @@
+"""`chip_smoke.py` on the CPU: the device refusal, the stage functions
+and the pyarrow + numpy oracle against the engine at SF-0.01, and that
+a failed check reaches the exit code.  The chip run itself is the
+builder's and the driver's (`python chip_smoke.py` through the tool)."""
+
+from __future__ import annotations
+
+import os
+import subprocess
+import sys
+
+import pytest
+
+import chip_smoke
+from datafusion_tpu.exec.context import ExecutionContext
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+SF = 0.01
+
+
+@pytest.fixture(scope="module")
+def oracle():
+    return chip_smoke.Q1Oracle(chip_smoke.lineitem_path(SF))
+
+
+@pytest.fixture(scope="module")
+def resident_ctx():
+    ctx = ExecutionContext(device="cpu", result_cache=False)
+    ctx.register_datasource(
+        "lineitem", chip_smoke.resident_lineitem(SF, batch_size=1 << 13))
+    return ctx
+
+
+def test_refuses_without_the_chip():
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
+    proc = subprocess.run(
+        [sys.executable, os.path.join(REPO, "chip_smoke.py")],
+        cwd=REPO, env=env, capture_output=True, text=True, timeout=300,
+    )
+    assert proc.returncode == 1
+    assert "no TPU" in proc.stderr and "'cpu'" in proc.stderr
+    # no result line: the last stdout line is not the JSON verdict
+    assert '"ok"' not in proc.stdout
+
+
+def test_oracle_matches_pandas(oracle):
+    # the oracle itself against a third, dumber computation
+    import pandas as pd
+
+    df = pd.read_parquet(chip_smoke.lineitem_path(SF))
+    df = df[df.l_shipdate <= chip_smoke.CUTOFF]
+    g = df.groupby(["l_returnflag", "l_linestatus"])
+    want = sorted(
+        (f, s, float(d.l_quantity.sum()), len(d)) for (f, s), d in g
+    )
+    got = [(r[0], r[1], r[2], r[9]) for r in oracle.q1()]
+    chip_smoke.check_rows(got, want, "oracle vs pandas")
+
+
+def test_cold_stage_agrees_with_oracle(oracle):
+    out = chip_smoke.stage_cold("cpu", SF, oracle)
+    assert out["evidence"]["device.launches"] > 0
+    assert out["evidence"]["h2d.bytes"] > 0
+    assert len(out["rows"]) == len(oracle.q1()) > 0
+
+
+def test_warm_stage_is_compile_and_transfer_free(resident_ctx, oracle):
+    out = chip_smoke.stage_warm(resident_ctx, oracle)
+    assert out["evidence"]["kernel_cache.misses"] == 0
+    assert out["evidence"]["device.h2d.transfers"] == 0
+
+
+def test_serve_stage_megabatches(resident_ctx, oracle):
+    out = chip_smoke.stage_serve(resident_ctx, oracle,
+                                 clients=4, per_client=2)
+    assert out["queries"] == 9
+    assert out["evidence"]["serve.megabatch_launches"] > 0
+    assert out["pins"]
+
+
+def test_operator_stage(monkeypatch):
+    # interpret mode: the hash-build kernel engages on the CPU too, so
+    # the stage's engagement rule and the kernel-alone check both run
+    monkeypatch.setenv("DATAFUSION_TPU_PALLAS", "interpret")
+    out = chip_smoke.stage_operators("cpu", agg_rows=1 << 17)
+    assert out["join_8k_slots"]["hash_build_engaged"] is True
+    assert "matched" in out["hash_build_kernel"]
+    assert out["order_by_i64"]["device.launches.sort.run"] == 1
+
+
+def test_mesh_stage_places_four_shards(resident_ctx, oracle):
+    # conftest gives 8 virtual CPU devices; the stage takes four
+    src = resident_ctx.datasources["lineitem"]
+    out = chip_smoke.stage_mesh(src, oracle.q1())
+    assert len(out["devices"]) == 4
+
+
+def test_wrong_answer_fails_the_stage(oracle):
+    rows = oracle.q1()
+    bad = [rows[0][:2] + (rows[0][2] * (1 + 1e-6),) + rows[0][3:]] + rows[1:]
+    with pytest.raises(chip_smoke.SmokeFailure, match="vs oracle"):
+        chip_smoke.check_rows(bad, rows, "tampered")
+    with pytest.raises(chip_smoke.SmokeFailure, match="rows"):
+        chip_smoke.check_rows(rows[1:], rows, "short")
+
+
+def test_host_routing_fails_the_stage():
+    ev = {"device.launches": 3, "aggregate.host_routed_slots": 8,
+          "sort.host_routed_runs": 0}
+    with pytest.raises(chip_smoke.SmokeFailure, match="_decide_placement"):
+        chip_smoke.require_on_device("cold", ev)
+    ev = {"device.launches": 0, "aggregate.host_routed_slots": 0,
+          "sort.host_routed_runs": 0}
+    with pytest.raises(chip_smoke.SmokeFailure, match="no device launch"):
+        chip_smoke.require_on_device("cold", ev)
+
+
+def test_failed_stage_reaches_the_exit_code():
+    # no stage is wrapped in a catch-all: main() lets a failed check
+    # propagate, and an uncaught exception is a non-zero exit
+    code = (
+        "import chip_smoke, jax\n"
+        "class Dev:\n"
+        "    platform = 'tpu'; device_kind = 'fake'\n"
+        "    def memory_stats(self): return {}\n"
+        "jax.devices = lambda *a: [Dev()]\n"
+        "def boom(*a, **k): raise chip_smoke.SmokeFailure('stage failed')\n"
+        "chip_smoke.lineitem_path = boom\n"
+        "raise SystemExit(chip_smoke.main(['--sf', '1']))\n"
+    )
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
+    proc = subprocess.run(
+        [sys.executable, "-c", code], cwd=REPO, env=env,
+        capture_output=True, text=True, timeout=300,
+    )
+    assert proc.returncode != 0
+    assert "SmokeFailure: stage failed" in proc.stderr
+    assert '"ok"' not in proc.stdout
+
+
+def test_unknown_device_kind_has_no_peak():
+    from benchmarks import suite
+
+    assert suite.hbm_peak_gbps("TPU v5 lite") == 819.0
+    with pytest.raises(KeyError, match="no published HBM peak"):
+        suite.hbm_peak_gbps("cpu")

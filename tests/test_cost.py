@@ -339,30 +339,6 @@ class TestAdvisor:
     def test_agg_shape_is_order_insensitive(self):
         assert advisor.agg_shape(["b", "a"]) == advisor.agg_shape(["a", "b"])
 
-    def test_pallas_agg_window_needs_samples(self):
-        from datafusion_tpu.exec.pallas import agg_max_groups
-
-        st = CostStore()
-        # an empty store keeps the static env window
-        assert advisor.pallas_agg_window(st) == agg_max_groups()
-
-    def test_pallas_agg_window_disengages_when_slower(self):
-        st = CostStore()
-        for _ in range(4):
-            advisor.observe_agg_route(st, "pallas", 1024, 1.0, 1000)
-            advisor.observe_agg_route(st, "sortmerge", 1024, 0.1, 1000)
-        assert advisor.pallas_agg_window(st) == 0
-
-    def test_pallas_agg_window_widens_when_faster(self):
-        from datafusion_tpu.exec.pallas import agg_max_groups
-
-        st = CostStore()
-        static = agg_max_groups()
-        for _ in range(4):
-            advisor.observe_agg_route(st, "pallas", static, 0.1, 1000)
-            advisor.observe_agg_route(st, "sortmerge", static, 1.0, 1000)
-        assert advisor.pallas_agg_window(st) > static
-
     def test_serve_window_shrinks_for_sparse_arrivals(self):
         st = CostStore()
         st.observe(cost.SERVE_KEY, "arrivals", interval_s=1.0)

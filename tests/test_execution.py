@@ -785,44 +785,6 @@ class TestWireCompression:
         assert got == want
         assert all(row[0] > "k010" for row in r1.to_rows())
 
-    def test_blob_vs_per_wire_parity(self, monkeypatch):
-        # the single-buffer wire format must decode identically to
-        # per-wire device_put (DATAFUSION_TPU_H2D_BLOB=0)
-        import numpy as np
-
-        from datafusion_tpu.datatypes import DataType, Field, Schema
-        from datafusion_tpu.exec.batch import device_inputs, make_host_batch
-
-        schema = Schema(
-            [
-                Field("i", DataType.INT64, True),
-                Field("p", DataType.FLOAT64, False),
-                Field("d", DataType.FLOAT64, False),
-                Field("r", DataType.FLOAT64, False),
-            ]
-        )
-        rng = np.random.default_rng(7)
-        cols = [
-            rng.integers(-100, 100, 2048).astype(np.int64),
-            np.round(rng.uniform(900, 105000, 2048), 2),
-            rng.integers(0, 9, 2048) / 100.0,
-            rng.standard_normal(2048),
-        ]
-        valid = rng.random(2048) > 0.5
-
-        def build():
-            return make_host_batch(schema, cols, [valid, None, None, None], [None] * 4)
-
-        monkeypatch.setenv("DATAFUSION_TPU_H2D_BLOB", "1")
-        blob_data, blob_valid, _ = device_inputs(build())
-        monkeypatch.setenv("DATAFUSION_TPU_H2D_BLOB", "0")
-        per_data, per_valid, _ = device_inputs(build())
-        for g, w in zip(blob_data, per_data):
-            assert np.array_equal(
-                np.asarray(g).view(np.int64), np.asarray(w).view(np.int64)
-            )
-        assert np.array_equal(np.asarray(blob_valid[0]), np.asarray(per_valid[0]))
-
     def test_packed_mask_pull(self):
         import jax.numpy as jnp
         import numpy as np

@@ -349,15 +349,11 @@ class TestTypedRetry:
             DeviceTransientError,
         )
         assert classify_transient(XlaRuntimeError("INVALID_ARGUMENT: shape")) is None
-        # wrapped messages: the status token is not the leading word —
-        # the marker fallback must still classify these as transient
-        assert isinstance(
-            classify_transient(
-                XlaRuntimeError("Error executing computation: "
-                                "UNAVAILABLE: channel closed")
-            ),
-            DeviceTransientError,
-        )
+        # only the LEADING status token decides: free text that merely
+        # mentions a retryable word is a permanent failure
+        assert classify_transient(
+            XlaRuntimeError("INTERNAL: transport unavailable, read body")
+        ) is None
         assert classify_transient(ValueError("UNAVAILABLE: nope")) is None
         assert isinstance(classify_transient(ConnectionResetError()), TransientError)
         # already-typed errors pass through unchanged

@@ -2,9 +2,9 @@
 
 Three layers, all on the CPU tier-1 backend:
 
-- Pallas kernels against their numpy oracles through the Pallas
-  interpreter (`interpret=True` — same kernel code path the TPU runs,
-  minus Mosaic lowering).
+- The Pallas hash-build kernel against its numpy oracle through the
+  Pallas interpreter (`interpret=True` — same kernel code path the TPU
+  runs, minus Mosaic lowering).
 - The engine's fused-pass mode (`DATAFUSION_TPU_FUSE`, default on)
   against the unfused per-operator path: identical results, fewer
   launches, plan-chain collapse in effect.
@@ -62,109 +62,53 @@ def _rows(ctx, sql):
 
 
 class TestPallasKernelParity:
-    def test_hash_agg_sum_min_max_parity(self):
-        from datafusion_tpu.exec.pallas import hash_agg
+    """The one Pallas kernel (exec/pallas/hash_build.py) against its
+    numpy oracle through the interpreter, at the 2-D (8, 128)-aligned
+    block shapes the chip compiles."""
 
+    @pytest.mark.parametrize("n,slots", [
+        (8192, 8192),   # window edge: BUILD_MAX_SLOTS slots, one row each
+        (5000, 8192),   # sparse table, ragged row padding
+        (700, 300),     # duplicates: counts > 1, max row index wins
+        (1, 1),
+    ])
+    def test_hash_build_parity(self, n, slots):
         import jax
 
+        from datafusion_tpu.exec.pallas import hash_build
+
         rng = np.random.default_rng(7)
-        n, g = 6000, 900
-        ids = rng.integers(0, g, n).astype(np.int32)
+        pos = (rng.permutation(max(n, slots))[:n] % slots).astype(np.int32)
         live = rng.random(n) > 0.15
-        for vals in (
-            rng.normal(size=n),                                # f64
-            rng.integers(-10**6, 10**6, n).astype(np.int64),   # i64
-        ):
-            for kind in ("sum", "min", "max"):
-                got = np.asarray(jax.jit(
-                    lambda i, v, l, k=kind: hash_agg.grouped_reduce(
-                        i, v, l, g, k, interpret=True
-                    )
-                )(ids, vals, live))
-                want = hash_agg.grouped_reduce_numpy(ids, vals, live, g, kind)
-                if vals.dtype.kind == "f":
-                    np.testing.assert_allclose(
-                        got, want, rtol=1e-12, err_msg=f"{kind}/{vals.dtype}"
-                    )
-                else:
-                    np.testing.assert_array_equal(
-                        got, want, err_msg=f"{kind}/{vals.dtype}"
-                    )
+        want = hash_build.build_slot_table_numpy(pos, live, slots)
+        got = jax.jit(lambda p, l: hash_build.build_slot_table(
+            p, l, slots, interpret=True))(pos, live)
+        xla = jax.jit(lambda p, l: hash_build.build_slot_table_xla(
+            p, l, slots))(pos, live)
+        for g, x, w in zip(got, xla, want):
+            np.testing.assert_array_equal(np.asarray(g), w)
+            np.testing.assert_array_equal(np.asarray(x), w)
 
-    def test_hash_agg_empty_groups_keep_identity(self):
-        from datafusion_tpu.exec.pallas import hash_agg
+    def test_hash_build_blocks_are_tile_aligned(self):
+        from datafusion_tpu.exec import pallas
+        from datafusion_tpu.exec.pallas import hash_build
 
-        ids = np.zeros(16, np.int32)  # every row hits group 0
-        vals = np.arange(16).astype(np.int64)
-        live = np.ones(16, bool)
-        out = hash_agg.grouped_reduce_numpy(ids, vals, live, 8, "min")
-        assert out[0] == 0
-        assert (out[1:] == np.iinfo(np.int64).max).all()
+        assert hash_build.BLOCK_R % 8 == 0 and hash_build.TILE_S % 128 == 0
+        assert pallas.BUILD_MAX_SLOTS % hash_build.TILE_S == 0
 
-    def test_bitonic_argsort_stability_and_sizes(self):
-        from datafusion_tpu.exec.pallas import sort_kernel
+    def test_engagement_is_a_stated_rule(self, monkeypatch):
+        # no probe, no try/except: mode + device platform decide
+        import jax
 
-        rng = np.random.default_rng(11)
-        for n in (1, 2, 3, 17, 128, 1000, 2048):
-            keys = rng.integers(0, 40, n).astype(np.int64)  # heavy ties
-            got = np.asarray(sort_kernel.argsort_i64(keys, interpret=True))
-            want = np.argsort(keys, kind="stable")
-            np.testing.assert_array_equal(got, want, err_msg=f"n={n}")
+        from datafusion_tpu.exec import pallas
 
-    def test_bitonic_multi_key_vs_lexsort(self):
-        from datafusion_tpu.exec.pallas import sort_kernel
-
-        rng = np.random.default_rng(13)
-        a = rng.integers(0, 6, 700).astype(np.int64)
-        b = rng.integers(-50, 50, 700).astype(np.int64)
-        c = rng.integers(0, 3, 700).astype(np.int64)
-        got = np.asarray(sort_kernel.argsort_multi([a, b, c], interpret=True))
-        want = sort_kernel.argsort_numpy([a, b, c])
-        np.testing.assert_array_equal(got, want)
-
-    def test_engine_aggregate_under_interpret_kernels(self, monkeypatch):
-        # end to end: DATAFUSION_TPU_PALLAS=interpret routes the
-        # high-cardinality aggregate through the Pallas hash-agg kernel
+        cpu = jax.devices("cpu")[0]
+        monkeypatch.delenv("DATAFUSION_TPU_PALLAS", raising=False)
+        assert not pallas.enabled_for(cpu)
         monkeypatch.setenv("DATAFUSION_TPU_PALLAS", "interpret")
-        rng = np.random.default_rng(17)
-        n, g = 4000, 300
-        schema = Schema([
-            Field("k", DataType.INT64, False),
-            Field("v", DataType.FLOAT64, False),
-            Field("w", DataType.INT64, True),
-        ])
-        k = rng.integers(0, g, n)
-        v = rng.normal(size=n)
-        w = rng.integers(-9, 9, n)
-        wv = rng.random(n) > 0.2
-        sql = ("SELECT k, SUM(v), MIN(w), MAX(w), COUNT(w), COUNT(1) "
-               "FROM t GROUP BY k")
-        got = sorted(_rows(_ctx(schema, [k, v, w], [None, None, wv]), sql))
+        assert pallas.enabled_for(cpu) and pallas.interpret_mode()
         monkeypatch.setenv("DATAFUSION_TPU_PALLAS", "0")
-        want = sorted(_rows(_ctx(schema, [k, v, w], [None, None, wv]), sql))
-        assert len(got) == len(want) == g
-        for a, b in zip(got, want):
-            np.testing.assert_allclose(
-                np.asarray(a, float), np.asarray(b, float), rtol=1e-9
-            )
-
-    def test_engine_full_sort_under_interpret_kernels(self, monkeypatch):
-        rng = np.random.default_rng(19)
-        n = 3000
-        schema = Schema([
-            Field("a", DataType.INT64, False),
-            Field("tag", DataType.INT64, False),
-        ])
-        a = rng.integers(0, 50, n)
-        tag = np.arange(n, dtype=np.int64)
-        sql = "SELECT a, tag FROM t ORDER BY a"
-        monkeypatch.setenv("DATAFUSION_TPU_PALLAS", "interpret")
-        METRICS.reset()
-        got = _rows(_ctx(schema, [a, tag], batch_size=n), sql)
-        assert METRICS.snapshot()["counts"].get("sort.pallas_runs")
-        monkeypatch.setenv("DATAFUSION_TPU_PALLAS", "0")
-        want = _rows(_ctx(schema, [a, tag], batch_size=n), sql)
-        assert got == want  # incl. tag order: stability parity
+        assert not pallas.enabled_for(cpu)
 
 
 # ------------------------------------------------------------ fused pass
