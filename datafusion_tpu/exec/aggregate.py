@@ -72,8 +72,12 @@ from datafusion_tpu.utils.retry import device_call
 
 DENSE_GROUP_MAX = 64
 
+def widen_group_ids(w):
+    return w.astype(jnp.int32)
+
+
 # widen narrow wire-format group ids back to int32 on device
-_WIDEN_IDS_JIT = jax.jit(lambda w: w.astype(jnp.int32))
+_WIDEN_IDS_JIT = jax.jit(widen_group_ids)
 
 # serving-path lowering mode (datafusion_tpu/serve.py): keep the
 # predicate IN the device core (as parameter slots) instead of routing
@@ -1715,7 +1719,7 @@ class AggregateRelation(Relation):
         if hit is not None and hit[0] is self.encoder:
             if not keep_np or batch.cache.get("group_ids_np") is not None:
                 return hit[1]
-        with self._ids_lock:
+        with self._ids_lock, METRICS.timer("aggregate.group_ids"):
             return self._group_ids_locked(batch, upload, keep_np)
 
     def _group_ids_locked(self, batch: RecordBatch, upload: bool = True,

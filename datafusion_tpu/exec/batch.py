@@ -230,7 +230,11 @@ def _decimal_division_exact(device=None) -> bool:
         rng = np.random.default_rng(0xD1CE)
         ints = rng.integers(-(2**31) + 1, 2**31 - 1, _SAMPLE).astype(np.int32)
         hit = True
-        fn = jax.jit(lambda x, s: x.astype(jnp.float64) / s[0])
+
+        def decimal_probe(x, s):
+            return x.astype(jnp.float64) / s[0]
+
+        fn = jax.jit(decimal_probe)
         for scale in (100, 1000):
             want = ints.astype(np.float64) / scale
             got = np.asarray(
@@ -570,12 +574,15 @@ def _decode_jit(specs):
 
     hit = _DECODE_JITS.get(specs)
     if hit is None:
-        hit = _DECODE_JITS[specs] = jax.jit(
-            lambda wire_lists: tuple(
+        # the function's name is the program's in a profile
+        # (`jit_h2d_wire_decode`); a lambda's is `jit__lambda`
+        def h2d_wire_decode(wire_lists):
+            return tuple(
                 _decode_wire(spec, wires)
                 for spec, wires in zip(specs, wire_lists)
             )
-        )
+
+        hit = _DECODE_JITS[specs] = jax.jit(h2d_wire_decode)
     return hit
 
 
@@ -655,7 +662,7 @@ def _d2h_pack_jit(sig, strategy):
             return x
         return lax.bitcast_convert_type(x, jnp.uint8).reshape(-1)
 
-    def pack(leaves):
+    def d2h_pack(leaves):
         parts = []
         for leaf in leaves:
             x = leaf.reshape(-1)
@@ -671,7 +678,7 @@ def _d2h_pack_jit(sig, strategy):
                 parts.append(to_u8(x))
         return jnp.concatenate(parts) if parts else jnp.zeros(0, jnp.uint8)
 
-    hit = _D2H_PACK_JITS[key] = jax.jit(pack)
+    hit = _D2H_PACK_JITS[key] = jax.jit(d2h_pack)
     return hit
 
 
@@ -706,45 +713,49 @@ class PendingPull:
         from datafusion_tpu.obs.device import record_d2h as _d2h_event
         from datafusion_tpu.utils.metrics import METRICS
 
-        t0 = _time.perf_counter()
-        out = list(self._leaves)
-        for i in self._extra_direct:
-            out[i] = np.asarray(out[i])
-            _record_d2h(METRICS, out[i].nbytes)
-        if self._blob is None:
-            pulled = 0
-            for i in self._dev_idx:
+        # where the host blocks for device results, whoever pulls (a
+        # result batch, an aggregate's state, a TopK's rows): the device
+        # finishing what is queued before the copy can start is in here
+        with METRICS.timer("d2h.wait"):
+            t0 = _time.perf_counter()
+            out = list(self._leaves)
+            for i in self._extra_direct:
                 out[i] = np.asarray(out[i])
                 _record_d2h(METRICS, out[i].nbytes)
-                pulled += out[i].nbytes
-            if pulled:
-                _d2h_event(pulled, _time.perf_counter() - t0)
+            if self._blob is None:
+                pulled = 0
+                for i in self._dev_idx:
+                    out[i] = np.asarray(out[i])
+                    _record_d2h(METRICS, out[i].nbytes)
+                    pulled += out[i].nbytes
+                if pulled:
+                    _d2h_event(pulled, _time.perf_counter() - t0)
+                return jax.tree.unflatten(self._treedef, out)
+            blob = np.asarray(self._blob)
+            _record_d2h(METRICS, blob.nbytes)
+            _d2h_event(blob.nbytes, _time.perf_counter() - t0)
+            off = 0
+            split = self._strategy == "split"
+            for i, (dtype_str, shape) in zip(self._dev_idx, self._sig):
+                n_elems = int(np.prod(shape, dtype=np.int64))
+                if dtype_str == "bool":
+                    arr = blob[off : off + n_elems].astype(bool)
+                    off += n_elems
+                elif split and dtype_str in ("int64", "uint64"):
+                    lo, off = self._take(blob, off, np.dtype(np.uint32), n_elems)
+                    hi, off = self._take(blob, off, np.dtype(np.uint32), n_elems)
+                    arr = (
+                        (hi.astype(np.uint64) << np.uint64(32))
+                        | lo.astype(np.uint64)
+                    ).view(np.dtype(dtype_str))
+                elif split and dtype_str == "float64":
+                    hi, off = self._take(blob, off, np.dtype(np.float32), n_elems)
+                    lo, off = self._take(blob, off, np.dtype(np.float32), n_elems)
+                    arr = _f64_join(hi, lo)
+                else:
+                    arr, off = self._take(blob, off, np.dtype(dtype_str), n_elems)
+                out[i] = arr.reshape(shape)
             return jax.tree.unflatten(self._treedef, out)
-        blob = np.asarray(self._blob)
-        _record_d2h(METRICS, blob.nbytes)
-        _d2h_event(blob.nbytes, _time.perf_counter() - t0)
-        off = 0
-        split = self._strategy == "split"
-        for i, (dtype_str, shape) in zip(self._dev_idx, self._sig):
-            n_elems = int(np.prod(shape, dtype=np.int64))
-            if dtype_str == "bool":
-                arr = blob[off : off + n_elems].astype(bool)
-                off += n_elems
-            elif split and dtype_str in ("int64", "uint64"):
-                lo, off = self._take(blob, off, np.dtype(np.uint32), n_elems)
-                hi, off = self._take(blob, off, np.dtype(np.uint32), n_elems)
-                arr = (
-                    (hi.astype(np.uint64) << np.uint64(32))
-                    | lo.astype(np.uint64)
-                ).view(np.dtype(dtype_str))
-            elif split and dtype_str == "float64":
-                hi, off = self._take(blob, off, np.dtype(np.float32), n_elems)
-                lo, off = self._take(blob, off, np.dtype(np.float32), n_elems)
-                arr = _f64_join(hi, lo)
-            else:
-                arr, off = self._take(blob, off, np.dtype(dtype_str), n_elems)
-            out[i] = arr.reshape(shape)
-        return jax.tree.unflatten(self._treedef, out)
 
 
 def device_pull_start(tree) -> PendingPull:
@@ -904,7 +915,10 @@ def put_compressed(host_arrays, device=None, hints=None, owner="h2d"):
         )
         for ws in wire_lists
     )
-    decoded = _decode_jit(tuple(specs))(wire_dev)
+    with METRICS.timer("h2d.decode"):
+        # the decode program's launch (not through device_call: it is
+        # part of the transfer, not one of the query's kernel launches)
+        decoded = _decode_jit(tuple(specs))(wire_dev)
     LEDGER.adopt(tuple(decoded[i] for i in host_pos), owner, device=device)
     return decoded
 
@@ -916,12 +930,9 @@ def device_inputs(batch: RecordBatch, device=None, hints=None):
     restores the exact original dtypes on device.  `hints` (optional,
     caller-owned) carries per-column codec memory across batches — see
     put_compressed."""
-    from datafusion_tpu.utils.metrics import METRICS
-
     key = ("device", None if device is None else repr(device))
     hit = batch.cache.get(key)
     if hit is not None:
-        METRICS.add("h2d.cache_hits")
         return hit
 
     # layout: data columns, then the present validity arrays, then mask

@@ -23,6 +23,7 @@ import numpy as np
 from datafusion_tpu.datatypes import DataType
 from datafusion_tpu.errors import ExecutionError, NotSupportedError
 from datafusion_tpu.exec.batch import RecordBatch
+from datafusion_tpu.utils.metrics import METRICS
 from datafusion_tpu.plan.expr import (
     BinaryExpr,
     Cast,
@@ -149,13 +150,14 @@ def host_pred_mask(
     with SQL semantics: a NULL predicate drops the row.  The one shared
     definition of this fold — the pipeline and aggregate host-predicate
     paths must never diverge on it."""
-    pv, pvalid = eval_host_expr(expr, batch, metas)
-    pm = np.broadcast_to(np.asarray(pv, dtype=bool), (batch.capacity,))
-    if pvalid is not None:
-        pm = pm & np.broadcast_to(
-            np.asarray(pvalid, dtype=bool), (batch.capacity,)
-        )
-    return pm
+    with METRICS.timer("host.predicate"):
+        pv, pvalid = eval_host_expr(expr, batch, metas)
+        pm = np.broadcast_to(np.asarray(pv, dtype=bool), (batch.capacity,))
+        if pvalid is not None:
+            pm = pm & np.broadcast_to(
+                np.asarray(pvalid, dtype=bool), (batch.capacity,)
+            )
+        return pm
 
 
 def eval_host_expr(

@@ -14,7 +14,7 @@ import numpy as np
 
 from datafusion_tpu.datatypes import DataType, Schema
 from datafusion_tpu.exec.batch import RecordBatch, bucket_capacity
-from datafusion_tpu.utils.metrics import METRICS
+from datafusion_tpu.utils.metrics import METRICS, QUERY_IDS
 
 # device-side compaction pays off when it at least halves the D2H bytes
 _COMPACT_FACTOR = 2
@@ -32,7 +32,10 @@ def _gather_compact(arrays, idxs):
     if _GATHER_JIT is None:
         import jax
 
-        _GATHER_JIT = jax.jit(lambda arrs, idx: tuple(a[idx] for a in arrs))
+        def compact_gather(arrs, idx):
+            return tuple(a[idx] for a in arrs)
+
+        _GATHER_JIT = jax.jit(compact_gather)
     return _GATHER_JIT(arrays, idxs)
 
 
@@ -58,12 +61,12 @@ def _start_mask_pull(batch) -> None:
         import jax
         import jax.numpy as jnp
 
-        def pack(mask):
+        def mask_packbits(mask):
             bits = mask.reshape(-1, 8).astype(jnp.uint8)
             weights = jnp.asarray([128, 64, 32, 16, 8, 4, 2, 1], jnp.uint8)
             return (bits * weights[None, :]).sum(axis=1, dtype=jnp.uint8)
 
-        _PACKBITS_JIT = jax.jit(pack)
+        _PACKBITS_JIT = jax.jit(mask_packbits)
     packed = _PACKBITS_JIT(m)
     packed.copy_to_host_async()
     batch.cache["packed_mask"] = packed
@@ -119,12 +122,12 @@ class _PendingCompact:
     def resolve(self):
         batch, live, n = self.batch, self.live, self.batch.num_rows
         pulled: dict[tuple[str, int], np.ndarray] = {}
-        with METRICS.timer("d2h.wait"):
-            # the blob-packed transfer began at dispatch; finish() just
-            # blocks on it (one round trip for all device outputs)
-            host_arrays = self.pull.finish()
-            for pos, a in zip(self.dev_pos, host_arrays):
-                pulled[pos] = a[: self.count] if self.compacted else a
+        # the blob-packed transfer began at dispatch; finish() just
+        # blocks on it (one round trip for all device outputs, timed
+        # there as `d2h.wait`)
+        host_arrays = self.pull.finish()
+        for pos, a in zip(self.dev_pos, host_arrays):
+            pulled[pos] = a[: self.count] if self.compacted else a
 
         def select(kind, i, a):
             hit = pulled.get((kind, i))
@@ -285,7 +288,24 @@ def collect_columns(relation):
     (`cache/result.py`) gets the fully-materialized columns handed to
     that hook after a complete, exception-free run — caching never
     changes what this function returns or how batches are pulled.
+
+    A root relation's whole materialisation on the calling thread is
+    the `query` stage timer (span `dftpu.query`, with the `qid` its
+    other spans share); `query.other` is the part of it that no other
+    stage timer of this thread names.
     """
+    if getattr(relation, "_telemetry_query", None) is None:
+        return _collect_columns(relation)
+    qid = getattr(relation, "_query_id", None) or next(QUERY_IDS)
+    span = METRICS.timer("query", qid=qid)
+    try:
+        with span:
+            return _collect_columns(relation)
+    finally:
+        METRICS.observe("query.other", span.self_s)
+
+
+def _collect_columns(relation):
     import time as _time
 
     t0 = _time.perf_counter()

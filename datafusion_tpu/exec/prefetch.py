@@ -29,6 +29,8 @@ import queue
 import threading
 from typing import Callable, Iterator, Optional
 
+from datafusion_tpu.utils.metrics import METRICS
+
 _DEPTH = 2  # batches in flight: N computing, N+1 staged, N+2 parsing
 
 
@@ -60,6 +62,7 @@ def staged_prefetch(
     batches: Iterator,
     stage: Optional[Callable] = None,
     depth: int = _DEPTH,
+    wait_timer: str = "pipeline.wait",
 ) -> Iterator:
     """Yield `batches` in order, pulling and staging them on a
     background thread.
@@ -75,6 +78,10 @@ def staged_prefetch(
     Exceptions from the source iterator or stage() re-raise in the
     consumer.  Abandoning the generator (early close) stops the
     producer promptly.
+
+    Two stage timers say which side sets the pace: `wait_timer` is the
+    consumer blocked on the queue, `pipeline.stage` the producer inside
+    stage().
     """
     q: queue.Queue = queue.Queue(maxsize=max(depth, 1))
     stop = threading.Event()
@@ -95,7 +102,8 @@ def staged_prefetch(
                 if stop.is_set():
                     return
                 if stage is not None:
-                    stage(b)
+                    with METRICS.timer("pipeline.stage"):
+                        stage(b)
                 put(b)
             put(DONE)
         except _Stop:
@@ -110,7 +118,8 @@ def staged_prefetch(
     t.start()
     try:
         while True:
-            item = q.get()
+            with METRICS.timer(wait_timer):
+                item = q.get()
             if item is DONE:
                 return
             if isinstance(item, BaseException):
@@ -128,5 +137,6 @@ def staged_pipeline(batches: Iterator, stage: Callable, depth: int = _DEPTH):
     thread; on scan-heavy cold paths they are comparable in cost, so
     splitting them roughly halves the critical path."""
     return staged_prefetch(
-        staged_prefetch(batches, None, depth), stage, depth
+        staged_prefetch(batches, None, depth, "pipeline.scan_wait"),
+        stage, depth,
     )

@@ -34,9 +34,14 @@ def device_scope(device):
     return jax.default_device(device) if device is not None else nullcontext()
 
 
+def mask_and(a, b):
+    return a & b
+
+
 # tiny fused AND for combining a host predicate mask with a device-
-# resident upstream mask (built lazily; one jit for every shape pair)
-_MASK_AND_JIT = None
+# resident upstream mask (one jit for every shape pair; exec/sort.py
+# shares it)
+_MASK_AND_JIT = jax.jit(mask_and)
 
 
 def _is_accelerator(device) -> bool:
@@ -766,9 +771,6 @@ class PipelineRelation(Relation):
         if hasattr(batch.mask, "copy_to_host_async"):  # device mask
             from datafusion_tpu.obs.device import LEDGER
 
-            global _MASK_AND_JIT
-            if _MASK_AND_JIT is None:
-                _MASK_AND_JIT = jax.jit(lambda a, b: a & b)
             with device_scope(self.device):
                 return _MASK_AND_JIT(
                     LEDGER.put(pm, None, owner="mask"), batch.mask
@@ -898,7 +900,6 @@ def run_pipeline_megabatch(rels: list["PipelineRelation"]) -> float:
             METRICS.add("fused.group_batches", len(buf))
             METRICS.add("serve.megabatch_launches")
             METRICS.add("serve.megabatch_queries", n_live)
-            METRICS.add("serve.megabatch_batches", len(buf))
             outs = device_call(
                 core.multi_group_jit, tuple(group), buf[0][2],
                 params_list, _tag="pipeline.mega",

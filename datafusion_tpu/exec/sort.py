@@ -772,8 +772,6 @@ class SortRelation(Relation):
                 # upstream mask lives on device: one tiny fused AND
                 from datafusion_tpu.exec import relation as _rel
 
-                if _rel._MASK_AND_JIT is None:
-                    _rel._MASK_AND_JIT = jax.jit(lambda a, b: a & b)
                 m = _rel._MASK_AND_JIT(m, upstream_dev_mask)
         batch.cache["sort_pred_dev_mask"] = (self, m)
         return m
@@ -1657,7 +1655,6 @@ def run_topk_megabatch(rels: list["SortRelation"]) -> float:
                 METRICS.add("fused.group_batches", len(idxs))
                 METRICS.add("serve.megabatch_launches")
                 METRICS.add("serve.megabatch_queries", len(rels))
-                METRICS.add("serve.megabatch_batches", len(idxs))
                 states = device_call(
                     core.multi_group_jit, ks, states, tuple(group),
                     ranks, _tag="topk.mega",
@@ -1685,7 +1682,6 @@ def run_topk_megabatch(rels: list["SortRelation"]) -> float:
                 METRICS.add("fused.group_batches", len(idxs))
                 METRICS.add("serve.megabatch_launches")
                 METRICS.add("serve.megabatch_queries", len(rels))
-                METRICS.add("serve.megabatch_batches", len(idxs))
                 if gi == len(groups) - 1:
                     return device_call(
                         core.multi_final_jit, ks, st, tuple(group),

@@ -41,6 +41,14 @@ fragment, per worker:
   and coordinators; `datafusion-tpu debug-bundle` pulls every live
   member's bundle.
 
+One seam joins these to the device's clock.  A `METRICS` stage timer
+(`utils/metrics.py`: `timer`, `timed_iter`) is at once a stage timing
+(`\\timing`, `/debug/metrics`, the benchmark's per-layer metrics), the
+sampling profiler's stage, and a `dftpu.<name>` span in any running JAX
+profile, on `/host:CPU` beside the device plane, with the query's `qid`.
+`utils.profiling.trace(dir)` is how an operator takes such a profile; no
+flag or environment variable turns the spans on.
+
 Env knobs: `DATAFUSION_TPU_TRACE=1` enables span collection engine-wide;
 `DATAFUSION_TPU_TRACE_FILE=path.json` additionally writes a Chrome trace
 at process exit; `DATAFUSION_TPU_TRACE_BUF` bounds the in-memory span

@@ -63,8 +63,6 @@ from datafusion_tpu.obs.recorder import _env_flag
 from datafusion_tpu.obs.recorder import record as _flight_record
 from datafusion_tpu.obs.trace import _current_trace
 from datafusion_tpu.utils.metrics import METRICS
-from datafusion_tpu.utils.metrics import stage_enter as _stage_enter
-from datafusion_tpu.utils.metrics import stage_exit as _stage_exit
 
 
 _ENABLED = _env_flag("DATAFUSION_TPU_DEVICE_LEDGER", True)
@@ -227,20 +225,16 @@ class DeviceLedger:
             self._register(out, owner, cached, device)
             return out
         synced = profile_sync_active()
-        # stage publication for the sampling profiler: samples taken
-        # inside the put attribute to the "h2d" phase (lock-free —
-        # obs/profiler.py; same contract as the ledger bookkeeping)
-        stage_tok = _stage_enter("h2d.dispatch")
-        t0 = time.perf_counter()
-        try:
+        # the transfer is a stage-timer interval (utils/metrics.py):
+        # the "h2d" phase, the sampling profiler's stage and the
+        # `dftpu.h2d.dispatch` span (lock-free, same contract as the
+        # ledger bookkeeping)
+        with METRICS.timer("h2d.dispatch") as span:
             out = jax.device_put(arr, device)
             if synced:
                 jax.block_until_ready(out)
-        finally:
-            _stage_exit(stage_tok)
         nbytes = int(getattr(arr, "nbytes", 0) or 0)
-        self.note_h2d(nbytes, time.perf_counter() - t0, device,
-                      synced=synced)
+        self.note_h2d(nbytes, span.wall_s, device, synced=synced)
         self._register(out, owner, cached, device)
         return out
 
@@ -261,17 +255,12 @@ class DeviceLedger:
         if not profile:
             return jax.device_put(arr, device)
         synced = profile_sync_active()
-        stage_tok = _stage_enter("h2d.dispatch")
-        t0 = time.perf_counter()
-        try:
+        with METRICS.timer("h2d.dispatch") as span:
             out = jax.device_put(arr, device)
             if synced:
                 jax.block_until_ready(out)
-        finally:
-            _stage_exit(stage_tok)
         nbytes = int(getattr(arr, "nbytes", 0) or 0)
-        self.note_h2d(nbytes, time.perf_counter() - t0, device,
-                      synced=synced)
+        self.note_h2d(nbytes, span.wall_s, device, synced=synced)
         return out
 
     def adopt(self, value: Any, owner: str = "anon", cached: bool = True,
@@ -359,13 +348,13 @@ class DeviceLedger:
     def note_h2d(self, nbytes: int, seconds: float, device=None,
                  synced: bool = True) -> None:
         """Record one H2D transfer (or one batch of parallel transfers
-        the caller timed as a unit): stage timer, per-operator transfer
-        time, and the ``device.h2d`` flight event.  ``synced=False``
+        the caller timed as a unit, under its own ``h2d.dispatch``
+        stage timer): transfer count, per-operator transfer time, and
+        the ``device.h2d`` flight event.  ``synced=False``
         marks a dispatch-only wall (async production put): the event
         claims no GB/s — a dispatch-based rate would read absurdly
         above the link baseline and mislead the overlap-vs-encoding
         diagnosis the events exist for."""
-        METRICS.observe("h2d.dispatch", seconds)
         # event COUNT beside the byte counter: the serving path's
         # warm-pinned-table contract is "zero transfers", and a count
         # is assertable where a ring of flight events is not

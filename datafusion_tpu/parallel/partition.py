@@ -27,7 +27,6 @@ is needed until the final combine).
 
 from __future__ import annotations
 
-import time
 from typing import Iterator, Optional, Sequence
 
 import jax
@@ -205,17 +204,17 @@ class _MeshStacker:
         # per-shard profiled transfers would serialize the links they
         # measure
         synced = profile_sync_active()
-        t0 = time.perf_counter()
-        put = [
-            LEDGER.transfer(np.asarray(a)[None], d, profile=False)
-            for a, d in zip(shards, self.devices)
-        ]
-        if _ledger_on():
+        with METRICS.timer("h2d.dispatch") as span:
+            put = [
+                LEDGER.transfer(np.asarray(a)[None], d, profile=False)
+                for a, d in zip(shards, self.devices)
+            ]
             if synced:
                 jax.block_until_ready(put)
+        if _ledger_on():
             LEDGER.note_h2d(
                 sum(int(p.nbytes) for p in put),
-                time.perf_counter() - t0,
+                span.wall_s,
                 self.devices[0],
                 synced=synced,
             )

@@ -68,7 +68,7 @@ from datafusion_tpu.exec.datasource import DataSource
 from datafusion_tpu.obs import recorder
 from datafusion_tpu.obs.device import LEDGER
 from datafusion_tpu.utils.deadline import Deadline, deadline_scope
-from datafusion_tpu.utils.metrics import METRICS
+from datafusion_tpu.utils.metrics import METRICS, QUERY_IDS
 
 
 def _env_int(name: str, default: int) -> int:
@@ -105,12 +105,13 @@ class Ticket:
                  "_table", "_error", "_rel", "signature", "client_id",
                  "entry_mono", "admitted_mono", "enqueued_mono",
                  "flushed_mono", "exec_start_mono", "launch_share_s",
-                 "demux_share_s")
+                 "demux_share_s", "qid")
 
     def __init__(self, sql: str, plan, deadline: Optional[Deadline],
                  signature, client_id: str = "default",
-                 entry_mono: Optional[float] = None):
+                 entry_mono: Optional[float] = None, qid: int = 0):
         self.sql = sql
+        self.qid = qid  # shared by this query's spans (utils/metrics.py)
         self.plan = plan
         self.deadline = deadline
         self.signature = signature
@@ -476,6 +477,7 @@ class Server:
         self._closed = False
         self._window: list[Ticket] = []          # loop thread only
         self._window_timer = None                # loop thread only
+        self._window_span = None                 # loop thread only
         self._lock = lockcheck.make_lock("serve.server")
         self._pending = 0                        # queued, not yet executing
         # queued-but-undispatched tickets, keyed by identity: stop()
@@ -543,6 +545,12 @@ class Server:
         launch shares, H2D bytes, pin residency, hedge duplicates —
         apportions back to it (``tenant.<id>.*`` gauges,
         ``/debug/tenants``); unset, costs pool under ``"default"``."""
+        qid = next(QUERY_IDS)
+        with METRICS.timer("serve.submit", qid=qid):
+            return self._submit(sql, deadline_s, client_id, qid)
+
+    def _submit(self, sql: str, deadline_s: Optional[float],
+                client_id: Optional[str], qid: int) -> Ticket:
         from datafusion_tpu.errors import NotSupportedError
         from datafusion_tpu.sql import ast
         from datafusion_tpu.sql.parser import parse_sql
@@ -602,7 +610,7 @@ class Server:
             raise self._shed_submit(sql, reason, client)
 
         ticket = Ticket(sql, plan, deadline, self._mega_signature(plan),
-                        client_id=client, entry_mono=entry_mono)
+                        client_id=client, entry_mono=entry_mono, qid=qid)
         # 3. queue depth — checked and RESERVED in one lock acquisition
         # (a read-then-increment across two acquisitions would let N
         # concurrent submitters all pass a depth-1 check), re-checking
@@ -817,6 +825,11 @@ class Server:
             self._window_timer = self._loop.call_later(
                 self._effective_window_s(), self._flush_window
             )
+            # the batching window as a stage timer, once a flush: it
+            # opens here and closes in `_flush_window`, both on the
+            # loop thread, so what the loop runs meanwhile nests in it
+            self._window_span = METRICS.timer("serve.window")
+            self._window_span.__enter__()
 
     def _effective_window_s(self) -> float:
         """The megabatch wait actually armed: the configured window,
@@ -845,6 +858,9 @@ class Server:
 
     def _flush_window(self) -> None:
         self._window_timer = None
+        span, self._window_span = self._window_span, None
+        if span is not None:
+            span.__exit__(None, None, None)
         if not self._window:
             return
         batch, self._window = self._window, []
@@ -866,7 +882,6 @@ class Server:
             else:
                 groups.setdefault(t.signature, []).append(t)
         work = singles + list(groups.values())
-        METRICS.add("serve.windows")
         for group in work:
             self._loop.defer(partial(self._run_group, group),
                              self._group_done)
@@ -978,6 +993,22 @@ class Server:
 
     # -- execution (executor threads) ----------------------------------
     def _run_group(self, group: list[Ticket]) -> None:
+        with METRICS.timer("serve.group",
+                           qids=" ".join(str(t.qid) for t in group)):
+            rest = self._run_shared(group)
+        # per-ticket materialization fans back out over the executor
+        # pool: finalizes of THIS window overlap the next window's
+        # megabatch scan instead of serializing behind it, and each
+        # client unblocks as soon as ITS result is ready
+        for t in rest[1:]:
+            self._loop.defer(partial(self._finish, t), self._group_done)
+        if rest:
+            self._finish(rest[0])
+
+    def _run_shared(self, group: list[Ticket]) -> list[Ticket]:
+        """What one flushed group's tickets share on a worker: residency,
+        lowering, and the megabatched pass of those that fuse.  Returns
+        the tickets to finish, each on its own."""
         from datafusion_tpu.cache import scan_tables
         from datafusion_tpu.exec.aggregate import force_core_predicate
         from datafusion_tpu.obs.attribution import client_scope
@@ -991,7 +1022,7 @@ class Server:
                 continue
             ready.append(t)
         if not ready:
-            return
+            return []
         if self._pin_enabled:
             for t in ready:
                 for tbl in scan_tables(t.plan):
@@ -1053,14 +1084,7 @@ class Server:
                         t._rel.__dict__.pop("_injected_batches", None)
                 rest.extend(sub)
             rest.extend(ts)
-        # per-ticket materialization fans back out over the executor
-        # pool: finalizes of THIS window overlap the next window's
-        # megabatch scan instead of serializing behind it, and each
-        # client unblocks as soon as ITS result is ready
-        for t in rest[1:]:
-            self._loop.defer(partial(self._finish, t), self._group_done)
-        if rest:
-            self._finish(rest[0])
+        return rest
 
     def _member_weights(self, tickets: list) -> list:
         """Per-member megabatch cost weights from REAL scan row
@@ -1259,7 +1283,6 @@ class Server:
                 states = list(out[:n_live])
                 METRICS.add("serve.megabatch_launches")
                 METRICS.add("serve.megabatch_queries", n_live)
-                METRICS.add("serve.megabatch_batches", len(idxs))
             chunk.clear()
 
         rows_seen = 0
@@ -1380,38 +1403,45 @@ class Server:
             observe_path,
         )
 
-        try:
-            rel = t._rel
-            if "_injected_state" not in getattr(rel, "__dict__", {}):
-                self._adopt_shared_if_aggregate(rel)
-            fin_t0 = time.monotonic()
-            with deadline_scope(t.deadline), \
-                    client_scope(t.client_id) as launch_acc:
-                table = collect(rel)
-            fin_wall = time.monotonic() - fin_t0
-            t._fulfill(table)
-            t.launch_share_s += launch_acc[0]
-            wall = time.monotonic() - t.entry_mono
-            observe_latency("serve.latency", wall)
-            slo.WATCHDOG.observe(wall)
-            observe_path(t.client_id, wall, self._segments(
-                t, wall, fin_wall, launch_acc[0]
-            ))
-            ewma = self._service_ewma_s
-            self._service_ewma_s = (
-                wall if ewma is None else 0.8 * ewma + 0.2 * wall
-            )
-            recorder.record("serve.done", ms=round(wall * 1e3, 3),
-                            client=t.client_id)
-        except BaseException as e:  # noqa: BLE001 — delivered to the client
-            METRICS.add("serve.query_errors")
-            # the error still counts against error-rate SLOs with the
-            # client-visible wall (the funnel's own watchdog feed is
-            # suppressed for served queries — see query_completed)
-            slo.WATCHDOG.observe(
-                time.monotonic() - t.entry_mono, error=True
-            )
-            t._fail(e)
+        with METRICS.timer("serve.finish", qid=t.qid):
+            try:
+                rel = t._rel
+                if "_injected_state" not in getattr(rel, "__dict__", {}):
+                    self._adopt_shared_if_aggregate(rel)
+                # the ticket's number rides to `collect_columns`' span
+                rel._query_id = t.qid
+                fin_t0 = time.monotonic()
+                with deadline_scope(t.deadline), \
+                        client_scope(t.client_id) as launch_acc:
+                    table = collect(rel)
+                fin_wall = time.monotonic() - fin_t0
+                t._fulfill(table)
+                t.launch_share_s += launch_acc[0]
+                wall = time.monotonic() - t.entry_mono
+                observe_latency("serve.latency", wall)
+                slo.WATCHDOG.observe(wall)
+                segments = self._segments(t, wall, fin_wall, launch_acc[0])
+                observe_path(t.client_id, wall, segments)
+                # the same account as stage timings, summed over tickets
+                # (differences of stamps taken on three threads: no span)
+                for name, seconds in segments.items():
+                    METRICS.observe("serve.path." + name, seconds)
+                METRICS.observe("serve.path.wall", wall)
+                ewma = self._service_ewma_s
+                self._service_ewma_s = (
+                    wall if ewma is None else 0.8 * ewma + 0.2 * wall
+                )
+                recorder.record("serve.done", ms=round(wall * 1e3, 3),
+                                client=t.client_id)
+            except BaseException as e:  # noqa: BLE001 — delivered to the client
+                METRICS.add("serve.query_errors")
+                # the error still counts against error-rate SLOs with the
+                # client-visible wall (the funnel's own watchdog feed is
+                # suppressed for served queries — see query_completed)
+                slo.WATCHDOG.observe(
+                    time.monotonic() - t.entry_mono, error=True
+                )
+                t._fail(e)
 
     @staticmethod
     def _segments(t: Ticket, wall: float, fin_wall: float,

@@ -6,14 +6,22 @@ console (`src/bin/console/main.rs:133`) and a `println!` of the plan
 compile/execute stage timings plus engine counters (rows scanned,
 bytes H2D, jit cache activity) — queryable via
 `ExecutionContext.metrics()` and printed by the CLI's `\\timing` mode.
+
+A stage timer (`Metrics.timer` / `timed_iter`) is the engine's one
+tracing seam: the same interval accumulates `timings[name]`, publishes
+the sampling profiler's stage, and is a `dftpu.<name>` span
+(`jax.profiler.TraceAnnotation`) in whatever JAX profile is running —
+on `/host:CPU`, nested by thread and time, on the clock the device
+plane uses.  With no profile running no annotation is made (one
+`is_enabled()` call an entry; PERF.md section 6 has the cost).
 """
 
 from __future__ import annotations
 
+import itertools
 import threading
 import time
 from collections import defaultdict
-from contextlib import contextmanager
 
 # -- profiler publication tables (obs/profiler.py) --------------------
 # While the sampling profiler has at least one active capture, these
@@ -74,6 +82,61 @@ def stage_exit(token) -> None:
         tbl[tid] = prev
 
 
+SPAN_PREFIX = "dftpu."
+
+# the number one query's spans share (`qid=` on the spans that begin a
+# thread's share of a query): taken at `Server.submit` for a ticket and
+# at `collect_columns` for a query no server carries
+QUERY_IDS = itertools.count(1)
+
+# per-thread seconds spent inside stage timers that closed within the
+# innermost open one: what a span's self time subtracts
+_CHILDREN = threading.local()
+
+_TRACE_ANNOTATION = None  # jax.profiler.TraceAnnotation, on first use
+
+
+class _Span:
+    """One stage-timer interval (see the module docstring).  After the
+    block, `wall_s` is its duration and `self_s` that less the stage
+    timers this thread ran inside it."""
+
+    __slots__ = ("_metrics", "name", "_annotation", "_stage", "_t0",
+                 "_outer", "wall_s", "self_s")
+
+    def __init__(self, metrics: "Metrics", name: str, ids: dict):
+        global _TRACE_ANNOTATION
+        annotation = _TRACE_ANNOTATION
+        if annotation is None:
+            from jax.profiler import TraceAnnotation as annotation
+
+            _TRACE_ANNOTATION = annotation
+        self._metrics = metrics
+        self.name = name
+        # no profile running (one call into the profiler to ask): no
+        # annotation object, a quarter of an entry's cost
+        self._annotation = (annotation(SPAN_PREFIX + name, **ids)
+                            if annotation.is_enabled() else None)
+
+    def __enter__(self) -> "_Span":
+        self._stage = stage_enter(self.name)
+        self._outer = getattr(_CHILDREN, "s", 0.0)
+        _CHILDREN.s = 0.0
+        if self._annotation is not None:
+            self._annotation.__enter__()
+        self._t0 = time.perf_counter()
+        return self
+
+    def __exit__(self, *exc) -> None:
+        wall = self.wall_s = time.perf_counter() - self._t0
+        if self._annotation is not None:
+            self._annotation.__exit__(*exc)
+        self.self_s = wall - _CHILDREN.s
+        _CHILDREN.s = self._outer + wall
+        self._metrics.timings[self.name] += wall
+        stage_exit(self._stage)
+
+
 class Metrics:
     def __init__(self):
         self.timings: dict[str, float] = defaultdict(float)
@@ -88,15 +151,11 @@ class Metrics:
         for name in self._declared:  # declared names survive resets
             self.counts[name] += 0
 
-    @contextmanager
-    def timer(self, name: str):
-        tok = stage_enter(name)
-        t0 = time.perf_counter()
-        try:
-            yield
-        finally:
-            self.timings[name] += time.perf_counter() - t0
-            stage_exit(tok)
+    def timer(self, name: str, **ids) -> _Span:
+        """`with METRICS.timer(name):` times the block into
+        `timings[name]` and spans it as `dftpu.<name>`; `ids` (`qid=`)
+        become the span's stats in a running profile."""
+        return _Span(self, name, ids)
 
     def add(self, name: str, n: int = 1):
         self.counts[name] += n
@@ -112,25 +171,23 @@ class Metrics:
             self.counts[name] += 0
 
     def observe(self, name: str, seconds: float):
-        """Fold an externally-measured duration into a stage timing
-        (the obs subsystem's XLA-compile listener lands here — this
-        registry is the single counter backend; see obs/export.py's
-        `prometheus_text` for the scrape format)."""
+        """Fold a duration that has no interval of its own on the
+        calling thread into a stage timing (`compile.xla` from the
+        jax.monitoring listener, `host.gc_pause`, `query.other`, the
+        `serve.path.*` segments); no span.  This registry is the single
+        counter backend; see obs/export.py's `prometheus_text` for the
+        scrape format."""
         self.timings[name] += seconds
 
     def timed_iter(self, name: str, it):
         """Wrap a generator so time spent *producing* items (host parse,
         encode) accrues to `name`, while consumer time doesn't."""
         while True:
-            tok = stage_enter(name)
-            t0 = time.perf_counter()
-            try:
-                item = next(it)
-            except StopIteration:
-                return
-            finally:
-                self.timings[name] += time.perf_counter() - t0
-                stage_exit(tok)
+            with self.timer(name):
+                try:
+                    item = next(it)
+                except StopIteration:
+                    return
             yield item
 
     def gauge(self, name: str, value: float) -> None:

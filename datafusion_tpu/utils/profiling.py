@@ -1,11 +1,11 @@
-"""XLA profiler integration (SURVEY §5.1).
+"""Taking a JAX/XLA profile of the engine (SURVEY §5.1).
 
-The reference's only observability is a console wall clock
-(`src/bin/console/main.rs:133`); this engine already records per-stage
-timers and counters (utils/metrics.py, CLI `\\timing`).  For
-kernel-level analysis, `trace(dir)` wraps a block in the JAX/XLA
-profiler — the resulting TensorBoard trace shows each fused query
-kernel, its device occupancy, and transfer timelines:
+`trace(dir)` wraps a block in the JAX profiler.  The trace it writes
+(TensorBoard / `jax.profiler.ProfileData`) holds the device plane (each
+program `jit_<function>`, its operations, the transfers) and, on
+`/host:CPU`, every stage timer of `utils/metrics.py` as a `dftpu.<name>`
+span on the same clock, nested by thread, with `qid` on the spans that
+begin a thread's share of a query:
 
     from datafusion_tpu.utils.profiling import trace
     with trace("/tmp/q1_profile"):
@@ -28,9 +28,3 @@ def trace(log_dir: str):
     finally:
         jax.profiler.stop_trace()
 
-
-def annotate(name: str):
-    """Named sub-span inside a trace (shows up on the host timeline)."""
-    import jax
-
-    return jax.profiler.TraceAnnotation(name)

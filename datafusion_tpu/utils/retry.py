@@ -288,14 +288,11 @@ def device_call(fn, /, *args, _tag=None, **kwargs):
         try:
             faults.check("device.call", attempt=attempt)
             from datafusion_tpu.obs.device import profile_sync_active
-            from datafusion_tpu.utils.metrics import stage_enter, stage_exit
 
-            # published as this thread's active stage so the sampling
-            # profiler (obs/profiler.py) attributes samples taken here
-            # to the "execute" phase — same name as the stage timer
-            stage_tok = stage_enter("device.dispatch")
-            t0 = time.perf_counter()
-            try:
+            # the launch is a stage-timer interval (utils/metrics.py):
+            # the "execute" slice of the phase breakdown, the sampling
+            # profiler's stage, and the `dftpu.device.dispatch` span
+            with METRICS.timer("device.dispatch") as span:
                 out = fn(*args, **kwargs)
                 if profile_sync_active():
                     # phase-profiled run (EXPLAIN ANALYZE, bench cold
@@ -305,15 +302,12 @@ def device_call(fn, /, *args, _tag=None, **kwargs):
                     import jax
 
                     jax.block_until_ready(out)
-            finally:
-                stage_exit(stage_tok)
-            wall = time.perf_counter() - t0
+            wall = span.wall_s
             # every successful dispatch is one executable launch — the
             # unit the fused-pass work minimizes (launches_per_pass in
             # EXPLAIN ANALYZE / bench derives from this counter);
             # counted AFTER fn so failed attempts/retries don't inflate
             METRICS.add("device.launches")
-            METRICS.observe("device.dispatch", wall)
             if _tag is not None:
                 METRICS.add(f"device.launches.{_tag}")
             from datafusion_tpu.obs.attribution import note_launch

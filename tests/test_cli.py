@@ -294,30 +294,55 @@ class TestTimingMode:
 
 
 class TestProfilerTrace:
-    def test_trace_writes_profile(self, tmp_path):
-        import os
-
+    def test_trace_writes_profile(self, tmp_path, monkeypatch):
+        """`utils.profiling.trace(dir)` around one `ctx.sql` + `collect`
+        writes a profile that holds the engine's stage timers as
+        `dftpu.*` spans on the host plane, nested inside the query's."""
         import numpy as np
 
         from datafusion_tpu.datatypes import DataType, Field, Schema
         from datafusion_tpu.exec.batch import make_host_batch
         from datafusion_tpu.exec.context import ExecutionContext
         from datafusion_tpu.exec.datasource import MemoryDataSource
-        from datafusion_tpu.utils.profiling import annotate, trace
+        from datafusion_tpu.exec.materialize import collect
+        from datafusion_tpu.utils.profiling import trace
+        from spans_helper import host_spans
 
-        schema = Schema([Field("x", DataType.FLOAT64, False)])
-        batch = make_host_batch(schema, [np.arange(100.0)], [None], [None])
-        ctx = ExecutionContext(device="cpu")
-        ctx.register_datasource("t", MemoryDataSource(schema, [batch]))
+        # the chip's path on the CPU: staged pipeline, compressed wire
+        monkeypatch.setenv("DATAFUSION_TPU_PREFETCH", "1")
+        monkeypatch.setenv("DATAFUSION_TPU_WIRE", "always")
+        schema = Schema([Field("k", DataType.INT64, False),
+                         Field("x", DataType.FLOAT64, False)])
+        batches = [
+            make_host_batch(schema, [np.arange(100) % 3, np.arange(100.0)])
+            for _ in range(3)
+        ]
+        ctx = ExecutionContext(device="cpu", result_cache=False)
+        ctx.register_datasource("t", MemoryDataSource(schema, batches))
         out_dir = str(tmp_path / "prof")
         with trace(out_dir):
-            with annotate("q1"):
-                ctx.sql_collect("SELECT SUM(x), COUNT(1) FROM t WHERE x > 1")
-        # a plugins/profile/<ts>/ tree with at least one trace artifact
-        found = []
-        for _root, _dirs, files in os.walk(out_dir):
-            found.extend(files)
-        assert found, "profiler produced no trace files"
+            table = collect(ctx.sql(
+                "SELECT k, SUM(x), COUNT(1) FROM t WHERE x > 1 GROUP BY k"))
+        assert table.num_rows == 3
+        spans = host_spans(out_dir)
+        names = {s.name for s in spans}
+        assert {"dftpu.query", "dftpu.parse", "dftpu.h2d.encode",
+                "dftpu.pipeline.wait", "dftpu.pipeline.stage",
+                "dftpu.device.dispatch", "dftpu.h2d.dispatch"} <= names
+        (query,) = [s for s in spans if s.name == "dftpu.query"]
+        assert query.stats["qid"] >= 1
+        parse = next(s for s in spans if s.name == "dftpu.parse")
+        assert parse.thread == query.thread and parse.end <= query.start
+        for name, same_thread in (("dftpu.pipeline.wait", True),
+                                  ("dftpu.device.dispatch", True),
+                                  ("dftpu.h2d.encode", False),
+                                  ("dftpu.pipeline.stage", False)):
+            inner = [s for s in spans if s.name == name]
+            assert inner
+            for s in inner:  # inside the query's span in time,
+                assert query.start <= s.start and s.end <= query.end
+                # on its thread or on a prefetch thread
+                assert (s.thread == query.thread) == same_thread
 
 
 class TestReferenceBenches:

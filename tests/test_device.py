@@ -142,7 +142,8 @@ class TestLedgerChurn:
     def test_transfer_profile_false_is_silent(self, ledger):
         # the mesh stacker's fan-out arm: dispatch without blocking or
         # recording — no flight event, no timer accrual; the caller
-        # times the batch and records ONE note_h2d
+        # times the batch under ONE h2d.dispatch stage timer and
+        # records ONE note_h2d with its wall
         recorder.clear()
         before_t = METRICS.timings.get("h2d.dispatch", 0.0)
         out = ledger.transfer(np.arange(1024), None, profile=False)
@@ -151,8 +152,11 @@ class TestLedgerChurn:
         assert not [
             e for e in recorder.events() if e["kind"] == "device.h2d"
         ]
-        ledger.note_h2d(out.nbytes, 0.001)
-        assert METRICS.timings.get("h2d.dispatch", 0.0) > before_t
+        with METRICS.timer("h2d.dispatch") as span:
+            pass
+        ledger.note_h2d(out.nbytes, span.wall_s)
+        assert METRICS.timings.get("h2d.dispatch", 0.0) == pytest.approx(
+            before_t + span.wall_s)
         events = [
             e for e in recorder.events() if e["kind"] == "device.h2d"
         ]
