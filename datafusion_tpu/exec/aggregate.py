@@ -48,6 +48,7 @@ from __future__ import annotations
 
 import contextlib
 import contextvars
+import weakref
 from typing import Iterator, Optional
 
 import jax
@@ -1119,9 +1120,12 @@ class AggregateRelation(Relation):
         # SF-1 scan to a 0.75 MB mask).  The predicate — literals and
         # all — lives on THIS relation; the core is built as if there
         # were no predicate, so every host-filtered query shape shares
-        # one device kernel regardless of literal values.  No function
-        # metas reach this ctor, so predicates containing UDFs
-        # conservatively stay on device ({} finds no host_fn).
+        # one device kernel regardless of literal values — and one set
+        # of device copies of the data columns: over a reusable source
+        # those are cached on the table's batches and do not travel
+        # again either, only this query's mask does (_device_inputs).
+        # No function metas reach this ctor, so predicates containing
+        # UDFs conservatively stay on device ({} finds no host_fn).
         host_pred = (
             predicate is not None
             and _is_accelerator(device)
@@ -1455,6 +1459,7 @@ class AggregateRelation(Relation):
         if injected is not None:
             return injected
 
+        self._adopt_source_state()
         src = iter(iter_stats(self.child))
         first = next(src, None)
         if first is None:
@@ -1473,10 +1478,37 @@ class AggregateRelation(Relation):
         )
         return ("hostsplit", state, partials)
 
+    def _adopt_source_state(self) -> None:
+        """Swap this relation's per-query execution state for the one
+        its scan's source keeps (`datasource.SharedScanState`) where
+        the source hands out the same batches to every query: the
+        append-only encoder shared by every query over these GROUP BY
+        columns owns the per-batch group-id caches, so ids encoded (and
+        uploaded) by ANY earlier query over the table replay for this
+        one.  Ids are encoded over all rows and do not depend on a
+        mask, so a host predicate is no obstacle; groups with no
+        surviving row stay out of the answer through the live counts."""
+        ds = getattr(self.child, "datasource", None)
+        owner = getattr(ds, "shared_state_for", None)
+        if owner is None or not getattr(ds, "reusable_batches", False):
+            return
+        entry = owner(self.core)
+        self.encoder = entry["encoder"]
+        self._aux_cache = entry["aux"]
+        self._str_aux_cache = entry["str_aux"]
+        self._ids_lock = entry["lock"]
+
+    def _share_state_of(self, other: "AggregateRelation") -> None:
+        """Run on `other`'s encoder, caches and lock (the relations of
+        one megabatch: their ids must agree, pinned table or not)."""
+        self.encoder = other.encoder
+        self._aux_cache = other._aux_cache
+        self._str_aux_cache = other._str_aux_cache
+        self._ids_lock = other._ids_lock
+
     def _accumulate_core(self, batches, core, params, host_partials):
         """The scan loop over one device core (the full core, or the
         placement's reduced core — None when every slot went host)."""
-        from datafusion_tpu.exec.batch import device_inputs
         from datafusion_tpu.exec.prefetch import pipeline_enabled, staged_pipeline
         from datafusion_tpu.exec.relation import device_scope
         from datafusion_tpu.obs.stats import op_timer
@@ -1505,7 +1537,7 @@ class AggregateRelation(Relation):
                     tuple(compute_aux_values(core.aux_specs, b, self._aux_cache)),
                     self._compute_str_aux(b, core.slots),
                 )
-                device_inputs(self._device_view(b, core), self.device, core.wire_hints)
+                self._device_inputs(b, core)
 
             batches = staged_pipeline(batches, _stage)
 
@@ -1628,9 +1660,7 @@ class AggregateRelation(Relation):
                 aux = compute_aux_values(core.aux_specs, batch, self._aux_cache)
                 str_aux = self._compute_str_aux(batch, core.slots)
             with device_scope(self.device):
-                data, validity, mask = device_inputs(
-                    self._device_view(batch, core), self.device, core.wire_hints
-                )
+                data, validity, mask = self._device_inputs(batch, core)
             chunk.append(
                 (data, validity, tuple(aux), np.int32(batch.num_rows), mask,
                  ids, str_aux)
@@ -1645,56 +1675,62 @@ class AggregateRelation(Relation):
         return state
 
     def _device_view(self, batch: RecordBatch, core=None) -> RecordBatch:
-        """The batch as the device kernel sees it: only `used_cols`
-        (group keys travel as dense ids, host-predicate inputs not at
-        all), with the host-evaluated predicate folded into the mask.
-        Cached on the batch (relation+core-pinned) so re-scanned
-        in-memory batches keep their device copies across runs."""
-        if core is None:
-            core = self.core
-        if self._host_pred_expr is None and len(core.used_cols) == batch.num_columns:
-            return batch
+        """The batch's columns as the device kernel sees them: only
+        `used_cols` (group keys travel as dense ids, host-predicate
+        inputs not at all).  The view says nothing of any query's
+        literals: it is the batch itself or the `subset_view` cached on
+        it, the SAME object for every relation over this batch, so the
+        device copies `device_inputs` caches on it belong to the
+        table's batch, live as long as it does, and serve every later
+        or concurrent query whatever its predicate.  A long-lived batch
+        keeps one view (and its copies) per distinct used-column set —
+        bounded by query-shape diversity; pin eviction clears the whole
+        cache when HBM needs the room.  The host-evaluated predicate is
+        not in the view: `_query_mask` / `_device_inputs`."""
+        from datafusion_tpu.exec.batch import subset_view
+
+        core = self.core if core is None else core
+        return subset_view(batch, core.used_cols, tag="agg_subset")
+
+    def _query_mask(self, batch: RecordBatch) -> Optional[np.ndarray]:
+        """THIS query's host-evaluated predicate over one batch, as a
+        numpy bool array (None without one).  It carries the query's
+        literals, so it belongs to the relation, never to the batch."""
         if self._host_pred_expr is None:
-            # no per-query mask in the view: it depends only on the
-            # core's used columns, so share it (and, downstream, the
-            # device copies device_inputs caches on it) across EVERY
-            # relation over this batch — a warm repeated or concurrent
-            # query re-uses the same pinned device buffers instead of
-            # re-shipping per-query arrays (the serving-path refactor;
-            # subset_view caches by column tuple, not by relation).
-            # Trade accepted: a long-lived batch now retains one view
-            # (and its device copies) per distinct used-column set —
-            # bounded by query-shape diversity, the same discipline
-            # PipelineRelation's subset_view has always had; pin
-            # eviction clears the whole cache when HBM needs the room
-            from datafusion_tpu.exec.batch import subset_view
+            return None
+        from datafusion_tpu.exec.hostfn import host_pred_mask
 
-            return subset_view(batch, core.used_cols, tag="agg_subset")
-        key = "agg_view"
-        hit = batch.cache.get(key)
-        if hit is not None and hit[0] is self and hit[1] is core:
-            return hit[2]
-        mask = batch.mask
-        if self._host_pred_expr is not None:
-            from datafusion_tpu.exec.hostfn import host_pred_mask
+        return host_pred_mask(self._host_pred_expr, batch, {})
 
-            pm = host_pred_mask(self._host_pred_expr, batch, {})
-            # an upstream device mask would need a D2H pull to combine
-            # host-side — rare (the planner fuses filters into the
-            # aggregate), and still correct when it happens
-            mask = pm if mask is None else (np.asarray(mask) & pm)
-        view = RecordBatch(
-            core.sub_schema,
-            [batch.data[c] for c in core.used_cols],
-            [batch.validity[c] for c in core.used_cols],
-            [batch.dicts[c] for c in core.used_cols],
-            num_rows=batch.num_rows,
-            mask=mask,
+    def _device_inputs(self, batch: RecordBatch, core):
+        """(data, validity, mask) on the device for one batch of this
+        query.  Data and validity come from the literal-independent
+        `_device_view` (shipped once per long-lived batch); this
+        query's mask is the only thing that travels per query, and
+        `device_inputs` sends it alone or with the columns by what it
+        finds on the batch.  The result sits in ONE slot on the batch,
+        pinned by relation and core (the mask carries this query's
+        literals), so the consumer re-reads what the staging thread
+        placed and a long-lived batch holds one mask, not one per query
+        ever run; another relation's staging overwrites the slot and
+        this one then ships its mask again — correct, and rare.  The
+        pin is a weak reference: a resident batch must not keep every
+        last relation (and, through its scan, itself) alive."""
+        from datafusion_tpu.exec.batch import device_inputs
+
+        slot = batch.cache.get("agg_inputs")
+        if slot is not None and slot[0]() is self and slot[1] is core:
+            return slot[2]
+        out = device_inputs(
+            self._device_view(batch, core), self.device, core.wire_hints,
+            query_mask=self._query_mask(batch),
         )
-        # pinned by RELATION (the host-predicate mask carries THIS
-        # query's literals) and by the specific core (full vs reduced)
-        batch.cache[key] = (self, core, view)
-        return view
+        batch.cache["agg_inputs"] = (weakref.ref(self), core, out)
+        return out
+
+    @property
+    def _ids_slot(self):
+        return ("group_ids", tuple(self.key_cols))
 
     def _group_ids(self, batch: RecordBatch, upload: bool = True,
                    keep_np: bool = False):
@@ -1710,11 +1746,12 @@ class AggregateRelation(Relation):
         does all encoding, but a pin miss (another relation's encode
         overwrote the batch's slot) routes the consumer thread here
         concurrently, and GroupKeyEncoder mutation is not atomic."""
-        # single slot per batch (a different query's encoder overwrites
-        # it) so long-lived in-memory batches hold at most one ids array,
-        # not one per query ever run; the entry pins the encoder so the
-        # identity check can't hit a recycled object
-        key = "group_ids" if upload else "group_ids_np"
+        # one slot per batch and GROUP BY column set (a different
+        # encoder over the same keys overwrites it), so a long-lived
+        # batch holds one id array per key set, not one per query ever
+        # run; the entry pins the encoder so the identity check can't
+        # hit a recycled object
+        key = self._ids_slot if upload else "group_ids_np"
         hit = batch.cache.get(key)
         if hit is not None and hit[0] is self.encoder:
             if not keep_np or batch.cache.get("group_ids_np") is not None:
@@ -1724,7 +1761,7 @@ class AggregateRelation(Relation):
 
     def _group_ids_locked(self, batch: RecordBatch, upload: bool = True,
                           keep_np: bool = False):
-        key = "group_ids" if upload else "group_ids_np"
+        key = self._ids_slot if upload else "group_ids_np"
         hit = batch.cache.get(key)
         if hit is not None and hit[0] is self.encoder:
             if not keep_np or batch.cache.get("group_ids_np") is not None:
@@ -1772,7 +1809,7 @@ class AggregateRelation(Relation):
             if wire.dtype == np.int32
             else LEDGER.adopt(_WIDEN_IDS_JIT(dev_wire), owner="agg.ids")
         )
-        batch.cache["group_ids"] = (self.encoder, ids)
+        batch.cache[self._ids_slot] = (self.encoder, ids)
         return ids
 
     @staticmethod

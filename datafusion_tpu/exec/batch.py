@@ -923,19 +923,43 @@ def put_compressed(host_arrays, device=None, hints=None, owner="h2d"):
     return decoded
 
 
-def device_inputs(batch: RecordBatch, device=None, hints=None):
-    """(data, validity, mask) as device-resident arrays, cached on the
-    batch: a re-scanned in-memory batch transfers H2D once, not per
-    query run.  Host arrays travel wire-compressed; a jitted kernel
-    restores the exact original dtypes on device.  `hints` (optional,
-    caller-owned) carries per-column codec memory across batches — see
-    put_compressed."""
+def device_inputs(batch: RecordBatch, device=None, hints=None,
+                  query_mask=None):
+    """(data, validity, mask) as device-resident arrays.  Host arrays
+    travel wire-compressed; a jitted kernel restores the exact original
+    dtypes on device.  `hints` (optional, caller-owned) carries
+    per-column codec memory across batches — see put_compressed.
+
+    **Who owns the copies.**  The column, validity and upstream-mask
+    copies depend on the batch and on nothing a query's literals say,
+    so they are cached on `batch.cache` and live exactly as long as the
+    batch object does: a batch of a reusable source (MemoryDataSource,
+    a resident PinnedSource, the `subset_view`s cached on their
+    batches) transfers once and every later relation finds the copies
+    (`h2d.resident_hits`); a streamed batch ships (`h2d.resident_misses`)
+    and its copies die with it after the kernel consumed them.
+
+    `query_mask` (a host bool array: one query's host-evaluated
+    predicate) belongs to the CALLER and is never cached here.  What
+    this function observes decides how it travels: where the column
+    copies are already on the batch it ships alone, bit-packed; where
+    they are not it rides in the columns' one `put_compressed` call
+    (one decode launch per batch either way).  The returned mask is
+    the query's AND the batch's own, combined on the device."""
+    from datafusion_tpu.utils.metrics import METRICS
+
     key = ("device", None if device is None else repr(device))
     hit = batch.cache.get(key)
     if hit is not None:
-        return hit
+        METRICS.add("h2d.resident_hits")
+        if query_mask is None:
+            return hit
+        data, validity, mask = hit
+        qm = put_compressed([query_mask], device, owner="query.mask")[0]
+        return data, validity, _and_masks(qm, mask)
 
-    # layout: data columns, then the present validity arrays, then mask
+    # layout: data columns, then the present validity arrays, then the
+    # batch's mask, then the query's
     host_arrays: list = list(batch.data)
     valid_pos = []
     for i, v in enumerate(batch.validity):
@@ -945,6 +969,10 @@ def device_inputs(batch: RecordBatch, device=None, hints=None):
     has_mask = batch.mask is not None
     if has_mask:
         host_arrays.append(batch.mask)
+    if any(isinstance(a, np.ndarray) for a in host_arrays):
+        METRICS.add("h2d.resident_misses")
+    if query_mask is not None:
+        host_arrays.append(query_mask)
 
     # the ledger seam accrues the h2d.dispatch stage timing and the
     # per-transfer flight events; batch column copies land in
@@ -956,24 +984,49 @@ def device_inputs(batch: RecordBatch, device=None, hints=None):
     validity_list: list = [None] * n_cols
     for j, i in enumerate(valid_pos):
         validity_list[i] = decoded[n_cols + j]
-    mask = decoded[-1] if has_mask else None
+    mask = decoded[n_cols + len(valid_pos)] if has_mask else None
     out = (data, tuple(validity_list), mask)
     batch.cache[key] = out
-    return out
+    if query_mask is None:
+        return out
+    return out[0], out[1], _and_masks(decoded[-1], mask)
+
+
+def _and_masks(query_mask, batch_mask):
+    """One query's device mask AND the batch's upstream one, on the
+    device (the pipeline operator's `mask_and` program)."""
+    if batch_mask is None:
+        return query_mask
+    from datafusion_tpu.exec.relation import _MASK_AND_JIT
+
+    return _MASK_AND_JIT(query_mask, batch_mask)
+
+
+# the tag under which a source's column projection caches its views on
+# the table's batches: one for every door (MemoryDataSource.
+# with_projection, serve._PinnedProjection), so a table queried through
+# `ctx.sql` and through `Server.submit` holds ONE device copy
+PROJECTION_TAG = "pin_proj"
 
 
 def subset_view(batch: "RecordBatch", cols: list, tag: str = "subset_view"):
-    """A view batch holding only `cols`, cached on the parent batch so
-    device copies made against the view survive re-scans of in-memory
-    sources (device_inputs caches on the view object).  Used by the
-    pipeline/TopK operators to ship only the columns a kernel reads."""
-    if len(cols) == batch.num_columns:
+    """A view batch holding only `cols`, cached on the parent batch
+    under `(tag, cols)`: the SAME view object comes back for every
+    later caller, so the device copies `device_inputs` caches on it are
+    owned, through the parent's cache, by the parent batch and live as
+    long as it does (until the parent is dropped or its cache is
+    cleared, as pin eviction does).  A long-lived batch therefore
+    keeps one view, and one set of device copies, per distinct
+    (tag, column set): bounded by query-shape diversity.  The identity
+    projection is the batch itself."""
+    cols = list(cols)
+    if cols == list(range(batch.num_columns)):
         return batch
     key = (tag, tuple(cols))
     hit = batch.cache.get(key)
     if hit is None:
         hit = RecordBatch(
-            batch.schema.select(list(cols)),
+            batch.schema.select(cols),
             [batch.data[c] for c in cols],
             [batch.validity[c] for c in cols],
             [batch.dicts[c] for c in cols],

@@ -13,6 +13,7 @@ description of a source that distributed mode ships to workers.
 
 from __future__ import annotations
 
+import weakref
 from typing import Iterator, Optional, Sequence
 
 from datafusion_tpu.datatypes import Schema
@@ -188,14 +189,96 @@ class ParquetDataSource(DataSource):
         }
 
 
+class SharedScanState:
+    """What the relations over one reusable source keep in common,
+    owned by the source (a `MemoryDataSource`, a `serve.PinnedSource`)
+    for as long as it lives, keyed by what each piece depends on:
+
+    - per GROUP BY column set (table column ids, whatever projection
+      the query scans through): ONE append-only group-key encoder and
+      the lock that serialises its mutation across relations running
+      at once.  Ids depend on the key columns and on nothing a query's
+      literals or aggregates say, so every core over the same keys —
+      each date literal compiles its own — replays the one id array a
+      batch caches per key set (every global aggregate shares the zero
+      ids).  Bounded by the GROUP BY sets ever asked of the table.
+    - per core: the aux / rank-table caches (their keys are the core's
+      spec and slot positions), held WEAKLY: the kernel LRU
+      (`exec/kernels.py`) still decides how long a core lives.
+
+    Both doors reach it: `AggregateRelation.accumulate` adopts it for
+    `ctx.sql`, the serving megabatch for `Server.submit`."""
+
+    def __init__(self):
+        from datafusion_tpu.analysis import lockcheck
+
+        self._lock = lockcheck.make_lock("exec.shared_scan_state")
+        self._by_keys: dict = {}
+        self._by_core = weakref.WeakKeyDictionary()
+
+    def for_core(self, core, cols=None) -> dict:
+        """`cols` maps the core's column positions to the table's (a
+        projected scan); None where they are the same."""
+        from datafusion_tpu.analysis import lockcheck
+        from datafusion_tpu.exec.aggregate import GroupKeyEncoder
+
+        keys = tuple(
+            core.key_cols if cols is None
+            else (cols[k] for k in core.key_cols)
+        )
+        with self._lock:
+            ids = self._by_keys.get(keys)
+            if ids is None:
+                ids = self._by_keys[keys] = {
+                    "encoder": GroupKeyEncoder(len(keys)),
+                    "lock": lockcheck.make_lock("exec.shared_ids"),
+                }
+            caches = self._by_core.get(core)
+            if caches is None:
+                caches = self._by_core[core] = {"aux": {}, "str_aux": {}}
+        return {**ids, **caches}
+
+    def clear(self) -> None:
+        with self._lock:
+            self._by_keys.clear()
+            self._by_core.clear()
+
+
+def project_batches(batches, cols: Sequence[int]) -> Iterator[RecordBatch]:
+    """`batches` narrowed to `cols`, PRESERVING batch identity: each
+    projected batch is the `subset_view` cached on its parent batch, so
+    the device copies and group ids cached against a projection are
+    owned by the table's long-lived batch and found again by every
+    later query, whichever door it came through."""
+    from datafusion_tpu.exec.batch import PROJECTION_TAG, subset_view
+
+    cols = list(cols)
+    for b in batches:
+        yield subset_view(b, cols, tag=PROJECTION_TAG)
+
+
 class MemoryDataSource(DataSource):
-    """In-memory source over prebuilt RecordBatches (test/bench helper)."""
+    """In-memory source over prebuilt RecordBatches: a resident table.
+
+    Every scan hands out the SAME RecordBatch objects (`reusable_
+    batches`), and so does every projection of it (`with_projection`
+    yields the views cached on those batches, never fresh copies).
+    The source therefore owns, through its batches' caches, every
+    device copy made against them — columns (`device_inputs`), group
+    ids — for as long as the source holds the batches; and it owns the
+    `SharedScanState` that lets one query's group ids replay for the
+    next.  Nothing here is registered with `LEDGER.pin`: the
+    copies go when the source does (ROADMAP Queue 3)."""
 
     reusable_batches = True
 
     def __init__(self, schema: Schema, record_batches: list[RecordBatch]):
         self._schema = schema
         self._batches = list(record_batches)
+        self._shared = SharedScanState()
+        # a projection's columns as the table numbers them (None: the
+        # table itself)
+        self._table_cols: Optional[list] = None
 
     @property
     def schema(self) -> Schema:
@@ -205,16 +288,23 @@ class MemoryDataSource(DataSource):
         return iter(self._batches)
 
     def with_projection(self, projection: Sequence[int]) -> "DataSource":
-        out_schema = self._schema.select(list(projection))
-        projected = [
-            RecordBatch(
-                out_schema,
-                [b.data[i] for i in projection],
-                [b.validity[i] for i in projection],
-                [b.dicts[i] for i in projection],
-                num_rows=b.num_rows,
-                mask=b.mask,
-            )
-            for b in self._batches
-        ]
-        return MemoryDataSource(out_schema, projected)
+        cols = list(projection)
+        out = MemoryDataSource(
+            self._schema.select(cols),
+            list(project_batches(self._batches, cols)),
+        )
+        # one table, one set of encoders
+        out._shared = self._shared
+        out._table_cols = self._to_table(cols)
+        return out
+
+    def _to_table(self, cols):
+        """Column positions of this source as the table numbers them
+        (None: every column of this source)."""
+        mine = self._table_cols
+        if cols is None:
+            return mine
+        return list(cols) if mine is None else [mine[c] for c in cols]
+
+    def shared_state_for(self, core, cols=None) -> dict:
+        return self._shared.for_core(core, self._to_table(cols))

@@ -478,7 +478,6 @@ class MaterializedView:
 
     def _fold_incremental(self, source: AppendableSource,
                           deltas: Sequence[RecordBatch]) -> None:
-        from datafusion_tpu.exec.batch import device_inputs
         from datafusion_tpu.exec.expression import compute_aux_values
         from datafusion_tpu.exec.relation import device_scope
         from datafusion_tpu.utils.retry import device_call
@@ -500,9 +499,7 @@ class MaterializedView:
             aux = compute_aux_values(core.aux_specs, batch, agg._aux_cache)
             str_aux = agg._compute_str_aux(batch, core.slots)
             with device_scope(agg.device):
-                data, validity, mask = device_inputs(
-                    agg._device_view(batch, core), agg.device,
-                    core.wire_hints)
+                data, validity, mask = agg._device_inputs(batch, core)
             chunk.append((data, validity, tuple(aux),
                           np.int32(batch.num_rows), mask, ids, str_aux))
         # capacity picked AFTER the whole delta's keys are encoded

@@ -910,10 +910,17 @@ class PartitionedAggregateRelation(AggregateRelation):
                             if v is None
                             else stacker.pad(v, cap)
                         )
+                # the host-evaluated predicate folds into the shard's
+                # mask plane on the host: the round's stacked copies are
+                # this relation's own (the round cache), not the batches'
+                mask = self._query_mask(b)
+                if view.mask is not None:
+                    up = np.asarray(view.mask)
+                    mask = up if mask is None else up & mask
                 mask_shards.append(
                     stacker.fill(cap, bool, True)
-                    if view.mask is None
-                    else stacker.pad(view.mask, cap)
+                    if mask is None
+                    else stacker.pad(mask, cap)
                 )
                 for idx in self.key_cols:
                     if b.dicts[idx] is not None:

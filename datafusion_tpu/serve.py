@@ -181,10 +181,19 @@ class PinnedSource(DataSource):
         # here); invoked OUTSIDE self._lock, after ensure()/_drop()
         self.on_change = None
         self._lock = lockcheck.make_lock("serve.pin_source")
-        # per-core shared execution state (group-key encoders, aux
-        # caches) so ids/aux computed by one query replay for every
-        # later or concurrent one; strong core refs keep id() stable
-        self._shared: dict = {}
+        # cross-query execution state (group-key encoders, aux caches)
+        # so ids/aux computed by one query replay for every later or
+        # concurrent one.  An in-memory inner source already owns one
+        # over the SAME batches and is asked instead, so `ctx.sql` and
+        # `Server.submit` agree on ids and keep one id array per batch
+        from datafusion_tpu.exec.datasource import SharedScanState
+
+        if hasattr(inner, "shared_state_for"):
+            self._shared = inner._shared
+            self._state_for = inner.shared_state_for
+        else:
+            self._shared = SharedScanState()
+            self._state_for = self._shared.for_core
 
     @property
     def schema(self):
@@ -321,34 +330,20 @@ class PinnedSource(DataSource):
             return iter(list(res))
         return self.inner.batches()
 
-    def shared_state_for(self, core) -> dict:
-        """The cross-query execution state shared by every relation
-        compiled to `core` over this table: one append-only group-key
-        encoder (ids are stable, so per-batch id caches replay across
-        queries), shared aux/rank caches, and one lock serializing
-        encoder mutation across concurrently-executing relations."""
-        from datafusion_tpu.analysis import lockcheck
-        from datafusion_tpu.exec.aggregate import GroupKeyEncoder
-
-        with self._lock:
-            entry = self._shared.get(id(core))
-            if entry is None or entry["core"] is not core:
-                entry = self._shared[id(core)] = {
-                    "core": core,
-                    "encoder": GroupKeyEncoder(len(core.key_cols)),
-                    "aux": {},
-                    "str_aux": {},
-                    "lock": lockcheck.make_lock("serve.shared_ids"),
-                }
-            return entry
+    def shared_state_for(self, core, cols=None) -> dict:
+        """The cross-query execution state of the relations over this
+        table (`SharedScanState`); `cols` maps a projected scan's
+        column positions to the table's."""
+        return self._state_for(core, cols)
 
 
 class _PinnedProjection(DataSource):
     """Column projection over a PinnedSource that PRESERVES batch
-    identity: projected views are built with ``subset_view`` and cached
-    on the parent batches, so the device copies uploaded against a
-    projection survive re-scans and other queries — a fresh
-    ``MemoryDataSource``-style copy per query would orphan them."""
+    identity (``datasource.project_batches``, the code
+    ``MemoryDataSource.with_projection`` runs): projected views are
+    cached on the parent batches, so the device copies uploaded against
+    a projection survive re-scans and other queries, through either
+    door."""
 
     def __init__(self, parent: PinnedSource, cols: list):
         self.parent = parent
@@ -371,11 +366,13 @@ class _PinnedProjection(DataSource):
     def to_meta(self) -> dict:
         return self.parent.inner.with_projection(self.cols).to_meta()
 
-    def batches(self):
-        from datafusion_tpu.exec.batch import subset_view
+    def shared_state_for(self, core) -> dict:
+        return self.parent.shared_state_for(core, self.cols)
 
-        for b in self.parent.batches():
-            yield subset_view(b, self.cols, tag="pin_proj")
+    def batches(self):
+        from datafusion_tpu.exec.datasource import project_batches
+
+        return project_batches(self.parent.batches(), self.cols)
 
 
 def _host_bytes(batches) -> int:
@@ -385,16 +382,6 @@ def _host_bytes(batches) -> int:
             if isinstance(arr, np.ndarray):
                 total += arr.nbytes
     return total
-
-
-def _pin_of(rel) -> Optional[PinnedSource]:
-    """The PinnedSource behind a relation's scan, if any."""
-    ds = getattr(getattr(rel, "child", None), "datasource", None)
-    if isinstance(ds, _PinnedProjection):
-        return ds.parent
-    if isinstance(ds, PinnedSource):
-        return ds
-    return None
 
 
 class Server:
@@ -1174,20 +1161,6 @@ class Server:
             return None
         return (id(rel.core), child.table_name)
 
-    def _adopt_shared(self, rel) -> None:
-        """Swap a relation's per-query execution state for the pinned
-        table's cross-query one: the shared encoder keys the per-batch
-        group-id caches, so ids encoded (and uploaded) by ANY earlier
-        query replay for this one."""
-        pin = _pin_of(rel)
-        if pin is None or not pin.resident:
-            return
-        entry = pin.shared_state_for(rel.core)
-        rel.encoder = entry["encoder"]
-        rel._aux_cache = entry["aux"]
-        rel._str_aux_cache = entry["str_aux"]
-        rel._ids_lock = entry["lock"]
-
     def _run_megabatch(self, tickets: list[Ticket]) -> None:
         """ONE scan, ONE launch per batch group, N queries' states: the
         cross-query fused pass.  Preconditions (``_mega_key``): every
@@ -1206,7 +1179,6 @@ class Server:
         ticket's ``launch_share_s`` / ``demux_share_s`` record its
         share for the critical-path segments."""
         from datafusion_tpu.exec.aggregate import group_capacity
-        from datafusion_tpu.exec.batch import device_inputs
         from datafusion_tpu.exec.expression import compute_aux_values
         from datafusion_tpu.exec.fused import (
             bucket_group,
@@ -1235,14 +1207,11 @@ class Server:
             # placement decided here: megabatched states are device
             # accumulators, never host-split partials
             r._allow_host_split = False
-            self._adopt_shared(r)
+            r._adopt_source_state()
             if r is not leader:
                 # one encoder/caches for the whole group even when the
                 # table is not pinned (cold megabatch): ids must agree
-                r.encoder = leader.encoder
-                r._aux_cache = leader._aux_cache
-                r._str_aux_cache = leader._str_aux_cache
-                r._ids_lock = leader._ids_lock
+                r._share_state_of(leader)
 
         n_live = len(rels)
         n_q = bucket_group(n_live)
@@ -1304,10 +1273,7 @@ class Server:
                     ))
                     str_aux = leader._compute_str_aux(batch, core.slots)
                 with device_scope(device):
-                    data, validity, mask = device_inputs(
-                        leader._device_view(batch, core), device,
-                        core.wire_hints,
-                    )
+                    data, validity, mask = leader._device_inputs(batch, core)
                 chunk.append((data, validity, aux,
                               np.int32(batch.num_rows),
                               mask, ids, str_aux))
@@ -1406,8 +1372,6 @@ class Server:
         with METRICS.timer("serve.finish", qid=t.qid):
             try:
                 rel = t._rel
-                if "_injected_state" not in getattr(rel, "__dict__", {}):
-                    self._adopt_shared_if_aggregate(rel)
                 # the ticket's number rides to `collect_columns`' span
                 rel._query_id = t.qid
                 fin_t0 = time.monotonic()
@@ -1478,13 +1442,6 @@ class Server:
         }
         seg["other"] = max(wall - sum(seg.values()), 0.0)
         return seg
-
-    def _adopt_shared_if_aggregate(self, rel) -> None:
-        from datafusion_tpu.exec.aggregate import AggregateRelation
-
-        if (type(rel) is AggregateRelation
-                and rel._host_pred_expr is None):
-            self._adopt_shared(rel)
 
     # -- pinning -------------------------------------------------------
     def _ensure_resident(self, table: str,

@@ -1442,3 +1442,336 @@ class TestHostPartialsGrowth:
             assert ra[0] == rb[0] and ra[3] == rb[3]
             np.testing.assert_allclose(ra[1], rb[1], rtol=1e-12)
             np.testing.assert_allclose(ra[2], rb[2], rtol=1e-12)
+
+
+class TestResidentTableShipsOnlyItsMask:
+    """The `ctx.sql` door over a reusable source (the twin of
+    tests/test_serve.py::test_warm_pinned_table_skips_h2d_entirely):
+    device copies of columns and group ids are keyed by the table's
+    long-lived batch, so after the first query only a host-evaluated
+    predicate's bit-packed mask travels.  The accelerator lowering is
+    forced as in TestHostRouting, the wire as in TestWirePolicy."""
+
+    ROWS, BATCHES = 2048, 4
+    MASK_BYTES = ROWS // 8 * BATCHES  # one packed mask a batch
+    # names all seven columns, as TPC-H Q1 does: the scan has no projection
+    Q1 = ("SELECT flag, status, SUM(qty), SUM(price * (1 - disc)), "
+          "SUM(price * (1 - disc) * (1 + tax)), AVG(qty), COUNT(1) "
+          "FROM t{where} GROUP BY flag, status")
+    # the later two empty the ('N', 'F') group, whose rows all shipped
+    # in August 1998
+    LITERALS = ("1998-09-02", "1998-06-15", "1997-01-01")
+
+    @pytest.fixture
+    def accel(self, monkeypatch):
+        import datafusion_tpu.exec.kernels as kernels
+        import datafusion_tpu.exec.relation as relation
+
+        monkeypatch.setattr(relation, "_is_accelerator", lambda device: True)
+        monkeypatch.setenv("DATAFUSION_TPU_WIRE", "always")
+        # a fast link keeps every slot on the device for the streamed
+        # twin of the table (no link-aware host split)
+        monkeypatch.setenv("DATAFUSION_TPU_LINK_MBPS", "1e9")
+        saved = dict(kernels._REGISTRY)
+        kernels._REGISTRY.clear()
+        yield
+        kernels._REGISTRY.clear()
+        kernels._REGISTRY.update(saved)
+
+    @classmethod
+    def _batches(cls):
+        from datafusion_tpu.exec.batch import StringDictionary, make_host_batch
+
+        schema = Schema([
+            Field("flag", DataType.UTF8, False),
+            Field("status", DataType.UTF8, False),
+            Field("qty", DataType.FLOAT64, False),
+            Field("price", DataType.FLOAT64, False),
+            Field("disc", DataType.FLOAT64, True),
+            Field("tax", DataType.FLOAT64, False),
+            Field("shipdate", DataType.UTF8, False),
+        ])
+        rng = np.random.default_rng(26)
+        dicts = [StringDictionary() for _ in range(3)]
+        out = []
+        for _ in range(cls.BATCHES):
+            n = cls.ROWS
+            flag = rng.choice(["A", "N", "R"], n)
+            status = rng.choice(["F", "O"], n)
+            year = rng.integers(1992, 1999, n)
+            month = rng.integers(1, 13, n)
+            late = (flag == "N") & (status == "F")
+            year[late], month[late] = 1998, 8
+            date = [f"{y}-{m:02d}-{d:02d}" for y, m, d in zip(
+                year, month, rng.integers(1, 29, n))]
+            cols = [
+                dicts[0].encode(list(flag)), dicts[1].encode(list(status)),
+                rng.integers(1, 51, n).astype(np.float64),
+                np.round(rng.uniform(900, 105000, n), 2),
+                np.round(rng.uniform(0, 0.1, n), 2),
+                np.round(rng.uniform(0, 0.08, n), 2),
+                dicts[2].encode(date),
+            ]
+            valid = [None] * 7
+            valid[4] = rng.random(n) > 0.1
+            out.append(make_host_batch(
+                schema, cols, valid,
+                [dicts[0], dicts[1], None, None, None, None, dicts[2]]))
+        return schema, out
+
+    @classmethod
+    def _ctx(cls, reusable=True):
+        from datafusion_tpu.exec.batch import RecordBatch
+        from datafusion_tpu.exec.datasource import MemoryDataSource
+
+        class StreamSource(MemoryDataSource):
+            """As a file scan: new batch objects every scan."""
+
+            reusable_batches = False
+
+            def batches(self):
+                for b in self._batches:
+                    yield RecordBatch(b.schema, list(b.data), list(b.validity),
+                                      list(b.dicts), num_rows=b.num_rows)
+
+            def with_projection(self, projection):
+                base = super().with_projection(projection)
+                return StreamSource(base.schema, base._batches)
+
+        schema, batches = cls._batches()
+        c = ExecutionContext(result_cache=False)
+        c.register_datasource(
+            "t", (MemoryDataSource if reusable else StreamSource)(schema, batches))
+        return c
+
+    @classmethod
+    def _sql(cls, literal):
+        where = "" if literal is None else f" WHERE shipdate <= '{literal}'"
+        return cls.Q1.format(where=where)
+
+    @staticmethod
+    def _run(c, sql):
+        """(sorted rows, h2d.bytes moved, h2d.resident hits, misses)."""
+        from datafusion_tpu.exec.materialize import collect
+        from datafusion_tpu.utils.metrics import METRICS
+
+        names = ("h2d.bytes", "h2d.resident_hits", "h2d.resident_misses")
+        before = [METRICS.counts.get(n, 0) for n in names]
+        rows = sorted(collect(c.sql(sql)).to_rows())
+        return (rows, *(METRICS.counts.get(n, 0) - b
+                        for n, b in zip(names, before)))
+
+    @staticmethod
+    def _same(got, want):
+        assert len(got) == len(want)
+        for ra, rb in zip(got, want):
+            for va, vb in zip(ra, rb):
+                if isinstance(va, float):
+                    np.testing.assert_allclose(va, vb, rtol=1e-12)
+                else:
+                    assert va == vb
+
+    @pytest.mark.parametrize("predicate", [True, False])
+    def test_later_queries_ship_the_mask_alone(self, accel, predicate):
+        from datafusion_tpu.exec.aggregate import AggregateRelation
+
+        literals = self.LITERALS if predicate else (None,) * 3
+        c = self._ctx()
+        rel = c.sql(self._sql(literals[0]))
+        assert isinstance(rel, AggregateRelation)
+        assert (rel._host_pred_expr is not None) == predicate
+        moved = []
+        for i, lit in enumerate(literals):
+            rows, nbytes, hits, misses = self._run(c, self._sql(lit))
+            moved.append(nbytes)
+            # one count a batch a query: found on the batch, or shipped
+            assert (hits, misses) == ((0, self.BATCHES) if i == 0
+                                      else (self.BATCHES, 0))
+            # the unshared path: a table nobody has queried before
+            self._same(rows, self._run(self._ctx(), self._sql(lit))[0])
+            if lit == self.LITERALS[-1]:
+                assert ("N", "F") not in {r[:2] for r in rows}
+                assert len(rows) == 5
+        assert moved[0] > 4 * self.MASK_BYTES  # columns and the mask
+        assert moved[1:] == [self.MASK_BYTES if predicate else 0] * 2
+
+    def test_answers_match_the_predicate_in_the_core(self, accel):
+        from datafusion_tpu.exec.aggregate import force_core_predicate
+
+        shared, plain = self._ctx(), self._ctx()
+        for lit in self.LITERALS:
+            with force_core_predicate():
+                want = self._run(plain, self._sql(lit))[0]
+            self._same(self._run(shared, self._sql(lit))[0], want)
+
+    def test_projected_query_ships_nothing_the_second_time(self, accel):
+        c = self._ctx()
+        sql = "SELECT status, SUM(qty), MAX(tax) FROM t GROUP BY status"
+        first = self._run(c, sql)
+        assert first[1] > 0 and first[2:] == (0, self.BATCHES)
+        for _ in range(2):
+            again = self._run(c, sql)
+            assert again[0] == first[0]
+            assert again[1:] == (0, self.BATCHES, 0)
+        # every query's projection is the same view on the table's batch
+        from datafusion_tpu.exec.batch import PROJECTION_TAG
+
+        table = c.datasources["t"]
+        narrowed = table.with_projection([1, 2, 5])
+        for parent, view in zip(table.batches(), narrowed.batches()):
+            assert parent.cache[(PROJECTION_TAG, (1, 2, 5))] is view
+        # a projection of a projection is a view cached on that view
+        deeper = narrowed.with_projection([0, 2])  # status, tax
+        for view, inner in zip(narrowed.batches(), deeper.batches()):
+            assert view.cache[(PROJECTION_TAG, (0, 2))] is inner
+            assert [f.name for f in inner.schema.fields] == ["status", "tax"]
+        # ... and whatever the projection, one encoder per GROUP BY
+        # column set of the table: status is its column 1
+        enc = c.sql(sql)
+        enc._adopt_source_state()
+        by_status = table._shared._by_keys[(1,)]["encoder"]
+        assert enc.encoder is by_status
+        wide = c.sql("SELECT status, MIN(price) FROM t GROUP BY status")
+        wide._adopt_source_state()
+        assert wide.core is not enc.core and wide.encoder is by_status
+
+    @pytest.mark.parametrize("reusable", [False, True])
+    def test_one_put_compressed_call_per_batch_when_columns_ship(
+            self, accel, reusable, monkeypatch):
+        import datafusion_tpu.exec.batch as batch_mod
+
+        calls = []
+        real = batch_mod.put_compressed
+
+        def counting(host_arrays, *args, **kwargs):
+            calls.append(len(host_arrays))
+            return real(host_arrays, *args, **kwargs)
+
+        monkeypatch.setattr(batch_mod, "put_compressed", counting)
+        c = self._ctx(reusable=reusable)
+        # the used columns, disc's validity, the mask
+        width = len(c.sql(self._sql(self.LITERALS[0])).core.used_cols) + 1 + 1
+        for i, lit in enumerate(self.LITERALS):
+            del calls[:]
+            _, _, hits, misses = self._run(c, self._sql(lit))
+            if reusable and i > 0:
+                # the copies are on the batch: the mask travels alone
+                assert calls == [1] * self.BATCHES
+                assert (hits, misses) == (self.BATCHES, 0)
+            else:
+                # columns and mask in ONE call, one decode launch
+                assert calls == [width] * self.BATCHES
+                assert (hits, misses) == (0, self.BATCHES)
+
+    def test_resident_batch_cache_is_bounded(self, accel):
+        c = self._ctx()
+        shapes = [self._sql(lit) for lit in self.LITERALS] + [
+            self._sql(None),
+            "SELECT status, SUM(qty), MAX(tax) FROM t GROUP BY status",
+            "SELECT status, SUM(qty) FROM t WHERE tax < 0.05 GROUP BY status",
+        ]
+        def nodes(b):
+            """The batch and every view cached on it, at any depth."""
+            yield b
+            for v in list(b.cache.values()):
+                if hasattr(v, "cache"):
+                    yield from nodes(v)
+
+        def entries():
+            return [sum(len(n.cache) for n in nodes(b))
+                    for b in c.datasources["t"].batches()]
+
+        sizes = []
+        for i in range(20):
+            self._run(c, shapes[i % len(shapes)])
+            sizes.append(entries())
+        # every shape has run by the sixth query: nothing grows after
+        assert sizes[len(shapes):] == [sizes[len(shapes) - 1]] * (
+            20 - len(shapes))
+        for b in c.datasources["t"].batches():
+            keys = [k if isinstance(k, str) else k[0] for k in b.cache]
+            # on the table's own batch: one mask slot, one id slot for
+            # the one GROUP BY column set that scans it unprojected,
+            # one view per distinct column set
+            assert keys.count("agg_inputs") == 1
+            assert keys.count("group_ids") == 1
+            assert keys.count("agg_subset") == 1
+            assert keys.count("pin_proj") == 2
+            assert len(b.cache) <= 6  # + the aux pin where staging runs
+            for n in nodes(b):
+                inner = [k if isinstance(k, str) else k[0] for k in n.cache]
+                assert inner.count("agg_inputs") <= 1
+                # one id slot per GROUP BY column set: the two
+                # narrower shapes read one view through two cores and
+                # share it
+                assert inner.count("group_ids") <= 1
+
+    def test_literal_cores_share_one_id_array_and_are_not_pinned(self):
+        """With the predicate in the core (the CPU, the served path)
+        every date literal compiles its own core: the table keeps one
+        encoder and one id array a batch for all of them, and holds no
+        core alive past the kernel LRU."""
+        import gc
+
+        from datafusion_tpu.exec import kernels
+
+        c = self._ctx()
+        table = c.datasources["t"]
+        sizes, cores = [], set()
+        for day in range(1, 21):
+            rel = c.sql(self._sql(f"1998-08-{day:02d}"))
+            assert rel._host_pred_expr is None
+            cores.add(id(rel.core))
+            self._same(
+                self._run(c, self._sql(f"1998-08-{day:02d}"))[0],
+                self._run(self._ctx(), self._sql(f"1998-08-{day:02d}"))[0])
+            sizes.append([len(b.cache) for b in table.batches()])
+            del rel
+        assert len(cores) == 20
+        assert sizes[1:] == [sizes[0]] * 19
+        assert list(table._shared._by_keys) == [(0, 1)]
+        kernels._REGISTRY.clear()
+        gc.collect()
+        # the last query's core is still the pin of the batches' one
+        # `agg_inputs` slot
+        assert len(table._shared._by_core) <= 1
+
+    def test_concurrent_literals_get_their_own_answers(self, accel):
+        import sys
+        import threading
+
+        c = self._ctx()
+        want = {lit: self._run(self._ctx(), self._sql(lit))[0]
+                for lit in self.LITERALS[::2]}
+        self._run(c, self._sql(self.LITERALS[1]))  # the copies are there
+        clients = [lit for lit in want for _ in range(2)]
+        got, errors = [[] for _ in clients], []
+        start = threading.Barrier(len(clients))
+
+        def client(i, lit):
+            try:
+                start.wait(timeout=30)
+                for _ in range(4):
+                    got[i].append(self._run(c, self._sql(lit))[0])
+            except BaseException as e:  # noqa: BLE001 — reported below
+                errors.append(e)
+
+        threads = [threading.Thread(target=client, args=(i, lit))
+                   for i, lit in enumerate(clients)]
+        switch = sys.getswitchinterval()
+        sys.setswitchinterval(1e-4)  # the mask slot changes hands often
+        try:
+            for t in threads:
+                t.start()
+            for t in threads:
+                t.join(timeout=120)
+        finally:
+            sys.setswitchinterval(switch)
+        assert not any(t.is_alive() for t in threads)
+        assert not errors, errors
+        for lit, runs in zip(clients, got):
+            assert len(runs) == 4
+            for rows in runs:
+                self._same(rows, want[lit])
+        assert len(want[self.LITERALS[0]]) == 6
+        assert len(want[self.LITERALS[2]]) == 5

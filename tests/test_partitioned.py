@@ -105,11 +105,24 @@ class TestPartitionedAggregate:
         np.testing.assert_allclose(s, sum(prices), rtol=1e-9)
         assert mn == min(qtys) and mx == max(qtys)
 
-    def test_where_fused_into_partials(self, parts):
+    @pytest.mark.parametrize("host_predicate", [False, True])
+    def test_where_fused_into_partials(self, parts, host_predicate, monkeypatch):
         paths, _ = parts
         sql = "SELECT region, COUNT(price), SUM(price) FROM sales WHERE qty > 100 GROUP BY region"
-        got = _as_dict(_partitioned_ctx(paths).sql_collect(sql))
         want = _as_dict(_single_ctx(paths).sql_collect(sql))
+        if host_predicate:
+            # the accelerator lowering: the predicate runs on the host
+            # and folds into each shard's mask plane (`_query_mask`)
+            import datafusion_tpu.exec.kernels as kernels
+            import datafusion_tpu.exec.relation as relation
+
+            monkeypatch.setattr(relation, "_is_accelerator", lambda device: True)
+            monkeypatch.setattr(kernels, "_REGISTRY", {})
+        rel = _partitioned_ctx(paths).sql(sql)
+        assert (rel._host_pred_expr is not None) == host_predicate
+        from datafusion_tpu.exec.materialize import collect
+
+        got = _as_dict(collect(rel))
         assert set(got) == set(want)
         for k in want:
             np.testing.assert_allclose(
