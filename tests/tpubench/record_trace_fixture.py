@@ -1,11 +1,16 @@
-"""Records the small profiler trace the trace-reduction test reads
-(`tests/tpubench/data/tiny_v5e.xplane.pb`).  Run once on the chip:
+"""Records the small profiler traces the trace-reduction test reads
+(`tests/tpubench/data/`).  Run once on the chip:
 
-    python3 tests/tpubench/record_trace_fixture.py chiprun_out/tpubench/tiny_v5e.xplane.pb
+    python3 tests/tpubench/record_trace_fixture.py chiprun_out/tpubench/tiny_v5e_engine.xplane.pb
 
 Three requests of one jitted program (four matmul + tanh steps), each
 after a host pause with no device work, under the benchmark's own span
-names, so that busy time, idle gaps and their labels are all there.
+names; the second request's pause sits inside an engine stage timer
+(`METRICS.timer("pipeline.wait")`, the seam PR 24 made: a
+`dftpu.pipeline.wait` span nested in `tpubench.call.sql`).  Busy time, idle
+gaps and their labels of both prefixes are all there.
+`tiny_v5e.xplane.pb` is this script's recording from before the engine had
+spans (PR 22): the same three requests, the benchmark's span names only.
 """
 
 import os
@@ -19,6 +24,7 @@ def main(out_path: str) -> int:
     import jax
     import jax.numpy as jnp
 
+    from datafusion_tpu.utils.metrics import METRICS
     from tpubench import trace_reduce
 
     if jax.devices()[0].platform != "tpu":
@@ -42,10 +48,14 @@ def main(out_path: str) -> int:
     trace_dir = tempfile.mkdtemp(dir=os.path.dirname(os.path.abspath(out_path)))
     jax.profiler.start_trace(trace_dir, profiler_options=opts)
     with jax.profiler.TraceAnnotation(trace_reduce.WINDOW_SPAN):
-        for _ in range(3):
+        for i in range(3):
             with jax.profiler.TraceAnnotation("tpubench.request"):
                 with jax.profiler.TraceAnnotation("tpubench.call.sql"):
-                    time.sleep(0.004)
+                    if i == 1:
+                        with METRICS.timer("pipeline.wait"):
+                            time.sleep(0.004)
+                    else:
+                        time.sleep(0.004)
                 with jax.profiler.TraceAnnotation("tpubench.call.collect"):
                     step(x, w).block_until_ready()
             time.sleep(0.002)

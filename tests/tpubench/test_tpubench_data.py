@@ -2,6 +2,10 @@
 engine's reader gives back unchanged, and their oracles equal to the
 engine at ~1e4 rows."""
 
+import hashlib
+import os
+from types import SimpleNamespace
+
 import numpy as np
 import pytest
 
@@ -20,6 +24,16 @@ def _plain(col):
     return col[0] if isinstance(col, tuple) else col
 
 
+def _only_table(name):
+    """(table name, its schema) of a data set of one table."""
+    (table, schema), = SPEC.dataset(name).TABLES.items()
+    return table, schema
+
+
+def _columns(made, name):
+    return made[name]["tables"][_only_table(name)[0]]
+
+
 @pytest.fixture(scope="module")
 def made():
     return {n: SPEC.dataset(n).generate(5, ROWS, threads=2) for n in DATASETS}
@@ -31,7 +45,7 @@ def files(made, tmp_path_factory):
     out = {}
     for name in DATASETS:
         out[name] = str(tmp_path_factory.mktemp(name) / "table.parquet")
-        tdata.write_parquet(made[name]["columns"], out[name], ROW_GROUP)
+        tdata.write_parquet(_columns(made, name), out[name], ROW_GROUP)
     return out
 
 
@@ -44,7 +58,7 @@ def contexts(files):
     for name in DATASETS:
         ctx = ExecutionContext(device="cpu", batch_size=BATCH,
                                result_cache=False)
-        ctx.register_parquet(SPEC.dataset(name).TABLE, files[name])
+        ctx.register_parquet(_only_table(name)[0], files[name])
         out[name] = ctx
     return out
 
@@ -60,17 +74,19 @@ def _engine(contexts, name, queries, template, params):
 @pytest.mark.parametrize("name", DATASETS)
 def test_generator_is_a_function_of_the_seed_alone(made, name):
     ds = SPEC.dataset(name)
-    again = ds.generate(5, ROWS, threads=1)["columns"]
-    other = ds.generate(6, ROWS, threads=2)["columns"]
-    assert list(again) == list(ds.SCHEMA)
-    for col in ds.SCHEMA:
-        assert np.array_equal(_plain(made[name]["columns"][col]), _plain(again[col]))
-    assert any(not np.array_equal(_plain(made[name]["columns"][c]), _plain(other[c]))
-               for c in ds.SCHEMA)
+    table, schema = _only_table(name)
+    first = _columns(made, name)
+    again = ds.generate(5, ROWS, threads=1)["tables"]
+    other = ds.generate(6, ROWS, threads=2)["tables"][table]
+    assert list(again) == [table] and list(again[table]) == list(schema)
+    for col in schema:
+        assert np.array_equal(_plain(first[col]), _plain(again[table][col]))
+    assert any(not np.array_equal(_plain(first[c]), _plain(other[c]))
+               for c in schema)
 
 
 def test_lineitem_domains(made):
-    c = made["tpch_lineitem"]["columns"]
+    c = _columns(made, "tpch_lineitem")
     assert c["l_quantity"].min() >= 1 and c["l_quantity"].max() <= 50
     assert np.array_equal(c["l_quantity"], np.floor(c["l_quantity"]))
     assert set(np.rint(c["l_discount"] * 100).astype(int)) <= set(range(11))
@@ -85,7 +101,7 @@ def test_lineitem_domains(made):
 
 
 def test_h2o_domains(made):
-    c = made["h2o_g1"]["columns"]
+    c = _columns(made, "h2o_g1")
     assert len(c["id1"][1]) == 100 and c["id1"][1][0] == "id001"
     assert len(c["id3"][1]) == ROWS // 100 and c["id3"][1][0] == "id0000000001"
     assert c["id4"].min() >= 1 and c["id4"].max() <= 100
@@ -97,16 +113,16 @@ def test_h2o_domains(made):
 
 @pytest.mark.parametrize("name", DATASETS)
 def test_the_engines_reader_gives_the_generated_columns_back(made, contexts, name):
-    ds = SPEC.dataset(name)
-    scan = contexts[name].datasources[ds.TABLE]
-    assert scan.schema.names() == list(ds.SCHEMA)
-    got = {col: [] for col in ds.SCHEMA}
+    table, schema = _only_table(name)
+    scan = contexts[name].datasources[table]
+    assert scan.schema.names() == list(schema)
+    got = {col: [] for col in schema}
     for b in scan.batches():
-        for i, col in enumerate(ds.SCHEMA):
+        for i, col in enumerate(schema):
             part = b.data[i][: b.num_rows]
             got[col].append(b.dicts[i].decode(part) if b.dicts[i] is not None
                             else part)
-    for col, want in made[name]["columns"].items():
+    for col, want in _columns(made, name).items():
         if isinstance(want, tuple):
             want = np.asarray(want[1], object)[want[0]]
         assert np.array_equal(np.concatenate(got[col]), want), col
@@ -118,8 +134,8 @@ def test_a_resident_table_is_the_engines_reading_of_the_file(files, entry):
     benchmark cuts nothing itself."""
     from tpubench import entries
 
-    e = entries.ENTRIES[entry]("cpu", {}, "lineitem", entries.Spans(),
-                               files["tpch_lineitem"])
+    e = SPEC.entry(entry)("cpu", {}, {"lineitem": files["tpch_lineitem"]},
+                          entries.Spans())
     try:
         from datafusion_tpu.exec.datasource import ParquetDataSource
 
@@ -160,30 +176,138 @@ def test_h2o_oracle_equals_the_engine(contexts, made, question):
     assert made["h2o_g1"]["oracle"].check(question, {}, got) is not None
 
 
-def _file(ds, seed, rows, row_group, root):
-    made = tdata.prepare(ds, "tpch_lineitem", seed, rows, root, threads=2)
-    return made, tdata.parquet_file(made, row_group)
+def _files(ds, name, seed, rows, row_group, root):
+    made = tdata.prepare(ds, name, seed, rows, root, threads=2)
+    return (made, *tdata.parquet_files(made, row_group))
 
 
 def test_oracle_cubes_survive_the_file_cache(tmp_path):
     ds = SPEC.dataset("tpch_lineitem")
-    first, path = _file(ds, 9, ROWS, ROW_GROUP, str(tmp_path))
-    again, path2 = _file(ds, 9, ROWS, ROW_GROUP, str(tmp_path))
+    args = (ds, "tpch_lineitem", 9, ROWS, ROW_GROUP, str(tmp_path))
+    first, paths, rows = _files(*args)
+    again, paths2, rows2 = _files(*args)
     assert (first["cached"], again["cached"]) == (False, True)
-    assert path == path2 and path.startswith(tdata.data_dir(str(tmp_path)))
-    assert "columns" not in again
+    assert paths == paths2 and list(paths) == ["lineitem"]
+    assert paths["lineitem"].startswith(tdata.data_dir(str(tmp_path)))
+    # the row counts are the files' own: found again without the columns
+    assert rows == rows2 == {"lineitem": ROWS} and "tables" not in again
     p = {"year": 1995, "discount_pct": 4, "quantity": 25}
     assert first["oracle"].answer("q6", p) == again["oracle"].answer("q6", p)
     assert first["oracle"].answer("q1", {"delta": 75}) == \
         again["oracle"].answer("q1", {"delta": 75})
 
 
-def test_only_the_newest_files_are_kept(tmp_path):
-    import os
+class _SumOracle:
+    def __init__(self, total):
+        self.total = int(total)
 
-    ds = SPEC.dataset("tpch_lineitem")
+    def arrays(self):
+        return {"total": np.array(self.total)}
+
+    @classmethod
+    def from_arrays(cls, arrays):
+        return cls(arrays["total"])
+
+
+# a data set of two tables, the second a quarter of the first
+TWO_TABLES = SimpleNamespace(
+    TABLES={"big": {"k": "i64"}, "small": {"k": "i64", "v": "f64"}},
+    Oracle=_SumOracle,
+    generate=lambda seed, rows, threads: {
+        "tables": {"big": {"k": np.arange(rows) + seed},
+                   "small": {"k": np.arange(rows // 4),
+                             "v": np.full(rows // 4, 0.5)}},
+        "oracle": _SumOracle(rows + seed)})
+
+
+def test_a_data_set_of_two_tables_is_a_file_each_and_the_arrays_once(tmp_path):
+    import pyarrow.parquet as pq
+
+    args = (TWO_TABLES, "two", 3, 1_000, 400, str(tmp_path))
+    first, paths, rows = _files(*args)
+    again, paths2, rows2 = _files(*args)
+    assert (first["cached"], again["cached"]) == (False, True)
+    assert rows == rows2 == {"big": 1_000, "small": 250} and paths == paths2
+    assert sorted(os.listdir(tdata.data_dir(str(tmp_path)))) == [
+        "two_1000_seed3.big.parquet", "two_1000_seed3.npz",
+        "two_1000_seed3.small.parquet"]
+    assert pq.read_table(paths["small"]).column_names == ["k", "v"]
+    assert pq.read_metadata(paths["big"]).num_row_groups == 3
+    assert again["oracle"].total == first["oracle"].total == 1_003
+    # one table's file lost: the set is made anew, not half found
+    os.remove(paths["small"])
+    assert _files(*args)[0]["cached"] is False
+
+
+@pytest.mark.parametrize("ds,name,per_set", [
+    (SPEC.dataset("tpch_lineitem"), "tpch_lineitem", 2),
+    (TWO_TABLES, "two", 3),
+])
+def test_only_the_newest_sets_of_files_are_kept(tmp_path, ds, name, per_set):
     for seed in range(tdata.KEEP_FILES + 2):
-        _file(ds, seed, 1_000, 500, str(tmp_path))
+        _files(ds, name, seed, 1_000, 500, str(tmp_path))
     left = sorted(os.listdir(tdata.data_dir(str(tmp_path))))
-    assert len([f for f in left if f.endswith(".parquet")]) == tdata.KEEP_FILES
+    assert len(left) == tdata.KEEP_FILES * per_set
     assert len([f for f in left if f.endswith(".npz")]) == tdata.KEEP_FILES
+    assert {f.split("_seed")[1].split(".")[0] for f in left} == \
+        {str(s) for s in range(2, tdata.KEEP_FILES + 2)}
+
+
+def _sum(*arrays):
+    m = hashlib.sha256()
+    for a in arrays:
+        a = np.ascontiguousarray(a)
+        m.update(str(a.dtype).encode())
+        m.update(a.tobytes())
+    return m.hexdigest()[:16]
+
+
+# seed 7, 10,000 rows, as `generate` of the parent of PR 27 (f69e170) made
+# them: column -> checksum of its values (a string column: its int32 codes,
+# then its dictionary), and the oracle's answers
+AT_THE_PARENT = {
+    "tpch_lineitem": ({
+        "l_returnflag": "855b439860200df9", "l_linestatus": "a5f19041687863bd",
+        "l_quantity": "95491f290246fdfe", "l_extendedprice": "e1d1b6ef1e46aa64",
+        "l_discount": "69fa386859b4e1df", "l_tax": "3ec1b990fcf650d2",
+        "l_shipdate": "85d5c4c6f2d87fab",
+    }, {
+        "q1": "e1301a7097e59d05",
+        "q1 groups": [("A", "F", 2543), ("N", "F", 1166), ("N", "O", 3404),
+                      ("R", "F", 2531)],
+        "q6": [(373304.1944,)],
+    }),
+    "h2o_g1": ({
+        "id1": "c9005599992a7c45", "id2": "60177f0e71a2ca4d",
+        "id3": "0d844579709cdc4c", "id4": "356dfd591c2e2518",
+        "id5": "07e83984e4402d69", "id6": "b55200cd88c50061",
+        "v1": "f417622f45e4f18b", "v2": "fe6b0e8f1bceabe7",
+        "v3": "32f3af89f39aa6dc",
+    }, {
+        "q1": ("6cad046396d506e3", 100), "q2": ("69c71c1061df56de", 6329),
+        "q3": ("12ff57a03be505fc", 100), "q5": ("cdcdf85660ae4cd0", 100),
+    }),
+}
+
+
+@pytest.mark.parametrize("name", DATASETS)
+def test_the_same_seed_still_gives_the_parents_columns_and_answers(name):
+    """Moving a data set to `TABLES` changed no byte of what it makes."""
+    made = SPEC.dataset(name).generate(7, 10_000, threads=2)
+    want_cols, want = AT_THE_PARENT[name]
+    (table, cols), = made["tables"].items()
+    assert table == _only_table(name)[0]
+    got = {c: (_sum(v[0], np.asarray(v[1], dtype="S")) if isinstance(v, tuple)
+               else _sum(v)) for c, v in cols.items()}
+    assert got == want_cols
+    oracle = made["oracle"]
+    if name == "tpch_lineitem":
+        q1 = oracle.answer("q1", {"delta": 90})
+        assert _sum(np.array([r[2:] for r in q1], float)) == want["q1"]
+        assert [r[:2] + (r[-1],) for r in q1] == want["q1 groups"]
+        assert oracle.answer("q6", {"year": 1995, "discount_pct": 4,
+                                    "quantity": 25}) == want["q6"]
+    else:
+        for q, (checksum, groups) in want.items():
+            keys, vals = oracle.answer(q)
+            assert (_sum(*keys, *vals), len(keys[0])) == (checksum, groups)

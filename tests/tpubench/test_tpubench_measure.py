@@ -13,8 +13,12 @@ def _run(cell, seed, value, wall_s=10.0):
 
 @pytest.mark.parametrize("values,spread", [
     ([100, 100, 100], 0.0),
-    ([98, 100, 102], 0.02),  # quartiles 99 and 101 over the median 100
-    ([90, 100, 100, 100, 100, 130], 0.0),  # one outlier each side moves nothing
+    # quartiles as `statistics.quantiles` (what the driver reads): of three
+    # values the outer two, of six a quarter of the way to the outer ones
+    ([98, 100, 102], 0.04),
+    ([90, 100, 100, 100, 100, 130], 0.1),  # 97.5 and 107.5 over 100
+    ([100, 100, 100, 100, 100, 100, 100, 130], 0.0),
+    ([100], 0.0),
 ])
 def test_spread_is_the_quartile_distance_over_the_median(values, spread):
     assert measure.quartile_spread(values) == pytest.approx(spread)
@@ -27,7 +31,7 @@ def test_summary_gives_each_set_and_the_shift_between_them():
     m = measure.summarise(runs)["rows_per_s"]
     assert [s["median"] for s in m["sets"]] == [100, 105]
     assert [s["n"] for s in m["sets"]] == [3, 3]
-    assert m["sets"][0]["spread"] == pytest.approx(0.02)
+    assert m["sets"][0]["spread"] == pytest.approx(0.04)
     assert m["shift"] == pytest.approx(0.05)
 
 
@@ -46,7 +50,8 @@ def test_no_run_is_started_that_the_budget_cannot_hold(monkeypatch, tmp_path, ca
         root=str(tmp_path), bench={"run_seconds": 51}, cell=lambda name: {}))
     code = measure.main(["--cells", "q6_sf10_streams", "--sets", "2", "--runs", "3",
                          "--first-seed", "21", "--budget-s", "450"])
-    # runs end at 100 .. 400 s; a fifth would end at 500
-    assert code == 0 and seeds == [21, 22, 23, 24]
+    # runs end at 100 .. 400 s; a fifth would end at 500.  The second set
+    # starts again from the first seed
+    assert code == 0 and seeds == [21, 22, 23, 21]
     out = capsys.readouterr().out
     assert out.count("no time left") == 2 and "(n=3)" in out and "(n=1)" in out

@@ -23,7 +23,8 @@ def test_an_unknown_device_kind_is_an_error_not_a_default(kind):
         peaks.roofline_share(1e9, 1.0, kind)
 
 
-# (data set, query dir, template) -> the resident columns it must read
+# (data set, template) -> (rows of its one table, bytes a row of the
+# resident columns it must read)
 CASES = {
     ("tpch_lineitem", "q1"): (60_000_000, 3 * 4 + 4 * 8),  # all seven
     ("tpch_lineitem", "q6"): (60_000_000, 4 + 3 * 8),  # shipdate, price, discount, quantity
@@ -38,14 +39,38 @@ CASES = {
 def test_required_bytes_per_template(dataset, template):
     rows, per_row = CASES[(dataset, template)]
     sql = SPEC.query(dataset, template)
-    assert peaks.required_bytes(sql, SPEC.dataset(dataset).SCHEMA, rows) == \
-        rows * per_row
+    tables = SPEC.dataset(dataset).TABLES
+    (table, schema), = tables.items()
+    assert peaks.required_bytes(sql, schema, rows) == rows * per_row
+    # a query over a data set of one table scans that table, once
+    assert peaks.query_scan(sql, tables, {table: rows}) == (rows, rows * per_row)
 
 
 def test_a_column_is_referenced_by_name_not_by_prefix():
     schema = {"id1": "str", "id10": "i64", "v1": "f64"}
-    assert peaks.referenced_columns("SELECT id10, SUM(v1) FROM x GROUP BY id10",
-                                    schema) == ["id10", "v1"]
+    assert peaks.named_in("SELECT id10, SUM(v1) FROM x GROUP BY id10",
+                          schema) == ["id10", "v1"]
+
+
+TWO = {"fact": {"f_key": "i64", "f_v": "f64", "f_note": "str"},
+       "dim": {"d_key": "i64", "d_grp": "str"},
+       "dim2": {"d_key": "i64"}}
+ROWS = {"fact": 4_000, "dim": 1_000, "dim2": 10}
+
+
+@pytest.mark.parametrize("sql,rows,nbytes", [
+    # both tables, each at its own count and with its own columns
+    ("SELECT d_grp, SUM(f_v) FROM fact JOIN dim ON fact.f_key = dim.d_key "
+     "GROUP BY d_grp", 5_000, 4_000 * 16 + 1_000 * 12),
+    # one table named: the other is not scanned, though it has the column
+    ("SELECT COUNT(1) FROM dim WHERE d_key > 5", 1_000, 1_000 * 8),
+    # a table is named as a whole word, not as a prefix of another
+    ("SELECT COUNT(1) FROM dim2", 10, 0),
+    ("SELECT 1", 0, 0),
+])
+def test_rows_and_bytes_are_summed_over_the_tables_a_query_names(
+        sql, rows, nbytes):
+    assert peaks.query_scan(sql, TWO, ROWS) == (rows, nbytes)
 
 
 def test_roofline_share_is_least_time_over_time_taken():

@@ -7,8 +7,10 @@ import re
 
 import pytest
 
-from bench_helpers import REPO, copy_benchmark, edit_json
-from tpubench.spec import Spec, SpecError
+from bench_helpers import (DEVICE_GUARD, REPO, copy_benchmark, edit_json,
+                           snapshot_files)
+from tpubench import entries, peaks
+from tpubench.spec import Spec, SpecError, device_guard
 
 NAME = re.compile(r"^[A-Za-z0-9][A-Za-z0-9_.-]{0,63}$")
 LAYER = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.-]{0,63}$")
@@ -73,11 +75,19 @@ def test_every_name_of_a_cell_resolves_to_a_file(spec, cell):
     config = spec.config(w["config"])
     mix = spec.traffic(w["traffic"])
     dataset = spec.dataset(config["dataset"])
-    for attr in ("TABLE", "SCHEMA", "generate", "bind", "Oracle"):
+    for attr in ("TABLES", "generate", "bind", "Oracle"):
         assert hasattr(dataset, attr)
+    for schema in dataset.TABLES.values():
+        assert set(schema.values()) <= set(peaks.RESIDENT_BYTES)
     for t in mix["templates"]:
         text = spec.query(config["queries"], t["name"])
-        assert dataset.TABLE in text
+        assert peaks.named_in(text, dataset.TABLES)
+    assert issubclass(spec.entry(mix["entry"]), entries.Entry)
+    # the configuration says how a run shows the device did the work
+    assert device_guard(config) == (
+        "device.launches",
+        ["aggregate.host_routed_slots", "sort.host_routed_runs"])
+    assert isinstance(config["guarantees"]["device"]["sentence"], str)
     e2e = {m["name"] for m in spec.metrics_of(cell, "end_to_end")}
     assert "setup_s" in e2e and len(e2e) >= 2
     layers = spec.metrics_of(cell, "per_layer")
@@ -117,24 +127,30 @@ def test_unknown_names_are_errors(spec):
         spec.metric_reader("no_such_metric")
     with pytest.raises(SpecError):
         spec.query("tpch_lineitem", "q99")
+    with pytest.raises(SpecError):
+        spec.entry("no_such_entry")
 
 
 def test_new_files_are_found_without_editing_any(tmp_path):
     """A cell, a configuration, a traffic mix, a query template, a data
-    set and a metric, each added as files plus a BENCHMARK.json entry."""
+    set, an entry point and a metric, each added as files plus a
+    BENCHMARK.json entry."""
     root = copy_benchmark(tmp_path)
     bench = os.path.join(root, "tpubench")
-    before = {}
-    for d, _, fs in os.walk(bench):
-        for f in fs:
-            p = os.path.join(d, f)
-            before[p] = open(p, "rb").read()
+    before = snapshot_files(root)
 
     with open(os.path.join(bench, "datasets", "h2o_g1_wide.py"), "w") as f:
         f.write("from tpubench.spec import Spec\n"
                 "_base = Spec().dataset('h2o_g1')\n"
-                "TABLE, SCHEMA, Oracle = _base.TABLE, _base.SCHEMA, _base.Oracle\n"
+                "TABLES, Oracle = _base.TABLES, _base.Oracle\n"
                 "generate, bind = _base.generate, _base.bind\n")
+    with open(os.path.join(bench, "entries", "sql_logged.py"), "w") as f:
+        f.write("from tpubench.entries.sql import SqlEntry\n\n\n"
+                "class SqlLoggedEntry(SqlEntry):\n"
+                "    def query(self, q, req):\n"
+                "        print(q.sql)\n"
+                "        return super().query(q, req)\n\n\n"
+                "ENTRY = SqlLoggedEntry\n")
     os.makedirs(os.path.join(bench, "queries", "h2o_wide"))
     with open(os.path.join(bench, "queries", "h2o_wide", "q2.sql"), "w") as f:
         f.write("SELECT id1, id2, SUM(v1) FROM x GROUP BY id1, id2\n")
@@ -142,9 +158,9 @@ def test_new_files_are_found_without_editing_any(tmp_path):
         json.dump({"name": "h2o_wide", "dataset": "h2o_g1_wide",
                    "queries": "h2o_wide", "rows": 5000, "row_group_rows": 2000,
                    "engine": {"device": "tpu", "result_cache": False},
-                   "reduced": {}}, f)
+                   "reduced": {}, "guarantees": {"device": DEVICE_GUARD}}, f)
     with open(os.path.join(bench, "traffic", "q2_closed2.json"), "w") as f:
-        json.dump({"entry": "sql", "loop": {"kind": "closed", "clients": 2},
+        json.dump({"entry": "sql_logged", "loop": {"kind": "closed", "clients": 2},
                    "request": "query", "trace_seconds": 1,
                    "templates": [{"name": "q2", "params": {}}]}, f)
     with open(os.path.join(bench, "metrics", "groups_per_query.py"), "w") as f:
@@ -166,7 +182,10 @@ def test_new_files_are_found_without_editing_any(tmp_path):
 
     spec = Spec(root)
     cfg = spec.config(spec.cell("h2o_wide.q2")["config"])
-    assert spec.dataset(cfg["dataset"]).TABLE == "x"
+    assert list(spec.dataset(cfg["dataset"]).TABLES) == ["x"]
+    entry = spec.entry(spec.traffic("q2_closed2")["entry"])
+    assert entry.__name__ == "SqlLoggedEntry"
+    assert issubclass(entry, entries.Entry)
     assert "GROUP BY id1, id2" in spec.query(cfg["queries"], "q2")
     assert spec.traffic("q2_closed2")["loop"]["clients"] == 2
     assert spec.metric_reader("groups_per_query")(None) == 7.0
@@ -174,5 +193,5 @@ def test_new_files_are_found_without_editing_any(tmp_path):
     assert "groups_per_query" in names and "h2o_q1_ms" not in names
     assert "groups_per_query" not in {
         m["name"] for m in spec.metrics_of("q1_sf10_warm", "per_layer")}
-    for p, content in before.items():
-        assert open(p, "rb").read() == content
+    after = snapshot_files(root)
+    assert all(after[p] == content for p, content in before.items())

@@ -1,6 +1,7 @@
 """The reduction from a profiler trace to busy, idle, top operations and
-labelled idle gaps: on hand-made events with known answers, and on a trace
-recorded on a TPU v5e (tests/tpubench/data/, made by record_trace_fixture.py)."""
+labelled idle gaps: on hand-made events with known answers, and on two traces
+recorded on a TPU v5e (tests/tpubench/data/, made by record_trace_fixture.py):
+one under the benchmark's span names only, one with an engine span inside."""
 
 import os
 
@@ -72,6 +73,36 @@ def test_segments_are_labelled_by_the_innermost_open_span():
         ("tpubench.request", 5.5, 6.0), ("no_span", 6.0, 9.0),
         ("tpubench.request", 9.0, 10.0)]
     assert sum(b - a for _, a, b in segs) == pytest.approx(10.0)
+
+
+def test_an_engine_span_inside_a_benchmark_span_takes_the_label():
+    """The engine's stage timers (`dftpu.*`) nest under the benchmark's
+    calls on the same clock: the innermost open span labels the segment,
+    whichever prefix it has, and every moment is still counted once."""
+    spans = E([("tpubench.window", 0.0, 10.0), ("tpubench.request", 1.0, 9.0),
+               ("tpubench.call.collect", 2.0, 8.0), ("dftpu.query", 2.5, 7.5),
+               ("dftpu.pipeline.wait", 3.0, 5.0),
+               ("dftpu.pipeline.stage", 3.5, 4.0),  # the stager's thread
+               ("dftpu.device.dispatch", 6.0, 7.0)])
+    segs = [(n, a, b) for n, a, b in tr.label_segments(spans, 0.0, 10.0)]
+    assert segs == [
+        ("no_span", 0.0, 1.0), ("tpubench.request", 1.0, 2.0),
+        ("tpubench.call.collect", 2.0, 2.5), ("dftpu.query", 2.5, 3.0),
+        ("dftpu.pipeline.wait", 3.0, 3.5), ("dftpu.pipeline.stage", 3.5, 4.0),
+        ("dftpu.pipeline.wait", 4.0, 5.0), ("dftpu.query", 5.0, 6.0),
+        ("dftpu.device.dispatch", 6.0, 7.0), ("dftpu.query", 7.0, 7.5),
+        ("tpubench.call.collect", 7.5, 8.0), ("tpubench.request", 8.0, 9.0),
+        ("no_span", 9.0, 10.0)]
+    assert sum(b - a for _, a, b in segs) == pytest.approx(10.0)
+    # idle seconds by label add up to the window's idle seconds
+    ops = E([("fusion.1", 3.2, 3.7), ("fusion.1", 6.5, 8.5)])
+    r = tr.reduce(tr.Trace({0: ops}, {}, spans, []))
+    gaps = dict(map(tuple, r["idle_gaps"]))
+    assert gaps["dftpu.pipeline.wait"] == pytest.approx(0.5 + 1.0 - 0.3)
+    assert gaps["dftpu.pipeline.stage"] == pytest.approx(0.5 - 0.2)
+    assert gaps["dftpu.device.dispatch"] == pytest.approx(0.5)
+    assert sum(gaps.values()) == pytest.approx(r["window_s"] - r["busy_s"])
+    assert r["busy_s"] == pytest.approx(2.5)
 
 
 def test_spans_of_several_threads_never_count_a_moment_twice():
@@ -177,6 +208,47 @@ def test_the_recorded_trace_reduces_to_known_numbers(recorded):
     assert gaps["tpubench.call.collect"] == pytest.approx(0.003776329, rel=1e-6)
     assert sum(gaps.values()) + r["busy_s"] == pytest.approx(r["window_s"], rel=1e-9)
     assert r["longest_gap_s"] == pytest.approx(0.008340351, rel=1e-6)
+
+
+# -- the same three requests recorded again (PR 27) with the second request's
+# pause inside the engine's stage timer `pipeline.wait`: a `dftpu.` span nested
+# in `tpubench.call.sql`, from the engine's own seam
+
+@pytest.fixture(scope="module")
+def recorded_engine():
+    return tr.load(os.path.join(HERE, "data", "tiny_v5e_engine.xplane.pb"))
+
+
+def test_the_engines_span_is_kept_beside_the_benchmarks(recorded_engine):
+    assert recorded_engine.spans.names == ["tpubench.window"] + [
+        "tpubench.request", "tpubench.call.sql", "tpubench.call.collect",
+        "tpubench.request", "tpubench.call.sql", "dftpu.pipeline.wait",
+        "tpubench.call.collect",
+        "tpubench.request", "tpubench.call.sql", "tpubench.call.collect"]
+    assert ("/device:TPU:0", "XLA Ops", 18) in recorded_engine.lines
+
+
+def test_an_idle_gap_is_labelled_by_the_engine_span_that_covers_it(
+        recorded_engine):
+    r = tr.reduce(recorded_engine)
+    assert r["window_s"] == pytest.approx(0.024975848, rel=1e-6)
+    assert r["busy_s"] == pytest.approx(0.001085888, rel=1e-6)
+    # busy and idle read as on the earlier recording: the same program, the
+    # same pauses (0.95747 there)
+    assert 1 - r["busy_s"] / r["window_s"] == pytest.approx(0.95652, rel=1e-4)
+    gaps = dict(map(tuple, r["idle_gaps"]))
+    assert list(gaps) == ["no_span", "tpubench.call.sql", "dftpu.pipeline.wait",
+                          "tpubench.call.collect", "tpubench.request"]
+    # one of the three ~4 ms pauses moved from `call.sql` to the engine's span
+    assert gaps["dftpu.pipeline.wait"] == pytest.approx(0.00398235, rel=1e-6)
+    assert gaps["tpubench.call.sql"] == pytest.approx(0.00794408, rel=1e-6)
+    assert gaps["tpubench.call.sql"] + gaps["dftpu.pipeline.wait"] == \
+        pytest.approx(3 * 0.004, rel=0.02)
+    # the labelled seconds still add up to the window's idle seconds
+    assert sum(gaps.values()) == pytest.approx(r["window_s"] - r["busy_s"],
+                                               rel=1e-9)
+    ops = dict(map(tuple, r["device_ops"]))
+    assert sum(ops.values()) == pytest.approx(r["busy_s"], rel=1e-6)
 
 
 def test_describe_lists_lines_and_top_ops(recorded):

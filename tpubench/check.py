@@ -14,7 +14,21 @@ import numpy as np
 RTOL = 1e-9
 
 
-def diff_rows(got: list, want: list, rtol: float = RTOL) -> "str | None":
+class Worst:
+    """The widest relative gap |got - oracle| / |oracle| among the float
+    values that the comparisons handed this object saw: the number a run
+    reports beside `RTOL` (an oracle value of 0 has to be met exactly and
+    gives no gap)."""
+
+    def __init__(self):
+        self.gap = 0.0
+
+    def see(self, gap: float) -> None:
+        self.gap = max(self.gap, float(gap))
+
+
+def diff_rows(got: list, want: list, rtol: float = RTOL,
+              worst: "Worst | None" = None) -> "str | None":
     """Compare two small lists of row tuples, in any order.  None where
     they agree, else the first difference."""
     got, want = sorted(got), sorted(want)
@@ -25,6 +39,8 @@ def diff_rows(got: list, want: list, rtol: float = RTOL) -> "str | None":
             return f"row widths differ: {g} vs {w}"
         for gv, wv in zip(g, w):
             if isinstance(gv, float) or isinstance(wv, float):
+                if worst is not None and gv is not None and wv:
+                    worst.see(abs(gv - wv) / abs(wv))
                 if gv is None or not (np.isfinite(gv)
                                       and abs(gv - wv) <= rtol * abs(wv)):
                     return f"{gv!r} vs oracle {wv!r} in {g} vs {w}"
@@ -34,7 +50,8 @@ def diff_rows(got: list, want: list, rtol: float = RTOL) -> "str | None":
 
 
 def diff_columns(got_keys: list, got_vals: list, want_keys: list,
-                 want_vals: list, rtol: float = RTOL) -> "str | None":
+                 want_vals: list, rtol: float = RTOL,
+                 worst: "Worst | None" = None) -> "str | None":
     """The same comparison on whole columns, for results of many rows.
     Keys are integer arrays (a data set turns its string keys into
     codes first); both sides are put in key order and compared."""
@@ -53,7 +70,10 @@ def diff_columns(got_keys: list, got_vals: list, want_keys: list,
     for i, (g, w) in enumerate(zip(got_vals, want_vals)):
         g, w = np.asarray(g)[g_ord], np.asarray(w)[w_ord]
         if np.issubdtype(w.dtype, np.floating):
-            bad = ~(np.isfinite(g) & (np.abs(g - w) <= rtol * np.abs(w)))
+            gap, size = np.abs(g - w), np.abs(w)
+            if worst is not None and size.any():
+                worst.see(np.max(gap[size > 0] / size[size > 0]))
+            bad = ~(np.isfinite(g) & (gap <= rtol * size))
         else:
             bad = g.astype(np.int64) != w.astype(np.int64)
         if bad.any():

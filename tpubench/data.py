@@ -1,21 +1,22 @@
-"""From a data set's columns to the file the engine is given.
+"""From a data set's tables to the files the engine is given.
 
-Every cell starts from one Parquet file: a cold cell reads it in every
-request, a resident cell has the engine's own reader turn it into the
-table it keeps (`entries.SqlEntry`), so nothing here imitates the
-reader.  The file is written once per (data set, rows, seed) under the
+Every cell starts from one Parquet file per table: a cold cell reads them
+in every request, a resident cell has the engine's own reader turn each
+into the table it keeps (`entries/sql.py`), so nothing here imitates the
+reader.  The files are written once per (data set, rows, seed) under the
 git-ignored `test/data/bench/tpubench/`, with the oracle's cubes beside
-it where the oracle has any, and found again by later runs of that seed;
-the newest `KEEP_FILES` are kept.
+them (once per data set) where the oracle has any, and found again by
+later runs of that seed; the newest `KEEP_FILES` sets are kept.
 """
 
 from __future__ import annotations
 
 import os
+import re
 
 import numpy as np
 
-KEEP_FILES = 4  # Parquet files kept per data set (SF-10 is ~0.6 GB each)
+KEEP_FILES = 4  # sets of files kept per data set (SF-10 lineitem is ~0.6 GB)
 
 
 def data_dir(root: str) -> str:
@@ -55,48 +56,60 @@ def write_parquet(columns: dict, path: str, row_group_rows: int) -> None:
 
 
 def _prune(directory: str, prefix: str, keep: int) -> None:
-    files = sorted(
-        (f for f in os.listdir(directory)
-         if f.startswith(prefix) and f.endswith(".parquet")),
-        key=lambda f: os.path.getmtime(os.path.join(directory, f)),
-    )
-    for f in files[:-keep]:
-        for path in (os.path.join(directory, f),
-                     os.path.join(directory, f[:-len(".parquet")] + ".npz")):
-            if os.path.exists(path):
-                os.remove(path)
+    """Keep the `keep` newest sets of `<prefix>_seed<n>.*` files: a set is
+    one seed's table files and its oracle's arrays."""
+    sets: dict = {}  # "<prefix>_seed<n>" -> its files
+    for f in os.listdir(directory):
+        m = re.match(re.escape(prefix) + r"_seed\d+(?=\.)", f)
+        if m:
+            sets.setdefault(m.group(), []).append(os.path.join(directory, f))
+    for stem in sorted(sets, key=lambda s: max(map(os.path.getmtime, sets[s]))
+                       )[:-keep]:
+        for path in sets[stem]:
+            os.remove(path)
 
 
 def prepare(dataset, name: str, seed: int, rows: int, root: str,
             threads: int) -> dict:
-    """{"path", "columns", "oracle", "cached": False}, made from the seed;
-    or, where the file of this (data set, rows, seed) and its oracle's
-    cubes are there already, {"path", "oracle", "cached": True}.  numpy
-    only, so that it can run on a thread; `parquet_file` does the writing."""
+    """{"stem", "paths", "tables", "oracle", "cached": False}, made from
+    the seed with `rows` rows in the data set's first table; or, where the
+    files of this (data set, rows, seed) and its oracle's arrays are there
+    already, {"stem", "paths", "oracle", "cached": True}.  `paths` is
+    {table: file}, each `<stem>.<table>.parquet`; the arrays are
+    `<stem>.npz`.  numpy only, so that it can run on a thread;
+    `parquet_files` does the writing."""
     directory = data_dir(root)
     os.makedirs(directory, exist_ok=True)
-    path = os.path.join(directory, f"{name}_{rows}_seed{seed}.parquet")
-    cubes = path[:-len(".parquet")] + ".npz"
+    stem = os.path.join(directory, f"{name}_{rows}_seed{seed}")
+    paths = {table: f"{stem}.{table}.parquet" for table in dataset.TABLES}
     restore = getattr(dataset.Oracle, "from_arrays", None)
-    if restore and os.path.exists(path) and os.path.exists(cubes):
-        os.utime(path)  # newest: the last to be pruned
-        with np.load(cubes) as z:
-            return {"path": path, "oracle": restore(dict(z)), "cached": True}
-    return {"path": path, "cached": False,
+    if restore and all(map(os.path.exists, [stem + ".npz", *paths.values()])):
+        for path in paths.values():
+            os.utime(path)  # newest: the last to be pruned
+        with np.load(stem + ".npz") as z:
+            return {"stem": stem, "paths": paths, "oracle": restore(dict(z)),
+                    "cached": True}
+    return {"stem": stem, "paths": paths, "cached": False,
             **dataset.generate(seed, rows, threads)}
 
 
-def parquet_file(made: dict, row_group_rows: int) -> str:
-    """The path of the run's file, written now unless `prepare` found
-    it.  Call it on a thread that lives as long as the process: pyarrow's
-    native state does not survive the death of a thread that used it
+def parquet_files(made: dict, row_group_rows: int) -> tuple:
+    """({table: path}, {table: rows}) of the run's files, written now
+    unless `prepare` found them; the row counts are the files' own, so a
+    run that found them says the same without the columns.  Call it on a
+    thread that lives as long as the process: pyarrow's native state does
+    not survive the death of a thread that used it
     (`datafusion_tpu/io/io_thread.py`)."""
-    path = made["path"]
-    if made["cached"]:
-        return path
-    write_parquet(made.pop("columns"), path, row_group_rows)
-    if hasattr(made["oracle"], "arrays"):
-        np.savez(path[:-len(".parquet")] + ".npz", **made["oracle"].arrays())
-    _prune(os.path.dirname(path), os.path.basename(path).split("_seed")[0],
-           KEEP_FILES)
-    return path
+    import pyarrow.parquet as pq
+
+    paths = made["paths"]
+    if not made["cached"]:
+        tables = made.pop("tables")
+        for table, path in paths.items():
+            write_parquet(tables[table], path, row_group_rows)
+        stem = made["stem"]
+        if hasattr(made["oracle"], "arrays"):
+            np.savez(stem + ".npz", **made["oracle"].arrays())
+        _prune(os.path.dirname(stem),
+               os.path.basename(stem).split("_seed")[0], KEEP_FILES)
+    return paths, {t: pq.read_metadata(p).num_rows for t, p in paths.items()}

@@ -21,12 +21,12 @@ from concurrent.futures import ThreadPoolExecutor
 
 import numpy as np
 
-TABLE = "x"
-SCHEMA = {
+TABLES = {"x": {
     "id1": "str", "id2": "str", "id3": "str",
     "id4": "i64", "id5": "i64", "id6": "i64",
     "v1": "i64", "v2": "i64", "v3": "f64",
-}
+}}
+KINDS = TABLES["x"]
 CHUNK = 1_000_000
 K = 100
 
@@ -59,11 +59,11 @@ def _chunk(seed: int, index: int, n: int, rows: int) -> dict:
 
 
 def generate(seed: int, rows: int, threads: int = 8) -> dict:
-    """{"columns": {name: ndarray | (int32 codes, dictionary values)},
-    "oracle": Oracle}; string code c stands for "id%0Nd" % (c + 1)."""
+    """{"tables": {"x": {column: ndarray | (int32 codes, dictionary
+    values)}}, "oracle": Oracle}; string code c stands for "id%0Nd" % (c + 1)."""
     starts = range(0, rows, CHUNK)
     probe = _chunk(seed, 0, 1, rows)
-    cols = {name: np.empty(rows, probe[name].dtype) for name in SCHEMA}
+    cols = {name: np.empty(rows, probe[name].dtype) for name in KINDS}
 
     def work(i):
         lo = starts[i]
@@ -78,7 +78,7 @@ def generate(seed: int, rows: int, threads: int = 8) -> dict:
                       ("id3", "id%010d")):
         cols[name] = (cols[name],
                       tuple(fmt % (i + 1) for i in range(dom[name])))
-    return {"columns": cols, "oracle": oracle}
+    return {"tables": {"x": cols}, "oracle": oracle}
 
 
 def bind(template: str, params: dict) -> dict:
@@ -108,7 +108,7 @@ class Oracle:
 
     def _zero_based(self, name: str) -> np.ndarray:
         col = self.c[name].astype(np.int64)
-        return col if SCHEMA[name] == "str" else col - 1
+        return col if KINDS[name] == "str" else col - 1
 
     def answer(self, template: str, params: dict = None) -> tuple:
         """(key columns as 0-based codes, value columns), one row per
@@ -131,7 +131,7 @@ class Oracle:
             total = np.bincount(gid, weights=self.c[col], minlength=space)[pick]
             if fn == "mean":
                 vals.append(total / count[pick])
-            elif SCHEMA[col] == "i64":
+            elif KINDS[col] == "i64":
                 vals.append(np.rint(total).astype(np.int64))
             else:
                 vals.append(total)
@@ -139,9 +139,11 @@ class Oracle:
         self._answers[template] = out
         return out
 
-    def check(self, template: str, params: dict, result) -> "str | None":
+    def check(self, template: str, params: dict, result,
+              worst=None) -> "str | None":
         """None where `result` (an engine ResultTable) holds the right
-        groups and aggregates, else what differs."""
+        groups and aggregates, else what differs; `worst` (`check.Worst`)
+        is shown the float values' gaps."""
         from tpubench.check import diff_columns
 
         keys, _ = QUESTIONS[template]
@@ -151,11 +153,12 @@ class Oracle:
             col = result.columns[i]
             if len(col) == 0:
                 got_keys.append(np.zeros(0, np.int64))
-            elif SCHEMA[k] == "str":
+            elif KINDS[k] == "str":
                 got_keys.append(_id_codes(col))
             else:
                 got_keys.append(np.asarray(col, np.int64) - 1)
         got_vals = [np.asarray(c) for c in result.columns[len(keys):]]
         if len(got_vals) != len(want_vals):
             return f"{len(got_vals)} value columns, oracle has {len(want_vals)}"
-        return diff_columns(got_keys, got_vals, want_keys, want_vals)
+        return diff_columns(got_keys, got_vals, want_keys, want_vals,
+                            worst=worst)

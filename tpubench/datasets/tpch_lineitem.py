@@ -21,13 +21,13 @@ from concurrent.futures import ThreadPoolExecutor
 
 import numpy as np
 
-TABLE = "lineitem"
-# column -> resident kind ("str": dictionary codes, int32 on the device)
-SCHEMA = {
+# table -> column -> resident kind ("str": dictionary codes, int32 on the
+# device), in registration order; `rows` counts the first table
+TABLES = {"lineitem": {
     "l_returnflag": "str", "l_linestatus": "str", "l_quantity": "f64",
     "l_extendedprice": "f64", "l_discount": "f64", "l_tax": "f64",
     "l_shipdate": "str",
-}
+}}
 CHUNK = 1_000_000
 N_DATES = 2526  # 1992-01-02 .. 1998-12-01
 BASE_DATE = np.datetime64("1992-01-02")
@@ -87,13 +87,14 @@ def _cubes(c: dict) -> tuple:
 
 
 def generate(seed: int, rows: int, threads: int = 8) -> dict:
-    """{"columns": {name: ndarray | (int32 codes, dictionary values)},
-    "oracle": Oracle}.  Chunks are made, written into their place and
+    """{"tables": {"lineitem": {column: ndarray | (int32 codes, dictionary
+    values)}}, "oracle": Oracle}.  Chunks are made, written into their place and
     folded into the oracle's cubes on `threads` threads (numpy releases
     the GIL in all three)."""
     starts = range(0, rows, CHUNK)
     probe = _chunk(seed, 0, 1)
-    cols = {name: np.empty(rows, probe[name].dtype) for name in SCHEMA}
+    cols = {name: np.empty(rows, probe[name].dtype)
+            for name in TABLES["lineitem"]}
 
     def work(i):
         lo = starts[i]
@@ -109,7 +110,7 @@ def generate(seed: int, rows: int, threads: int = 8) -> dict:
         cols[name] = (cols[name], values)
     q1 = np.sum([c[0] for c in cubes], axis=0)
     q6 = np.sum([c[1] for c in cubes], axis=0)
-    return {"columns": cols, "oracle": Oracle(q1, q6)}
+    return {"tables": {"lineitem": cols}, "oracle": Oracle(q1, q6)}
 
 
 def bind(template: str, params: dict) -> dict:
@@ -163,9 +164,12 @@ class Oracle:
             return [(float(cube.sum()),)]
         raise KeyError(f"tpch_lineitem has no template {template!r}")
 
-    def check(self, template: str, params: dict, result) -> "str | None":
+    def check(self, template: str, params: dict, result,
+              worst=None) -> "str | None":
         """None where `result` (an engine ResultTable) holds the right
-        rows, else what differs."""
+        rows, else what differs; `worst` (`check.Worst`) is shown the
+        float values' gaps."""
         from tpubench.check import diff_rows
 
-        return diff_rows(result.to_rows(), self.answer(template, params))
+        return diff_rows(result.to_rows(), self.answer(template, params),
+                         worst=worst)

@@ -5,12 +5,13 @@
 The process is the only one that touches JAX, so the only holder of the
 chip.  Without a TPU (or with fewer chips than the cell asks for) it exits
 with code 2 and prints no result.  `--rehearse-rows N` is the sandbox
-rehearsal: it needs an explicit `JAX_PLATFORMS=cpu`, cuts the table to N
-rows, and prints counts only, never a time under a device metric's name.
+rehearsal: it needs an explicit `JAX_PLATFORMS=cpu`, cuts the data set's
+first table to N rows (the others by the data set's own rule), and prints
+counts only, never a time under a device metric's name.
 
 Set-up (everything before the first timed request) is: the data from the
-seed on a thread beside JAX's start-up, its Parquet file, the resident
-table the engine's reader makes of it, the programs of this cell's
+seed on a thread beside JAX's start-up, its Parquet files, the resident
+tables the engine's reader makes of them, the programs of this cell's
 traffic (compiled, or loaded from the persistent cache at its fixed path
 inside the checkout), and warm-up until a pass brings no new program.  With `--trace 1` the window is the
 mix's `trace_seconds` under the JAX profiler and the metrics are the cell's
@@ -29,9 +30,9 @@ import threading
 import time
 from dataclasses import dataclass, field
 
+from tpubench import check, traffic
 from tpubench import data as tdata
-from tpubench import traffic
-from tpubench.spec import Spec
+from tpubench.spec import Spec, device_guard
 
 NO_DEVICE_EXIT = 2
 WARM_PASSES = 3
@@ -51,8 +52,10 @@ class Run:
     compiles_in_window: int
     trace: "dict | None"  # trace_reduce.reduce() of the traced window
     device: dict
-    rows: int  # rows of the table every query scans
-    bytes_needed: int  # peaks.required_bytes, summed over `done`
+    # peaks.query_scan summed over the queries of `done`: the rows of the
+    # tables each names, and the least bytes it has to read of them
+    rows_scanned: int
+    bytes_needed: int
     detail: dict = field(default_factory=dict)
 
     @property
@@ -184,12 +187,12 @@ class CellRun:
         self.oracle = made["oracle"]
         self.spans = entries.Spans()
         engine_device = "cpu" if self.rehearsal else self.config["engine"]["device"]
-        path = tdata.parquet_file(made, self.config["row_group_rows"])
-        self.say(f"file ready (found again: {made['cached']})")
-        self.entry = entries.ENTRIES[self.mix["entry"]](
-            engine_device, self.config["engine"], self.dataset.TABLE,
-            self.spans, path)
-        self.say(f"entry ready ({self.rows} rows)")
+        paths, self.table_rows = tdata.parquet_files(
+            made, self.config["row_group_rows"])
+        self.say(f"files ready (found again: {made['cached']})")
+        self.entry = self.spec.entry(self.mix["entry"])(
+            engine_device, self.config["engine"], paths, self.spans)
+        self.say(f"entry ready (rows {self.table_rows})")
         self.maker = traffic.RequestMaker(self.mix, self._sql)
         return True
 
@@ -271,10 +274,9 @@ class CellRun:
                  f"{window.t_close - window.t_open:.3f} s")
 
         reduced = self._reduce_trace(trace_dir) if args.trace else None
-        done, errors = self._check(window)
-        schema = self.dataset.SCHEMA
-        bytes_needed = sum(peaks.required_bytes(q.sql, schema, self.rows)
-                           for o in done for q in o.request.queries)
+        done, errors, worst_gap = self._check(window)
+        scans = [peaks.query_scan(q.sql, self.dataset.TABLES, self.table_rows)
+                 for o in done for q in o.request.queries]
         peak_bytes = max((d.memory_stats() or {}).get("peak_bytes_in_use", 0)
                          for d in self.devices[: self.cell["chips"]])
         device = {**self.device, "memory_peak_bytes": peak_bytes}
@@ -282,12 +284,22 @@ class CellRun:
             device.update(busy_s=reduced["busy_s"], window_s=reduced["window_s"])
         compiles = (self.watch.between(window.t_open, window.t_close)
                     + counts.get("kernel_cache.misses", 0))
+        # what `correct` compares, each number beside its limit: the answers
+        # against the oracle (how many were wrong, and the widest relative gap
+        # of a float value), and the configuration's guard that the device
+        # did the work (`guarantees.device`)
+        must_launch, must_be_zero = device_guard(self.config)
+        compared = {
+            "wrong_answers": {
+                "value": sum("wrong answer" in e for e in errors), "at_most": 0},
+            "worst_rel_gap": {"value": worst_gap, "at_most": check.RTOL},
+            must_launch: {"value": counts.get(must_launch, 0), "at_least": 1},
+            **{c: {"value": counts.get(c, 0), "at_most": 0}
+               for c in must_be_zero},
+        }
         detail = {
             "requests_done": len(done), "errors": errors[:5],
-            "wrong": sum("wrong answer" in e for e in errors),
-            "on_device": (counts.get("device.launches", 0) > 0
-                          and counts.get("aggregate.host_routed_slots", 0) == 0
-                          and counts.get("sort.host_routed_runs", 0) == 0),
+            "compared": compared,
             "compiles_in_window": compiles,
             "setup": setup,
             "counts": {k: v for k, v in counts.items() if v},
@@ -296,8 +308,9 @@ class CellRun:
         }
         return Run(mix=self.mix, window=window, done=done, counts=counts, timings=timings,
                    spans=self.spans, setup=setup, compiles_in_window=compiles,
-                   trace=reduced, device=device, rows=self.rows,
-                   bytes_needed=bytes_needed, detail=detail)
+                   trace=reduced, device=device,
+                   rows_scanned=sum(r for r, _ in scans),
+                   bytes_needed=sum(b for _, b in scans), detail=detail)
 
     def _reduce_trace(self, trace_dir: str) -> "dict | None":
         from tpubench import trace_reduce
@@ -316,15 +329,16 @@ class CellRun:
 
     def _check(self, window: traffic.Window) -> tuple:
         """Every result of the window against the oracle, after it closed:
-        (the outcomes that were right, what was wrong with the others)."""
-        done, errors = [], []
+        (the outcomes that were right, what was wrong with the others, the
+        widest relative gap of any float value compared)."""
+        done, errors, worst = [], [], check.Worst()
         outcomes = sorted(window.outcomes, key=lambda o: o.request.rid)
         for o in outcomes:
             if o.error is not None:
                 errors.append(f"rid {o.request.rid}: {o.error!r}")
                 continue
             bad = [d for q, r in zip(o.request.queries, o.results)
-                   if (d := self.oracle.check(q.template, q.params, r))]
+                   if (d := self.oracle.check(q.template, q.params, r, worst))]
             if bad:
                 errors.append(f"rid {o.request.rid}: wrong answer: {bad[0]}")
             else:
@@ -332,7 +346,7 @@ class CellRun:
             o.results = None  # checked: let it go
         self.say("first requests " + json.dumps(
             [q.sql for o in outcomes[:8] for q in o.request.queries]))
-        return done, errors
+        return done, errors, worst.gap
 
     # -- the last line ---------------------------------------------------
     def report(self, run: Run) -> dict:
@@ -344,8 +358,11 @@ class CellRun:
             value = self.spec.metric_reader(m["name"])(run)
             if value is not None:
                 metrics[m["name"]] = {"value": float(value), "unit": m["unit"]}
+        compared = run.detail["compared"]
         line = {
-            "correct": run.detail["wrong"] == 0 and run.detail["on_device"],
+            "correct": all(
+                c["value"] <= c["at_most"] if "at_most" in c
+                else c["value"] >= c["at_least"] for c in compared.values()),
             "attempted": len(run.window.outcomes),
             "failed": len(run.window.outcomes) - len(run.done),
             "metrics": metrics,
@@ -354,6 +371,7 @@ class CellRun:
         if run.trace:
             line["breakdown"] = {"device_ops": run.trace["device_ops"],
                                  "idle_gaps": run.trace["idle_gaps"]}
+        line["compared"] = compared  # last in the line, and on stderr below
         self.say("detail " + json.dumps(run.detail, default=str))
         return line
 
@@ -383,4 +401,8 @@ def main(argv=None, t0: "float | None" = None, root: "str | None" = None) -> int
     finally:
         cell.log.close()
     print(json.dumps(line), flush=True)
+    for name, c in line["compared"].items():
+        limit = next(k for k in c if k != "value")
+        print(f"tpubench compared: {name} {c['value']} {limit} {c[limit]}",
+              file=sys.stderr, flush=True)
     return 0

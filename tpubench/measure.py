@@ -3,9 +3,9 @@
     python3 -m tpubench.measure --cells q1_sf10_warm,q1_sf10_cold \
         --sets 2 --runs 6 [--seconds N] [--trace 1] [--tag name]
 
-Each run is `python3 -m tpubench ...` in a process of its own with another
-seed; this parent never imports JAX, so it never holds the chip.  For each
-cell and metric it prints, per set, the median and the spread (distance
+Each run is `python3 -m tpubench ...` in a process of its own, each run of a
+set with another seed and every set with the same seeds; this parent never
+imports JAX, so it never holds the chip.  For each cell and metric it prints, per set, the median and the spread (distance
 between the quartiles over the median), and how far the second set's median
 lies from the first's: what the bounds in BENCHMARK.json are set from
 (about five times the widest spread).  Every run's last line is kept in
@@ -17,6 +17,7 @@ from __future__ import annotations
 import argparse
 import json
 import os
+import statistics
 import subprocess
 import sys
 import time
@@ -27,8 +28,11 @@ from tpubench.spec import Spec
 
 
 def quartile_spread(values: list) -> float:
-    """(Q3 - Q1) / median, by linear interpolation."""
-    q1, med, q3 = np.percentile(values, [25, 50, 75])
+    """(Q3 - Q1) / median, the quartiles as `statistics.quantiles(values,
+    n=4)` gives them: what the driver reads (numpy's lie closer together)."""
+    if len(values) < 2:
+        return 0.0
+    q1, med, q3 = statistics.quantiles(values, n=4)
     return float((q3 - q1) / med) if med else float("inf")
 
 
@@ -93,8 +97,9 @@ def main(argv=None) -> int:
     failed, t0, longest = 0, time.time(), 0.0
     for cell in args.cells.split(","):
         spec.cell(cell)
-        runs, seed = [], args.first_seed
+        runs = []
         for s in range(args.sets):
+            seed = args.first_seed  # the same seeds in every set, as the driver's
             for _ in range(args.runs):
                 if args.budget_s and (time.time() - t0 + longest
                                       > args.budget_s):

@@ -1,6 +1,7 @@
-"""Input rows of the tables scanned by completed requests over the
-measured seconds (window opening to the last completion counted)."""
+"""Input rows of the tables scanned by completed requests (for each query,
+the rows of the tables its text names) over the measured seconds (window
+opening to the last completion counted)."""
 
 
 def read(run):
-    return run.rows * run.queries / run.measured_s if run.queries else None
+    return run.rows_scanned / run.measured_s if run.queries else None

@@ -31,10 +31,11 @@ def peak(device_kind: str) -> dict:
     return PEAKS[device_kind]
 
 
-def referenced_columns(sql: str, schema: dict) -> list:
-    """The columns of `schema` that the query text names."""
+def named_in(sql: str, names) -> list:
+    """Those of `names` (tables, or a table's columns) that occur in the
+    query text as whole words."""
     words = set(re.findall(r"[A-Za-z_][A-Za-z_0-9]*", sql))
-    return [c for c in schema if c in words]
+    return [n for n in names if n in words]
 
 
 def required_bytes(sql: str, schema: dict, rows: int) -> int:
@@ -44,7 +45,16 @@ def required_bytes(sql: str, schema: dict, rows: int) -> int:
     so the roofline share taken against this is a share of the least
     possible time, bounded by HBM bandwidth."""
     return rows * sum(RESIDENT_BYTES[schema[c]]
-                      for c in referenced_columns(sql, schema))
+                      for c in named_in(sql, schema))
+
+
+def query_scan(sql: str, tables: dict, rows: dict) -> tuple:
+    """(rows scanned, least bytes read) of one query over a data set's
+    `tables` ({table: schema}) holding `rows` ({table: count}): the sums
+    over the tables its text names, each with its own schema and count."""
+    named = named_in(sql, tables)
+    return (sum(rows[t] for t in named),
+            sum(required_bytes(sql, tables[t], rows[t]) for t in named))
 
 
 def roofline_share(bytes_needed: float, busy_s: float, device_kind: str) -> float:

@@ -1,8 +1,8 @@
 """Finds the benchmark's files by the names `BENCHMARK.json` gives them.
 
 Everything that belongs to one configuration, one traffic mix, one query
-template, one data set or one metric sits in a file of its own under
-`tpubench/`; nothing here lists them.  `Spec(root)` reads from any
+template, one data set, one entry point or one metric sits in a file of
+its own under `tpubench/`; nothing here lists them.  `Spec(root)` reads from any
 checkout root, so a test can point it at a copy with files added.
 """
 
@@ -17,6 +17,20 @@ ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 class SpecError(ValueError):
     """A name in BENCHMARK.json or a data file does not resolve."""
+
+
+def device_guard(config: dict, file: str = "the configuration") -> tuple:
+    """(the counter a window has to bump, the counters it must leave at
+    zero): the machine-read part of `guarantees.device`."""
+    guard = config.get("guarantees", {}).get("device")
+    if not (isinstance(guard, dict)
+            and isinstance(guard.get("must_launch"), str)
+            and isinstance(guard.get("must_be_zero"), list)
+            and all(isinstance(c, str) for c in guard["must_be_zero"])):
+        raise SpecError(
+            f"{file}: guarantees.device needs \"must_launch\" (a counter "
+            "name) and \"must_be_zero\" (a list of counter names)")
+    return guard["must_launch"], guard["must_be_zero"]
 
 
 class Spec:
@@ -57,12 +71,16 @@ class Spec:
             return json.load(f)
 
     def config(self, name: str) -> dict:
-        """The configuration as it is run: the file BENCHMARK.json names."""
+        """The configuration as it is run: the file BENCHMARK.json names.
+        It has to say how a run shows that the device did the work
+        (`device_guard`), so no cell runs unguarded."""
         file = self._entry("configs", name)["file"]
         if not os.path.isfile(os.path.join(self.root, file)):
             raise SpecError(f"{file} not found")
         with open(os.path.join(self.root, file)) as f:
-            return json.load(f)
+            doc = json.load(f)
+        device_guard(doc, file)
+        return doc
 
     def traffic(self, name: str) -> dict:
         return self._json("traffic", name + ".json")
@@ -80,9 +98,14 @@ class Spec:
         return mod
 
     def dataset(self, name: str):
-        """`datasets/<name>.py`: `generate`, `write_parquet`, `Oracle`,
-        `bind`, `SCHEMA`, `TABLE` (see datasets/tpch_lineitem.py)."""
+        """`datasets/<name>.py`: `TABLES`, `generate`, `bind`, `Oracle`
+        (see datasets/tpch_lineitem.py)."""
         return self._module("datasets", name)
+
+    def entry(self, name: str):
+        """`entries/<name>.py`: its `ENTRY`, a class built as
+        `entries.Entry(device, engine_cfg, tables, spans)`."""
+        return self._module("entries", name).ENTRY
 
     def metric_reader(self, name: str):
         """`metrics/<name>.py`: `read(run)` -> a number, or None where

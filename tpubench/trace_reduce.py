@@ -1,6 +1,8 @@
 """From a JAX profiler trace (`.xplane.pb`) to device busy and idle time,
 the device operations that took most of it, and the idle gaps labelled by
-what the benchmark's host side was doing.
+what the host was doing: the innermost open span of the benchmark
+(`tpubench.*`) or of the engine's stage timers (`dftpu.*`, the seam in
+`datafusion_tpu/utils/metrics.py`).
 
 What the trace holds, as read on a TPU v5e with jax 0.9 (PERF.md, PR 22):
 one plane per chip named `/device:TPU:<n>` whose line `XLA Ops` has one
@@ -28,7 +30,7 @@ import numpy as np
 DEVICE_PLANE = re.compile(r"^/device:TPU:(\d+)$")
 OPS_LINE = "XLA Ops"
 MODULES_LINE = "XLA Modules"
-SPAN_PREFIX = "tpubench."
+SPAN_PREFIXES = ("tpubench.", "dftpu.")  # the benchmark's, the engine's
 WINDOW_SPAN = "tpubench.window"
 TOP_N = 10
 
@@ -52,7 +54,7 @@ class Events:
 class Trace:
     ops: dict  # device index -> Events of the XLA Ops line
     modules: dict  # device index -> Events of the XLA Modules line
-    spans: Events  # the benchmark's host spans, all threads
+    spans: Events  # the host spans of `SPAN_PREFIXES`, all threads
     lines: list  # (plane, line, number of events), for a look by hand
 
 
@@ -79,7 +81,7 @@ def load(path: str) -> Trace:
             elif dev and line.name == MODULES_LINE:
                 modules[int(dev.group(1))] = Events.of(rows)
             elif not dev:
-                span_rows += [r for r in rows if r[0].startswith(SPAN_PREFIX)]
+                span_rows += [r for r in rows if r[0].startswith(SPAN_PREFIXES)]
     return Trace(ops, modules, Events.of(span_rows), lines)
 
 
@@ -146,8 +148,8 @@ class Busy:
 
 
 def label_segments(spans: Events, lo: float, hi: float) -> list:
-    """Cut [lo, hi) into segments labelled by the benchmark span that was
-    open: of those open at once (nested, or on several threads) the one
+    """Cut [lo, hi) into segments labelled by the host span that was open,
+    the benchmark's or the engine's: of those open at once (nested, or on several threads) the one
     opened last, which for nested spans is the innermost; "no_span" where
     none was.  Returns (label, start, end) rows."""
     inner = [i for i, n in enumerate(spans.names) if n != WINDOW_SPAN]
