@@ -16,8 +16,10 @@ process exits non-zero without printing a result:
 3. serve  a few dozen concurrent `submit`s of Q1's aggregates under
           `l_quantity < x` (distinct x) through the megabatcher
 4. ops    GROUP BY at ~8 k and 100 k groups, int64 ORDER BY over 2^18
-          rows, TopK, and a dense-int join at the Pallas hash-build
-          kernel's window edge (8,192 slots)
+          rows, TopK, a dense-int join at the Pallas hash-build kernel's
+          window edge (8,192 slots), and a join on sparse keys over 2^22
+          slots grouped by a build-side string (the device probe that
+          `tpubench`'s q12_sf10_join measures)
 5. mesh   only with >= 4 TPU devices: Q1 over lineitem split four ways
           through `PartitionedDataSource` + `make_mesh(4)`
 
@@ -351,7 +353,8 @@ def stage_operators(device: str, agg_rows: int = 1 << 21) -> dict:
 
     out: dict = {}
     extra = ("join.build.dense", "join.build.pallas_runs",
-             "device.launches.sort.run")
+             "device.launches.sort.run", "device.launches.join.probe",
+             "d2h.bytes", "join.probe.rows", "join.host_probe.rows")
 
     def run(label, src_by_name, sql, want, batch_size=1 << 19):
         ctx = ExecutionContext(device=device, batch_size=batch_size,
@@ -443,6 +446,56 @@ def stage_operators(device: str, agg_rows: int = 1 << 21) -> dict:
             f"join_8k_slots: hash_build engaged={engaged}, the rule says "
             f"{not engaged}")
     out["join_8k_slots"] = {**ev, "hash_build_engaged": engaged}
+
+    # a fact-to-fact join as `tpubench`'s q12_sf10_join measures it, at a
+    # sixteenth: 2^20 build rows on TPC-H's sparse keys (the first 8 of
+    # every 32: 2^22 slots, past the old 2^20 cap and the kernel's window),
+    # probed by clustered keys of which a fifth dangle, grouped by a
+    # build-side string whose ids are made on the device, inside the
+    # aggregate's own launches
+    from datafusion_tpu.exec.batch import StringDictionary
+
+    n_build = 1 << 20
+    i = np.arange(n_build, dtype=np.int64)
+    build_k = i // 8 * 32 + i % 8 + 1
+    prios = StringDictionary()
+    prio_codes = prios.encode(["1-URGENT", "2-HIGH", "3-MEDIUM"])[
+        rng.integers(0, 3, n_build)]
+    probe_k = np.sort(np.where(rng.random(n_sort) < 0.8,
+                               rng.choice(build_k, n_sort),
+                               rng.integers(9, 4 * n_build, n_sort) | 8))
+    o_schema = Schema([Field("ok", DataType.INT64, False),
+                       Field("prio", DataType.UTF8, False)])
+    l_schema = Schema([Field("lk", DataType.INT64, False),
+                       Field("seq", DataType.INT64, False)])
+    orders = MemoryDataSource(o_schema, [make_host_batch(
+        o_schema, [build_k, prio_codes], [None] * 2, [None, prios])])
+    lines = MemoryDataSource(l_schema, [
+        make_host_batch(l_schema, [probe_k[lo: lo + (1 << 17)],
+                                   seq[lo: lo + (1 << 17)]], [None] * 2,
+                        [None] * 2)
+        for lo in range(0, n_sort, 1 << 17)])
+    slot = np.minimum(np.searchsorted(build_k, probe_k), n_build - 1)
+    hit = (build_k[slot] == probe_k) & (seq >= 1000)
+    tally = np.bincount(prio_codes[slot[hit]], minlength=3)
+    want = [(prios.values[c], int(n)) for c, n in enumerate(tally)]
+    got, ev = run(
+        "join_sparse_4m_slots", {"lines": lines, "orders": orders},
+        "SELECT prio, COUNT(1) FROM lines JOIN orders ON lines.lk = orders.ok "
+        "WHERE seq >= 1000 GROUP BY prio", want, batch_size=1 << 17,
+    )
+    require(sorted(got) == want, "join_sparse_4m_slots: rows differ from numpy")
+    require_on_device("join_sparse_4m_slots", ev)
+    require(ev["join.build.dense"] == 1 and ev["join.build.pallas_runs"] == 0,
+            "join_sparse_4m_slots: not the device build by the XLA scatter")
+    require(ev["join.host_probe.rows"] == 0
+            and ev["join.probe.rows"] == n_sort
+            and ev["device.launches.join.probe"] == n_sort >> 17,
+            f"join_sparse_4m_slots: not every row probed on the device: {ev}")
+    require(0 < ev["d2h.bytes"] <= 1024,
+            "join_sparse_4m_slots: more than the answer came back "
+            f"({ev['d2h.bytes']} B): group ids not made on the device")
+    out["join_sparse_4m_slots"] = ev
 
     # the kernel alone against its numpy oracle at the same shape
     if engaged:

@@ -80,6 +80,31 @@ def widen_group_ids(w):
 # widen narrow wire-format group ids back to int32 on device
 _WIDEN_IDS_JIT = jax.jit(widen_group_ids)
 
+
+def _ids_of(ids):
+    """Group ids as the kernels take them: an int array, or, for a
+    batch whose dictionary-coded key columns were born on the device
+    (`AggregateRelation._device_group_ids`), the tuple (key columns,
+    validities, table), turned into ids here, inside the launch that
+    consumes them.  `table` is each key's (radix, dictionary size) and
+    then the id of every key tuple: the tuple packed by the radices (a
+    NULL coded as the dictionary's size) finds its id by
+    compare-and-select, for a table of at most `DENSE_GROUP_MAX` ids
+    needs no gather."""
+    if not isinstance(ids, tuple):
+        return ids
+    cols, valids, table = ids
+    meta, lut = table[:2 * len(cols)].reshape(-1, 2), table[2 * len(cols):]
+    packed = jnp.zeros(cols[0].shape, jnp.int32)
+    for i, (c, v) in enumerate(zip(cols, valids)):
+        code = c.astype(jnp.int32)
+        if v is not None:
+            code = jnp.where(v, code, meta[i, 1])
+        packed = packed * meta[i, 0] + code
+    onehot = packed[:, None] == jnp.arange(lut.shape[0], dtype=jnp.int32)[None, :]
+    return jnp.sum(jnp.where(onehot, lut[None, :], 0), axis=1, dtype=jnp.int32)
+
+
 # serving-path lowering mode (datafusion_tpu/serve.py): keep the
 # predicate IN the device core (as parameter slots) instead of routing
 # host-evaluable predicates to the host.  Cross-query megabatching
@@ -624,6 +649,7 @@ class _AggregateCore:
 
     def _kernel(self, cols, valids, aux, num_rows, base_mask, ids, state,
                 str_aux=(), params=()):
+        ids = _ids_of(ids)
         env = Env(cols, valids, aux, self.col_map, params)
         capacity = cols[0].shape[0] if cols else ids.shape[0]
         mask = jnp.arange(capacity, dtype=jnp.int32) < num_rows
@@ -876,6 +902,7 @@ class _AggregateCore:
         keys_l, contribs_l = [], []
         payload_of: dict[int, int] = {}
         for cols, valids, num_rows, mask, ids in entries:
+            ids = _ids_of(ids)
             env = Env(cols, valids, aux, self.col_map, params)
             capacity = cols[0].shape[0] if cols else ids.shape[0]
             m = jnp.arange(capacity, dtype=jnp.int32) < num_rows
@@ -1126,10 +1153,15 @@ class AggregateRelation(Relation):
         # again either, only this query's mask does (_device_inputs).
         # No function metas reach this ctor, so predicates containing
         # UDFs conservatively stay on device ({} finds no host_fn).
+        # A child whose batches are born on the device (a join's probe
+        # output) keeps its predicate in the core: the columns are
+        # there already, and the host could only read them by pulling
+        # every batch back.
         host_pred = (
             predicate is not None
             and _is_accelerator(device)
             and not _FORCE_CORE_PRED.get()
+            and not getattr(child, "device_batches", False)
             and host_evaluable(predicate, {}, child.schema)
         )
         self._host_pred_expr = predicate if host_pred else None
@@ -1770,6 +1802,11 @@ class AggregateRelation(Relation):
         if np_hit is not None and np_hit[0] is self.encoder:
             ids_np = np_hit[1]
         elif self.key_cols:
+            if upload and not keep_np:
+                ids = self._device_group_ids(batch)
+                if ids is not None:
+                    batch.cache[self._ids_slot] = (self.encoder, ids)
+                    return ids
             key_cols = [np.asarray(batch.data[idx]) for idx in self.key_cols]
             key_valids = [
                 None if batch.validity[idx] is None else np.asarray(batch.validity[idx])
@@ -1811,6 +1848,51 @@ class AggregateRelation(Relation):
         )
         batch.cache[self._ids_slot] = (self.encoder, ids)
         return ids
+
+    def _device_group_ids(self, batch: RecordBatch):
+        """What the kernels make group ids from (`_ids_of`) for a batch
+        whose key columns were born on the device (a join's probe
+        output), or None where the host encode has to do it.  Every
+        key has to be dictionary coded, which bounds its values: the
+        encoder is shown the whole key space once, on the host (at
+        most `DENSE_GROUP_MAX` tuples, the dense kernel's own reach,
+        so the accumulator is no larger than those keys could make it;
+        tuples no row has stay out of the answer through the live
+        counts), and the launch that folds a batch turns its codes
+        into ids by that table: no key column comes back to the host
+        and no launch is added."""
+        cols = tuple(batch.data[i] for i in self.key_cols)
+        dicts = [batch.dicts[i] for i in self.key_cols]
+        if any(isinstance(c, np.ndarray) for c in cols) or any(
+                d is None for d in dicts):
+            return None
+        valids = tuple(batch.validity[i] for i in self.key_cols)
+        sizes = tuple(d.version for d in dicts)
+        # a nullable key has one more value, NULL, coded past the last
+        radices = tuple(n + (v is not None) for n, v in zip(sizes, valids))
+        space = int(np.prod(radices))
+        if not 0 < space <= DENSE_GROUP_MAX:
+            return None
+        key = ("device_ids", radices, sizes)
+        hit = self._aux_cache.get(key)
+        if hit is None:
+            from datafusion_tpu.obs.device import LEDGER
+
+            codes = np.indices(radices).reshape(len(radices), space)
+            lut = self.encoder.encode(
+                [np.minimum(c, n - 1).astype(np.int32)
+                 for c, n in zip(codes, sizes)],
+                [None if r == n else c < n
+                 for c, r, n in zip(codes, radices, sizes)],
+            )
+            table = np.concatenate([
+                np.array([radices, sizes], np.int32).T.ravel(),
+                np.asarray(lut, np.int32)])
+            hit = self._aux_cache[key] = (
+                LEDGER.put(table, self.device, owner="agg.ids")
+                if self.device is not None
+                else LEDGER.adopt(jnp.asarray(table), owner="agg.ids"))
+        return cols, valids, hit
 
     @staticmethod
     def _numeric_output(s: AggregateSpec, sums, cnts, live_counts):

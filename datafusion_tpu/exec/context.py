@@ -18,6 +18,7 @@ scalar UDF lookup (`context.rs:222-224`) — is implemented.
 
 from __future__ import annotations
 
+import itertools
 import threading
 import time
 from typing import Callable, Iterator, Optional, Union
@@ -114,6 +115,22 @@ class _ContextSchemaProvider:
 
     def get_function_meta(self, name: str) -> Optional[FunctionMeta]:
         return self.ctx.functions.get(name.lower())
+
+
+_SOURCE_SERIALS = itertools.count(1)
+
+
+def _source_serial(ds) -> int:
+    """A number this process gives an in-memory datasource once, for
+    `query_fingerprint`; a wrapper that serves the same data (serve's
+    `PinnedSource.inner`) has its source's."""
+    while getattr(ds, "inner", None) is not None:
+        ds = ds.inner
+    serial = ds.__dict__.get("_fingerprint_serial")
+    if serial is None:
+        serial = ds.__dict__.setdefault(
+            "_fingerprint_serial", next(_SOURCE_SERIALS))
+    return serial
 
 
 class ExecutionContext:
@@ -382,8 +399,13 @@ class ExecutionContext:
                     entry.append(source_version(ds.to_meta()))
                 except PlanError:
                     # non-serializable (in-memory) sources have no file
-                    # identity; the catalog version alone covers them
-                    pass
+                    # identity: the object stands for its data.  Two
+                    # contexts of one process that register different
+                    # in-memory tables under one name at one catalog
+                    # version must not share a fingerprint, since what
+                    # it keys (a pinned join build, a cached result)
+                    # outlives the context in process-wide stores
+                    entry.append(["mem", _source_serial(ds)])
             versions[t] = entry
         return plan_fingerprint(plan, versions, extra={
             "device": str(self.device) if self.device is not None else "",

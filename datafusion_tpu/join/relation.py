@@ -6,19 +6,35 @@ left input).  The build side fully materializes once into a
 `JoinBuildArtifact`; probe batches stream through one of two paths:
 
 - **dense-int device probe**: single integer key, unique on the build
-  side, with a small value range — the build fills a direct-address
-  slot table on device (`exec/pallas/hash_build` kernel when it
-  engages, stock-XLA scatter otherwise; both launch under
-  ``device.launches.join.build``) and every probe batch runs ONE fused
-  launch (``device.launches.join.probe``) computing hit mask + payload
-  gather at probe capacity — no host round trip, masks carried, zero
-  extra H2D once the artifact is resident.
+  side — the build fills a direct-address slot table on device
+  (`exec/pallas/hash_build` kernel when it engages, stock-XLA scatter
+  otherwise; both launch under ``device.launches.join.build``) and
+  every probe batch runs ONE fused launch
+  (``device.launches.join.probe``, program ``jit_join_probe``)
+  computing hit mask + payload gather at probe capacity — no host
+  round trip, masks carried, zero extra H2D once the artifact is
+  resident.
 - **host probe**: everything else (multi-key, strings, duplicate
-  keys).  `core.HashIndex` CSR-expands matches per batch.
+  keys, a build that does not fit).  `core.HashIndex` CSR-expands
+  matches per batch; its rows count as ``join.host_probe.rows``.
+
+**The one size rule.**  A unique-integer-key build goes to the device
+when its direct-address table is no sparser than a hash table would
+be roomy — at most `_SLOTS_PER_BUILD_ROW` slots a live build row: an
+open-addressing table of (8 B key, 4 B row) entries padded to 16 B,
+at half load, spends 32 B a row, which is what 8 slots of 4 B cost — and the table and
+the payload fit what the device ledger says is free less what the
+probe side has yet to upload (`LEDGER.fits`: capacity less every live
+ledger buffer; admission keeps no further reserve, so neither does
+this).  It is pinned under the same test.  A dimension table and a
+15 M-row fact side are the same case.  A build that stays on the host
+(strings, duplicate keys, too sparse) holds no HBM: it is pinned when
+its host arrays fit the host's free memory.  One that only found no
+room is not pinned, so the next query asks the ledger again.
 
 Artifacts pin in the device ledger under the build subtree's query
-fingerprint (``join:<fp>``): a warm query probing the same dimension
-table reuses the resident build — zero H2D for the build side — and a
+fingerprint (``join:<fp>``): a warm query probing the same build side
+reuses the resident build — zero H2D for the build side — and a
 catalog/data version bump changes the fingerprint, so stale builds are
 never probed.  Pin residency charges probing clients by use count
 (obs/attribution.py), same as pinned scan tables.
@@ -42,21 +58,15 @@ from datafusion_tpu.exec.batch import (
 )
 from datafusion_tpu.exec.relation import Relation
 from datafusion_tpu.join import core as _core
-from datafusion_tpu.obs.device import LEDGER
+from datafusion_tpu.obs.device import LEDGER, host_fits
 from datafusion_tpu.utils.metrics import METRICS
 from datafusion_tpu.utils.retry import device_call
 
 
-def _dense_max_slots() -> int:
-    """Largest direct-address table the dense path will build; above it
-    (sparse/huge key ranges) the host index keeps the job."""
-    return int(os.environ.get("DATAFUSION_TPU_JOIN_DENSE_SLOTS", 1 << 20))
-
-
-def _pin_max_bytes() -> int:
-    """Largest build artifact the ledger pins (dimension tables are
-    small; a fact-side build must not squat on HBM accounting)."""
-    return int(os.environ.get("DATAFUSION_TPU_JOIN_PIN_MAX", 64 << 20))
+# slot positions and build-row indices travel and gather as int32
+_MAX_SLOTS = (1 << 31) - 1
+# at most this many slots a live build row (module doc)
+_SLOTS_PER_BUILD_ROW = 8
 
 
 def _device_path_enabled() -> bool:
@@ -73,8 +83,9 @@ class JoinBuildArtifact:
     table and payload columns the fused probe launches gather from."""
 
     __slots__ = ("cols", "valids", "dicts", "n_rows", "index", "dense",
-                 "kmin", "num_slots", "device", "dev_slot_row", "dev_cols",
-                 "dev_valids", "nbytes", "fingerprint")
+                 "kmin", "num_slots", "device", "dev_kmin", "dev_num_slots",
+                 "dev_slot_row", "dev_cols", "dev_valids", "nbytes", "keep",
+                 "fingerprint")
 
     def __init__(self):
         self.dense = False
@@ -82,35 +93,96 @@ class JoinBuildArtifact:
         self.fingerprint = None
 
 
-@functools.lru_cache(maxsize=256)
-def _probe_fn_for(kmin: int, num_slots: int, join_type: str):
+# The probe's tables stay on the device as whole rows, `[n / 128, 128]`,
+# and are read as a gather of rows with the lane selected afterwards:
+# 131,072 scattered int32 values cost a v5e 0.37 ms that way from a
+# 60 MB table against 1.15 ms as an element gather (PERF.md section 6,
+# PR 28).  Reshaping a 1-D table inside the probe would copy all of it
+# in every launch.
+_LANE_BITS = 7
+_LANES = 1 << _LANE_BITS
+
+
+def _pad_rows(n: int) -> int:
+    return -(-n // _LANES) * _LANES
+
+
+def _take_rows(table, idx):
+    """`table.reshape(-1)[idx]` of a `[rows, _LANES]` table, as a
+    gather of rows and a lane select.  `idx` is int32 and in range."""
+    import jax.numpy as jnp
+    from jax import lax
+
+    rows = table[idx >> _LANE_BITS]
+    lane = lax.broadcasted_iota(jnp.int32, rows.shape, 1)
+    picked = jnp.where(lane == (idx & (_LANES - 1))[:, None], rows,
+                       jnp.zeros((), table.dtype))
+    if table.dtype == jnp.bool_:
+        return jnp.any(picked, axis=1)
+    return jnp.sum(picked, axis=1, dtype=table.dtype)
+
+
+@functools.lru_cache(maxsize=None)
+def _probe_fn_for(join_type: str):
     """One fused probe launch: slot lookup, hit mask, payload gather,
-    validity, selection-mask combine — all inside a single jit.
-    Module-cached so a pinned artifact probed by many relations (and
-    by INNER and LEFT queries alike) shares compiled probes."""
+    validity, selection-mask combine — all inside a single jit whose
+    program (`jit_join_probe`) is specialised by shapes alone: `kmin`
+    and the slot count are arguments, so every dense artifact of one
+    shape class shares compiled probes.  The slot table and the
+    payload are `[rows, _LANES]` (`_take_rows`)."""
     import jax
     import jax.numpy as jnp
 
-    def f(key, kvalid, mask, slot_row, pcols, pvalids):
+    def join_probe(key, kvalid, mask, kmin, num_slots, slot_row, pcols,
+                   pvalids):
         # range check in int64 BEFORE the int32 cast: a far-out-of-range
         # probe key must not wrap into a valid slot
         d = key.astype(jnp.int64) - kmin
         inr = (d >= 0) & (d < num_slots)
-        safe = jnp.where(inr, d, 0).astype(jnp.int32)
-        bidx = jnp.where(inr, slot_row[safe], -1)
-        hit = bidx >= 0
         if kvalid is not None:
-            hit = hit & kvalid
+            inr = inr & kvalid
+        safe = jnp.where(inr, d, 0).astype(jnp.int32)
+        bidx = jnp.where(inr, _take_rows(slot_row, safe), -1)
+        hit = bidx >= 0
         sb = jnp.where(hit, bidx, 0)
-        gath = tuple(c[sb] for c in pcols)
-        gval = tuple(hit if v is None else hit & v[sb] for v in pvalids)
-        if join_type == "inner":
+        inner = join_type == "inner"
+        gath = tuple(_take_rows(c, sb) for c in pcols)
+        # an INNER join masks its misses out: a build column without
+        # NULLs stays without a validity array
+        gval = tuple(
+            (None if inner else hit) if v is None else hit & _take_rows(v, sb)
+            for v in pvalids)
+        if inner:
             out_mask = hit if mask is None else mask & hit
         else:
             out_mask = mask
         return gath, gval, out_mask
 
-    return jax.jit(f)
+    return jax.jit(join_probe)
+
+
+def _unplaced_bytes(rel: Relation, device) -> int:
+    """Host bytes of `rel`'s resident tables (sources that hand out the
+    same batches to every scan) not yet copied to `device`: what the
+    probe about to start will add to the ledger (`device_inputs` keeps
+    those copies on the batch), and so what a build placed before it
+    must leave room for.  A streamed source's copies die batch by
+    batch and count nothing."""
+    slot = ("device", None if device is None else repr(device))
+    total = 0
+    stack = [rel]
+    while stack:
+        node = stack.pop()
+        ds = getattr(node, "datasource", None)
+        if ds is None:
+            stack.extend(node.op_children())
+        elif getattr(ds, "reusable_batches", False):
+            for b in ds.batches():
+                if slot not in b.cache:
+                    total += sum(
+                        a.nbytes for a in (*b.data, *b.validity, b.mask)
+                        if isinstance(a, np.ndarray))
+    return total
 
 
 class HashJoinRelation(Relation):
@@ -128,6 +200,23 @@ class HashJoinRelation(Relation):
         self.build_key = build_key
         self.children = [left, right]
         self._artifact: Optional[JoinBuildArtifact] = None
+
+    @property
+    def device_batches(self) -> bool:
+        """Whether this join may hand out batches born on the device
+        (the dense probe's output): a consumer then keeps its own work
+        there too instead of reading their columns on the host.  Said
+        from the key's shape alone (one integer key, no Utf8 on either
+        side), before any build: a build that turns out not to qualify
+        (`_dense_slots`) yields host batches, which every consumer
+        takes as well."""
+        if not _device_path_enabled() or len(self.on) != 1:
+            return False
+        li, ri = self.on[0]
+        return all(
+            f.data_type.np_dtype.kind in "iu" and not _is_utf8_field(f)
+            for f in (self.left.schema.field(li), self.right.schema.field(ri))
+        )
 
     @property
     def schema(self) -> Schema:
@@ -159,10 +248,11 @@ class HashJoinRelation(Relation):
                 return art
         art = self._materialize_build()
         art.fingerprint = fp
-        if fp is not None and art.nbytes <= _pin_max_bytes():
+        if fp is not None and art.keep:
             from datafusion_tpu.obs.attribution import forget_pin
 
-            LEDGER.pin(fp, art.nbytes, owner="join.build",
+            # the pin's bytes are HBM's: a host index holds none
+            LEDGER.pin(fp, art.nbytes if art.dense else 0, owner="join.build",
                        on_evict=lambda: forget_pin(fp), artifact=art)
             cid = current_client()
             if cid is not None:
@@ -189,7 +279,17 @@ class HashJoinRelation(Relation):
                 int(v.nbytes) for v in valids if v is not None
             )
             METRICS.add("join.build.rows", n)
-            self._try_dense(art)
+            # the one size rule (module doc)
+            slots = self._dense_slots(art)
+            if slots is not None and LEDGER.fits(
+                    art.nbytes + _pad_rows(slots[1]) * 4
+                    + _unplaced_bytes(self.left, self.device)):
+                self._build_dense(art, *slots)
+            # a dense candidate that found no room is not kept: the
+            # next query asks the ledger again
+            art.keep = (art.dense if slots is not None
+                        else host_fits(art.nbytes))
+            METRICS.add("join.build.bytes", art.nbytes)
         # single-table build sides (the plan->operator boundary fills
         # `_cost_obs`) teach the cost store the dimension's size — the
         # evidence the build-side/order rewrites plan from next time
@@ -200,58 +300,63 @@ class HashJoinRelation(Relation):
             _cost.store().observe(obs[0], obs[1], rows=n, nbytes=art.nbytes)
         return art
 
-    def _try_dense(self, art: JoinBuildArtifact) -> None:
-        """Engage the device probe path when the key shape allows it:
-        one integer key, unique among live build rows, value range
-        small enough to direct-address."""
-        if not _device_path_enabled() or len(self.on) != 1:
-            return
-        li, ri = self.on[0]
+    def _dense_slots(self, art: JoinBuildArtifact) -> Optional[tuple]:
+        """(kmin, num_slots) of the direct-address table when the key
+        shape allows the device probe — one integer key, unique among
+        live build rows and at most `_SLOTS_PER_BUILD_ROW` slots a row
+        apart — else None."""
+        if not self.device_batches:
+            return None
+        ri = self.on[0][1]
         bkey = art.cols[ri]
-        pfield = self.left.schema.field(li)
-        if bkey.dtype.kind not in "iu" or pfield.data_type.np_dtype.kind not in "iu":
-            return
         # dictionary-coded (Utf8) keys LOOK integral but their codes
         # are per-dictionary — direct-address matching would compare
         # codes, not content; only the host index joins strings
-        if art.dicts[ri] is not None or _is_utf8_field(pfield):
-            return
+        if bkey.dtype.kind not in "iu" or art.dicts[ri] is not None:
+            return None
         if not art.index.unique_keys:
-            return
+            return None
         valid = art.valids[ri]
-        live = np.ones(art.n_rows, bool) if valid is None else valid.copy()
-        if art.n_rows == 0 or not live.any():
+        live = bkey if valid is None else bkey[valid]
+        if len(live) == 0:
             # empty/all-NULL build: the fused probe gathers payload rows
             # by slot, which needs at least one build row to address;
             # the host index gives "nothing matches" for free instead
-            return
-        kv = bkey[live].astype(np.int64)
-        kmin = int(kv.min())
-        num_slots = int(kv.max()) - kmin + 1
-        if num_slots > _dense_max_slots():
-            return
-        pos = (bkey.astype(np.int64) - kmin).astype(np.int32)
+            return None
+        kmin = int(live.min())
+        num_slots = int(live.max()) - kmin + 1
+        if num_slots > min(_MAX_SLOTS, _SLOTS_PER_BUILD_ROW * len(live)):
+            return None
+        return kmin, num_slots
+
+    def _build_dense(self, art: JoinBuildArtifact, kmin: int,
+                     num_slots: int) -> None:
+        """Fill the device-resident slot table and payload columns."""
+        ri = self.on[0][1]
+        bkey, valid = art.cols[ri], art.valids[ri]
+        live = np.ones(art.n_rows, bool) if valid is None else valid
+        # a NULL key's value is arbitrary: keep its position on the table
+        pos = np.clip(bkey.astype(np.int64) - kmin, 0, num_slots - 1).astype(
+            np.int32)
         art.dense = True
         art.kmin, art.num_slots = kmin, num_slots
+        art.nbytes += _pad_rows(num_slots) * 4
 
         # device residency: slot inputs + payload columns travel the
-        # compressed wire once, at build time; warm probes reuse them
-        uploads = [pos, live] + list(art.cols) + [
-            v for v in art.valids if v is not None
-        ]
-        dev = put_compressed(uploads, self.device, owner="join.build")
-        pos_d, live_d = dev[0], dev[1]
+        # compressed wire once, at build time; warm probes reuse them.
+        # Payload and slot table are padded to whole `_LANES`-wide rows
+        # (`_take_rows`); no slot and no hit points into the padding
+        pad = _pad_rows(art.n_rows) - art.n_rows
+        held = [v for v in art.valids if v is not None]
+        dev = put_compressed(
+            [pos, live] + [np.pad(a, (0, pad)) for a in art.cols + held],
+            self.device, owner="join.build",
+        )
         ncols = len(art.cols)
-        art.dev_cols = tuple(dev[2:2 + ncols])
-        vi = 2 + ncols
-        dvalids = []
-        for v in art.valids:
-            if v is None:
-                dvalids.append(None)
-            else:
-                dvalids.append(dev[vi])
-                vi += 1
-        art.dev_valids = tuple(dvalids)
+        dev_valids = iter(dev[2 + ncols:])
+        payload = (tuple(dev[2:2 + ncols]),
+                   tuple(None if v is None else next(dev_valids)
+                         for v in art.valids))
 
         # the stated engagement rule (exec/pallas): TPU batches and a
         # slot table within the kernel's window; operands are int32
@@ -261,11 +366,24 @@ class HashJoinRelation(Relation):
         )
         if use_pallas:
             METRICS.add("join.build.pallas_runs")
-        art.dev_slot_row = device_call(
-            _build_jit(num_slots, use_pallas, _pallas.interpret_mode()),
-            pos_d, live_d, _tag="join.build",
-        )
-        art.nbytes += num_slots * 4
+        # one launch fills the slot table and lays it and the payload
+        # out as rows; these outputs are what stays resident (the
+        # flat uploads go with this frame)
+        art.dev_slot_row, (art.dev_cols, art.dev_valids) = LEDGER.adopt(
+            device_call(
+                _build_jit(_pad_rows(num_slots), use_pallas,
+                           _pallas.interpret_mode()),
+                dev[0], dev[1], payload, _tag="join.build",
+            ), owner="join.build", device=self.device)
+        # the probe's two scalars, placed once: a numpy scalar handed to
+        # a jitted call is a transfer of its own in every launch
+        import jax.numpy as jnp
+
+        art.dev_kmin, art.dev_num_slots = (
+            LEDGER.put(np.int64(x), self.device, owner="join.build")
+            if self.device is not None
+            else LEDGER.adopt(jnp.asarray(np.int64(x)), owner="join.build")
+            for x in (kmin, num_slots))
         METRICS.add("join.build.dense")
 
     # -- probe ---------------------------------------------------------
@@ -290,14 +408,17 @@ class HashJoinRelation(Relation):
 
     def _dense_batches(self, art: JoinBuildArtifact):
         li = self.on[0][0]
-        probe_fn = _probe_fn_for(art.kmin, art.num_slots, self.join_type)
+        probe_fn = _probe_fn_for(self.join_type)
         for batch in self.left.batches():
-            data, validity, mask = device_inputs(batch, self.device)
-            gath, gval, out_mask = device_call(
-                probe_fn,
-                data[li], validity[li], mask, art.dev_slot_row,
-                art.dev_cols, art.dev_valids, _tag="join.probe",
-            )
+            with METRICS.timer("join.probe"):
+                data, validity, mask = device_inputs(batch, self.device)
+                gath, gval, out_mask = device_call(
+                    probe_fn,
+                    data[li], validity[li], mask, art.dev_kmin,
+                    art.dev_num_slots, art.dev_slot_row, art.dev_cols,
+                    art.dev_valids,
+                    _tag="join.probe",
+                )
             METRICS.add("join.probe.rows", batch.num_rows)
             yield RecordBatch(
                 self._schema,
@@ -316,33 +437,36 @@ class HashJoinRelation(Relation):
 
         l_keys = [k for k, _ in self.on]
         for batch in iter_with_mask_prefetch(self.left.batches()):
-            cols, valids, dicts, n = compact_batch(batch)
-            METRICS.add("join.probe.rows", n)
-            if n == 0:
-                continue
-            lidx, ridx = art.index.probe(
-                [cols[k] for k in l_keys],
-                [valids[k] for k in l_keys],
-                [dicts[k] for k in l_keys],
-                self.join_type,
-            )
-            if len(lidx) == 0:
-                continue
-            out_cols, out_valids = _core.gather_joined(
-                cols, valids, art.cols, art.valids, lidx, ridx,
-                self.join_type,
-            )
-            yield make_host_batch(
-                self._schema, out_cols, out_valids,
-                list(dicts) + list(art.dicts),
-            )
+            with METRICS.timer("join.host_probe"):
+                cols, valids, dicts, n = compact_batch(batch)
+                METRICS.add("join.host_probe.rows", n)
+                if n == 0:
+                    continue
+                lidx, ridx = art.index.probe(
+                    [cols[k] for k in l_keys],
+                    [valids[k] for k in l_keys],
+                    [dicts[k] for k in l_keys],
+                    self.join_type,
+                )
+                if len(lidx) == 0:
+                    continue
+                out_cols, out_valids = _core.gather_joined(
+                    cols, valids, art.cols, art.valids, lidx, ridx,
+                    self.join_type,
+                )
+                out = make_host_batch(
+                    self._schema, out_cols, out_valids,
+                    list(dicts) + list(art.dicts),
+                )
+            yield out
 
 
 _BUILD_JITS: dict = {}
 
 
 def _build_jit(num_slots: int, use_pallas: bool, interpret: bool):
-    """Jitted slot-table build, one per (slots, kernel-choice)."""
+    """Jitted build, one per (slots, kernel-choice): the slot table
+    filled, and it and the payload laid out as `_LANES`-wide rows."""
     key = (num_slots, use_pallas, interpret)
     hit = _BUILD_JITS.get(key)
     if hit is None:
@@ -350,13 +474,15 @@ def _build_jit(num_slots: int, use_pallas: bool, interpret: bool):
 
         from datafusion_tpu.exec.pallas import hash_build
 
-        if use_pallas:
-            def fn(pos, live):
-                return hash_build.build_slot_table(
-                    pos, live, num_slots, interpret=interpret
-                )[0]
-        else:
-            def fn(pos, live):
-                return hash_build.build_slot_table_xla(pos, live, num_slots)[0]
-        hit = _BUILD_JITS[key] = jax.jit(fn)
+        def join_build(pos, live, payload):
+            if use_pallas:
+                slot_row = hash_build.build_slot_table(
+                    pos, live, num_slots, interpret=interpret)[0]
+            else:
+                slot_row = hash_build.build_slot_table_xla(
+                    pos, live, num_slots)[0]
+            return jax.tree.map(lambda a: a.reshape(-1, _LANES),
+                                (slot_row, payload))
+
+        hit = _BUILD_JITS[key] = jax.jit(join_build)
     return hit

@@ -677,6 +677,21 @@ class DeviceLedger:
             return None
         return cap - self.live_bytes()
 
+    def fits(self, nbytes: int) -> bool:
+        """Whether `nbytes` of new residency fit what is free: the
+        test admission applies to a cold table (`need <= headroom`),
+        for whoever else places something that stays (a join's build
+        side).  `headroom` counts every live ledger buffer, pinned or
+        not, so a table's resident copies weigh in from the moment
+        they are uploaded; what a caller knows is about to follow it
+        adds to `nbytes` itself.  Where the capacity is unknowable the
+        device is the host platform and residency is host memory
+        (`host_fits`)."""
+        free = self.headroom()
+        if free is None:
+            return host_fits(nbytes)
+        return int(nbytes) <= free
+
     # -- rendering -----------------------------------------------------
     def report_text(self) -> str:
         """The ``\\hbm`` console view."""
@@ -704,6 +719,16 @@ class DeviceLedger:
         if snap["leaks_reported"]:
             lines.append(f"  leaks reported: {snap['leaks_reported']}")
         return "\n".join(lines)
+
+
+def host_fits(nbytes: int) -> bool:
+    """Whether `nbytes` fit the host's available memory (true where
+    that cannot be read): the size test for what stays on the host."""
+    try:
+        free = os.sysconf("SC_AVPHYS_PAGES") * os.sysconf("SC_PAGE_SIZE")
+    except (ValueError, OSError, AttributeError):
+        return True
+    return int(nbytes) <= free
 
 
 def hbm_capacity_bytes() -> Optional[int]:

@@ -86,6 +86,11 @@ def test_operator_stage(monkeypatch):
     assert out["join_8k_slots"]["hash_build_engaged"] is True
     assert "matched" in out["hash_build_kernel"]
     assert out["order_by_i64"]["device.launches.sort.run"] == 1
+    # the join past 2^20 slots: every row probed by a device launch
+    sparse = out["join_sparse_4m_slots"]
+    assert sparse["join.host_probe.rows"] == 0
+    assert sparse["join.probe.rows"] == 1 << 18
+    assert sparse["device.launches.join.probe"] == 2
 
 
 def test_mesh_stage_places_four_shards(resident_ctx, oracle):

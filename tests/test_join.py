@@ -20,6 +20,7 @@ import pytest
 
 from datafusion_tpu import DataType, ExecutionContext, Field, Schema
 from datafusion_tpu.exec.materialize import collect
+from datafusion_tpu.obs.device import LEDGER
 from datafusion_tpu.utils.metrics import METRICS
 
 
@@ -412,3 +413,312 @@ class TestShuffleUnits:
             exp = pd.DataFrame({"k": lk}).merge(
                 pd.DataFrame({"k": rk}), on="k", how=how).shape[0]
             assert tot == exp, (join_type, tot, exp)
+
+
+# -- a fact-to-fact join: sparse keys over a wide range, a large build ------
+
+
+def _mem_table(ctx, name, columns, batch_rows=512):
+    """Register an in-memory table from {column: ndarray | (values,
+    validity) | list of str}: int64, or Utf8 through one dictionary."""
+    from datafusion_tpu.exec.batch import StringDictionary, make_host_batch
+    from datafusion_tpu.exec.datasource import MemoryDataSource
+
+    fields, cols, valids, dicts = [], [], [], []
+    for cname, col in columns.items():
+        valid = None
+        if isinstance(col, tuple):
+            col, valid = col
+        if isinstance(col, list):
+            d = StringDictionary()
+            col, dtype = d.encode(col), DataType.UTF8
+        else:
+            d, dtype = None, DataType.INT64
+        fields.append(Field(cname, dtype, valid is not None))
+        cols.append(np.asarray(col))
+        valids.append(valid)
+        dicts.append(d)
+    schema = Schema(fields)
+    n = len(cols[0])
+    batches = [
+        make_host_batch(
+            schema, [c[lo: lo + batch_rows] for c in cols],
+            [None if v is None else v[lo: lo + batch_rows] for v in valids],
+            dicts)
+        for lo in range(0, max(n, 1), batch_rows)
+    ]
+    ctx.register_datasource(name, MemoryDataSource(schema, batches))
+
+
+PRIOS = ["1-URGENT", "2-HIGH", "3-MEDIUM"]
+
+
+def _sparse_pair(ctx, suffix, n_build=400_000, n_probe=5_000, span=1_600_000):
+    """`o<suffix>` with `n_build` unique keys spread over `span` (past
+    2^20 slots, four a build row as TPC-H's orders have them),
+    `l<suffix>` probing it with hits, in-range misses, keys far outside
+    the build's range and NULLs.  Returns the arrays."""
+    rng = np.random.default_rng(11)
+    okey = np.sort(rng.choice(span, n_build, replace=False)).astype(np.int64) + 7
+    oprio = rng.integers(0, len(PRIOS), n_build)
+    lkey = np.concatenate([
+        rng.choice(okey, n_probe - 900),
+        rng.integers(7, span + 7, 500),  # mostly misses, inside the range
+        rng.integers(-(1 << 40), 0, 200),  # far below
+        rng.integers(span + 8, 1 << 41, 200),  # far above: must not wrap
+    ]).astype(np.int64)
+    rng.shuffle(lkey)
+    lvalid = rng.random(n_probe) > 0.05
+    lseq = np.arange(n_probe, dtype=np.int64)
+    _mem_table(ctx, "o" + suffix, {
+        "ok": okey, "oprio": [PRIOS[i] for i in oprio]}, batch_rows=1 << 16)
+    _mem_table(ctx, "l" + suffix, {"lk": (lkey, lvalid), "lseq": lseq})
+    return okey, oprio, lkey, lvalid, lseq
+
+
+def _numpy_merge(okey, oprio, lkey, lvalid, lseq, how):
+    """The join written out: each probe row's build row by its key in
+    the sorted build keys."""
+    pos = np.minimum(np.searchsorted(okey, lkey), len(okey) - 1)
+    hit = lvalid & (okey[pos] == lkey)
+    rows = [(int(s), PRIOS[oprio[p]]) for s, p in zip(lseq[hit], pos[hit])]
+    if how == "left":
+        rows += [(int(s), None) for s in lseq[~hit]]
+    return sorted(rows, key=lambda r: (r[0], r[1] is None))
+
+
+class TestFactToFactJoin:
+    @pytest.fixture(autouse=True)
+    def _no_learned_sizes(self, monkeypatch):
+        """These tables probe a large build with a few rows; a planner
+        that has seen the sizes would build from the small side."""
+        monkeypatch.setenv("DATAFUSION_TPU_COST", "0")
+
+    @pytest.mark.parametrize("how", ["inner", "left"])
+    def test_sparse_key_past_2_20_slots_probes_on_the_device(
+            self, how, monkeypatch):
+        join = "JOIN" if how == "inner" else "LEFT JOIN"
+        sql = "SELECT lseq, oprio FROM l{0} " + join + " o{0} ON l{0}.lk = o{0}.ok"
+        ctx = ExecutionContext(batch_size=512)
+        arrays = _sparse_pair(ctx, "_dev_" + how)
+        assert arrays[0].max() - arrays[0].min() + 1 > 1 << 20
+        s0 = _counts()
+        got = _rows(ctx, sql.format("_dev_" + how))
+        s1 = _counts()
+        want = _numpy_merge(*arrays, how)
+        assert got == want and len(want) > 3_000
+        assert 4 * len(arrays[0]) > arrays[0].max() - arrays[0].min() + 1
+        assert _delta(s0, s1, "join.build.dense") == 1
+        assert _delta(s0, s1, "device.launches.join.probe") == -(-5_000 // 512)
+        assert _delta(s0, s1, "join.probe.rows") == 5_000
+        assert _delta(s0, s1, "join.host_probe.rows") == 0
+        assert _delta(s0, s1, "join.build.bytes") > 4 * (1 << 20)
+        # the same tables through the host HashIndex
+        monkeypatch.setenv("DATAFUSION_TPU_JOIN_DEVICE", "0")
+        host = ExecutionContext(batch_size=512)
+        _sparse_pair(host, "_host_" + how)
+        assert _rows(host, sql.format("_host_" + how)) == want
+        s2 = _counts()
+        assert _delta(s1, s2, "device.launches.join.probe") == 0
+        assert _delta(s1, s2, "join.probe.rows") == 0
+        # the host probe compacts first: NULL keys stay in, masked rows out
+        assert _delta(s1, s2, "join.host_probe.rows") == 5_000
+
+    def test_a_build_over_64_mb_is_pinned_and_probed_again(self):
+        """2.2 M keys, every fourth of 8.8 M: 35 MB of columns and a
+        35 MB slot table.  It fits what is free, so the second query
+        builds nothing."""
+        ctx = ExecutionContext(batch_size=512)
+        okey = np.arange(2_200_000, dtype=np.int64) * 4 + 5
+        _mem_table(ctx, "o_big", {"ok": okey, "oval": okey * 3},
+                   batch_rows=1 << 18)
+        lkey = np.array([5, 6, 8_800_001, 8_800_005, 9], np.int64)
+        _mem_table(ctx, "l_big", {"lk": lkey,
+                                  "lseq": np.arange(5, dtype=np.int64)})
+        sql = "SELECT lseq, oval FROM l_big JOIN o_big ON l_big.lk = o_big.ok"
+        want = [(0, 15), (2, 26_400_003), (4, 27)]
+        s0 = _counts()
+        assert _rows(ctx, sql) == want
+        s1 = _counts()
+        assert _delta(s0, s1, "join.build.bytes") > 64 << 20
+        assert _delta(s0, s1, "device.launches.join.build") == 1
+        assert LEDGER.pinned_bytes() >= _delta(s0, s1, "join.build.bytes")
+        assert _rows(ctx, sql + " WHERE lseq >= 0") == want
+        s2 = _counts()
+        assert _delta(s1, s2, "join.build.reuse") == 1
+        assert _delta(s1, s2, "join.build.rows") == 0
+        assert _delta(s1, s2, "join.build.bytes") == 0
+        assert _delta(s1, s2, "device.launches.join.build") == 0
+        assert _delta(s1, s2, "device.launches.join.probe") == 1
+
+    def test_a_small_build_over_a_wide_key_range_stays_on_the_host(self):
+        """The density side of the rule: 100 keys spread over 2^31
+        would scatter an 8.6 GB slot table.  The host index keeps the
+        job, is pinned (it holds no HBM: the pin counts none), and its
+        bytes are its columns' alone."""
+        ctx = ExecutionContext(batch_size=512)
+        okey = np.arange(100, dtype=np.int64) * ((1 << 31) // 100) + 1
+        _mem_table(ctx, "o_wide", {"ok": okey, "oval": okey * 3})
+        lkey = np.concatenate([okey[::7], okey[:5] + 1])
+        _mem_table(ctx, "l_wide", {"lk": lkey,
+                                   "lseq": np.arange(len(lkey), dtype=np.int64)})
+        sql = "SELECT lseq, oval FROM l_wide JOIN o_wide ON l_wide.lk = o_wide.ok"
+        want = [(i, int(k) * 3) for i, k in enumerate(okey[::7])]
+        s0, pinned0 = _counts(), LEDGER.pinned_bytes()
+        assert _rows(ctx, sql) == want
+        assert _rows(ctx, sql + " WHERE lseq >= 0") == want
+        s1 = _counts()
+        assert _delta(s0, s1, "join.build.dense") == 0
+        assert _delta(s0, s1, "device.launches.join.build") == 0
+        assert _delta(s0, s1, "join.host_probe.rows") == 2 * len(lkey)
+        assert _delta(s0, s1, "join.build.bytes") == 100 * 16
+        assert _delta(s0, s1, "join.build.reuse") == 1
+        assert LEDGER.pinned_bytes() == pinned0
+        # eight slots a row are the limit: 100 keys over 800 build dense
+        _mem_table(ctx, "o_near", {"ok": np.arange(100, dtype=np.int64) * 8 + 1,
+                                   "oval": okey})
+        assert len(_rows(ctx, sql.replace("o_wide", "o_near"))) == 1
+        assert _delta(s1, _counts(), "join.build.dense") == 1
+
+    def test_a_build_that_does_not_fit_is_probed_on_the_host(self, monkeypatch):
+        """The other side of the rule: what the ledger has free is less
+        than the slot table, so the host index keeps the job, is not
+        pinned, and the counter says so."""
+        ctx = ExecutionContext(batch_size=512)
+        arrays = _sparse_pair(ctx, "_nofit")  # 4.8 MB of columns, 6.4 MB of slots
+        monkeypatch.setenv("DATAFUSION_TPU_HBM_BYTES",
+                           str(LEDGER.live_bytes() + (4 << 20)))
+        assert not LEDGER.fits(12 << 20) and LEDGER.fits(1 << 20)
+        sql = ("SELECT lseq, oprio FROM l_nofit JOIN o_nofit "
+               "ON l_nofit.lk = o_nofit.ok")
+        s0 = _counts()
+        assert _rows(ctx, sql) == _numpy_merge(*arrays, "inner")
+        assert _rows(ctx, sql + " WHERE lseq >= 0") == _numpy_merge(
+            *arrays, "inner")
+        s1 = _counts()
+        assert _delta(s0, s1, "join.build.dense") == 0
+        assert _delta(s0, s1, "device.launches.join.probe") == 0
+        assert _delta(s0, s1, "join.host_probe.rows") == 2 * 5_000
+        assert _delta(s0, s1, "join.build.reuse") == 0  # built twice
+        assert _delta(s0, s1, "join.build.rows") == 2 * 400_000
+        # no slot table was made, and none is counted
+        assert _delta(s0, s1, "join.build.bytes") == 2 * 400_000 * 12
+
+    def test_grouping_join_output_by_a_build_side_string_pulls_no_key(self):
+        """Aggregate(Selection(Join)) grouped by (probe-side string,
+        build-side string): the ids are made on the device from the
+        dictionaries' codes, and the answer is all that comes back."""
+        ctx = ExecutionContext(batch_size=512)
+        okey, oprio, lkey, lvalid, lseq = _sparse_pair(ctx, "_agg")
+        modes = ["MAIL", "SHIP", "RAIL"]
+        lmode = np.random.default_rng(5).integers(0, 3, len(lkey))
+        _mem_table(ctx, "l_agg", {"lk": (lkey, lvalid), "lseq": lseq,
+                                  "lmode": [modes[i] for i in lmode]})
+        sql = ("SELECT lmode, oprio, COUNT(1) FROM l_agg JOIN o_agg "
+               "ON l_agg.lk = o_agg.ok WHERE lseq >= 100 AND "
+               "(lmode = 'MAIL' OR lmode = 'SHIP') GROUP BY lmode, oprio")
+        pos = np.minimum(np.searchsorted(okey, lkey), len(okey) - 1)
+        assert _rows(ctx, sql)  # warm: programs, dictionaries, the build
+        s0 = _counts()
+        got = collect(ctx.sql(sql.replace("100", "101")))
+        s1 = _counts()
+        keep = (lvalid & (okey[pos] == lkey) & (lseq >= 101) & (lmode < 2))
+        want = {}
+        for m, p in zip(lmode[keep], oprio[pos[keep]]):
+            want[(modes[m], PRIOS[p])] = want.get((modes[m], PRIOS[p]), 0) + 1
+        assert sorted(got.to_rows()) == sorted(
+            (m, p, n) for (m, p), n in want.items())
+        assert len(want) == 6
+        # the ids are made inside the aggregate's own launches
+        assert _delta(s0, s1, "device.launches") == -(-5_000 // 512) + sum(
+            _delta(s0, s1, "device.launches.agg" + t)
+            for t in ("", ".group", ".chunk"))
+        assert _delta(s0, s1, "device.launches.join.probe") == -(-5_000 // 512)
+        assert _delta(s0, s1, "join.host_probe.rows") == 0
+        # the accumulator's state (counts of at most 16 slots and the
+        # group's row counts) is all that crosses: no 512-row key column
+        assert 0 < _delta(s0, s1, "d2h.bytes") <= 1024
+
+
+@pytest.mark.parametrize("how", ["inner", "left"])
+@pytest.mark.parametrize("keys", ["clustered", "scattered", "no_live_row"])
+def test_probe_reading_rows_equals_numpy_reading_elements(how, keys):
+    """The probe program (tables read as 128-wide rows, the lane
+    selected afterwards) against the same lookups written with numpy's
+    element indexing: a nullable payload, NULL and far keys, keys that
+    would wrap into a slot as int32."""
+    import jax.numpy as jnp
+
+    from datafusion_tpu.join import relation as jr
+
+    rng = np.random.default_rng(3)
+    n_build, kmin = 40_000, 1_000
+    num_slots = 700_077
+    pos = np.sort(rng.choice(num_slots, n_build, replace=False))
+    slot_row = np.full(jr._pad_rows(num_slots), -1, np.int32)
+    slot_row[pos] = np.arange(n_build, dtype=np.int32)
+    pad = jr._pad_rows(n_build) - n_build
+    bkey = np.pad(pos.astype(np.int64) + kmin, (0, pad))
+    pay = np.pad(rng.integers(0, 1 << 40, n_build), (0, pad))
+    pay_valid = np.pad(rng.random(n_build) > 0.2, (0, pad))
+    cap = 4_096
+    if keys == "clustered":
+        lo = int(pos[20_000])
+        key = rng.integers(lo, lo + 60_000, cap) + kmin
+        key[:2_000] = rng.choice(pos[(pos >= lo) & (pos < lo + 60_000)], 2_000) + kmin
+    else:
+        key = rng.integers(-5, num_slots + 5, cap) + kmin
+        key[:2_000] = rng.choice(pos, 2_000) + kmin
+        key[2_000:2_010] = [-(1 << 40), 1 << 41, kmin - 1, kmin + num_slots,
+                            (1 << 32) + kmin + int(pos[0])] * 2
+    rows = lambda a: jnp.asarray(a).reshape(-1, jr._LANES)  # noqa: E731
+    kvalid = rng.random(cap) > 0.1
+    if keys == "no_live_row":
+        kvalid[:] = False
+    mask = rng.random(cap) > 0.3
+    gath, gval, out_mask = jr._probe_fn_for(how)(
+        jnp.asarray(key.astype(np.int64)), jnp.asarray(kvalid),
+        jnp.asarray(mask), np.int64(kmin), np.int64(num_slots),
+        rows(slot_row), (rows(bkey), rows(pay)), (None, rows(pay_valid)))
+    d = key - kmin
+    inr = kvalid & (d >= 0) & (d < num_slots)
+    bidx = np.where(inr, slot_row[np.where(inr, d, 0)], -1)
+    hit = bidx >= 0
+    assert np.array_equal(hit, kvalid & np.isin(d, pos))
+    assert hit.sum() > (0 if keys != "no_live_row" else -1)
+    sb = np.where(hit, bidx, 0)
+    assert np.array_equal(np.asarray(gath[0]), bkey[sb])
+    assert np.array_equal(np.asarray(gath[1]), pay[sb])
+    assert np.array_equal(np.asarray(gval[1]), hit & pay_valid[sb])
+    if how == "inner":
+        assert gval[0] is None
+        assert np.array_equal(np.asarray(out_mask), mask & hit)
+    else:
+        assert np.array_equal(np.asarray(gval[0]), hit)
+        assert np.array_equal(np.asarray(out_mask), mask)
+    assert np.array_equal(np.asarray(gath[0])[hit], key[hit])
+
+
+def test_contexts_with_same_named_memory_tables_do_not_share_a_build():
+    """A pinned build outlives its context in the process-wide ledger:
+    two contexts that register different in-memory tables under the
+    same names (a test process, a benchmark run after another) each
+    get their own, by the fingerprint of the tables' identity."""
+    sql = "SELECT lseq, oprio FROM l_same JOIN o_same ON l_same.lk = o_same.ok"
+    seen = []
+    for prios in (["1-URGENT", "2-HIGH"], ["3-MEDIUM", "2-HIGH"]):
+        ctx = ExecutionContext(batch_size=512)
+        _mem_table(ctx, "o_same", {"ok": np.array([3, 9], np.int64),
+                                   "oprio": prios})
+        _mem_table(ctx, "l_same", {"lk": np.array([9, 3, 4], np.int64),
+                                   "lseq": np.arange(3, dtype=np.int64)})
+        s0 = _counts()
+        assert _rows(ctx, sql) == [(0, prios[1]), (1, prios[0])]
+        assert _rows(ctx, sql + " WHERE lseq >= 0") == [(0, prios[1]),
+                                                        (1, prios[0])]
+        s1 = _counts()
+        # its own build, once; the second query of the context reuses it
+        assert _delta(s0, s1, "join.build.rows") == 2
+        assert _delta(s0, s1, "join.build.reuse") == 1
+        seen.append(ctx.query_fingerprint(_plan_of(ctx, sql)))
+    assert seen[0] != seen[1]
