@@ -354,7 +354,8 @@ def stage_operators(device: str, agg_rows: int = 1 << 21) -> dict:
     out: dict = {}
     extra = ("join.build.dense", "join.build.pallas_runs",
              "device.launches.sort.run", "device.launches.join.probe",
-             "d2h.bytes", "join.probe.rows", "join.host_probe.rows")
+             "d2h.bytes", "join.probe.rows", "join.host_probe.rows",
+             "join.probe.gathers")
 
     def run(label, src_by_name, sql, want, batch_size=1 << 19):
         ctx = ExecutionContext(device=device, batch_size=batch_size,
@@ -492,6 +493,14 @@ def stage_operators(device: str, agg_rows: int = 1 << 21) -> dict:
             and ev["join.probe.rows"] == n_sort
             and ev["device.launches.join.probe"] == n_sort >> 17,
             f"join_sparse_4m_slots: not every row probed on the device: {ev}")
+    # the build's arrays other than its key: `prio`'s codes, no validity
+    payload_arrays = 1
+    require(ev["join.probe.gathers"]
+            == ev["device.launches.join.probe"] * payload_arrays,
+            "join_sparse_4m_slots: a probe launch gathers "
+            f"{ev['join.probe.gathers']} build arrays in "
+            f"{ev['device.launches.join.probe']} launches; the build key "
+            "comes from the probe key and `prio` alone is gathered")
     require(0 < ev["d2h.bytes"] <= 1024,
             "join_sparse_4m_slots: more than the answer came back "
             f"({ev['d2h.bytes']} B): group ids not made on the device")

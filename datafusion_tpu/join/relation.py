@@ -13,7 +13,16 @@ left input).  The build side fully materializes once into a
   (``device.launches.join.probe``, program ``jit_join_probe``)
   computing hit mask + payload gather at probe capacity — no host
   round trip, masks carried, zero extra H2D once the artifact is
-  resident.
+  resident.  The resident payload is the build's columns **other than
+  the key**: on a hit the build row's key IS the probe row's key, and
+  on a miss it is masked out (INNER) or NULL (LEFT OUTER), so the
+  join's output column for the build key is the probe batch's own key
+  (cast to the build column's dtype where the two differ) and no
+  launch reads the key column — at TPC-H's 15 M-row int64
+  `o_orderkey` its two gathers and the split into u32 halves were
+  2.4 of 3.84 device seconds a query (PERF.md section 6, PR 29).
+  ``join.probe.gathers`` counts, per launch, the build-side arrays
+  (columns and validity arrays) the launch still gathers.
 - **host probe**: everything else (multi-key, strings, duplicate
   keys, a build that does not fit).  `core.HashIndex` CSR-expands
   matches per batch; its rows count as ``join.host_probe.rows``.
@@ -23,7 +32,8 @@ when its direct-address table is no sparser than a hash table would
 be roomy — at most `_SLOTS_PER_BUILD_ROW` slots a live build row: an
 open-addressing table of (8 B key, 4 B row) entries padded to 16 B,
 at half load, spends 32 B a row, which is what 8 slots of 4 B cost — and the table and
-the payload fit what the device ledger says is free less what the
+the payload (what is placed: not the key column) fit what the device
+ledger says is free less what the
 probe side has yet to upload (`LEDGER.fits`: capacity less every live
 ledger buffer; admission keeps no further reserve, so neither does
 this).  It is pinned under the same test.  A dimension table and a
@@ -80,7 +90,11 @@ def _is_utf8_field(field) -> bool:
 class JoinBuildArtifact:
     """The materialized build side: compacted host columns + the
     `HashIndex`, plus — on the dense path — the device-resident slot
-    table and payload columns the fused probe launches gather from."""
+    table and payload the fused probe launches gather from.  The
+    payload (`dev_cols`, `dev_valids`, in column order) leaves the key
+    column out: a hit's build key is its probe key, so the probe hands
+    it on from its own batch (module doc).  `cols` and `index` stay
+    whole: the host probe of the same artifact reads them."""
 
     __slots__ = ("cols", "valids", "dicts", "n_rows", "index", "dense",
                  "kmin", "num_slots", "device", "dev_kmin", "dev_num_slots",
@@ -123,15 +137,24 @@ def _take_rows(table, idx):
 
 
 @functools.lru_cache(maxsize=None)
-def _probe_fn_for(join_type: str):
+def _probe_fn_for(join_type: str, build_key_dtype: str):
     """One fused probe launch: slot lookup, hit mask, payload gather,
     validity, selection-mask combine — all inside a single jit whose
     program (`jit_join_probe`) is specialised by shapes alone: `kmin`
     and the slot count are arguments, so every dense artifact of one
     shape class shares compiled probes.  The slot table and the
-    payload are `[rows, _LANES]` (`_take_rows`)."""
+    payload (`pcols`, `pvalids`: the build's columns other than its
+    key) are `[rows, _LANES]` (`_take_rows`).
+
+    Returns `(kcol, kval, gath, gval, out_mask)`: `kcol` / `kval` are
+    the join's output column for the build key, made from the probe
+    key — `kcol` None where the probe key already has
+    `build_key_dtype` (the caller hands on the probe's own array),
+    else the cast; at miss rows its values are not observable."""
     import jax
     import jax.numpy as jnp
+
+    key_dtype = jnp.dtype(build_key_dtype)
 
     def join_probe(key, kvalid, mask, kmin, num_slots, slot_row, pcols,
                    pvalids):
@@ -146,6 +169,10 @@ def _probe_fn_for(join_type: str):
         hit = bidx >= 0
         sb = jnp.where(hit, bidx, 0)
         inner = join_type == "inner"
+        kcol = None if key.dtype == key_dtype else key.astype(key_dtype)
+        # only live build rows are in the slot table: a hit's build key
+        # is not NULL, whatever the build column's validity array says
+        kval = None if inner else hit
         gath = tuple(_take_rows(c, sb) for c in pcols)
         # an INNER join masks its misses out: a build column without
         # NULLs stays without a validity array
@@ -156,7 +183,7 @@ def _probe_fn_for(join_type: str):
             out_mask = hit if mask is None else mask & hit
         else:
             out_mask = mask
-        return gath, gval, out_mask
+        return kcol, kval, gath, gval, out_mask
 
     return jax.jit(join_probe)
 
@@ -282,7 +309,7 @@ class HashJoinRelation(Relation):
             # the one size rule (module doc)
             slots = self._dense_slots(art)
             if slots is not None and LEDGER.fits(
-                    art.nbytes + _pad_rows(slots[1]) * 4
+                    self._placed_bytes(art, slots[1])
                     + _unplaced_bytes(self.left, self.device)):
                 self._build_dense(art, *slots)
             # a dense candidate that found no room is not kept: the
@@ -329,6 +356,14 @@ class HashJoinRelation(Relation):
             return None
         return kmin, num_slots
 
+    def _placed_bytes(self, art: JoinBuildArtifact, num_slots: int) -> int:
+        """HBM a dense build of `art` holds: the slot table and every
+        column but the key (module doc)."""
+        ri = self.on[0][1]
+        key = art.cols[ri].nbytes + (
+            0 if art.valids[ri] is None else art.valids[ri].nbytes)
+        return art.nbytes - key + _pad_rows(num_slots) * 4
+
     def _build_dense(self, art: JoinBuildArtifact, kmin: int,
                      num_slots: int) -> None:
         """Fill the device-resident slot table and payload columns."""
@@ -340,23 +375,27 @@ class HashJoinRelation(Relation):
             np.int32)
         art.dense = True
         art.kmin, art.num_slots = kmin, num_slots
-        art.nbytes += _pad_rows(num_slots) * 4
+        # from here on the artifact's bytes are what it holds in HBM
+        art.nbytes = self._placed_bytes(art, num_slots)
 
-        # device residency: slot inputs + payload columns travel the
-        # compressed wire once, at build time; warm probes reuse them.
-        # Payload and slot table are padded to whole `_LANES`-wide rows
-        # (`_take_rows`); no slot and no hit points into the padding
+        # device residency: slot inputs + payload columns (every column
+        # but the key) travel the compressed wire once, at build time;
+        # warm probes reuse them.  Payload and slot table are padded to
+        # whole `_LANES`-wide rows (`_take_rows`); no slot and no hit
+        # points into the padding
         pad = _pad_rows(art.n_rows) - art.n_rows
-        held = [v for v in art.valids if v is not None]
+        cols = art.cols[:ri] + art.cols[ri + 1:]
+        valids = art.valids[:ri] + art.valids[ri + 1:]
+        held = [v for v in valids if v is not None]
         dev = put_compressed(
-            [pos, live] + [np.pad(a, (0, pad)) for a in art.cols + held],
+            [pos, live] + [np.pad(a, (0, pad)) for a in cols + held],
             self.device, owner="join.build",
         )
-        ncols = len(art.cols)
+        ncols = len(cols)
         dev_valids = iter(dev[2 + ncols:])
         payload = (tuple(dev[2:2 + ncols]),
                    tuple(None if v is None else next(dev_valids)
-                         for v in art.valids))
+                         for v in valids))
 
         # the stated engagement rule (exec/pallas): TPU batches and a
         # slot table within the kernel's window; operands are int32
@@ -407,12 +446,16 @@ class HashJoinRelation(Relation):
         return iter_stats(self, it)
 
     def _dense_batches(self, art: JoinBuildArtifact):
-        li = self.on[0][0]
-        probe_fn = _probe_fn_for(self.join_type)
+        li, ri = self.on[0]
+        probe_fn = _probe_fn_for(self.join_type, art.cols[ri].dtype.name)
+        # what a launch gathers from: the build's arrays other than its
+        # key's, which the launch makes from the probe key (module doc)
+        gathers = len(art.dev_cols) + sum(
+            v is not None for v in art.dev_valids)
         for batch in self.left.batches():
             with METRICS.timer("join.probe"):
                 data, validity, mask = device_inputs(batch, self.device)
-                gath, gval, out_mask = device_call(
+                kcol, kval, gath, gval, out_mask = device_call(
                     probe_fn,
                     data[li], validity[li], mask, art.dev_kmin,
                     art.dev_num_slots, art.dev_slot_row, art.dev_cols,
@@ -420,10 +463,14 @@ class HashJoinRelation(Relation):
                     _tag="join.probe",
                 )
             METRICS.add("join.probe.rows", batch.num_rows)
+            METRICS.add("join.probe.gathers", gathers)
+            gath, gval = list(gath), list(gval)
+            gath.insert(ri, data[li] if kcol is None else kcol)
+            gval.insert(ri, kval)
             yield RecordBatch(
                 self._schema,
-                list(data) + list(gath),
-                list(validity) + list(gval),
+                list(data) + gath,
+                list(validity) + gval,
                 list(batch.dicts) + list(art.dicts),
                 num_rows=batch.num_rows,
                 mask=out_mask,

@@ -60,22 +60,19 @@ def jctx(tmp_path):
     return ctx
 
 
-def _rows(ctx, sql):
-    def key(row):
-        return tuple((v is None, 0 if v is None else v) for v in row)
+def _nulls_last(row):
+    return tuple((v is None, 0 if v is None else v) for v in row)
 
-    return sorted(collect(ctx.sql(sql)).to_rows(), key=key)
+
+def _rows(ctx, sql):
+    return sorted(collect(ctx.sql(sql)).to_rows(), key=_nulls_last)
 
 
 def _pd_rows(df, cols):
     out = []
     for t in df[cols].itertuples(index=False):
         out.append(tuple(None if pd.isna(v) else v for v in t))
-
-    def key(row):
-        return tuple((v is None, 0 if v is None else v) for v in row)
-
-    return sorted(out, key=key)
+    return sorted(out, key=_nulls_last)
 
 
 def _counts():
@@ -244,7 +241,9 @@ class TestPinnedBuild:
         _rows(jctx, q)
         s1 = _counts()
         # different predicate -> result cache miss, same build subtree
-        _rows(jctx, q + " WHERE x > 5")
+        # (over a column the cold query read too: the probe side ships
+        # the same columns both times)
+        _rows(jctx, q + " WHERE seq >= 0")
         s2 = _counts()
         assert _delta(s1, s2, "join.build.reuse") == 1
         assert _delta(s1, s2, "device.launches.join.build") == 0
@@ -253,7 +252,8 @@ class TestPinnedBuild:
         # pass which also uploaded the build artifact
         cold = _delta(s0, s1, "device.h2d.transfers")
         warm = _delta(s1, s2, "device.h2d.transfers")
-        assert warm < cold
+        # slot positions, live flags and `name`'s codes: not the key
+        assert cold - warm == 3
 
     def test_distinct_key_columns_distinct_pins(self, jctx):
         # same build subtree joined on DIFFERENT right-side key columns
@@ -420,7 +420,9 @@ class TestShuffleUnits:
 
 def _mem_table(ctx, name, columns, batch_rows=512):
     """Register an in-memory table from {column: ndarray | (values,
-    validity) | list of str}: int64, or Utf8 through one dictionary."""
+    validity) | list of str}: the array's own integer type, or Utf8
+    through one dictionary."""
+    from datafusion_tpu.datatypes import from_np_dtype
     from datafusion_tpu.exec.batch import StringDictionary, make_host_batch
     from datafusion_tpu.exec.datasource import MemoryDataSource
 
@@ -433,7 +435,7 @@ def _mem_table(ctx, name, columns, batch_rows=512):
             d = StringDictionary()
             col, dtype = d.encode(col), DataType.UTF8
         else:
-            d, dtype = None, DataType.INT64
+            d, dtype = None, from_np_dtype(np.asarray(col).dtype)
         fields.append(Field(cname, dtype, valid is not None))
         cols.append(np.asarray(col))
         valids.append(valid)
@@ -525,22 +527,28 @@ class TestFactToFactJoin:
         assert _delta(s1, s2, "join.host_probe.rows") == 5_000
 
     def test_a_build_over_64_mb_is_pinned_and_probed_again(self):
-        """2.2 M keys, every fourth of 8.8 M: 35 MB of columns and a
-        35 MB slot table.  It fits what is free, so the second query
-        builds nothing."""
+        """2.2 M keys, every fourth of 8.8 M: 35 MB of payload columns
+        and a 35 MB slot table; the 18 MB key column is not placed.  It
+        fits what is free, so the second query builds nothing."""
+        from datafusion_tpu.join.relation import _pad_rows
+
         ctx = ExecutionContext(batch_size=512)
         okey = np.arange(2_200_000, dtype=np.int64) * 4 + 5
-        _mem_table(ctx, "o_big", {"ok": okey, "oval": okey * 3},
-                   batch_rows=1 << 18)
+        _mem_table(ctx, "o_big", {"ok": okey, "oval": okey * 3,
+                                  "oneg": -okey}, batch_rows=1 << 18)
         lkey = np.array([5, 6, 8_800_001, 8_800_005, 9], np.int64)
         _mem_table(ctx, "l_big", {"lk": lkey,
                                   "lseq": np.arange(5, dtype=np.int64)})
-        sql = "SELECT lseq, oval FROM l_big JOIN o_big ON l_big.lk = o_big.ok"
-        want = [(0, 15), (2, 26_400_003), (4, 27)]
+        sql = ("SELECT lseq, oval, oneg FROM l_big JOIN o_big "
+               "ON l_big.lk = o_big.ok")
+        want = [(0, 15, -5), (2, 26_400_003, -8_800_001), (4, 27, -9)]
         s0 = _counts()
         assert _rows(ctx, sql) == want
         s1 = _counts()
-        assert _delta(s0, s1, "join.build.bytes") > 64 << 20
+        # what is placed: two payload columns and the slot table
+        assert _delta(s0, s1, "join.build.bytes") == (
+            2 * okey.nbytes + 4 * _pad_rows(int(okey[-1] - okey[0]) + 1)
+        ) > 64 << 20
         assert _delta(s0, s1, "device.launches.join.build") == 1
         assert LEDGER.pinned_bytes() >= _delta(s0, s1, "join.build.bytes")
         assert _rows(ctx, sql + " WHERE lseq >= 0") == want
@@ -646,7 +654,8 @@ def test_probe_reading_rows_equals_numpy_reading_elements(how, keys):
     """The probe program (tables read as 128-wide rows, the lane
     selected afterwards) against the same lookups written with numpy's
     element indexing: a nullable payload, NULL and far keys, keys that
-    would wrap into a slot as int32."""
+    would wrap into a slot as int32.  The build key column is made
+    from the probe key: equal to `bkey[sb]` wherever a row hit."""
     import jax.numpy as jnp
 
     from datafusion_tpu.join import relation as jr
@@ -658,7 +667,7 @@ def test_probe_reading_rows_equals_numpy_reading_elements(how, keys):
     slot_row = np.full(jr._pad_rows(num_slots), -1, np.int32)
     slot_row[pos] = np.arange(n_build, dtype=np.int32)
     pad = jr._pad_rows(n_build) - n_build
-    bkey = np.pad(pos.astype(np.int64) + kmin, (0, pad))
+    bkey = np.pad(pos.astype(np.int32) + kmin, (0, pad))
     pay = np.pad(rng.integers(0, 1 << 40, n_build), (0, pad))
     pay_valid = np.pad(rng.random(n_build) > 0.2, (0, pad))
     cap = 4_096
@@ -676,10 +685,12 @@ def test_probe_reading_rows_equals_numpy_reading_elements(how, keys):
     if keys == "no_live_row":
         kvalid[:] = False
     mask = rng.random(cap) > 0.3
-    gath, gval, out_mask = jr._probe_fn_for(how)(
-        jnp.asarray(key.astype(np.int64)), jnp.asarray(kvalid),
-        jnp.asarray(mask), np.int64(kmin), np.int64(num_slots),
-        rows(slot_row), (rows(bkey), rows(pay)), (None, rows(pay_valid)))
+    args = (jnp.asarray(key.astype(np.int64)), jnp.asarray(kvalid),
+            jnp.asarray(mask), np.int64(kmin), np.int64(num_slots),
+            rows(slot_row), (rows(pay),), (rows(pay_valid),))
+    kcol, kval, gath, gval, out_mask = jr._probe_fn_for(how, "int32")(*args)
+    # a probe key of the build column's own dtype is handed on as it is
+    assert jr._probe_fn_for(how, "int64")(*args)[0] is None
     d = key - kmin
     inr = kvalid & (d >= 0) & (d < num_slots)
     bidx = np.where(inr, slot_row[np.where(inr, d, 0)], -1)
@@ -687,16 +698,172 @@ def test_probe_reading_rows_equals_numpy_reading_elements(how, keys):
     assert np.array_equal(hit, kvalid & np.isin(d, pos))
     assert hit.sum() > (0 if keys != "no_live_row" else -1)
     sb = np.where(hit, bidx, 0)
-    assert np.array_equal(np.asarray(gath[0]), bkey[sb])
-    assert np.array_equal(np.asarray(gath[1]), pay[sb])
-    assert np.array_equal(np.asarray(gval[1]), hit & pay_valid[sb])
+    assert kcol.dtype == bkey.dtype
+    assert np.array_equal(np.asarray(kcol)[hit], bkey[sb][hit])
+    # across signedness too: a hit's key fits the build column's type
+    ucol = jr._probe_fn_for(how, "uint32")(*args)[0]
+    assert ucol.dtype == np.uint32
+    assert np.array_equal(np.asarray(ucol)[hit], bkey[sb][hit])
+    assert len(gath) == len(gval) == 1
+    assert np.array_equal(np.asarray(gath[0]), pay[sb])
+    assert np.array_equal(np.asarray(gval[0]), hit & pay_valid[sb])
     if how == "inner":
-        assert gval[0] is None
+        assert kval is None
         assert np.array_equal(np.asarray(out_mask), mask & hit)
     else:
-        assert np.array_equal(np.asarray(gval[0]), hit)
+        assert np.array_equal(np.asarray(kval), hit)
         assert np.array_equal(np.asarray(out_mask), mask)
-    assert np.array_equal(np.asarray(gath[0])[hit], key[hit])
+
+
+# -- the build key column is made from the probe key ------------------------
+# (probe key dtype, build key dtype, join, nullable build key, select
+# list; the build has a payload column `oval` where the list names it,
+# else its key is its only column): every dense probe hands the build
+# key on from the probe batch, so each shape of key must read as the
+# gather did
+_KEY_CASES = {
+    "i32_probe_i64_build": (np.int32, np.int64, "inner", False,
+                            "lseq, ok, oval"),
+    "i64_probe_i32_build": (np.int64, np.int32, "inner", False,
+                            "lseq, ok, oval"),
+    # (the planner coerces no unsigned key to a signed one: the program
+    # test above casts across signedness)
+    "u32_probe_u64_build": (np.uint32, np.uint64, "left", False,
+                            "lseq, ok, oval"),
+    "u32_probe_u32_build": (np.uint32, np.uint32, "inner", False,
+                            "lseq, ok"),
+    "left_outer_null_key": (np.int64, np.int64, "left", False,
+                            "lseq, lk, ok, oval"),
+    "nullable_build_key_inner": (np.int64, np.int64, "inner", True,
+                                 "lseq, ok, oval"),
+    "nullable_build_key_left": (np.int64, np.int64, "left", True,
+                                "lseq, ok, oval"),
+    "key_only_build_inner": (np.int64, np.int64, "inner", False,
+                             "lseq, ok"),
+    "key_only_build_left": (np.int32, np.int64, "left", False,
+                            "lseq, ok"),
+    "select_build_key_alone": (np.int64, np.int64, "inner", False,
+                               "ok"),
+    "group_by_build_key": (np.int64, np.int64, "inner", False,
+                           "ok, COUNT(1)"),
+    "group_by_cast_build_key": (np.int64, np.int32, "inner", False,
+                                "ok, COUNT(1)"),
+}
+
+
+def _key_case_tables(ctx, suffix, probe_dt, build_dt, null_build, payload):
+    """`o<suffix>` (300 unique keys over 1,500 slots, its key of
+    `build_dt`, NULL in a tenth of its rows if `null_build`) and
+    `l<suffix>` (1,000 rows of `probe_dt`: hits, misses inside the
+    range, keys whose cast to the build's type would wrap onto a build
+    key, NULLs).  Returns the arrays."""
+    rng = np.random.default_rng(29)
+    okey = (np.sort(rng.choice(1_500, 300, replace=False)) + 40).astype(build_dt)
+    ovalid = rng.random(300) > 0.1 if null_build else None
+    oval = rng.integers(-(1 << 40), 1 << 40, 300)
+    lkey = np.concatenate([rng.choice(okey, 600),
+                           rng.integers(0, 1_600, 380)]).astype(np.int64)
+    far = okey[:20].astype(np.int64)
+    if np.dtype(probe_dt).itemsize == 8:
+        far = far + (1 << 32)  # as int32 / uint32: a build key
+    lkey = np.concatenate([lkey, far])
+    rng.shuffle(lkey)
+    lkey = lkey.astype(probe_dt)
+    lvalid = rng.random(1_000) > 0.05
+    lseq = np.arange(1_000, dtype=np.int64)
+    build = {"ok": okey if ovalid is None else (okey, ovalid)}
+    if payload:
+        build["oval"] = oval
+    _mem_table(ctx, "o" + suffix, build, batch_rows=128)
+    _mem_table(ctx, "l" + suffix, {"lk": (lkey, lvalid), "lseq": lseq},
+               batch_rows=256)
+    return okey, ovalid, oval, lkey, lvalid, lseq
+
+
+def _key_case_rows(arrays, how, select):
+    """The join written out with a dict from live build key to row."""
+    okey, ovalid, oval, lkey, lvalid, lseq = arrays
+    row_of = {int(k): i for i, k in enumerate(okey)
+              if ovalid is None or ovalid[i]}
+    rows = []
+    for k, v, seq in zip(lkey.tolist(), lvalid.tolist(), lseq.tolist()):
+        b = row_of.get(k) if v else None
+        if b is None and how == "inner":
+            continue
+        rows.append({
+            "lseq": seq, "lk": k if v else None,
+            "ok": None if b is None else int(okey[b]),
+            "oval": None if b is None else int(oval[b])})
+    names = select.split(", ")
+    if names[-1] == "COUNT(1)":
+        tally: dict = {}
+        for r in rows:
+            tally[r["ok"]] = tally.get(r["ok"], 0) + 1
+        out = list(tally.items())
+    else:
+        out = [tuple(r[n] for n in names) for r in rows]
+    return sorted(out, key=_nulls_last)
+
+
+@pytest.mark.parametrize("case", list(_KEY_CASES))
+def test_build_key_column_is_made_from_the_probe_key(case, monkeypatch):
+    """A dense probe's output column for the build key against numpy
+    and against the host `HashIndex` path over the same tables, and
+    `join.probe.gathers`: a launch gathers from the build's arrays
+    other than its key's."""
+    monkeypatch.setenv("DATAFUSION_TPU_COST", "0")
+    probe_dt, build_dt, how, null_build, select = _KEY_CASES[case]
+    payload = "oval" in select
+    sql = ("SELECT {0} FROM l{1} " + ("JOIN" if how == "inner" else "LEFT JOIN")
+           + " o{1} ON l{1}.lk = o{1}.ok"
+           + (" GROUP BY ok" if "COUNT" in select else ""))
+    ctx = ExecutionContext(batch_size=256)
+    arrays = _key_case_tables(ctx, "_kd_" + case, probe_dt, build_dt,
+                              null_build, payload)
+    want = _key_case_rows(arrays, how, select)
+    assert len(want) > (200 if "COUNT" in select else 500)
+    s0 = _counts()
+    assert _rows(ctx, sql.format(select, "_kd_" + case)) == want
+    s1 = _counts()
+    assert _delta(s0, s1, "join.build.dense") == 1
+    assert _delta(s0, s1, "join.host_probe.rows") == 0
+    assert _delta(s0, s1, "join.probe.rows") == 1_000
+    launches = _delta(s0, s1, "device.launches.join.probe")
+    assert launches == -(-1_000 // 256)
+    # the key's column and validity are never among them, nor placed:
+    # the slot table and the payload column are all
+    assert _delta(s0, s1, "join.probe.gathers") == launches * payload
+    assert _delta(s0, s1, "join.build.bytes") == 4 * 1_536 + 8 * 300 * payload
+    monkeypatch.setenv("DATAFUSION_TPU_JOIN_DEVICE", "0")
+    host = ExecutionContext(batch_size=256)
+    _key_case_tables(host, "_kh_" + case, probe_dt, build_dt, null_build,
+                     payload)
+    assert _rows(host, sql.format(select, "_kh_" + case)) == want
+    s2 = _counts()
+    assert _delta(s1, s2, "join.probe.gathers") == 0
+    assert _delta(s1, s2, "join.host_probe.rows") > 0
+
+
+def test_probe_gathers_counts_the_builds_arrays_other_than_the_key():
+    """Two payload columns, one of them nullable, the key standing
+    between them: three arrays a launch, where the build has five
+    (key, its validity, two columns, one validity)."""
+    ctx = ExecutionContext(batch_size=256)
+    rng = np.random.default_rng(31)
+    okey = np.arange(500, dtype=np.int64) * 3 + 11
+    _mem_table(ctx, "o_g3", {
+        "oa": okey * 7, "ok": (okey, rng.random(500) > 0.1),
+        "ob": (okey * 9, rng.random(500) > 0.5)})
+    _mem_table(ctx, "l_g3", {"lk": rng.integers(0, 1_600, 700),
+                             "lseq": np.arange(700, dtype=np.int64)})
+    s0 = _counts()
+    got = _rows(ctx, "SELECT lseq, ok, oa, ob FROM l_g3 JOIN o_g3 "
+                     "ON l_g3.lk = o_g3.ok")
+    s1 = _counts()
+    assert got and all(k * 7 == a and b in (None, k * 9)
+                       for _, k, a, b in got)
+    assert _delta(s0, s1, "device.launches.join.probe") == 2
+    assert _delta(s0, s1, "join.probe.gathers") == 2 * 3
 
 
 def test_contexts_with_same_named_memory_tables_do_not_share_a_build():
