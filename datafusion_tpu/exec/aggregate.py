@@ -1025,96 +1025,6 @@ class _AggregateCore:
         return new_counts, tuple(new_accs)
 
 
-# host throughput assumed by the placement cost model: one grouped
-# pass (numpy eval + bincount) over a column on one core.  Measured
-# ~100-150 M rows/s here; the constant only needs order-of-magnitude
-# accuracy — link rates differ from it by 50x in either direction.
-_HOST_AGG_SECONDS_PER_ROW = 8e-9
-
-
-class _Placement:
-    """Outcome of the link-aware slot split: which SELECT-list specs
-    compute on host, and the (smaller) device core for the rest."""
-
-    __slots__ = ("host_idx", "core", "params")
-
-    def __init__(self, host_idx, core, params):
-        self.host_idx = host_idx  # frozenset of spec positions
-        self.core = core          # _AggregateCore or None (full host)
-        self.params = params
-
-
-class _HostPartials:
-    """Grouped partial aggregation on the host for link-expensive
-    slots: per-batch numpy eval of the slot argument + np.bincount per
-    group.  Arithmetic is plain IEEE f64 — the same number class as
-    the engine's CPU path.  Only float SUM/AVG and COUNT route here
-    (integer sums keep exact int64 accumulation on device; bincount
-    weights are f64)."""
-
-    __slots__ = ("rel", "sum_exprs", "cnt_exprs", "sums", "cnts", "rowcounts")
-
-    def __init__(self, rel, host_idx):
-        self.rel = rel
-        self.sum_exprs: dict[str, Expr] = {}
-        self.cnt_exprs: dict[str, Expr] = {}
-        for j in host_idx:
-            s = rel.specs[j]
-            k = repr(s.arg)
-            if s.name in ("sum", "avg"):
-                self.sum_exprs[k] = s.arg
-                self.cnt_exprs[k] = s.arg
-            elif s.name == "count" and not s.count_star:
-                self.cnt_exprs[k] = s.arg
-        self.sums: dict[str, np.ndarray] = {}
-        self.cnts: dict[str, np.ndarray] = {}
-        self.rowcounts: Optional[np.ndarray] = None
-
-    @staticmethod
-    def _grown(arr, n, dtype):
-        if arr is None:
-            return np.zeros(n, dtype)
-        if len(arr) < n:
-            return np.pad(arr, (0, n - len(arr)))
-        return arr
-
-    def update(self, batch, ids_np, live, track_rowcounts):
-        from datafusion_tpu.exec.hostfn import eval_host_expr
-
-        n = max(self.rel.encoder.num_groups, 1) if self.rel.key_cols else 1
-        if track_rowcounts:
-            self.rowcounts = self._grown(self.rowcounts, n, np.int64)
-            rc = np.bincount(ids_np[live], minlength=n)
-            self.rowcounts[: len(rc)] += rc
-        for k in set(self.sum_exprs) | set(self.cnt_exprs):
-            e = self.sum_exprs.get(k)
-            count_only = e is None
-            if count_only:
-                e = self.cnt_exprs[k]
-            if count_only and isinstance(e, Column):
-                # COUNT(col): only the validity matters — never decode
-                # or materialize the values (Utf8 columns would build
-                # an object array per batch just to be discarded)
-                v = None
-                valid = batch.validity[e.index]
-                valid = None if valid is None else np.asarray(valid)
-            else:
-                v, valid = eval_host_expr(e, batch, {})
-            ok = live if valid is None else (live & np.asarray(valid, bool))
-            idsk = ids_np[ok]
-            if k in self.sum_exprs:
-                vv = np.broadcast_to(
-                    np.asarray(v, np.float64), (batch.capacity,)
-                )
-                s = np.bincount(idsk, weights=vv[ok], minlength=n)
-                self.sums[k] = self._grown(self.sums.get(k), n, np.float64)
-                self.sums[k][: len(s)] += s
-            if k in self.cnt_exprs:
-                c = np.bincount(idsk, minlength=n)
-                self.cnts[k] = self._grown(self.cnts.get(k), n, np.int64)
-                self.cnts[k][: len(c)] += c
-
-
 class AggregateRelation(Relation):
     """Executes [Selection +] Aggregate over a child relation in one
     fused kernel; emits a single result batch.
@@ -1168,13 +1078,6 @@ class AggregateRelation(Relation):
         core_pred = None if host_pred else predicate
         self._core_pred = core_pred
         self._group_expr = list(group_expr)
-        self._aggr_expr = list(aggr_expr)
-        self._functions = functions
-        # link-aware slot placement (decided lazily from the first
-        # batch; see _decide_placement).  Workers disable it: their
-        # partial-state wire protocol ships device accumulators.
-        self._placement = None
-        self._allow_host_split = True
         self.core = _AggregateCore.build(
             child.schema, list(group_expr), list(aggr_expr), core_pred,
             functions,
@@ -1349,138 +1252,12 @@ class AggregateRelation(Relation):
 
         _cost.store().observe(obs[0], obs[1], groups=self.encoder.num_groups)
 
-    def _decide_placement(self, batch) -> Optional[_Placement]:
-        """Link-aware split of the SELECT-list aggregates between host
-        and device, decided once per query from the first batch.
-
-        Accelerator links vary by ~50x in both directions around the
-        break-even point, so placement must be measured, not assumed:
-        shipping a column costs wire_bytes/link_rate; computing its
-        grouped partials on the host costs ~rows * 8 ns per pass.  On
-        a slow link wide columns — or everything — stay on the host;
-        on a fast one everything ships.  Only float SUM/AVG and COUNT
-        are eligible
-        (exact integer accumulation, MIN/MAX, and Utf8 slots keep
-        their device forms); in-memory (reusable) sources always ship
-        because their device copies amortize across queries.
-        """
-        from datafusion_tpu.exec.batch import (
-            _encode_wire,
-            _wire_enabled,
-            link_rate_mbps,
-        )
-        from datafusion_tpu.exec.hostfn import host_evaluable
-
-        if not self._allow_host_split or not _wire_enabled(self.device):
-            return None
-        # reusable sources: upload once, re-query forever — always ship
-        node = self.child
-        while node is not None:
-            ds = getattr(node, "datasource", None)
-            if ds is not None:
-                if getattr(ds, "reusable_batches", False):
-                    return None
-                break
-            node = getattr(node, "child", None)
-        # host slots need a host-visible mask
-        if batch.mask is not None and hasattr(batch.mask, "copy_to_host_async"):
-            return None
-        # ... and a host-evaluable predicate: host partials must apply
-        # the same row filter the device kernel would (a device-only
-        # predicate would silently include filtered rows in host sums)
-        if self._core_pred is not None and not host_evaluable(
-            self._core_pred, {}, self.child.schema
-        ):
-            return None
-        host_idx = set()
-        for j, s in enumerate(self.specs):
-            if s.is_string or s.name in ("min", "max") or s.count_star:
-                continue
-            if s.name in ("sum", "avg") and np.dtype(s.sum_dtype).kind != "f":
-                continue
-            # COUNT(col) needs only the column's validity, so any bare
-            # column reference (Utf8 included) is host-computable
-            count_of_col = s.name == "count" and isinstance(s.arg, Column)
-            if not count_of_col and not host_evaluable(
-                s.arg, {}, self.child.schema
-            ):
-                continue
-            host_idx.add(j)
-        if not host_idx:
-            return None
-        # bytes saved = wire bytes of columns used ONLY by host slots
-        host_cols: set[int] = set()
-        for j in host_idx:
-            self.specs[j].arg.collect_columns(host_cols)
-        kept: set[int] = set()
-        if self._core_pred is not None:
-            self._core_pred.collect_columns(kept)
-        for j, s in enumerate(self.specs):
-            if j not in host_idx:
-                s.arg.collect_columns(kept)
-        saved = host_cols - kept
-        if not saved:
-            return None
-        bytes_per_row = 0.0
-        for c in sorted(saved):
-            col = np.asarray(batch.data[c])
-            _, wires = _encode_wire(col, self.device)
-            bytes_per_row += sum(
-                w.nbytes for w in wires if isinstance(w, np.ndarray)
-            ) / max(batch.capacity, 1)
-        passes = len({repr(self.specs[j].arg) for j in host_idx})
-        ship_s = bytes_per_row / (link_rate_mbps(self.device) * 1e6)
-        host_s = passes * _HOST_AGG_SECONDS_PER_ROW
-        if ship_s <= host_s:
-            return None
-        METRICS.add("aggregate.host_routed_slots", len(host_idx))
-        dev_idx = [j for j in range(len(self.specs)) if j not in host_idx]
-        if all(self.specs[j].count_star for j in dev_idx):
-            # only COUNT(*) would remain: its value is the host row
-            # counts — skip the device entirely
-            host_idx.update(dev_idx)
-            dev_idx = []
-        if dev_idx:
-            from datafusion_tpu.exec.kernels import parameterize_exprs
-
-            dev_exprs = [self._aggr_expr[j] for j in dev_idx]
-            core2 = _AggregateCore.build(
-                self.child.schema, self._group_expr, dev_exprs,
-                self._core_pred, self._functions,
-            )
-            params2 = parameterize_exprs(
-                _AggregateCore.param_exprs(self._core_pred, dev_exprs)
-            )[2]
-        else:
-            core2, params2 = None, ()
-        return _Placement(frozenset(host_idx), core2, params2)
-
-    def _host_live_mask(self, batch) -> np.ndarray:
-        """Numpy row-liveness for host-side slot updates: row bound +
-        upstream mask + the query predicate (whether it was routed to
-        the host or rides in the device core — _decide_placement
-        guarantees it is host-evaluable whenever this path runs)."""
-        live = np.zeros(batch.capacity, bool)
-        live[: batch.num_rows] = True
-        pred = self._host_pred_expr or self._core_pred
-        if pred is not None:
-            from datafusion_tpu.exec.hostfn import host_pred_mask
-
-            live &= host_pred_mask(pred, batch, {})
-        if batch.mask is not None:
-            live &= np.asarray(batch.mask)
-        return live
-
     def accumulate(self):
-        """Run the scan, returning the partial-aggregate device state
-        (or a ("hostsplit", device_state, partials) triple when the
-        link-aware placement routed slots to the host).
+        """Run the scan, returning the partial-aggregate device state.
 
         Partitioned mode calls this per shard and combines states with
         collectives; single-device mode finalizes it directly.
         """
-        import itertools
-
         from datafusion_tpu.obs.stats import iter_stats
 
         # serving megabatch (serve.py): the cross-query fused launch
@@ -1492,23 +1269,9 @@ class AggregateRelation(Relation):
             return injected
 
         self._adopt_source_state()
-        src = iter(iter_stats(self.child))
-        first = next(src, None)
-        if first is None:
-            return self._init_state(group_capacity(1))
-        if self._placement is None:
-            self._placement = self._decide_placement(first) or False
-        placement = self._placement or None
-        batches = itertools.chain([first], src)
-        if placement is None:
-            return self._accumulate_core(
-                batches, self.core, self._params, host_partials=None
-            )
-        partials = _HostPartials(self, placement.host_idx)
-        state = self._accumulate_core(
-            batches, placement.core, placement.params, host_partials=partials
+        return self._accumulate_core(
+            iter_stats(self.child), self.core, self._params
         )
-        return ("hostsplit", state, partials)
 
     def _adopt_source_state(self) -> None:
         """Swap this relation's per-query execution state for the one
@@ -1538,9 +1301,8 @@ class AggregateRelation(Relation):
         self._str_aux_cache = other._str_aux_cache
         self._ids_lock = other._ids_lock
 
-    def _accumulate_core(self, batches, core, params, host_partials):
-        """The scan loop over one device core (the full core, or the
-        placement's reduced core — None when every slot went host)."""
+    def _accumulate_core(self, batches, core, params):
+        """The scan loop over one device core: stage, group, launch."""
         from datafusion_tpu.exec.prefetch import pipeline_enabled, staged_pipeline
         from datafusion_tpu.exec.relation import device_scope
         from datafusion_tpu.obs.stats import op_timer
@@ -1551,12 +1313,7 @@ class AggregateRelation(Relation):
             # consumer below dispatches batch N's kernel; results land
             # in batch.cache / relation caches and are re-read as hits
             def _stage(b):
-                self._group_ids(
-                    b, upload=core is not None,
-                    keep_np=host_partials is not None,
-                )
-                if core is None:
-                    return
+                self._group_ids(b)
                 # pin the aux tables computed NOW on the batch: global
                 # dictionaries keep growing while later batches parse,
                 # so a consumer-side recompute could see a bigger table
@@ -1575,20 +1332,14 @@ class AggregateRelation(Relation):
 
         from datafusion_tpu.exec.fused import (
             fuse_group_max,
-            fusion_enabled,
             iter_groups,
             pad_group,
         )
-        from datafusion_tpu.exec.kernels import fuse_batch_count
 
-        # batches per device launch: prepared inputs accumulate host-
-        # side and dispatch as ONE fused kernel.  Fused-pass
-        # mode (the default) folds whole batch GROUPS — maximal runs of
-        # batches with one shape class — into one launch each;
-        # DATAFUSION_TPU_FUSE=0 restores the fixed 16-batch unrolled
-        # chunks byte-identically.
-        fused_mode = fusion_enabled()
-        fuse = fuse_group_max() if fused_mode else fuse_batch_count()
+        # batches per flush: prepared inputs accumulate host-side and
+        # dispatch as whole batch GROUPS — maximal runs of batches with
+        # one shape class — one launch each
+        fuse = fuse_group_max()
 
         state = None
         capacity = 0
@@ -1600,11 +1351,6 @@ class AggregateRelation(Relation):
                 return device_call(
                     core.jit, c[0], c[1], c[2], c[3], c[4], c[5], state,
                     c[6], params, _tag="agg",
-                )
-            if not fused_mode:
-                return device_call(
-                    core.fused_jit, tuple(chunk), state, params,
-                    _tag="agg.chunk",
                 )
             # one launch per shape-homogeneous batch group, padded to
             # the group-size ladder with zero-row (identity) entries so
@@ -1668,23 +1414,7 @@ class AggregateRelation(Relation):
             for idx in self.key_cols:
                 if batch.dicts[idx] is not None:
                     self._key_dicts[idx] = batch.dicts[idx]
-            ids = self._group_ids(
-                batch, upload=core is not None,
-                keep_np=host_partials is not None,
-            )
-            if host_partials is not None:
-                np_hit = batch.cache.get("group_ids_np")
-                ids_np = (
-                    np_hit[1]
-                    if np_hit is not None and np_hit[0] is self.encoder
-                    else self._group_ids(batch, upload=False)
-                )
-                host_partials.update(
-                    batch, ids_np, self._host_live_mask(batch),
-                    track_rowcounts=core is None,
-                )
-            if core is None:
-                continue
+            ids = self._group_ids(batch)
             staged = batch.cache.get("staged_aux")
             if staged is not None and staged[0] is core:
                 _, aux, str_aux = staged
@@ -1699,8 +1429,6 @@ class AggregateRelation(Relation):
             )
             if len(chunk) >= fuse:
                 flush()
-        if core is None:
-            return None
         flush()
         if state is None:
             state = core._init_state(group_capacity(1))
@@ -1764,15 +1492,11 @@ class AggregateRelation(Relation):
     def _ids_slot(self):
         return ("group_ids", tuple(self.key_cols))
 
-    def _group_ids(self, batch: RecordBatch, upload: bool = True,
-                   keep_np: bool = False):
-        """Dense group ids for one batch — the device array (plus,
-        under `keep_np`, the host `"group_ids_np"` cache entry the
-        host-partials path reads).  `upload=False` (full-host
-        placement) encodes without ever touching the device.  Cached on
-        the batch (keyed by this relation's encoder) so re-scanned
+    def _group_ids(self, batch: RecordBatch):
+        """Dense group ids for one batch, as the device array.  Cached
+        on the batch (keyed by this relation's encoder) so re-scanned
         in-memory batches skip both the host encode and the H2D
-        transfer; pure-device runs keep only the device copy.
+        transfer.
 
         Serialized by `_ids_lock`: the staging producer thread normally
         does all encoding, but a pin miss (another relation's encode
@@ -1783,30 +1507,21 @@ class AggregateRelation(Relation):
         # batch holds one id array per key set, not one per query ever
         # run; the entry pins the encoder so the identity check can't
         # hit a recycled object
-        key = self._ids_slot if upload else "group_ids_np"
-        hit = batch.cache.get(key)
+        hit = batch.cache.get(self._ids_slot)
         if hit is not None and hit[0] is self.encoder:
-            if not keep_np or batch.cache.get("group_ids_np") is not None:
-                return hit[1]
+            return hit[1]
         with self._ids_lock, METRICS.timer("aggregate.group_ids"):
-            return self._group_ids_locked(batch, upload, keep_np)
+            return self._group_ids_locked(batch)
 
-    def _group_ids_locked(self, batch: RecordBatch, upload: bool = True,
-                          keep_np: bool = False):
-        key = self._ids_slot if upload else "group_ids_np"
-        hit = batch.cache.get(key)
+    def _group_ids_locked(self, batch: RecordBatch):
+        hit = batch.cache.get(self._ids_slot)
         if hit is not None and hit[0] is self.encoder:
-            if not keep_np or batch.cache.get("group_ids_np") is not None:
-                return hit[1]
-        np_hit = batch.cache.get("group_ids_np")
-        if np_hit is not None and np_hit[0] is self.encoder:
-            ids_np = np_hit[1]
-        elif self.key_cols:
-            if upload and not keep_np:
-                ids = self._device_group_ids(batch)
-                if ids is not None:
-                    batch.cache[self._ids_slot] = (self.encoder, ids)
-                    return ids
+            return hit[1]
+        if self.key_cols:
+            ids = self._device_group_ids(batch)
+            if ids is not None:
+                batch.cache[self._ids_slot] = (self.encoder, ids)
+                return ids
             key_cols = [np.asarray(batch.data[idx]) for idx in self.key_cols]
             key_valids = [
                 None if batch.validity[idx] is None else np.asarray(batch.validity[idx])
@@ -1815,12 +1530,6 @@ class AggregateRelation(Relation):
             ids_np = self.encoder.encode(key_cols, key_valids)
         else:
             ids_np = np.zeros(batch.capacity, dtype=np.int32)
-        if keep_np or not upload:
-            batch.cache["group_ids_np"] = (self.encoder, ids_np)
-        if not upload:
-            return ids_np
-        if hit is not None and hit[0] is self.encoder:
-            return hit[1]  # device copy already cached; np now kept too
         # ship ids in the narrowest width that holds the group count and
         # widen on device (H2D bytes 4x/2x smaller for the common small-
         # cardinality GROUP BY); pointless when the target is the host
@@ -1898,8 +1607,7 @@ class AggregateRelation(Relation):
     def _numeric_output(s: AggregateSpec, sums, cnts, live_counts):
         """(values, validity) for a SUM/AVG/COUNT spec from its summed
         and counted per-group arrays — THE definition of these
-        aggregates' value/null semantics, shared by the device-pull and
-        host-partials finalize paths."""
+        aggregates' value/null semantics."""
         if s.name in ("sum", "avg"):
             if s.name == "sum":
                 vals = sums.astype(s.return_type.np_dtype)
@@ -1919,8 +1627,7 @@ class AggregateRelation(Relation):
     @classmethod
     def _spec_output(cls, s: AggregateSpec, slot_host, live_counts, str_dicts):
         """(values, validity, dict) for one aggregate spec from pulled
-        per-slot live-group arrays — shared by the plain and the
-        host-split finalize paths."""
+        per-slot live-group arrays."""
         if s.is_string:
             codes = slot_host[s.minmax_slot].astype(np.int32)
             valid = codes >= 0
@@ -1986,8 +1693,6 @@ class AggregateRelation(Relation):
 
     def finalize(self, state) -> RecordBatch:
         self._cost_observe_done()
-        if isinstance(state, tuple) and len(state) == 3 and state[0] == "hostsplit":
-            return self._finalize_split(state[1], state[2])
         counts, accs = self._pull_state(state)
         n_groups = self.encoder.num_groups if self.key_cols else 1
         if self.key_cols:
@@ -2007,51 +1712,6 @@ class AggregateRelation(Relation):
             out_valid.append(valid)
             out_dicts.append(d)
 
-        return make_host_batch(self._schema, out_cols, out_valid, out_dicts)
-
-    def _finalize_split(self, dev_state, partials: _HostPartials) -> RecordBatch:
-        """Merge device accumulators (reduced core) with host partials
-        into the SELECT-order output batch."""
-        placement = self._placement
-        core2 = placement.core
-        n_groups = max(self.encoder.num_groups, 1) if self.key_cols else 1
-        if core2 is not None and dev_state is not None:
-            counts, accs = self._pull_state(dev_state)
-        else:
-            counts = _HostPartials._grown(
-                partials.rowcounts, n_groups, np.int64
-            )
-            accs = []
-        if self.key_cols:
-            live = np.nonzero(counts[:n_groups] > 0)[0]
-        else:
-            live = np.array([0], dtype=np.int64)
-        out_cols, out_valid, out_dicts = self._key_outputs(live)
-        slot_host = [a[live] for a in accs]
-        live_counts = counts[live]
-        dev_pos = 0
-        grown = _HostPartials._grown
-        for j, s in enumerate(self.specs):
-            if j in placement.host_idx:
-                k = repr(s.arg)
-                sums = cnts = None
-                if s.name in ("sum", "avg"):
-                    sums = grown(partials.sums.get(k), n_groups, np.float64)[live]
-                if not s.count_star:
-                    cnts = grown(partials.cnts.get(k), n_groups, np.int64)[live]
-                vals, valid = self._numeric_output(s, sums, cnts, live_counts)
-                out_cols.append(vals)
-                out_valid.append(valid)
-                out_dicts.append(None)
-            else:
-                s2 = core2.specs[dev_pos]
-                dev_pos += 1
-                vals, valid, d = self._spec_output(
-                    s2, slot_host, live_counts, self._str_dicts
-                )
-                out_cols.append(vals)
-                out_valid.append(valid)
-                out_dicts.append(d)
         return make_host_batch(self._schema, out_cols, out_valid, out_dicts)
 
     def op_label(self) -> str:

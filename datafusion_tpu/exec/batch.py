@@ -341,11 +341,12 @@ def _dict_table(values_bits: np.ndarray) -> np.ndarray:
     return table.view(np.float64)
 
 
-# ---- link-rate probe: the placement cost model's one input ----------
+# ---- link-rate probe -------------------------------------------------
 # Accelerator links differ by orders of magnitude between
-# deployments.  Operators that can trade host compute against shipping
-# bytes (adaptive aggregate placement) read this once per process.
-# DATAFUSION_TPU_LINK_MBPS overrides (tests pin both modes).
+# deployments.  Read once per process by the scan-chunk sizing
+# (`cost/advisor.scan_chunk_rows`), the transfer-rate baseline of
+# `obs/device._link_baseline_mbps` and `chip_smoke.py`'s report.
+# DATAFUSION_TPU_LINK_MBPS overrides.
 _LINK_RATE: dict = {}
 
 

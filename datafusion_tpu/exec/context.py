@@ -638,10 +638,9 @@ class ExecutionContext:
 
     def _execute_plan(self, plan: LogicalPlan) -> Relation:
         fns = self._jax_functions()
-        if fused.fusion_enabled():
-            rel = self._execute_fused(plan, fns)
-            if rel is not None:
-                return rel
+        rel = self._execute_fused(plan, fns)
+        if rel is not None:
+            return rel
         if isinstance(plan, TableScan):
             ds = self.datasources.get(plan.table_name)
             if ds is None:

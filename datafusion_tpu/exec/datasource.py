@@ -33,9 +33,9 @@ class DataSource:
 
     # True when re-scans hand out the SAME RecordBatch objects, so
     # device copies cached on them amortize across queries (in-memory
-    # tables).  File scans parse fresh batches per query.  Operators
-    # use this for link-aware placement: shipping a reusable table to
-    # the accelerator pays once; shipping a stream pays every query.
+    # tables).  File scans parse fresh batches per query: shipping a
+    # reusable table to the accelerator pays once; shipping a stream
+    # pays every query.
     reusable_batches = False
 
     @property

@@ -1,7 +1,6 @@
 """Fused single-launch passes (ROADMAP item 4).
 
-Two independent fusion layers, both behind ``DATAFUSION_TPU_FUSE``
-(default on; ``=0`` restores the pre-fusion paths byte-identically):
+Two independent fusion layers:
 
 - **Plan-chain collapse** (used by `exec/context.py`): an entire
   filter -> project -> aggregate chain — and Sort/Limit over a
@@ -24,9 +23,8 @@ Two independent fusion layers, both behind ``DATAFUSION_TPU_FUSE``
   by (plan fingerprint, shape class, dtype tuple) through
   `exec/kernels.cached_kernel` + jit's own shape cache.
 
-Why: a warm scan otherwise pays one launch per 16-batch chunk (TPC-H
-Q1 at SF-10: 8 launches per pass); one launch per batch group makes the
-launch count independent of the table's size.
+Why: one launch per batch group, not per batch, keeps the launch count
+of a scan a function of its shape classes, not of the table's size.
 """
 
 from __future__ import annotations
@@ -51,12 +49,6 @@ from datafusion_tpu.plan.expr import (
 )
 
 
-def fusion_enabled() -> bool:
-    """The escape hatch: DATAFUSION_TPU_FUSE=0 restores the unfused
-    per-operator / per-chunk dispatch paths byte-identically."""
-    return os.environ.get("DATAFUSION_TPU_FUSE", "1") != "0"
-
-
 def fuse_group_max() -> int:
     """Max batches folded into one fused-pass launch (bounds how many
     batches' device inputs are held live at once on cold scans)."""
@@ -67,10 +59,7 @@ def pipeline_group_max() -> int:
     """Max batches per fused pipeline (filter/project) launch.  Smaller
     than the aggregate group: the pipeline yields its outputs, so
     grouping trades first-batch latency for launch count."""
-    from datafusion_tpu.exec.kernels import fuse_batch_count
-
-    v = os.environ.get("DATAFUSION_TPU_FUSE_PIPELINE")
-    return max(1, int(v)) if v else fuse_batch_count()
+    return max(1, int(os.environ.get("DATAFUSION_TPU_FUSE_PIPELINE", "16")))
 
 
 # group-size ladder: every group pads up to the next rung with dead

@@ -133,14 +133,6 @@ def parameterize_exprs(exprs):
     return fps, slot_by_id, tuple(values)
 
 
-def fuse_batch_count() -> int:
-    """Batches folded into one device launch by the state-carrying
-    operators (aggregate, TopK): fusing 16 batches turns a 16-launch
-    scan into one launch; the env knob exists for hosts where the
-    bigger unrolled program compiles too slowly."""
-    return max(1, int(os.environ.get("DATAFUSION_TPU_FUSE_BATCHES", "16")))
-
-
 def schema_fingerprint(schema) -> tuple:
     """Hashable image of a schema as kernels see it (positional
     dtypes + nullability; names ride along for dictionary wiring)."""

@@ -519,7 +519,7 @@ class PipelineRelation(Relation):
     def batches(self) -> Iterator[RecordBatch]:
         from datafusion_tpu.exec.batch import device_inputs
         from datafusion_tpu.exec.prefetch import pipeline_enabled, staged_pipeline
-        from datafusion_tpu.obs.stats import iter_stats, op_timer
+        from datafusion_tpu.obs.stats import iter_stats
 
         inj = self.__dict__.pop("_injected_batches", None)
         if inj is not None:
@@ -552,98 +552,41 @@ class PipelineRelation(Relation):
 
             batches = staged_pipeline(batches, _stage)
 
-        from datafusion_tpu.exec.fused import fusion_enabled
-
-        if core.needs_kernel and fusion_enabled():
-            # fused-pass mode: one launch per batch group instead of
-            # one per batch (DATAFUSION_TPU_FUSE=0 restores the
-            # per-batch loop below byte-identically)
+        if core.needs_kernel:
+            # one launch per batch group
             yield from self._batches_fused(batches)
             return
 
+        # pure column selection: yield a STABLE output batch per child
+        # batch (cached, core-pinned like group_ids) so a re-scan of an
+        # in-memory source hands downstream operators the same
+        # RecordBatch objects — their device copies (device_inputs
+        # cache) survive across runs instead of re-shipping every
+        # column per query run.  Pinned by RELATION when host-routed
+        # exprs exist (their literal values — and the host predicate's —
+        # are per-query; the core is shared across literals), by core
+        # otherwise
+        pin = (
+            self if (self._host_proj or self._host_pred_expr is not None)
+            else core
+        )
         for batch in batches:
-            if not core.needs_kernel:
-                # pure column selection: yield a STABLE output batch per
-                # child batch (cached, core-pinned like group_ids) so a
-                # re-scan of an in-memory source hands downstream
-                # operators the same RecordBatch objects — their device
-                # copies (device_inputs cache) survive across runs
-                # instead of re-shipping every column per query run
-                # pinned by RELATION when host-routed exprs exist (their
-                # literal values — and the host predicate's — are
-                # per-query; the core is shared across literals), by
-                # core otherwise
-                pin = (
-                    self if (self._host_proj or self._host_pred_expr is not None)
-                    else core
-                )
-                hit = batch.cache.get("pipeline_out")
-                if hit is not None and hit[0] is pin:
-                    yield hit[1]
-                    continue
-                cols, valids, mask = [], [], self._effective_mask(batch)
-            else:
-                staged = batch.cache.get("staged_aux")
-                if staged is not None and staged[0] is core:
-                    aux = staged[1]
-                else:
-                    aux = tuple(
-                        compute_aux_values(core.aux_specs, batch, self._aux_cache)
-                    )
-                with METRICS.timer("execute.pipeline"), op_timer(self), \
-                        device_scope(self.device):
-                    data, validity, mask_in = device_inputs(
-                        self._subset_view(batch), self.device, core.wire_hints
-                    )
-                    if self._host_pred_expr is not None:
-                        # the shared subset view keeps the column device
-                        # copies literal-independent; only this query's
-                        # predicate mask uploads per relation
-                        mask_in = self._device_mask(batch)
-                    cols, valids, mask = device_call(
-                        core.jit,
-                        data,
-                        validity,
-                        aux,
-                        np.int32(batch.num_rows),
-                        mask_in,
-                        self._params,
-                        _tag="pipeline",
-                    )
-            if core.proj_fns is None:
-                # filter-only: the input columns, untouched
-                cols, valids, dicts = batch.data, batch.validity, batch.dicts
-            else:
-                dicts = [
-                    batch.dicts[src] if src is not None else None
-                    for src in core.out_dict_sources
-                ]
-                cols, valids, dicts = self._assemble_outputs(
-                    batch, list(cols), list(valids), list(dicts)
-                )
-            out = RecordBatch(
-                self._schema,
-                list(cols),
-                list(valids),
-                dicts,
-                num_rows=batch.num_rows,
-                mask=mask,
+            hit = batch.cache.get("pipeline_out")
+            if hit is not None and hit[0] is pin:
+                yield hit[1]
+                continue
+            out = self._emit_output(
+                batch, [], [], self._effective_mask(batch)
             )
-            if not core.needs_kernel:
-                batch.cache["pipeline_out"] = (
-                    self
-                    if (self._host_proj or self._host_pred_expr is not None)
-                    else core,
-                    out,
-                )
+            batch.cache["pipeline_out"] = (pin, out)
             yield out
 
     def _batches_fused(self, batches) -> Iterator[RecordBatch]:
-        """Kernel-path batches in fused-pass mode: prepared per-batch
-        inputs buffer into shape-homogeneous groups of up to
-        `pipeline_group_max()` and each group dispatches as ONE device
-        launch (cold scans stop paying a dispatch round trip per
-        batch — the csv_scan_filter satellite)."""
+        """Kernel-path batches: prepared per-batch inputs buffer into
+        shape-homogeneous groups of up to `pipeline_group_max()` and
+        each group dispatches as ONE device launch (cold scans stop
+        paying a dispatch round trip per batch — the csv_scan_filter
+        satellite)."""
         from datafusion_tpu.exec.batch import device_inputs
         from datafusion_tpu.exec.fused import (
             entry_signature,
@@ -700,7 +643,7 @@ class PipelineRelation(Relation):
                         self._params, _tag="pipeline.group",
                     )
             emitted = [
-                self._emit_kernel_output(b, list(cols), list(valids), mask)
+                self._emit_output(b, list(cols), list(valids), mask)
                 for (b, _, _), (cols, valids, mask) in zip(buf, outs)
             ]
             buf.clear()
@@ -715,10 +658,10 @@ class PipelineRelation(Relation):
             buf.append((batch, entry, aux))
         yield from flush()
 
-    def _emit_kernel_output(self, batch, cols, valids, mask) -> RecordBatch:
+    def _emit_output(self, batch, cols, valids, mask) -> RecordBatch:
         """Assemble one output batch from the kernel's computed columns
-        (identity passthroughs and host-routed projections interleave
-        exactly as on the per-batch path)."""
+        (none for a pure column selection), interleaving identity
+        passthroughs and host-routed projections."""
         core = self.core
         if core.proj_fns is None:
             # filter-only: the input columns, untouched
@@ -907,7 +850,7 @@ def run_pipeline_megabatch(rels: list["PipelineRelation"]) -> float:
         for q, r in enumerate(rels):
             for (b, _, _), (cols, valids, mask) in zip(buf, outs[q]):
                 outs_per_rel[q].append(
-                    r._emit_kernel_output(b, list(cols), list(valids), mask)
+                    r._emit_output(b, list(cols), list(valids), mask)
                 )
         buf.clear()
 

@@ -102,12 +102,6 @@ def _np_sort_key(
     return dead, k
 
 
-# host throughput assumed by the sort placement cost model: np.lexsort
-# of one key pair over one core (order-of-magnitude constant, like
-# aggregate._HOST_AGG_SECONDS_PER_ROW)
-_HOST_SORT_SECONDS_PER_ROW = 1.5e-7
-
-
 class _KeyPlan:
     """How one ORDER BY key lowers onto a column: which column, its
     transform kind, direction, source width, and (for Utf8) a
@@ -179,7 +173,6 @@ class _TopKCore:
             self.jit = jax.jit(self._topk_wide_kernel, static_argnums=(0,))
         else:
             self.jit = jax.jit(self._topk_kernel, static_argnums=(0,))
-        self.fused_jit = jax.jit(self._fused_topk, static_argnums=(0,))
         # fused-pass batch-group fold: lax.scan over a stacked group —
         # the whole scan's merge is ONE launch, and the traced body is
         # one kernel, not one per batch (exec/fused.py)
@@ -187,10 +180,7 @@ class _TopKCore:
         # final-group fold + result-mask merge in ONE launch: the scan's
         # last batch group folds AND the (live-mask, row-ids) result
         # state collapses to a single int64 array inside the same
-        # program — the host then pulls ONE array, where the old tail
-        # paid a separate blob-pack launch just to ship the live mask
-        # beside the rows (the PR 6 follow-on: one fewer device launch
-        # per TopK pass)
+        # program — the host then pulls ONE array
         self.group_final_jit = jax.jit(self._group_final,
                                        static_argnums=(0,))
         self.final_jit = jax.jit(self._final_merge)
@@ -305,27 +295,6 @@ class _TopKCore:
         if entries:
             states = self._multi_group(ks, states, entries, rank_tables)
         return tuple(self._final_merge(st) for st in states)
-
-    def _fused_topk(self, k, state, chunk):
-        """Fold the per-batch merge over a chunk of prepared batches in
-        ONE device launch instead of one per batch."""
-        for cols, valids, mask, num_rows, row_base, rank_tables, img in chunk:
-            if self.single:
-                state = self._topk1_kernel(
-                    k, state, cols, valids, mask, num_rows, row_base,
-                    rank_tables,
-                )
-            elif self.wide:
-                state = self._topk_wide_kernel(
-                    k, state, cols, valids, mask, num_rows, row_base,
-                    rank_tables, img,
-                )
-            else:
-                state = self._topk_kernel(
-                    k, state, cols, valids, mask, num_rows, row_base,
-                    rank_tables,
-                )
-        return state
 
     @staticmethod
     def build(
@@ -653,14 +622,10 @@ class SortRelation(Relation):
         self.core = _TopKCore.build(self._key_plans)
         self._topk_jit = self.core.jit
         # warm-run artifacts per full-sort run, keyed by the run's
-        # source batch identities + dictionary versions: the device
-        # route stores its uploaded key operands (a warm re-query
-        # re-sorts the SAME device buffers instead of re-encoding +
-        # re-uploading), the host route stores the finished permutation
-        # (a warm re-query skips the np.lexsort outright); the values
-        # pin the batch objects so ids stay valid.  Mirrors
-        # device_inputs' per-batch caching on the pipeline/aggregate
-        # paths.  FIFO-bounded: multi-run sorts and cold re-scans
+        # source batch identities + dictionary versions: the finished
+        # permutation (a warm re-query skips the key encode, the sort
+        # launch and its D2H pull); the values pin the batch objects so
+        # ids stay valid.  FIFO-bounded: multi-run sorts and cold re-scans
         # (fresh batch objects every scan, so their keys can never hit)
         # must not accumulate buffers without bound.
         from collections import OrderedDict
@@ -668,7 +633,7 @@ class SortRelation(Relation):
         self._run_ops_cache: OrderedDict = OrderedDict()
         self._run_ops_cache_max = 4
         # second-chance admission: a key must be SEEN twice before its
-        # device buffers are stored, so one-shot file scans (fresh batch
+        # permutation is stored, so one-shot file scans (fresh batch
         # objects every scan — their keys can never repeat) pin nothing.
         # An id()-recycling false positive here merely admits an entry
         # early; entries themselves pin their batches, so a stored key
@@ -797,8 +762,6 @@ class SortRelation(Relation):
     def _topk_batches(self, core=None) -> Iterator[RecordBatch]:
         from datafusion_tpu.exec.batch import device_inputs
 
-        from datafusion_tpu.exec.kernels import fuse_batch_count
-
         inj = self.__dict__.pop("_injected_topk", None)
         if inj is not None and core is None:
             # serve-plane megabatch (run_topk_megabatch): the
@@ -818,13 +781,11 @@ class SortRelation(Relation):
         wide_f64 = core.wide and self._key_plans[0].kind == "f"
         from datafusion_tpu.exec.fused import (
             fuse_group_max,
-            fusion_enabled,
             iter_groups,
             pad_group,
         )
 
-        fused_mode = fusion_enabled()
-        fuse = fuse_group_max() if fused_mode else fuse_batch_count()
+        fuse = fuse_group_max()
         chunk: list = []
 
         def dispatch_chunk(state):
@@ -834,9 +795,6 @@ class SortRelation(Relation):
                 if core.wide:
                     args.append(c[6])
                 return device_call(topk_jit, *args, _tag="topk")
-            if not fused_mode:
-                return device_call(core.fused_jit, k, state, tuple(chunk),
-                                   _tag="topk.chunk")
             # one launch per shape-homogeneous batch group (lax.scan
             # over the stacked group), padded to the ladder with
             # zero-row entries that merge as all-dead
@@ -970,52 +928,28 @@ class SortRelation(Relation):
         if state is None and not chunk:
             yield self._empty_result(in_schema, dicts)
             return
-        if fused_mode:
-            # fused tail: the last batch group folds AND the result
-            # (live-mask, rows) merge happens inside ONE launch
-            # (`group_final_jit`) — the old path paid a separate
-            # blob-pack launch just to pull the mask beside the rows
-            packed = self._final_flush(core, chunk, state)
-            chunk.clear()
-            packed_h = np.asarray(device_pull(packed))
-            if core.wide and bool(packed_h[0]):
-                METRICS.add("sort.wide_fallbacks")
-                yield from self._topk_batches(
-                    _TopKCore.build(self._key_plans, force_general=True)
-                )
-                return
-            merged = packed_h[1:]
-            # dead slots merged to -1; live rows keep their (sorted)
-            # positions, so positional nonzero matches the old mask
-            take = np.nonzero(merged >= 0)[0][: self.limit]
-            win = merged[take]
-        else:
-            flush()
-            if state is None:
-                yield self._empty_result(in_schema, dicts)
-                return
-            if core.wide:
-                _, live, rows, flag = state
-                # ONE blob-packed transfer for the whole k-row result
-                live, rows, flag = device_pull((live, rows, flag))
-            else:
-                _, live, rows = state
-                live, rows = device_pull((live, rows))
-            if core.wide and bool(np.asarray(flag)):
-                # an integer key touched the sentinel ladder (values at
-                # the extreme two of the 2^64 range): replay the scan
-                # through the exact sort path — datasources are
-                # re-iterable
-                METRICS.add("sort.wide_fallbacks")
-                yield from self._topk_batches(
-                    _TopKCore.build(self._key_plans, force_general=True)
-                )
-                return
-            # the live bit separates real rows from dead-key padding
-            # when the scan produced fewer than k rows; the state is
-            # bucket-sized, so slice down to the actual LIMIT
-            take = np.nonzero(np.asarray(live))[0][: self.limit]
-            win = np.asarray(rows)[take]
+        # fused tail: the last batch group folds AND the result
+        # (live-mask, rows) merge happens inside ONE launch
+        # (`group_final_jit`), so the host pulls one array
+        packed = self._final_flush(core, chunk, state)
+        chunk.clear()
+        packed_h = np.asarray(device_pull(packed))
+        if core.wide and bool(packed_h[0]):
+            # an integer key touched the sentinel ladder (values at
+            # the extreme two of the 2^64 range): replay the scan
+            # through the exact sort path — datasources are
+            # re-iterable
+            METRICS.add("sort.wide_fallbacks")
+            yield from self._topk_batches(
+                _TopKCore.build(self._key_plans, force_general=True)
+            )
+            return
+        merged = packed_h[1:]
+        # dead slots merged to -1: they separate real rows from
+        # dead-key padding when the scan produced fewer than k rows;
+        # the state is bucket-sized, so slice down to the actual LIMIT
+        take = np.nonzero(merged >= 0)[0][: self.limit]
+        win = merged[take]
         yield self._topk_gather(win, src_batches, bases, dicts, in_schema)
 
     def _topk_gather(self, win, src_batches, bases, dicts, in_schema):
@@ -1075,8 +1009,7 @@ class SortRelation(Relation):
         one with the result merge (`_TopKCore._group_final`) so the
         pass ends in one launch whose single int64 output carries rows
         and live mask together.  With an empty tail chunk the merge
-        alone dispatches (`final_jit`) — still one launch, replacing
-        the blob-pack launch the multi-array pull used to cost."""
+        alone dispatches (`final_jit`) — still one launch."""
         from datafusion_tpu.exec.fused import iter_groups, pad_group
         from datafusion_tpu.obs.stats import op_timer
 
@@ -1160,53 +1093,6 @@ class SortRelation(Relation):
             keys.append(k)
         return keys
 
-    def _host_run_sort(self, keys: list[np.ndarray], n: int):
-        """Host np.lexsort permutation when the link makes the device
-        round trip unprofitable, or None to use the device.
-
-        The device sort's D2H cost is the permutation itself
-        (~ceil(bits/8) incompressible bytes per row); on a slow link
-        that dwarfs a host lexsort of the same key operands.  Both
-        sorts are stable over identical operands, so the permutations
-        are identical — except for two float-key cases where numpy
-        (IEEE compare) and XLA's total order disagree: NaNs (numpy
-        puts all NaNs last; XLA respects their sign) and signed zeros
-        (numpy ties -0.0 == +0.0, XLA orders -0.0 < +0.0).  Either
-        forces the device path."""
-        from datafusion_tpu.exec.batch import _wire_enabled, link_rate_mbps
-
-        if not _wire_enabled(self.device):
-            return None
-        cap = bucket_capacity(n)
-        perm_bytes = n * max(1, ((cap - 1).bit_length() + 7) >> 3)
-        dev_s = perm_bytes / (link_rate_mbps(self.device) * 1e6)
-        host_s = n * _HOST_SORT_SECONDS_PER_ROW * max(len(keys) // 2, 1)
-        if host_s >= dev_s:
-            return None
-        # NaN / signed-zero checks last: they are O(n) passes per float
-        # key, and on fast links the cost model above already routed to
-        # the device
-        for j in range(1, len(keys), 2):
-            if keys[j].dtype.kind != "f":
-                continue
-            vals = keys[j][:n]
-            if bool(np.isnan(vals).any()):
-                return None
-            # XLA's total order splits -0.0 < +0.0; np.lexsort ties
-            # them — with both present the permutations diverge
-            zero = vals == 0.0
-            if zero.any():
-                signs = np.signbit(vals[zero])
-                if bool(signs.any()) and not bool(signs.all()):
-                    return None
-        METRICS.add("sort.host_routed_runs")
-        # significance: np.lexsort's LAST key is primary — reversing
-        # [dead0, val0, dead1, val1, ...] reproduces the device
-        # operand order (dead flag before value, key 0 outermost)
-        return np.lexsort(tuple(k[:n] for k in reversed(keys))).astype(
-            np.int32
-        )
-
     def _sorted_run(self, keys: list[np.ndarray], n: int, cache_key=None,
                     pin=None) -> np.ndarray:
         """Device-sort one run of n rows; returns the permutation.
@@ -1217,15 +1103,14 @@ class SortRelation(Relation):
         The padding convention keeps the flag droppable: when a run has
         no nulls, padding rows' VALUE keys are +max sentinels, so they
         sort last without their flag.  `cache_key` stores the warm-run
-        artifact in _run_ops_cache (`pin` holds the source batches
-        alive): the uploaded device operands on the device route, the
-        finished permutation itself on the host route — either way a
-        warm re-query skips the key encode."""
+        artifact, the finished permutation, in _run_ops_cache (`pin`
+        holds the source batches alive), so a warm re-query skips the
+        key encode."""
         from datafusion_tpu.exec.batch import _wire_enabled, put_compressed
 
-        # second-chance admission (shared by both routes): a key must be
-        # SEEN twice before its artifact is stored, so one-shot file
-        # scans (fresh batch objects every scan) pin nothing
+        # second-chance admission: a key must be SEEN twice before its
+        # artifact is stored, so one-shot file scans (fresh batch
+        # objects every scan) pin nothing
         admit = False
         if cache_key is not None:
             if cache_key in self._run_seen:
@@ -1235,13 +1120,6 @@ class SortRelation(Relation):
                 while len(self._run_seen) > 32:
                     self._run_seen.popitem(last=False)
 
-        host_perm = self._host_run_sort(keys, n)
-        if host_perm is not None:
-            if admit:
-                self._run_ops_cache[cache_key] = ("perm", host_perm, pin)
-                while len(self._run_ops_cache) > self._run_ops_cache_max:
-                    self._run_ops_cache.popitem(last=False)
-            return host_perm
         cap = bucket_capacity(n)
         host_ops: list[np.ndarray] = []
         # keys come as (dead-flag, value) pairs per ORDER BY key
@@ -1431,8 +1309,7 @@ class SortRelation(Relation):
             with METRICS.timer("execute.sort"), op_timer(self), \
                     _device_scope(self.device):
                 if hit is not None:
-                    # cached run permutation — host- and device-routed
-                    # runs both store it now, so a warm re-query skips
+                    # cached run permutation: a warm re-query skips
                     # the key encode, the sort, and the D2H pull alike
                     METRICS.add("sort.perm_cache_hits")
                     perm = hit[1]

@@ -495,7 +495,7 @@ class MaterializedView:
             for idx in agg.key_cols:
                 if batch.dicts[idx] is not None:
                     agg._key_dicts[idx] = batch.dicts[idx]
-            ids = agg._group_ids(batch, upload=True)
+            ids = agg._group_ids(batch)
             aux = compute_aux_values(core.aux_specs, batch, agg._aux_cache)
             str_aux = agg._compute_str_aux(batch, core.slots)
             with device_scope(agg.device):
@@ -882,9 +882,6 @@ class IngestContext:
             return fallback("scan_shape")
         if any(sl.is_string for sl in agg.core.slots):
             return fallback("string_minmax")
-        # the accumulator must stay whole and device-resident: no
-        # link-aware host split of slots mid-stream
-        agg._allow_host_split = False
         return MaterializedView(name, query_sql, self.ctx, table,
                                 root, agg, proj)
 

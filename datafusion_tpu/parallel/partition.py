@@ -752,19 +752,17 @@ class PartitionedAggregateRelation(AggregateRelation):
         # the contextvar already being set) the device_call backoffs
         deadline = current_deadline()
 
-        # multi-round fold buffer (fused-pass mode): consecutive WARM
-        # rounds with one shape class collect here and dispatch as one
-        # launch through `self._multi_jit`; cold rounds, shape-class
-        # changes, and state growth flush first.
+        # multi-round fold buffer: consecutive WARM rounds with one
+        # shape class collect here and dispatch as one launch through
+        # `self._multi_jit`; cold rounds, shape-class changes, and
+        # state growth flush first.
         from datafusion_tpu.exec.fused import (
             entry_signature,
             fuse_group_max,
-            fusion_enabled,
             pad_group,
             shared_signature,
         )
 
-        fused_mode = fusion_enabled()
         round_fuse_max = fuse_group_max()
         round_buf: list = []
         round_sig = None
@@ -833,9 +831,9 @@ class PartitionedAggregateRelation(AggregateRelation):
                 # warm round: the padded shard stacks are already on
                 # their mesh devices (and the group ids this relation's
                 # encoder assigned are append-stable, so they replay
-                # exactly); only the state update kernel runs.  In
-                # fused-pass mode consecutive warm rounds of one shape
-                # class BUFFER and fold into one multi-round launch.
+                # exactly); only the state update kernel runs.
+                # Consecutive warm rounds of one shape class BUFFER and
+                # fold into one multi-round launch.
                 METRICS.add("mesh.round_cache_hits")
                 (_, put_cols, put_valids, aux, rows_dev, put_mask,
                  put_ids, str_aux) = hit
@@ -849,15 +847,6 @@ class PartitionedAggregateRelation(AggregateRelation):
                     group_cap = needed
                 entry = (put_cols, put_valids, aux, rows_dev, put_mask,
                          put_ids, str_aux)
-                if not fused_mode:
-                    with METRICS.timer("execute.partitioned_aggregate"), \
-                            op_timer(self):
-                        state = device_call(
-                            self._stacked_jit, put_cols, put_valids, aux,
-                            rows_dev, put_mask, put_ids, state, str_aux,
-                            self._params, _tag="mesh.stacked",
-                        )
-                    continue
                 sig = (
                     entry_signature((put_cols, put_valids, rows_dev,
                                      put_mask, put_ids)),

@@ -368,9 +368,6 @@ class WorkerState:
                 "execute_fragment needs an Aggregate fragment; "
                 f"got {type(rel).__name__} (use execute_plan)"
             )
-        # workers always ship device accumulator state — the partial-
-        # state wire protocol has no host-partials form
-        rel._allow_host_split = False
         counts, accs = rel.accumulate()
         self.queries += 1
         if rel.key_cols:

@@ -201,8 +201,7 @@ class PinnedSource(DataSource):
 
     @property
     def reusable_batches(self) -> bool:
-        # resident batches are the same objects every scan (the
-        # link-aware placement's "ship once, re-query forever" class)
+        # resident batches are the same objects every scan
         return self._resident is not None or getattr(
             self.inner, "reusable_batches", False
         )
@@ -1120,7 +1119,6 @@ class Server:
         relation — stricter than the plan signature: the relations must
         share one compiled core (identity) over one table scan, with
         the predicate in the core (no per-query host masks)."""
-        from datafusion_tpu.exec import fused
         from datafusion_tpu.exec.aggregate import AggregateRelation
         from datafusion_tpu.exec.relation import (
             DataSourceRelation,
@@ -1128,7 +1126,7 @@ class Server:
         )
         from datafusion_tpu.exec.sort import TOPK_MAX, SortRelation
 
-        if self._megabatch_max < 2 or not fused.fusion_enabled():
+        if self._megabatch_max < 2:
             return None
         if type(rel) is SortRelation:
             # streaming TopK lane: no fused predicate (the shared fold
@@ -1204,9 +1202,6 @@ class Server:
         leader = rels[0]
         core = leader.core
         for r in rels:
-            # placement decided here: megabatched states are device
-            # accumulators, never host-split partials
-            r._allow_host_split = False
             r._adopt_source_state()
             if r is not leader:
                 # one encoder/caches for the whole group even when the

@@ -42,7 +42,7 @@ python scripts/debug_smoke.py
 echo "== cache smoke (result + fragment caches, invalidation, off-switch) =="
 python scripts/cache_smoke.py
 
-echo "== kernel smoke (fused vs unfused parity, no-recompile-on-repeat, Pallas interpret parity) =="
+echo "== kernel smoke (no-recompile-on-repeat with equal rows, Pallas interpret parity) =="
 python scripts/kernel_smoke.py
 
 echo "== cluster smoke (failover + control plane: shared membership, shared cache tier, invalidation broadcast, fleet telemetry aggregation, primary/standby HA) =="

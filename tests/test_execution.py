@@ -1150,148 +1150,122 @@ class TestWirePolicy:
             assert np.array_equal(got, w, equal_nan=(w.dtype.kind == "f"))
 
 
-class TestAdaptivePlacement:
-    """Link-aware aggregate slot placement (aggregate._decide_placement):
-    on a slow measured link, float SUM/AVG/COUNT partials compute on the
-    host via bincount instead of shipping their columns.  Forced on CPU
-    via DATAFUSION_TPU_WIRE=always + a pinned DATAFUSION_TPU_LINK_MBPS."""
+def _approx_rows(got, want, rtol=1e-12):
+    assert len(got) == len(want)
+    for rg, rw in zip(got, want):
+        assert len(rg) == len(rw)
+        for vg, vw in zip(rg, rw):
+            if isinstance(vw, float):
+                np.testing.assert_allclose(vg, vw, rtol=rtol)
+            else:
+                assert vg == vw
+
+
+def _null_sort_key(row):
+    return tuple((x is None, 0 if x is None else x) for x in row)
+
+
+class TestAggregateOverTheWire:
+    """Float SUM / AVG / COUNT over scans whose batches are new objects
+    every query (files, streams), with the compressed wire forced on
+    (DATAFUSION_TPU_WIRE=always): every column the aggregate names
+    travels, and the answers match an oracle computed here in plain
+    Python / numpy."""
+
+    @pytest.fixture(autouse=True)
+    def wire(self, monkeypatch):
+        monkeypatch.setenv("DATAFUSION_TPU_WIRE", "always")
 
     def _rows(self, ctx, sql):
         from datafusion_tpu.exec.materialize import collect
 
         return sorted(collect(ctx.sql(sql)).to_rows())
 
-    def _assert_same(self, a, b):
-        assert len(a) == len(b)
-        for ra, rb in zip(a, b):
-            for va, vb in zip(ra, rb):
-                if isinstance(va, float):
-                    np.testing.assert_allclose(va, vb, rtol=1e-12)
-                else:
-                    assert va == vb
+    def _cities(self, test_data_dir):
+        import csv
 
-    @pytest.fixture
-    def slow_link(self, monkeypatch):
-        monkeypatch.setenv("DATAFUSION_TPU_WIRE", "always")
-        monkeypatch.setenv("DATAFUSION_TPU_LINK_MBPS", "0.001")
+        with open(os.path.join(test_data_dir, "uk_cities.csv")) as fh:
+            return [(c, float(lat), float(lng)) for c, lat, lng in csv.reader(fh)]
 
-    @pytest.fixture
-    def fast_link(self, monkeypatch):
-        monkeypatch.setenv("DATAFUSION_TPU_WIRE", "always")
-        monkeypatch.setenv("DATAFUSION_TPU_LINK_MBPS", "1e9")
+    def test_grouped_sum_avg_count_with_predicate(self, ctx, test_data_dir):
+        want = sorted(
+            (c, lat, lng, 1) for c, lat, lng in self._cities(test_data_dir)
+            if lat > 51.0
+        )
+        got = self._rows(
+            ctx,
+            "SELECT city, SUM(lat), AVG(lng), COUNT(1) FROM cities "
+            "WHERE lat > 51.0 GROUP BY city",
+        )
+        _approx_rows(got, want)
 
-    def test_full_host_split_matches_device(self, ctx, slow_link):
-        from datafusion_tpu.exec.aggregate import AggregateRelation
+    def test_global_sum_avg_count_min_max(self, ctx, test_data_dir):
+        live = [r for r in self._cities(test_data_dir) if r[1] > 51.0]
+        lngs = np.array([r[2] for r in live])
+        want = [(float(lngs.sum()), float(lngs.mean()), len(live),
+                 min(r[1] for r in live), max(r[0] for r in live))]
+        got = self._rows(
+            ctx,
+            "SELECT SUM(lng), AVG(lng), COUNT(1), MIN(lat), MAX(city) "
+            "FROM cities WHERE lat > 51.0",
+        )
+        _approx_rows(got, want)
+
+    def test_nulls_through_sum_avg_count(self, ctx):
+        # null_test.csv: c_float = 1.1, 2.2, NULL, 4.4, 6.6
+        vals = np.array([1.1, 2.2, 4.4, 6.6])
+        got = self._rows(
+            ctx,
+            "SELECT COUNT(1), COUNT(c_float), SUM(c_float), AVG(c_float) "
+            "FROM null_test",
+        )
+        _approx_rows(got, [(5, 4, float(vals.sum()), float(vals.mean()))])
+
+    def test_count_utf8_column(self, ctx):
+        # c_string: "1.11", "2.22", "3.33", and two the CSV reader
+        # takes as NULL (the empty field and the quoted empty string)
+        got = self._rows(
+            ctx, "SELECT COUNT(c_string), SUM(c_float) FROM null_test"
+        )
+        _approx_rows(got, [(3, 1.1 + 2.2 + 4.4 + 6.6)])
+
+    def test_every_named_column_travels(self, tmp_path):
+        # random doubles have no smaller exact wire form: a column that
+        # travels costs 8 bytes a row, and one the query stops naming
+        # stops travelling
         from datafusion_tpu.utils.metrics import METRICS
 
-        sql = (
-            "SELECT city, SUM(lat), AVG(lng), COUNT(1) FROM cities "
-            "WHERE lat > 51.0 GROUP BY city"
-        )
-        rel = ctx.sql(sql)
-        node = rel
-        while node is not None and not isinstance(node, AggregateRelation):
-            node = getattr(node, "child", None)
-        assert node is not None
-        from datafusion_tpu.exec.materialize import collect
-
-        METRICS.reset()
-        got = sorted(collect(rel).to_rows())
-        # every slot went host: the reduced device core is gone entirely
-        assert node._placement and node._placement.core is None
-        assert METRICS.snapshot()["counts"].get("aggregate.host_routed_slots")
-        ctx2_rows = self._rows(self._fresh_ctx(ctx), sql)
-        self._assert_same(got, ctx2_rows)
-
-    def _fresh_ctx(self, ctx):
-        # same tables, default (no-split) placement: the comparison run
-        from datafusion_tpu import ExecutionContext
-        import os as _os
-
-        _os.environ["DATAFUSION_TPU_LINK_MBPS"] = "1e9"
-        c = ExecutionContext(batch_size=1024)
-        c.datasources = dict(ctx.datasources)
-        return c
-
-    def test_mixed_split_keeps_minmax_on_device(self, ctx, slow_link):
-        from datafusion_tpu.exec.aggregate import AggregateRelation
-
-        sql = (
-            "SELECT SUM(lng), AVG(lng), COUNT(1), MIN(lat), MAX(city) "
-            "FROM cities WHERE lat > 51.0"
-        )
-        rel = ctx.sql(sql)
-        node = rel
-        while node is not None and not isinstance(node, AggregateRelation):
-            node = getattr(node, "child", None)
-        from datafusion_tpu.exec.materialize import collect
-
-        got = sorted(collect(rel).to_rows())
-        assert node._placement
-        assert node._placement.core is not None  # MIN/MAX stayed device
-        assert len(node._placement.core.specs) == 3  # count(*), min, max
-        self._assert_same(got, self._rows(self._fresh_ctx(ctx), sql))
-
-    def test_fast_link_never_splits(self, ctx, fast_link):
-        from datafusion_tpu.exec.aggregate import AggregateRelation
-
-        sql = "SELECT city, SUM(lat) FROM cities GROUP BY city"
-        rel = ctx.sql(sql)
-        node = rel
-        while node is not None and not isinstance(node, AggregateRelation):
-            node = getattr(node, "child", None)
-        from datafusion_tpu.exec.materialize import collect
-
-        sorted(collect(rel).to_rows())
-        assert node._placement is False  # decided: no split
-
-    def test_nulls_through_host_partials(self, ctx, slow_link):
-        sql = (
-            "SELECT COUNT(1), COUNT(c_float), SUM(c_float), AVG(c_float) "
-            "FROM null_test"
-        )
-        got = self._rows(ctx, sql)
-        want = self._rows(self._fresh_ctx(ctx), sql)
-        self._assert_same(got, want)
-
-    def test_memory_source_always_ships(self, monkeypatch, slow_link):
-        from datafusion_tpu import DataType, ExecutionContext, Field, Schema
-        from datafusion_tpu.exec.aggregate import AggregateRelation
-        from datafusion_tpu.exec.batch import make_host_batch
-        from datafusion_tpu.exec.datasource import MemoryDataSource
-
+        rng = np.random.default_rng(30)
+        n = 3000
+        k = rng.integers(0, 7, n)
+        v1, v2 = rng.uniform(-1, 1, n), rng.uniform(-1, 1, n)
+        path = tmp_path / "t.csv"
+        with open(path, "w") as fh:
+            fh.write("k,v1,v2\n")
+            for row in zip(k.tolist(), v1.tolist(), v2.tolist()):
+                fh.write("%d,%r,%r\n" % row)
         schema = Schema([Field("k", DataType.INT64, False),
-                         Field("v", DataType.FLOAT64, False)])
-        rng = np.random.default_rng(2)
-        b = make_host_batch(
-            schema,
-            [rng.integers(0, 4, 2048), np.round(rng.uniform(0, 9, 2048), 2)],
-            [None, None], [None, None],
+                         Field("v1", DataType.FLOAT64, False),
+                         Field("v2", DataType.FLOAT64, False)])
+
+        def run(sql):
+            c = ExecutionContext(batch_size=1024, result_cache=False)
+            c.register_csv("t", str(path), schema, has_header=True)
+            METRICS.reset()
+            rows = self._rows(c, sql)
+            return rows, METRICS.snapshot()["counts"].get("h2d.bytes", 0)
+
+        both, both_bytes = run("SELECT k, SUM(v1), AVG(v2) FROM t GROUP BY k")
+        one, one_bytes = run("SELECT k, SUM(v1) FROM t GROUP BY k")
+        want = sorted(
+            (int(g), float(v1[k == g].sum()), float(v2[k == g].mean()))
+            for g in np.unique(k)
         )
-        c = ExecutionContext(batch_size=2048)
-        c.register_datasource("t", MemoryDataSource(schema, [b]))
-        rel = c.sql("SELECT k, SUM(v) FROM t GROUP BY k")
-        node = rel
-        while node is not None and not isinstance(node, AggregateRelation):
-            node = getattr(node, "child", None)
-        from datafusion_tpu.exec.materialize import collect
-
-        sorted(collect(rel).to_rows())
-        assert node._placement is False  # reusable source: always device
-
-    def test_count_utf8_column_host(self, ctx, slow_link):
-        from datafusion_tpu.exec.aggregate import AggregateRelation
-        from datafusion_tpu.exec.materialize import collect
-
-        sql = "SELECT COUNT(c_string), SUM(c_float) FROM null_test"
-        rel = ctx.sql(sql)
-        node = rel
-        while not isinstance(node, AggregateRelation):
-            node = node.child
-        got = sorted(collect(rel).to_rows())
-        assert node._placement and node._placement.core is None
-        want = self._rows(self._fresh_ctx(ctx), sql)
-        self._assert_same(got, want)
+        _approx_rows(both, want, rtol=1e-9)
+        _approx_rows(one, [r[:2] for r in want], rtol=1e-9)
+        # batches are padded to their capacity bucket on the way
+        assert one_bytes >= 8 * n
+        assert both_bytes - one_bytes >= 8 * n
 
 
 def test_package_version_in_sync():
@@ -1310,138 +1284,97 @@ def test_package_version_in_sync():
     assert scripts["datafusion-tpu-worker"] == "datafusion_tpu.parallel.worker:main"
 
 
-class TestHostPartialsGrowth:
-    """Host accumulators must grow as later batches introduce new
-    groups (aggregate._HostPartials._grown)."""
+class TestAggregateOverStreamedBatches:
+    """Aggregates over a multi-batch stream with the wire forced on,
+    against numpy oracles: groups that first appear in later batches,
+    NULL group keys, and HAVING / ORDER BY / LIMIT over the result."""
 
-    def test_group_growth_across_batches_host_partials(self, monkeypatch):
+    @pytest.fixture(autouse=True)
+    def wire(self, monkeypatch):
         monkeypatch.setenv("DATAFUSION_TPU_WIRE", "always")
-        monkeypatch.setenv("DATAFUSION_TPU_LINK_MBPS", "0.001")
-        # groups appearing only in later batches: host accumulators grow
-        from datafusion_tpu import DataType, ExecutionContext, Field, Schema
+
+    def _ctx(self, schema, cols, valids, batch):
         from datafusion_tpu.exec.batch import make_host_batch
         from datafusion_tpu.exec.datasource import MemoryDataSource
+
+        class StreamSource(MemoryDataSource):
+            # as a file scan's, its batches are new to every query
+            reusable_batches = False
+
+        n = len(cols[0])
+        batches = [
+            make_host_batch(
+                schema, [c[i:i + batch] for c in cols],
+                [None if v is None else v[i:i + batch] for v in valids],
+                [None] * len(cols),
+            )
+            for i in range(0, n, batch)
+        ]
+        c = ExecutionContext(batch_size=batch, result_cache=False)
+        c.register_datasource("t", StreamSource(schema, batches))
+        return c
+
+    def test_group_growth_across_batches(self):
         from datafusion_tpu.exec.materialize import collect
 
         schema = Schema([Field("k", DataType.INT64, False),
                          Field("v", DataType.FLOAT64, False)])
         rng = np.random.default_rng(8)
+        # later batches introduce new keys: the accumulator grows
+        k = np.concatenate([rng.integers(lo, lo + 50, 4096)
+                            for lo in (0, 40, 90)])
+        v = np.round(rng.uniform(-10, 10, len(k)), 2)
+        c = self._ctx(schema, [k, v], [None, None], 4096)
+        got = sorted(collect(c.sql(
+            "SELECT k, SUM(v), AVG(v), COUNT(1) FROM t GROUP BY k")).to_rows())
+        want = sorted(
+            (int(g), float(v[k == g].sum()), float(v[k == g].mean()),
+             int((k == g).sum()))
+            for g in np.unique(k)
+        )
+        assert len(want) == 140
+        _approx_rows(got, want)
 
-        class StreamSource(MemoryDataSource):
-            reusable_batches = False  # force the placement decision
-
-        batches = []
-        for lo in (0, 40, 90):  # later batches introduce new keys
-            k = rng.integers(lo, lo + 50, 4096)
-            v = np.round(rng.uniform(-10, 10, 4096), 2)
-            batches.append(make_host_batch(schema, [k, v], [None, None], [None, None]))
-        from datafusion_tpu.exec.aggregate import AggregateRelation
-
-        src = StreamSource(schema, batches)
-        c = ExecutionContext(batch_size=4096)
-        c.register_datasource("t", src)
-        sql = "SELECT k, SUM(v), AVG(v), COUNT(1) FROM t GROUP BY k"
-        rel = c.sql(sql)
-        node = rel
-        while not isinstance(node, AggregateRelation):
-            node = node.child
-        got = sorted(collect(rel).to_rows())
-        # the point of this test is the HOST path's accumulator growth:
-        # fail loudly if placement ever stops routing this shape there
-        assert node._placement and node._placement.core is None
-        c2 = ExecutionContext(batch_size=4096)
-        c2.register_datasource("t", StreamSource(schema, batches))
-        monkeypatch.setenv("DATAFUSION_TPU_LINK_MBPS", "1e9")
-        want = sorted(collect(c2.sql(sql)).to_rows())
-        assert len(got) == len(want)
-        for ra, rb in zip(got, want):
-            for va, vb in zip(ra, rb):
-                if isinstance(va, float):
-                    np.testing.assert_allclose(va, vb, rtol=1e-12)
-                else:
-                    assert va == vb
-
-    def test_having_order_limit_over_placed_aggregate(self, monkeypatch):
-        # the aggregate's output batch feeds HAVING/ORDER BY/LIMIT
-        # downstream; the host-split result must be indistinguishable
-        monkeypatch.setenv("DATAFUSION_TPU_WIRE", "always")
-        from datafusion_tpu import DataType, ExecutionContext, Field, Schema
-        from datafusion_tpu.exec.batch import make_host_batch
-        from datafusion_tpu.exec.datasource import MemoryDataSource
+    def test_having_order_limit_over_aggregate(self):
         from datafusion_tpu.exec.materialize import collect
 
         schema = Schema([Field("k", DataType.INT64, False),
                          Field("v", DataType.FLOAT64, True)])
         rng = np.random.default_rng(12)
-
-        class StreamSource(MemoryDataSource):
-            reusable_batches = False
-
         k = rng.integers(0, 30, 8192)
         v = np.round(rng.uniform(-5, 5, 8192), 2)
         valid = rng.random(8192) > 0.15
-        batches = [make_host_batch(schema, [k[i:i+2048], v[i:i+2048]],
-                                   [None, valid[i:i+2048]], [None, None])
-                   for i in range(0, 8192, 2048)]
-        # predicate on the GROUP KEY: v stays exclusive to the host slots
-        # (a predicate on v would force v to ship and disable the split)
-        sql = ("SELECT k, SUM(v), COUNT(v) FROM t WHERE k < 25 GROUP BY k "
-               "HAVING COUNT(v) > 100 ORDER BY k LIMIT 10")
-        from datafusion_tpu.utils.metrics import METRICS
+        c = self._ctx(schema, [k, v], [None, valid], 2048)
+        got = collect(c.sql(
+            "SELECT k, SUM(v), COUNT(v) FROM t WHERE k < 25 GROUP BY k "
+            "HAVING COUNT(v) > 100 ORDER BY k LIMIT 10")).to_rows()
+        want = []
+        for g in range(25):
+            m = (k == g) & valid
+            if m.sum() > 100:
+                want.append((g, float(v[m].sum()), int(m.sum())))
+        want = want[:10]
+        assert len(want) == 10
+        _approx_rows(got, want)
 
-        outs = {}
-        for mode, mbps in (("host", "0.001"), ("device", "1e9")):
-            monkeypatch.setenv("DATAFUSION_TPU_LINK_MBPS", mbps)
-            METRICS.reset()
-            c = ExecutionContext(batch_size=2048)
-            c.register_datasource("t", StreamSource(schema, batches))
-            outs[mode] = collect(c.sql(sql)).to_rows()
-            routed = METRICS.snapshot()["counts"].get("aggregate.host_routed_slots")
-            assert bool(routed) == (mode == "host")
-        assert len(outs["host"]) == len(outs["device"]) > 0
-        for ra, rb in zip(outs["host"], outs["device"]):
-            assert ra[0] == rb[0] and ra[2] == rb[2]
-            np.testing.assert_allclose(ra[1], rb[1], rtol=1e-12)
-
-    def test_null_group_keys_host_partials(self, monkeypatch):
-        # NULL keys form their own group; host bincount must agree
-        monkeypatch.setenv("DATAFUSION_TPU_WIRE", "always")
-        from datafusion_tpu import DataType, ExecutionContext, Field, Schema
-        from datafusion_tpu.exec.batch import make_host_batch
-        from datafusion_tpu.exec.datasource import MemoryDataSource
+    def test_null_group_keys(self):
         from datafusion_tpu.exec.materialize import collect
 
         schema = Schema([Field("k", DataType.INT64, True),
                          Field("v", DataType.FLOAT64, False)])
         rng = np.random.default_rng(13)
-
-        class StreamSource(MemoryDataSource):
-            reusable_batches = False
-
         k = rng.integers(0, 5, 4096)
         kvalid = rng.random(4096) > 0.2
         v = np.round(rng.uniform(0, 10, 4096), 2)
-        batches = [make_host_batch(schema, [k[i:i+1024], v[i:i+1024]],
-                                   [kvalid[i:i+1024], None], [None, None])
-                   for i in range(0, 4096, 1024)]
-        sql = "SELECT k, SUM(v), AVG(v), COUNT(1) FROM t GROUP BY k"
-        from datafusion_tpu.utils.metrics import METRICS
-
-        outs = {}
-        for mode, mbps in (("host", "0.001"), ("device", "1e9")):
-            monkeypatch.setenv("DATAFUSION_TPU_LINK_MBPS", mbps)
-            METRICS.reset()
-            c = ExecutionContext(batch_size=1024)
-            c.register_datasource("t", StreamSource(schema, batches))
-            key = lambda r: tuple((x is None, 0 if x is None else x) for x in r)
-            outs[mode] = sorted(collect(c.sql(sql)).to_rows(), key=key)
-            routed = METRICS.snapshot()["counts"].get("aggregate.host_routed_slots")
-            assert bool(routed) == (mode == "host")
-        assert len(outs["host"]) == 6  # 5 keys + the NULL group
-        for ra, rb in zip(outs["host"], outs["device"]):
-            assert ra[0] == rb[0] and ra[3] == rb[3]
-            np.testing.assert_allclose(ra[1], rb[1], rtol=1e-12)
-            np.testing.assert_allclose(ra[2], rb[2], rtol=1e-12)
+        c = self._ctx(schema, [k, v], [kvalid, None], 1024)
+        got = sorted(collect(c.sql(
+            "SELECT k, SUM(v), AVG(v), COUNT(1) FROM t GROUP BY k")).to_rows(),
+            key=_null_sort_key)
+        groups = [(g, (k == g) & kvalid) for g in range(5)] + [(None, ~kvalid)]
+        want = [(g, float(v[m].sum()), float(v[m].mean()), int(m.sum()))
+                for g, m in groups]
+        assert len(got) == 6  # 5 keys + the NULL group
+        _approx_rows(got, want)
 
 
 class TestResidentTableShipsOnlyItsMask:
@@ -1469,9 +1402,6 @@ class TestResidentTableShipsOnlyItsMask:
 
         monkeypatch.setattr(relation, "_is_accelerator", lambda device: True)
         monkeypatch.setenv("DATAFUSION_TPU_WIRE", "always")
-        # a fast link keeps every slot on the device for the streamed
-        # twin of the table (no link-aware host split)
-        monkeypatch.setenv("DATAFUSION_TPU_LINK_MBPS", "1e9")
         saved = dict(kernels._REGISTRY)
         kernels._REGISTRY.clear()
         yield

@@ -5,9 +5,8 @@ Three layers, all on the CPU tier-1 backend:
 - The Pallas hash-build kernel against its numpy oracle through the
   Pallas interpreter (`interpret=True` — same kernel code path the TPU
   runs, minus Mosaic lowering).
-- The engine's fused-pass mode (`DATAFUSION_TPU_FUSE`, default on)
-  against the unfused per-operator path: identical results, fewer
-  launches, plan-chain collapse in effect.
+- The engine's fused passes against numpy / Python oracles: right
+  answers, one launch a batch group, plan-chain collapse in effect.
 - Sort semantics that must survive any backend/kernel swap: stability,
   NaN / signed-zero ordering, multi-key and mixed-dtype keys, and
   high-cardinality group-by exact-key/count parity vs numpy.
@@ -127,39 +126,71 @@ class TestFusedPasses:
                 rng.integers(-100, 100, n)]
         return schema, cols, g
 
-    def test_fused_vs_unfused_aggregate_parity(self, monkeypatch):
-        schema, cols, g = self._agg_data()
+    def test_fused_aggregate_matches_numpy(self):
+        schema, (k, v, w), g = self._agg_data()
         sql = ("SELECT k, SUM(w), MIN(v), MAX(v), COUNT(1) FROM t "
                "WHERE v > -1.5 GROUP BY k")
-        monkeypatch.setenv("DATAFUSION_TPU_FUSE", "1")
-        got = sorted(_rows(_ctx(schema, cols), sql))
-        monkeypatch.setenv("DATAFUSION_TPU_FUSE", "0")
-        want = sorted(_rows(_ctx(schema, cols), sql))
+        got = sorted(_rows(_ctx(schema, [k, v, w]), sql))
+        live = v > -1.5
+        want = sorted(
+            (int(key), int(w[m].sum()), float(v[m].min()),
+             float(v[m].max()), int(m.sum()))
+            for key in np.unique(k[live])
+            for m in [live & (k == key)]
+        )
         assert len(got) == len(want) == g
-        for a, b in zip(got, want):
-            assert a[0] == b[0] and a[1] == b[1] and a[4] == b[4]  # exact
-            np.testing.assert_allclose(a[2], b[2], rtol=1e-12)
-            np.testing.assert_allclose(a[3], b[3], rtol=1e-12)
+        assert got == want  # integer sums, counts, MIN and MAX are exact
 
-    def test_fused_mode_reduces_launches(self, monkeypatch):
+    def test_one_launch_per_batch_group(self):
         schema, cols, _ = self._agg_data()
         sql = "SELECT k, SUM(w), COUNT(1) FROM t GROUP BY k"
+        # 40,000 rows in batches of 2,048: 20 batches of one capacity
+        # (the short last one is padded), so one shape class
+        ctx = _ctx(schema, cols, batch_size=2048)
+        METRICS.reset()
+        collect(ctx.sql(sql))
+        snap = METRICS.snapshot()["counts"]
+        assert snap.get("fused.groups", 0) == 1
+        assert snap.get("fused.group_batches", 0) == 20
+        assert snap.get("device.launches.agg.group", 0) == 1
+        assert snap.get("device.launches", 0) == 1
 
-        def launches(fuse):
-            monkeypatch.setenv("DATAFUSION_TPU_FUSE", fuse)
-            monkeypatch.setenv("DATAFUSION_TPU_FUSE_BATCHES", "1")
-            ctx = _ctx(schema, cols, batch_size=2048)  # ~20 batches
-            METRICS.reset()
-            collect(ctx.sql(sql))
-            snap = METRICS.snapshot()["counts"]
-            return snap.get("device.launches", 0), snap.get("fused.groups", 0)
-
-        fused_n, groups = launches("1")
-        unfused_n, _ = launches("0")
-        assert groups >= 1
-        # ~20 per-batch launches collapse into one per batch group
-        assert fused_n < unfused_n
-        assert fused_n <= 4
+    @pytest.mark.parametrize("n_batches", [1, 2, 3, 17, 33])
+    @pytest.mark.parametrize("shape", ["aggregate", "topk"])
+    def test_batch_group_ladder(self, shape, n_batches):
+        # 17 and 33 batches fall between rungs of `fused._LADDER` (they
+        # pad to 24 and 48 with zero-row entries); 1 takes the
+        # single-batch launch.  Whatever the padding, one launch folds
+        # the scan and the dead entries change no answer.
+        rng = np.random.default_rng(59 + n_batches)
+        rows = 256
+        n = rows * n_batches - 100  # a short last batch
+        schema = Schema([
+            Field("k", DataType.INT64, False),
+            Field("v", DataType.FLOAT64, False),
+            Field("w", DataType.INT64, False),
+        ])
+        k = rng.integers(0, 12, n)
+        v = rng.normal(size=n)
+        w = rng.integers(-100, 100, n)
+        ctx = _ctx(schema, [k, v, w], batch_size=rows)
+        METRICS.reset()
+        if shape == "aggregate":
+            got = sorted(_rows(
+                ctx, "SELECT k, SUM(w), COUNT(1), MIN(v) FROM t GROUP BY k"))
+            want = sorted(
+                (int(g), int(w[k == g].sum()), int((k == g).sum()),
+                 float(v[k == g].min()))
+                for g in np.unique(k)
+            )
+        else:
+            got = _rows(ctx, "SELECT v, w FROM t ORDER BY v LIMIT 10")
+            want = sorted(zip(v.tolist(), w.tolist()))[:10]
+        assert got == want
+        snap = METRICS.snapshot()["counts"]
+        assert snap.get("device.launches", 0) == 1
+        assert snap.get("fused.group_batches", 0) == (
+            n_batches if n_batches > 1 or shape == "topk" else 0)
 
     def test_fuse_group_bucketing_bounds_compiles(self):
         from datafusion_tpu.exec.fused import bucket_group
@@ -169,7 +200,7 @@ class TestFusedPasses:
         assert bucket_group(115) == 128
         assert bucket_group(9000) == 9000  # beyond the ladder: as-is
 
-    def test_aggregate_over_projection_chain_collapses(self, monkeypatch):
+    def test_aggregate_over_projection_chain_collapses(self):
         # DataFrame-style Aggregate(Projection(Selection(scan))) lowers
         # to ONE AggregateRelation under fusion
         from datafusion_tpu.plan.expr import (
@@ -207,27 +238,25 @@ class TestFusedPasses:
                     Field("s", DataType.FLOAT64, False)]),
         )
 
-        def run(fuse):
-            monkeypatch.setenv("DATAFUSION_TPU_FUSE", fuse)
-            ctx = _ctx(schema, cols)
-            rel = ctx.execute(agg)
-            return sorted(collect(rel).to_rows()), rel
-
-        got, rel = run("1")
+        rel = _ctx(schema, cols).execute(agg)
+        got = sorted(collect(rel).to_rows())
         assert getattr(rel, "_fused_chain", None) == "filter+project+aggregate"
         assert type(rel).__name__ == "AggregateRelation"
         assert rel.op_children() and type(
             rel.op_children()[0]
         ).__name__ == "DataSourceRelation"  # no interposed pipeline
-        want, _ = run("0")
-        for a, b in zip(got, want):
-            np.testing.assert_allclose(
-                np.asarray(a, float), np.asarray(b, float), rtol=1e-12
-            )
+        a, k = cols
+        live = a > -0.7
+        want = sorted(
+            (int(key), float((a[live & (k == key)] * 3.0).sum()))
+            for key in np.unique(k[live])
+        )
+        assert [r[0] for r in got] == [r[0] for r in want]
+        np.testing.assert_allclose(
+            [r[1] for r in got], [r[1] for r in want], rtol=1e-12
+        )
 
-    def test_sort_chain_collapses_with_filter_and_projection(
-        self, monkeypatch
-    ):
+    def test_sort_chain_collapses_with_filter_and_projection(self):
         rng = np.random.default_rng(31)
         n = 20_000
         schema = Schema([
@@ -239,27 +268,19 @@ class TestFusedPasses:
                 rng.integers(0, 5, n)]
         sql = "SELECT b, a FROM t WHERE c < 3 ORDER BY b DESC, a LIMIT 25"
 
-        def run(fuse):
-            monkeypatch.setenv("DATAFUSION_TPU_FUSE", fuse)
-            ctx = _ctx(schema, cols)
-            rel = ctx.sql(sql)
-            return collect(rel).to_rows(), rel
-
-        got, rel = run("1")
+        rel = _ctx(schema, cols).sql(sql)
+        got = collect(rel).to_rows()
         assert getattr(rel, "_fused_chain", None) == "filter+project+sort"
         assert "+filter" in rel.op_label() and "+project" in rel.op_label()
-        want, _ = run("0")
-        assert got == want
+        a, b, c = cols
+        kept = [(int(y), float(x)) for x, y, z in zip(a, b, c) if z < 3]
+        assert got == sorted(kept, key=lambda r: (-r[0], r[1]))[:25]
         # and the full-sort (no LIMIT) variant
-        fsql = "SELECT b, a FROM t WHERE c < 3 ORDER BY b, a"
-        monkeypatch.setenv("DATAFUSION_TPU_FUSE", "1")
-        f_got = _rows(_ctx(schema, cols), fsql)
-        monkeypatch.setenv("DATAFUSION_TPU_FUSE", "0")
-        f_want = _rows(_ctx(schema, cols), fsql)
-        assert f_got == f_want
+        f_got = _rows(_ctx(schema, cols),
+                      "SELECT b, a FROM t WHERE c < 3 ORDER BY b, a")
+        assert f_got == sorted(kept)
 
-    def test_explain_analyze_reports_fused_passes(self, monkeypatch):
-        monkeypatch.setenv("DATAFUSION_TPU_FUSE", "1")
+    def test_explain_analyze_reports_fused_passes(self):
         rng = np.random.default_rng(37)
         n = 8000
         schema = Schema([
@@ -279,8 +300,7 @@ class TestFusedPasses:
         text = ctx.metrics_text()
         assert 'name="query.launches_per_pass"' in text
 
-    def test_repeat_query_no_kernel_cache_misses(self, monkeypatch):
-        monkeypatch.setenv("DATAFUSION_TPU_FUSE", "1")
+    def test_repeat_query_no_kernel_cache_misses(self):
         rng = np.random.default_rng(41)
         n = 5000
         schema = Schema([
@@ -301,7 +321,7 @@ class TestFusedPasses:
 
 
 class TestSortSemantics:
-    def test_stability_under_heavy_ties(self, monkeypatch):
+    def test_stability_under_heavy_ties(self):
         rng = np.random.default_rng(43)
         n = 30_000
         schema = Schema([
@@ -310,17 +330,15 @@ class TestSortSemantics:
         ])
         a = rng.integers(0, 8, n)  # 8 distinct keys: massive tie runs
         tag = np.arange(n, dtype=np.int64)
-        for fuse in ("1", "0"):
-            monkeypatch.setenv("DATAFUSION_TPU_FUSE", fuse)
-            rows = _rows(_ctx(schema, [a, tag], batch_size=4096),
-                         "SELECT a, tag FROM t ORDER BY a")
-            # within each key run, the original row order must survive
-            last = {}
-            for key, tag_v in rows:
-                assert last.get(key, -1) < tag_v, f"unstable at key {key}"
-                last[key] = tag_v
+        rows = _rows(_ctx(schema, [a, tag], batch_size=4096),
+                     "SELECT a, tag FROM t ORDER BY a")
+        # within each key run, the original row order must survive
+        last = {}
+        for key, tag_v in rows:
+            assert last.get(key, -1) < tag_v, f"unstable at key {key}"
+            last[key] = tag_v
 
-    def test_nan_and_signed_zero_ordering(self, monkeypatch):
+    def test_nan_and_signed_zero_ordering(self):
         vals = np.array([1.5, np.nan, -0.0, 0.0, -np.inf, np.inf,
                          -1.5, np.nan, 0.0, -0.0])
         tag = np.arange(len(vals), dtype=np.int64)
@@ -328,23 +346,19 @@ class TestSortSemantics:
             Field("a", DataType.FLOAT64, False),
             Field("tag", DataType.INT64, False),
         ])
-        outs = {}
-        for fuse in ("1", "0"):
-            monkeypatch.setenv("DATAFUSION_TPU_FUSE", fuse)
-            outs[fuse] = _rows(_ctx(schema, [vals, tag]),
-                               "SELECT a, tag FROM t ORDER BY a")
-        assert str(outs["1"]) == str(outs["0"])  # NaN-safe comparison
-        order = [t for _, t in outs["1"]]
+        rows = _rows(_ctx(schema, [vals, tag]),
+                     "SELECT a, tag FROM t ORDER BY a")
+        order = [t for _, t in rows]
         # -inf first, then -1.5; NaNs sort last (stable between them);
         # the four zeros stay contiguous (±0.0 compare equal or split —
         # backend-dependent — but never interleave with nonzeros)
         assert order[0] == 4 and order[1] == 6
         assert order[-2:] == [1, 7]
-        zeros = [t for v, t in outs["1"] if v == 0.0]
+        zeros = [t for v, t in rows if v == 0.0]
         assert sorted(zeros) == [2, 3, 8, 9]
         assert order[2:6] == zeros
 
-    def test_multi_key_mixed_dtype(self, monkeypatch):
+    def test_multi_key_mixed_dtype(self):
         rng = np.random.default_rng(47)
         n = 6000
         words = np.array(["ash", "birch", "cedar", "oak"], dtype=object)
@@ -357,20 +371,14 @@ class TestSortSemantics:
         f = rng.normal(size=n).round(1)  # ties across keys
         i = rng.integers(-40, 40, n)
         sql = "SELECT s, f, i FROM t ORDER BY s, f DESC, i"
-        got = {}
-        for fuse in ("1", "0"):
-            monkeypatch.setenv("DATAFUSION_TPU_FUSE", fuse)
-            got[fuse] = _rows(_ctx(schema, [s, f, i]), sql)
-        assert got["1"] == got["0"]
+        got = _rows(_ctx(schema, [s, f, i]), sql)
         want = sorted(
             zip(s.tolist(), f.tolist(), i.tolist()),
             key=lambda r: (r[0], -r[1], r[2]),
         )
-        assert got["1"] == [tuple(w) for w in want]
+        assert got == [tuple(w) for w in want]
 
-    def test_high_cardinality_groupby_exact_keys_and_counts(
-        self, monkeypatch
-    ):
+    def test_high_cardinality_groupby_exact_keys_and_counts(self):
         rng = np.random.default_rng(53)
         n, g = 60_000, 20_000  # most groups have 1-6 rows
         schema = Schema([
@@ -379,19 +387,17 @@ class TestSortSemantics:
         ])
         k = rng.integers(0, g, n)
         v = rng.normal(size=n)
-        for fuse in ("1", "0"):
-            monkeypatch.setenv("DATAFUSION_TPU_FUSE", fuse)
-            rows = _rows(_ctx(schema, [k, v], batch_size=8192),
-                         "SELECT k, COUNT(1), SUM(v) FROM t GROUP BY k")
-            got_keys = sorted(r[0] for r in rows)
-            want_keys, want_counts = np.unique(k, return_counts=True)
-            assert got_keys == want_keys.tolist()
-            counts = {r[0]: r[1] for r in rows}
-            assert all(
-                counts[kk] == cc
-                for kk, cc in zip(want_keys.tolist(), want_counts.tolist())
-            )
-            sums = {r[0]: r[2] for r in rows}
-            want_sums = np.bincount(k, weights=v, minlength=g)
-            for kk in want_keys.tolist():
-                np.testing.assert_allclose(sums[kk], want_sums[kk], rtol=1e-9)
+        rows = _rows(_ctx(schema, [k, v], batch_size=8192),
+                     "SELECT k, COUNT(1), SUM(v) FROM t GROUP BY k")
+        got_keys = sorted(r[0] for r in rows)
+        want_keys, want_counts = np.unique(k, return_counts=True)
+        assert got_keys == want_keys.tolist()
+        counts = {r[0]: r[1] for r in rows}
+        assert all(
+            counts[kk] == cc
+            for kk, cc in zip(want_keys.tolist(), want_counts.tolist())
+        )
+        sums = {r[0]: r[2] for r in rows}
+        want_sums = np.bincount(k, weights=v, minlength=g)
+        for kk in want_keys.tolist():
+            np.testing.assert_allclose(sums[kk], want_sums[kk], rtol=1e-9)

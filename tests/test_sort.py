@@ -462,7 +462,7 @@ class TestTopKFinalFold:
     """The TopK result's (live-mask, row-ids) pull is folded INTO the
     fused group launch: a warm pass is ONE counted device launch
     (`device.launches.topk.final`), with no separate blob-pack launch
-    for the mask — and parity against the unfused path holds."""
+    for the mask — and the rows are Python's `sorted` prefix."""
 
     def _ctx(self):
         rng = np.random.default_rng(21)
@@ -498,18 +498,19 @@ class TestTopKFinalFold:
         assert delta.get("device.launches.topk.final", 0) == 1
         assert delta.get("device.launches", 0) == 1
 
-    def test_parity_with_fuse_off(self):
-        import os
-
+    def test_folded_result_matches_sorted(self):
         from datafusion_tpu.exec.materialize import collect
 
         ctx, q = self._ctx()
-        want = collect(ctx.sql(q)).to_rows()
-        os.environ["DATAFUSION_TPU_FUSE"] = "0"
-        try:
-            assert collect(ctx.sql(q)).to_rows() == want
-        finally:
-            os.environ.pop("DATAFUSION_TPU_FUSE", None)
+        src = ctx.datasources["t"]
+        rows = [
+            (int(a), float(b))
+            for batch in src.batches()
+            for a, b in zip(np.asarray(batch.data[0])[: batch.num_rows],
+                            np.asarray(batch.data[1])[: batch.num_rows])
+        ]
+        want = sorted(rows, key=lambda r: r[0])[:10]
+        assert collect(ctx.sql(q)).to_rows() == want
 
     def test_empty_scan_and_wide_keys_still_fold(self):
         from datafusion_tpu.exec.materialize import collect
@@ -609,114 +610,109 @@ class TestTopKExactPayloads:
         collect(rel)  # executes end to end
 
 
-class TestHostRoutedRunSort:
-    """Link-aware full-sort placement (SortRelation._host_run_sort):
-    on a slow measured link the run permutation computes on the host
-    via np.lexsort; the stable orders must match the device path
-    exactly."""
+class TestRunSortOverTheWire:
+    """Full ORDER BY with the compressed wire forced on
+    (DATAFUSION_TPU_WIRE=always): the run's key operands travel
+    encoded, the device sorts, the permutation comes back as byte
+    planes.  Orders are held to Python's stable `sorted`: NULL keys
+    last whatever the direction, NaN above +inf, -0.0 before +0.0."""
 
-    def _src(self, nulls=False, nans=False):
-        import numpy as np
+    N = 4096
 
-        from datafusion_tpu import DataType, ExecutionContext, Field, Schema
-        from datafusion_tpu.exec.batch import make_host_batch
-        from datafusion_tpu.exec.datasource import MemoryDataSource
+    @pytest.fixture(autouse=True)
+    def wire(self, monkeypatch):
+        monkeypatch.setenv("DATAFUSION_TPU_WIRE", "always")
 
+    def _table(self, nulls=False, nans=False):
         rng = np.random.default_rng(21)
-        n = 4096
-        schema = Schema([
-            Field("a", DataType.FLOAT64, True),
-            Field("b", DataType.INT64, False),
-            Field("s", DataType.UTF8, False),
-        ])
+        n = self.N
         a = np.round(rng.uniform(-100, 100, n), 2)
         if nans:
             a[::97] = np.nan
         valid_a = rng.random(n) > 0.1 if nulls else None
         b = rng.integers(-50, 50, n)
+        s = [f"v{int(x) % 13}" for x in b]
+        return a, valid_a, b, s
+
+    def _ctx(self, a, valid_a, b, s):
         from datafusion_tpu.exec.batch import StringDictionary
 
+        schema = Schema([
+            Field("a", DataType.FLOAT64, True),
+            Field("b", DataType.INT64, False),
+            Field("s", DataType.UTF8, False),
+        ])
         d = StringDictionary()
-        codes = d.encode([f"v{int(x) % 13}" for x in b])
-        batches = []
-        half = n // 2
-        for lo, hi in ((0, half), (half, n)):
-            batches.append(make_host_batch(
+        codes = d.encode(s)
+        half = self.N // 2
+        batches = [
+            make_host_batch(
                 schema,
                 [a[lo:hi], b[lo:hi], codes[lo:hi]],
                 [None if valid_a is None else valid_a[lo:hi], None, None],
                 [None, None, d],
-            ))
+            )
+            for lo, hi in ((0, half), (half, self.N))
+        ]
         ctx = ExecutionContext(batch_size=half)
         ctx.register_datasource("t", MemoryDataSource(schema, batches))
         return ctx
 
-    def _run(self, ctx, sql, env, monkeypatch):
+    @staticmethod
+    def _key(v, desc=False):
+        """Sort key of one value: NULLs last, then the value (negated
+        for DESC; strings never sort DESC here)."""
+        if v is None:
+            return (1, 0)
+        return (0, -v if desc else v)
+
+    @pytest.mark.parametrize("sql,cols,key", [
+        ("SELECT a, b, s FROM t ORDER BY a, b", "abs",
+         lambda k, r: (k(r[0]), k(r[1]))),
+        ("SELECT a, b, s FROM t ORDER BY b DESC, a", "abs",
+         lambda k, r: (k(r[1], True), k(r[0]))),
+        ("SELECT s, a FROM t ORDER BY s, a DESC", "sa",
+         lambda k, r: (k(r[0]), k(r[1], True))),
+    ])
+    def test_multi_key_order_matches_sorted(self, sql, cols, key):
         from datafusion_tpu.exec.materialize import collect
 
-        for k, v in env.items():
-            monkeypatch.setenv(k, v)
-        return collect(ctx.sql(sql)).to_rows()
+        a, valid_a, b, s = self._table(nulls=True)
+        got = collect(self._ctx(a, valid_a, b, s).sql(sql)).to_rows()
+        col = {
+            "a": [float(x) if ok else None for x, ok in zip(a, valid_a)],
+            "b": b.tolist(),
+            "s": s,
+        }
+        rows = list(zip(*(col[c] for c in cols)))
+        assert got == sorted(rows, key=lambda r: key(self._key, r))
 
-    @pytest.mark.parametrize("sql", [
-        "SELECT a, b, s FROM t ORDER BY a, b",
-        "SELECT a, b, s FROM t ORDER BY b DESC, a",
-        "SELECT s, a FROM t ORDER BY s, a DESC",
-    ])
-    def test_host_sort_matches_device(self, sql, monkeypatch):
-        from datafusion_tpu.utils.metrics import METRICS
+    def test_nan_keys_sort_above_infinity(self):
+        from datafusion_tpu.exec.materialize import collect
 
-        slow = {"DATAFUSION_TPU_WIRE": "always", "DATAFUSION_TPU_LINK_MBPS": "0.001"}
-        fast = {"DATAFUSION_TPU_WIRE": "always", "DATAFUSION_TPU_LINK_MBPS": "1e9"}
-        METRICS.reset()
-        got = self._run(self._src(nulls=True), sql, slow, monkeypatch)
-        assert METRICS.snapshot()["counts"].get("sort.host_routed_runs")
-        want = self._run(self._src(nulls=True), sql, fast, monkeypatch)
-        assert got == want
+        a, _, b, s = self._table(nans=True)
+        ctx = self._ctx(a, None, b, s)
+        nan = np.isnan(a)
+        assert nan.any()
+        rest = [(float(x), int(y)) for x, y in zip(a[~nan], b[~nan])]
+        got = collect(ctx.sql("SELECT a, b FROM t ORDER BY a")).to_rows()
+        # ASC: the NaNs trail, in scan order (the sort is stable)
+        assert got[: len(rest)] == sorted(rest, key=lambda r: r[0])
+        assert all(np.isnan(r[0]) for r in got[len(rest):])
+        assert [r[1] for r in got[len(rest):]] == b[nan].tolist()
+        # DESC sorts by the negated key, and where -NaN stands is the
+        # backend's: at one end, together, never among the numbers
+        got = collect(ctx.sql("SELECT a, b FROM t ORDER BY a DESC")).to_rows()
+        nums = [r for r in got if not np.isnan(r[0])]
+        assert nums == sorted(rest, key=lambda r: -r[0])
+        n_nan = int(nan.sum())
+        assert nums in (got[n_nan:], got[: len(rest)])
 
-    def test_nan_keys_stay_on_device(self, monkeypatch):
-        from datafusion_tpu.utils.metrics import METRICS
-
-        slow = {"DATAFUSION_TPU_WIRE": "always", "DATAFUSION_TPU_LINK_MBPS": "0.001"}
-        METRICS.reset()
-        self._run(self._src(nans=True), "SELECT a, b FROM t ORDER BY a DESC", slow, monkeypatch)
-        assert not METRICS.snapshot()["counts"].get("sort.host_routed_runs")
-
-    def test_signed_zero_keys_stay_on_device(self, monkeypatch):
-        # XLA's total order splits -0.0 < +0.0; np.lexsort ties them —
-        # with both present the host route must bail (same contract as
-        # the NaN bail-out)
-        import numpy as np
-
-        from datafusion_tpu.exec.sort import SortRelation
-
-        monkeypatch.setenv("DATAFUSION_TPU_WIRE", "always")
-        monkeypatch.setenv("DATAFUSION_TPU_LINK_MBPS", "0.001")
-        rel = object.__new__(SortRelation)
-        rel.device = None
-
-        def keys_for(vals):
-            v = np.asarray(vals, np.float64)
-            return [np.zeros(len(v), bool), v]
-
-        both = keys_for([3.0, -0.0, 1.0, 0.0])
-        assert rel._host_run_sort(both, 4) is None
-        only_pos = keys_for([3.0, 0.0, 1.0, 0.0])
-        assert rel._host_run_sort(only_pos, 4) is not None
-        only_neg = keys_for([3.0, -0.0, 1.0, -0.0])
-        assert rel._host_run_sort(only_neg, 4) is not None
-        no_zero = keys_for([3.0, 2.0, 1.0, 4.0])
-        assert rel._host_run_sort(no_zero, 4) is not None
-
-    def test_signed_zero_sort_matches_device(self, monkeypatch):
-        # end to end: a float key containing both signed zeros, with the
-        # cost model begging for the host route — output order must
-        # equal the device path's (payload column detects divergence)
-        import numpy as np
-
-        from datafusion_tpu import DataType, ExecutionContext, Field, Schema
-        from datafusion_tpu.exec.batch import make_host_batch
-        from datafusion_tpu.exec.datasource import MemoryDataSource
+    def test_signed_zeros_stay_together(self):
+        # -0.0 and +0.0 tie or split by sign (the backend's total
+        # order), but never interleave with other values, and rows
+        # whose keys are the same bits keep their scan order (the
+        # payload column tells the zeros apart: -0.0 == 0.0 in Python)
         from datafusion_tpu.exec.materialize import collect
 
         rng = np.random.default_rng(9)
@@ -728,69 +724,52 @@ class TestHostRoutedRunSort:
             Field("a", DataType.FLOAT64, False),
             Field("tag", DataType.INT64, False),
         ])
+        b = make_host_batch(
+            schema, [a.copy(), np.arange(n, dtype=np.int64)],
+            [None, None], [None, None],
+        )
+        ctx = ExecutionContext(batch_size=n)
+        ctx.register_datasource("t", MemoryDataSource(schema, [b]))
+        got = [r[1] for r in collect(
+            ctx.sql("SELECT a, tag FROM t ORDER BY a")).to_rows()]
+        tied = sorted(range(n), key=lambda i: a[i])
+        split = sorted(range(n), key=lambda i: (a[i], not np.signbit(a[i])))
+        assert tied != split
+        assert got in (tied, split)
 
-        def run(env):
-            for k, v in env.items():
-                monkeypatch.setenv(k, v)
-            b = make_host_batch(
-                schema, [a.copy(), np.arange(n, dtype=np.int64)],
-                [None, None], [None, None],
-            )
-            ctx = ExecutionContext(batch_size=n)
-            ctx.register_datasource("t", MemoryDataSource(schema, [b]))
-            return collect(ctx.sql("SELECT a, tag FROM t ORDER BY a")).to_rows()
-
-        slow = run({"DATAFUSION_TPU_WIRE": "always",
-                    "DATAFUSION_TPU_LINK_MBPS": "0.001"})
-        fast = run({"DATAFUSION_TPU_WIRE": "always",
-                    "DATAFUSION_TPU_LINK_MBPS": "1e9"})
-        assert slow == fast
-
-    def test_host_perm_cached_on_warm_requery(self, monkeypatch):
-        # satellite: the host-routed permutation joins the same warm
-        # cache as device key uploads — the third batches() pass on one
-        # relation (seen, admitted, hit) skips the np.lexsort
+    def test_warm_requery_reuses_the_permutation(self):
+        # the third batches() pass on one relation (seen, admitted,
+        # hit) skips the key encode, the sort launch and its pull
         from datafusion_tpu.exec.materialize import collect
         from datafusion_tpu.utils.metrics import METRICS
 
-        monkeypatch.setenv("DATAFUSION_TPU_WIRE", "always")
-        monkeypatch.setenv("DATAFUSION_TPU_LINK_MBPS", "0.001")
-        ctx = self._src(nulls=False)
-        rel = ctx.sql("SELECT a, b, s FROM t ORDER BY a, b")
-        METRICS.reset()
+        rel = self._ctx(*self._table()).sql(
+            "SELECT a, b, s FROM t ORDER BY a, b")
         first = collect(rel).to_rows()
-        assert METRICS.snapshot()["counts"].get("sort.host_routed_runs")
         collect(rel)  # second pass: key admitted to the cache
-        before = METRICS.snapshot()["counts"].get("sort.perm_cache_hits", 0)
+        before = dict(METRICS.snapshot()["counts"])
         third = collect(rel).to_rows()
-        after = METRICS.snapshot()["counts"].get("sort.perm_cache_hits", 0)
-        assert after > before
+        after = METRICS.snapshot()["counts"]
+        assert after.get("sort.perm_cache_hits", 0) > before.get(
+            "sort.perm_cache_hits", 0)
+        assert after.get("device.launches.sort.run", 0) == before.get(
+            "device.launches.sort.run", 0)
         assert third == first
 
-    def test_full_sort_with_large_limit_host_route(self, monkeypatch):
-        # LIMIT above TOPK_MAX takes the full-sort path; the host-routed
+    def test_full_sort_with_large_limit(self):
+        # LIMIT above TOPK_MAX takes the full-sort path; the
         # permutation must honor the prefix take
-        import numpy as np
-
-        from datafusion_tpu import DataType, ExecutionContext, Field, Schema
-        from datafusion_tpu.exec.batch import make_host_batch
-        from datafusion_tpu.exec.datasource import MemoryDataSource
         from datafusion_tpu.exec.materialize import collect
         from datafusion_tpu.exec.sort import TOPK_MAX
-        from datafusion_tpu.utils.metrics import METRICS
 
-        monkeypatch.setenv("DATAFUSION_TPU_WIRE", "always")
-        monkeypatch.setenv("DATAFUSION_TPU_LINK_MBPS", "0.001")
         rng = np.random.default_rng(3)
         n = TOPK_MAX + 4096
         schema = Schema([Field("a", DataType.INT64, False)])
         b = make_host_batch(schema, [rng.integers(0, 10**6, n)], [None], [None])
         ctx = ExecutionContext(batch_size=n)
         ctx.register_datasource("t", MemoryDataSource(schema, [b]))
-        METRICS.reset()
         lim = TOPK_MAX + 1
         out = collect(ctx.sql(f"SELECT a FROM t ORDER BY a LIMIT {lim}"))
-        assert METRICS.snapshot()["counts"].get("sort.host_routed_runs")
         vals = [r[0] for r in out.to_rows()]
         want = sorted(np.asarray(b.data[0])[: b.num_rows].tolist())[:lim]
         assert vals == want
