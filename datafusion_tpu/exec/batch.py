@@ -118,9 +118,11 @@ class StringDictionary:
 
         Ordered comparisons on dictionary codes are meaningless (codes
         are append-ordered), so the host materializes this table — size
-        = dictionary size, recomputed per version — and the device does
-        a gather.  Lexicographic order means ISO dates compare
-        chronologically (the TPC-H shipdate filter rides this).
+        = dictionary size, recomputed per version — and the device looks
+        each row's code up in it, bit-packed (`exec/rowgather.py`; the
+        host predicate indexes it as it is).  Lexicographic order means
+        ISO dates compare chronologically (the TPC-H shipdate filter
+        rides this).
         """
         vals = np.asarray(self.values, dtype=object)
         if op == "<":

@@ -12,9 +12,11 @@ free), casts are `astype`, and nulls are validity bool tensors.
 String semantics (no tensor form for Utf8): columns carry int32
 dictionary codes.  Equality against a string literal compares codes
 (the literal's code is resolved per dictionary version on the host);
-ordered comparisons gather from a host-computed bool lookup table
-(`StringDictionary.compare_table`).  Both arrive as *aux inputs* so the
-jitted kernel stays pure.
+ordered comparisons look the code up in a host-computed truth table
+(`StringDictionary.compare_table`), packed a bit a code into 128-wide
+rows of words and read as a row gather + lane select + bit test
+(`exec/rowgather.py`).  Both arrive as *aux inputs* so the jitted
+kernel stays pure.
 """
 
 from __future__ import annotations
@@ -30,6 +32,8 @@ from jax import lax
 from datafusion_tpu.datatypes import DataType, Schema
 from datafusion_tpu.errors import ExecutionError, NotSupportedError
 from datafusion_tpu.exec.batch import RecordBatch, bucket_capacity
+from datafusion_tpu.exec.rowgather import WORD_BITS, pack_bits, take_bits
+from datafusion_tpu.utils.metrics import METRICS
 from datafusion_tpu.plan.expr import (
     AggregateFunction,
     BinaryExpr,
@@ -62,8 +66,10 @@ class AuxSpec:
 
     kind == "eq_code":   int32 scalar, the literal's dictionary code
                          (-1 if absent -> matches nothing)
-    kind == "cmp_table": bool[table_capacity] lookup table for an
-                         ordered comparison against the literal
+    kind == "cmp_table": uint32[rows, 128] truth table for an ordered
+                         comparison against the literal, one bit a
+                         dictionary code (`rowgather.pack_bits` of
+                         bool[table_capacity]: a row holds 4,096 codes)
     """
 
     kind: str
@@ -385,8 +391,8 @@ class ExprCompiler:
 
             def cmp_fn(env: Env):
                 table = env.aux[aux_i]
-                codes = jnp.clip(env.cols[col], 0, table.shape[0] - 1)
-                return table[codes], env.valids[valid_i]
+                codes = jnp.clip(env.cols[col], 0, table.size * WORD_BITS - 1)
+                return take_bits(table, codes), env.valids[valid_i]
 
             return cmp_fn
 
@@ -400,7 +406,11 @@ def compute_aux_values(
 
     Cached by (spec index, dictionary version): tables are recomputed
     only when a dictionary has grown.  Tables are padded to a bucketed
-    capacity so the jitted kernel recompiles O(log dict size) times.
+    capacity so the jitted kernel recompiles O(log dict size) times, and
+    handed over bit-packed in whole rows (`rowgather.pack_bits`; laid
+    out here, where it is free: inside the launch it would copy).
+    ``expr.cmp_lookups`` counts the `cmp_table` inputs handed out: one
+    device lookup a batch each (a call is one batch of one launch).
     """
     out = []
     for i, spec in enumerate(specs):
@@ -418,10 +428,10 @@ def compute_aux_values(
             val = np.int32(d.code_of(spec.literal))
         else:
             table = d.compare_table(spec.op, spec.literal)
-            cap = bucket_capacity(max(len(table), 1))
-            padded = np.zeros(cap, dtype=bool)
-            padded[: len(table)] = table
-            val = padded
+            val = pack_bits(table, bucket_capacity(max(len(table), 1)))
         cache[key] = val
         out.append(val)
+    lookups = sum(spec.kind == "cmp_table" for spec in specs)
+    if lookups:
+        METRICS.add("expr.cmp_lookups", lookups)
     return out

@@ -13,7 +13,10 @@ left input).  The build side fully materializes once into a
   (``device.launches.join.probe``, program ``jit_join_probe``)
   computing hit mask + payload gather at probe capacity — no host
   round trip, masks carried, zero extra H2D once the artifact is
-  resident.  The resident payload is the build's columns **other than
+  resident.  The slot table and the payload stay on the device as
+  128-wide rows and are read as a row gather + lane select
+  (`exec/rowgather.py`, shared with the string compare's truth
+  table).  The resident payload is the build's columns **other than
   the key**: on a hit the build row's key IS the probe row's key, and
   on a miss it is masked out (INNER) or NULL (LEFT OUTER), so the
   join's output column for the build key is the probe batch's own key
@@ -67,6 +70,7 @@ from datafusion_tpu.exec.batch import (
     put_compressed,
 )
 from datafusion_tpu.exec.relation import Relation
+from datafusion_tpu.exec.rowgather import LANES, pad_rows, take_rows
 from datafusion_tpu.join import core as _core
 from datafusion_tpu.obs.device import LEDGER, host_fits
 from datafusion_tpu.utils.metrics import METRICS
@@ -107,35 +111,6 @@ class JoinBuildArtifact:
         self.fingerprint = None
 
 
-# The probe's tables stay on the device as whole rows, `[n / 128, 128]`,
-# and are read as a gather of rows with the lane selected afterwards:
-# 131,072 scattered int32 values cost a v5e 0.37 ms that way from a
-# 60 MB table against 1.15 ms as an element gather (PERF.md section 6,
-# PR 28).  Reshaping a 1-D table inside the probe would copy all of it
-# in every launch.
-_LANE_BITS = 7
-_LANES = 1 << _LANE_BITS
-
-
-def _pad_rows(n: int) -> int:
-    return -(-n // _LANES) * _LANES
-
-
-def _take_rows(table, idx):
-    """`table.reshape(-1)[idx]` of a `[rows, _LANES]` table, as a
-    gather of rows and a lane select.  `idx` is int32 and in range."""
-    import jax.numpy as jnp
-    from jax import lax
-
-    rows = table[idx >> _LANE_BITS]
-    lane = lax.broadcasted_iota(jnp.int32, rows.shape, 1)
-    picked = jnp.where(lane == (idx & (_LANES - 1))[:, None], rows,
-                       jnp.zeros((), table.dtype))
-    if table.dtype == jnp.bool_:
-        return jnp.any(picked, axis=1)
-    return jnp.sum(picked, axis=1, dtype=table.dtype)
-
-
 @functools.lru_cache(maxsize=None)
 def _probe_fn_for(join_type: str, build_key_dtype: str):
     """One fused probe launch: slot lookup, hit mask, payload gather,
@@ -144,7 +119,7 @@ def _probe_fn_for(join_type: str, build_key_dtype: str):
     and the slot count are arguments, so every dense artifact of one
     shape class shares compiled probes.  The slot table and the
     payload (`pcols`, `pvalids`: the build's columns other than its
-    key) are `[rows, _LANES]` (`_take_rows`).
+    key) are `[rows, LANES]` (`take_rows`).
 
     Returns `(kcol, kval, gath, gval, out_mask)`: `kcol` / `kval` are
     the join's output column for the build key, made from the probe
@@ -165,7 +140,7 @@ def _probe_fn_for(join_type: str, build_key_dtype: str):
         if kvalid is not None:
             inr = inr & kvalid
         safe = jnp.where(inr, d, 0).astype(jnp.int32)
-        bidx = jnp.where(inr, _take_rows(slot_row, safe), -1)
+        bidx = jnp.where(inr, take_rows(slot_row, safe), -1)
         hit = bidx >= 0
         sb = jnp.where(hit, bidx, 0)
         inner = join_type == "inner"
@@ -173,11 +148,11 @@ def _probe_fn_for(join_type: str, build_key_dtype: str):
         # only live build rows are in the slot table: a hit's build key
         # is not NULL, whatever the build column's validity array says
         kval = None if inner else hit
-        gath = tuple(_take_rows(c, sb) for c in pcols)
+        gath = tuple(take_rows(c, sb) for c in pcols)
         # an INNER join masks its misses out: a build column without
         # NULLs stays without a validity array
         gval = tuple(
-            (None if inner else hit) if v is None else hit & _take_rows(v, sb)
+            (None if inner else hit) if v is None else hit & take_rows(v, sb)
             for v in pvalids)
         if inner:
             out_mask = hit if mask is None else mask & hit
@@ -362,7 +337,7 @@ class HashJoinRelation(Relation):
         ri = self.on[0][1]
         key = art.cols[ri].nbytes + (
             0 if art.valids[ri] is None else art.valids[ri].nbytes)
-        return art.nbytes - key + _pad_rows(num_slots) * 4
+        return art.nbytes - key + pad_rows(num_slots) * 4
 
     def _build_dense(self, art: JoinBuildArtifact, kmin: int,
                      num_slots: int) -> None:
@@ -381,9 +356,9 @@ class HashJoinRelation(Relation):
         # device residency: slot inputs + payload columns (every column
         # but the key) travel the compressed wire once, at build time;
         # warm probes reuse them.  Payload and slot table are padded to
-        # whole `_LANES`-wide rows (`_take_rows`); no slot and no hit
+        # whole `LANES`-wide rows (`take_rows`); no slot and no hit
         # points into the padding
-        pad = _pad_rows(art.n_rows) - art.n_rows
+        pad = pad_rows(art.n_rows) - art.n_rows
         cols = art.cols[:ri] + art.cols[ri + 1:]
         valids = art.valids[:ri] + art.valids[ri + 1:]
         held = [v for v in valids if v is not None]
@@ -410,7 +385,7 @@ class HashJoinRelation(Relation):
         # flat uploads go with this frame)
         art.dev_slot_row, (art.dev_cols, art.dev_valids) = LEDGER.adopt(
             device_call(
-                _build_jit(_pad_rows(num_slots), use_pallas,
+                _build_jit(pad_rows(num_slots), use_pallas,
                            _pallas.interpret_mode()),
                 dev[0], dev[1], payload, _tag="join.build",
             ), owner="join.build", device=self.device)
@@ -513,7 +488,7 @@ _BUILD_JITS: dict = {}
 
 def _build_jit(num_slots: int, use_pallas: bool, interpret: bool):
     """Jitted build, one per (slots, kernel-choice): the slot table
-    filled, and it and the payload laid out as `_LANES`-wide rows."""
+    filled, and it and the payload laid out as `LANES`-wide rows."""
     key = (num_slots, use_pallas, interpret)
     hit = _BUILD_JITS.get(key)
     if hit is None:
@@ -528,7 +503,7 @@ def _build_jit(num_slots: int, use_pallas: bool, interpret: bool):
             else:
                 slot_row = hash_build.build_slot_table_xla(
                     pos, live, num_slots)[0]
-            return jax.tree.map(lambda a: a.reshape(-1, _LANES),
+            return jax.tree.map(lambda a: a.reshape(-1, LANES),
                                 (slot_row, payload))
 
         hit = _BUILD_JITS[key] = jax.jit(join_build)

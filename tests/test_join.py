@@ -20,6 +20,7 @@ import pytest
 
 from datafusion_tpu import DataType, ExecutionContext, Field, Schema
 from datafusion_tpu.exec.materialize import collect
+from datafusion_tpu.exec.rowgather import LANES, pad_rows
 from datafusion_tpu.obs.device import LEDGER
 from datafusion_tpu.utils.metrics import METRICS
 
@@ -530,8 +531,6 @@ class TestFactToFactJoin:
         """2.2 M keys, every fourth of 8.8 M: 35 MB of payload columns
         and a 35 MB slot table; the 18 MB key column is not placed.  It
         fits what is free, so the second query builds nothing."""
-        from datafusion_tpu.join.relation import _pad_rows
-
         ctx = ExecutionContext(batch_size=512)
         okey = np.arange(2_200_000, dtype=np.int64) * 4 + 5
         _mem_table(ctx, "o_big", {"ok": okey, "oval": okey * 3,
@@ -547,7 +546,7 @@ class TestFactToFactJoin:
         s1 = _counts()
         # what is placed: two payload columns and the slot table
         assert _delta(s0, s1, "join.build.bytes") == (
-            2 * okey.nbytes + 4 * _pad_rows(int(okey[-1] - okey[0]) + 1)
+            2 * okey.nbytes + 4 * pad_rows(int(okey[-1] - okey[0]) + 1)
         ) > 64 << 20
         assert _delta(s0, s1, "device.launches.join.build") == 1
         assert LEDGER.pinned_bytes() >= _delta(s0, s1, "join.build.bytes")
@@ -664,9 +663,9 @@ def test_probe_reading_rows_equals_numpy_reading_elements(how, keys):
     n_build, kmin = 40_000, 1_000
     num_slots = 700_077
     pos = np.sort(rng.choice(num_slots, n_build, replace=False))
-    slot_row = np.full(jr._pad_rows(num_slots), -1, np.int32)
+    slot_row = np.full(pad_rows(num_slots), -1, np.int32)
     slot_row[pos] = np.arange(n_build, dtype=np.int32)
-    pad = jr._pad_rows(n_build) - n_build
+    pad = pad_rows(n_build) - n_build
     bkey = np.pad(pos.astype(np.int32) + kmin, (0, pad))
     pay = np.pad(rng.integers(0, 1 << 40, n_build), (0, pad))
     pay_valid = np.pad(rng.random(n_build) > 0.2, (0, pad))
@@ -680,7 +679,7 @@ def test_probe_reading_rows_equals_numpy_reading_elements(how, keys):
         key[:2_000] = rng.choice(pos, 2_000) + kmin
         key[2_000:2_010] = [-(1 << 40), 1 << 41, kmin - 1, kmin + num_slots,
                             (1 << 32) + kmin + int(pos[0])] * 2
-    rows = lambda a: jnp.asarray(a).reshape(-1, jr._LANES)  # noqa: E731
+    rows = lambda a: jnp.asarray(a).reshape(-1, LANES)  # noqa: E731
     kvalid = rng.random(cap) > 0.1
     if keys == "no_live_row":
         kvalid[:] = False
