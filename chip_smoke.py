@@ -20,6 +20,10 @@ process exits non-zero without printing a result:
           window edge (8,192 slots), and a join on sparse keys over 2^22
           slots grouped by a build-side string (the device probe that
           `tpubench`'s q12_sf10_join measures)
+4b. q3    TPC-H Q3 over customer, orders and lineitem at SF-1 against
+          `tpubench`'s numpy oracle: two pinned builds, the second
+          probed by a gathered column, three numeric group keys made
+          into groups on the device, `ORDER BY` an alias, `LIMIT 10`
 5. mesh   only with >= 4 TPU devices: Q1 over lineitem split four ways
           through `PartitionedDataSource` + `make_mesh(4)`
 
@@ -525,6 +529,84 @@ def stage_operators(device: str, agg_rows: int = 1 << 21) -> dict:
     return out
 
 
+Q3_SEED = 20260321
+
+
+def stage_q3(device: str, sf, batch_size: int = 1 << 17) -> dict:
+    """TPC-H Q3 over customer, orders and lineitem (`tpubench`'s data set
+    at 6 M lines an SF, and its numpy oracle): the first query builds and
+    pins both joins' build sides, the second finds them again; the three
+    numeric group keys become groups on the device and ten rows return."""
+    from datafusion_tpu.datatypes import DataType, Field, Schema
+    from datafusion_tpu.exec.batch import StringDictionary, make_host_batch
+    from datafusion_tpu.exec.context import ExecutionContext
+    from datafusion_tpu.exec.datasource import MemoryDataSource
+    from datafusion_tpu.exec.materialize import collect
+    from tpubench.spec import Spec
+
+    spec = Spec(REPO)
+    ds = spec.dataset("tpch_customer_orders_lineitem")
+    made = ds.generate(Q3_SEED, int(6_000_000 * sf),
+                       threads=min(8, os.cpu_count() or 1))
+    kinds = {"i64": DataType.INT64, "f64": DataType.FLOAT64,
+             "str": DataType.UTF8}
+    ctx = ExecutionContext(device=device, batch_size=batch_size,
+                           result_cache=False)
+    for table, cols in ds.TABLES.items():
+        schema = Schema([Field(c, kinds[k], False) for c, k in cols.items()])
+        arrays, dicts = [], []
+        for name in cols:
+            col = made["tables"][table][name]
+            d = None
+            if isinstance(col, tuple):
+                d = StringDictionary()
+                col = d.encode([col[1][c] for c in range(len(col[1]))])[col[0]]
+            arrays.append(col)
+            dicts.append(d)
+        n = len(arrays[0])
+        ctx.register_datasource(table, MemoryDataSource(schema, [
+            make_host_batch(schema, [a[lo: lo + batch_size] for a in arrays],
+                            None, dicts)
+            for lo in range(0, n, batch_size)]))
+    params = {"segment": ds.SEGMENT, "date": ds.DATE}
+    sql = spec.query("tpch_customer_orders_lineitem", "q3").format(
+        **ds.bind("q3", params))
+    want = made["oracle"].answer("q3", params)
+    extra = ("join.build.dense", "join.build.reuse", "join.host_probe.rows",
+             "device.launches.join.probe", "join.probe.gathers",
+             "aggregate.device_key.groups", "aggregate.device_key.rows",
+             "aggregate.key_pull.bytes", "device.launches.agg.key_ids",
+             "device.launches.topk.final", "d2h.bytes",
+             "h2d.resident_misses")
+    out = {"groups": made["oracle"].groups}
+    for label in ("q3_build", "q3_pinned"):
+        before = _counts()
+        got = collect(ctx.sql(sql)).to_rows()
+        ev = evidence(before, _counts(), extra)
+        require(len(got) == len(want),
+                f"{label}: {len(got)} rows, oracle has {len(want)}")
+        # the engine returns the keys, then the aggregate: in the spec's
+        # column order, row by row in the spec's row order
+        for i, ((k, d, p, r), w) in enumerate(zip(got, want)):
+            check_rows([(k, r, d, p)], [w], f"{label} row {i}")
+        require_on_device(label, ev)
+        require(ev["join.host_probe.rows"] == 0
+                and ev["aggregate.key_pull.bytes"] == 0,
+                f"{label}: the join or its group keys left the device: {ev}")
+        require(ev["aggregate.device_key.groups"] == made["oracle"].groups,
+                f"{label}: {ev['aggregate.device_key.groups']} groups on the "
+                f"device, the oracle keeps {made['oracle'].groups}")
+        out[label] = ev
+    require(out["q3_build"]["join.build.dense"] == 2
+            and out["q3_pinned"]["join.build.reuse"] == 2
+            and out["q3_pinned"]["join.build.dense"] == 0,
+            f"q3: two dense builds, then both found again, expected: {out}")
+    require(out["q3_pinned"]["h2d.resident_misses"] == 0
+            and 0 < out["q3_pinned"]["d2h.bytes"] <= 4096,
+            f"q3: the second query moved more than its answer: {out}")
+    return out
+
+
 def stage_mesh(src, want_rows, n_devices: int = 4) -> dict:
     """Q1 over the resident lineitem batches split `n_devices` ways."""
     from benchmarks.suite import Q1
@@ -682,6 +764,8 @@ def main(argv=None) -> int:
     report["warm"] = timed("warm", stage_warm, ctx, oracle)
     report["serve"] = timed("serve", stage_serve, ctx, oracle)
     report["operators"] = timed("operators", stage_operators, "tpu")
+    # Q3 at SF-1 at most: `tpubench`'s q3_sf10_join3 is the SF-10 run
+    report["q3"] = timed("q3", stage_q3, "tpu", min(sf, 1))
     if device["count"] >= 4:
         report["mesh"] = timed("mesh", stage_mesh, src, cold["rows"])
     else:

@@ -49,7 +49,7 @@ from __future__ import annotations
 import contextlib
 import contextvars
 import weakref
-from typing import Iterator, Optional
+from typing import Iterator, NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
@@ -66,6 +66,7 @@ from datafusion_tpu.exec.batch import (
 )
 from datafusion_tpu.exec.expression import Env, ExprCompiler, compute_aux_values
 from datafusion_tpu.exec.relation import Relation
+from datafusion_tpu.exec.wordsort import key_words, lex_perm
 from datafusion_tpu.plan.expr import AggregateFunction, Column, Expr
 from datafusion_tpu.utils.metrics import METRICS
 from datafusion_tpu.utils.retry import device_call
@@ -526,6 +527,13 @@ class _AggregateCore:
         # params) over ONE set of device inputs, returning one state
         # per query — one launch and one sync shared by all clients
         self.multi_group_jit = jax.jit(self._multi_fused_group)
+        # numeric group keys born on the device (`_KeyedAccumulator`)
+        self.keyed_counts_jit = jax.jit(self._keyed_counts)
+        self.keyed_rows_jit = jax.jit(self._keyed_rows, static_argnums=(0,))
+        self.keyed_store_jit = jax.jit(self._keyed_store)
+        self.keyed_reduce_jit = jax.jit(self._keyed_reduce)
+        self.keyed_outputs_jit = jax.jit(self._keyed_outputs,
+                                         static_argnums=(1, 2))
 
     def _fused_kernel(self, chunk, state, params):
         """Fold `_kernel` over a chunk of prepared batches in ONE device
@@ -940,6 +948,183 @@ class _AggregateCore:
             for st, ps in zip(states, params_list)
         )
 
+    # -- numeric group keys born on the device ---------------------------
+    # A join's probe output hands the aggregate key columns that exist
+    # only on the device, with values no dictionary bounds.  Nothing of
+    # such a batch goes to the host to be encoded: the rows the
+    # predicate keeps are compacted on the device and appended, key
+    # tuple and contributions, to a keyed buffer; one sort + segmented
+    # reduce turns the buffer into groups (`_KeyedAccumulator` drives
+    # the three programs).  An entry is (cols, valids, num_rows, mask,
+    # key cols, key valids); the state is (keys int64[B] a key, key
+    # NULL flags bool[B] a key, counts int64[B], accumulators [B] a
+    # slot); a row with count 0 is empty.
+
+    def _live_rows(self, cols, valids, aux, num_rows, base_mask, kcols,
+                   params):
+        """The rows of one batch that the predicate keeps, as a mask."""
+        env = Env(cols, valids, aux, self.col_map, params)
+        capacity = kcols[0].shape[0]
+        mask = jnp.arange(capacity, dtype=jnp.int32) < num_rows
+        if base_mask is not None:
+            mask = mask & base_mask
+        if self._pred_fn is not None:
+            pv, pvalid = self._pred_fn(env)
+            pv = jnp.broadcast_to(pv, (capacity,))
+            if pvalid is not None:
+                pv = pv & jnp.broadcast_to(pvalid, (capacity,))
+            mask = mask & pv
+        return mask
+
+    def _keyed_counts(self, entries, aux, params):
+        """The most rows the predicate keeps of any one batch of a
+        group: all the host learns of them (4 B; it sizes the append's
+        compaction)."""
+        from datafusion_tpu.exec.fused import stack_entries
+
+        def body(x):
+            cols, valids, num_rows, mask, kcols, _ = x
+            live = self._live_rows(cols, valids, aux, num_rows, mask, kcols,
+                                   params)
+            return jnp.sum(live, dtype=jnp.int32)
+
+        return jnp.max(jax.lax.map(body, stack_entries(entries)))
+
+    def _slot_identities(self):
+        return [jnp.asarray(self._slot_identity(sl)) for sl in self.slots]
+
+    def _keyed_rows(self, width, entries, aux, params):
+        """A batch group's kept rows as the keyed buffer holds them:
+        of each batch the first `width` rows in kept-first order (a
+        sort of row numbers; `width` holds every kept row of every
+        batch of the group, so what is cut off is dead), the key tuple
+        and one contribution a slot.  `width` equal to the batch
+        capacity takes the batch as it lies."""
+        from datafusion_tpu.exec.fused import stack_entries
+
+        idents = self._slot_identities()
+
+        def body(x):
+            cols, valids, num_rows, mask, kcols, kvalids = x
+            capacity = kcols[0].shape[0]
+            live = self._live_rows(cols, valids, aux, num_rows, mask, kcols,
+                                   params)
+            if width < capacity:
+                rows = jnp.arange(capacity, dtype=jnp.int32)
+                order = jax.lax.sort(jnp.where(live, rows, rows + capacity))
+                idx = order[:width]
+                idx = jnp.where(idx >= capacity, idx - capacity, idx)
+                live = jnp.arange(width, dtype=jnp.int32) < jnp.sum(
+                    live, dtype=jnp.int32)
+
+                def take(a):
+                    return None if a is None else a[idx]
+            else:
+                def take(a):
+                    return a
+
+            env = Env(tuple(take(c) for c in cols),
+                      tuple(take(v) for v in valids), aux, self.col_map,
+                      params)
+            keys, knull = [], []
+            for c, v in zip(kcols, kvalids):
+                null = jnp.zeros(width, bool) if v is None else ~take(v)
+                null = null & live
+                knull.append(null)
+                keys.append(jnp.where(live & ~null,
+                                      take(c).astype(jnp.int64), 0))
+            accs = []
+            for sl, ident, (v, ok) in zip(
+                    self.slots, idents, self._slot_inputs(env, width, live)):
+                if sl.kind == "cnt":
+                    accs.append(ok.astype(jnp.int64))
+                else:
+                    accs.append(jnp.where(ok, v.astype(sl.acc_dtype), ident))
+            return (tuple(keys), tuple(knull), live.astype(jnp.int64),
+                    tuple(accs))
+
+        return jax.tree.map(lambda rows: rows.reshape(-1),
+                            jax.lax.map(body, stack_entries(entries)))
+
+    @staticmethod
+    def _keyed_store(state, rows, offset):
+        """`rows` written into the keyed buffer at `offset` (a program
+        of its own, so that the one that makes the rows, whose sort
+        compiles for a third of a minute, does not depend on the
+        buffer's size)."""
+        return jax.tree.map(
+            lambda buf, new: jax.lax.dynamic_update_slice(
+                buf, new, (offset,)),
+            state, rows)
+
+    def _keyed_reduce(self, state):
+        """The buffer's rows merged into groups, first in the buffer:
+        (state, int64 [groups, rows in them]).  Sort by key tuple (empty
+        rows last; word by word, `exec/wordsort.py`), reduce each run
+        of equal tuples with one segmented scan, move each run's last
+        row, which holds its total, to the front by a second sort of
+        row numbers."""
+        keys, knull, counts, accs = state
+        size = counts.shape[0]
+        rows = jnp.arange(size, dtype=jnp.int32)
+        empty = counts == 0
+        words = key_words(empty)
+        for null, key in zip(knull, keys):
+            words += key_words(null) + key_words(key)
+        perm = lex_perm(words)
+        start = rows == 0
+        for w in words:
+            w = w[perm]
+            start = start | (w != jnp.roll(w, 1))
+        combines = [jnp.add] + [
+            jnp.add if sl.kind in ("sum", "cnt")
+            else jnp.minimum if sl.kind == "min" else jnp.maximum
+            for sl in self.slots]
+        totals = self._seg_scan(
+            [counts[perm]] + [a[perm] for a in accs], start, combines)
+        last = jnp.roll(start, -1).at[size - 1].set(True) & ~empty[perm]
+        n_groups = jnp.sum(last, dtype=jnp.int64)
+        order = jax.lax.sort(jnp.where(last, rows, rows + size))
+        src = jnp.where(order >= size, order - size, order)
+        live = rows < n_groups
+        pick = perm[src]
+        new_keys = tuple(jnp.where(live, k[pick], 0) for k in keys)
+        new_null = tuple(live & n[pick] for n in knull)
+        new_counts = jnp.where(live, totals[0][src], 0)
+        new_accs = tuple(
+            jnp.where(live, t[src], ident)
+            for t, ident in zip(totals[1:], self._slot_identities()))
+        tally = jnp.stack([n_groups, jnp.sum(counts)])
+        return (new_keys, new_null, new_counts, new_accs), tally
+
+    def _keyed_outputs(self, state, key_dtypes, rows):
+        """The output columns of a reduced keyed state's first `rows`
+        rows, on the device: ((values, validity) a key, (values,
+        validity) an aggregate), by
+        `AggregateRelation._numeric_output`'s definitions."""
+        keys, knull, counts, accs = jax.tree.map(lambda a: a[:rows], state)
+        out_keys = tuple(
+            (k.astype(jnp.dtype(dt)), ~null)
+            for k, null, dt in zip(keys, knull, key_dtypes))
+        out = []
+        for s in self.specs:
+            ret = s.return_type.np_dtype
+            cnts = counts if s.cnt_slot is None else accs[s.cnt_slot]
+            if s.name == "sum":
+                out.append((accs[s.sum_slot].astype(ret), cnts > 0))
+            elif s.name == "avg":
+                mean = accs[s.sum_slot].astype(jnp.float64) / jnp.maximum(
+                    cnts, 1)
+                out.append((mean.astype(ret), cnts > 0))
+            elif s.name == "count":
+                out.append((cnts.astype(ret), None))
+            else:
+                raw = accs[s.minmax_slot]
+                ident = (_min_identity if s.name == "min"
+                         else _max_identity)(np.dtype(raw.dtype))
+                out.append((raw.astype(ret), raw != jnp.asarray(ident)))
+        return out_keys, tuple(out)
+
     def _dense_update(self, env, capacity, mask, ids, counts, accs, str_aux=()):
         """Small-group path: segment reduction against a one-hot
         [rows, G] membership matrix.  Float sums and all counts stack
@@ -1023,6 +1208,206 @@ class _AggregateCore:
                     jnp.minimum(acc, red) if sl.kind == "min" else jnp.maximum(acc, red)
                 )
         return new_counts, tuple(new_accs)
+
+
+class _DeviceKeys(NamedTuple):
+    """What `_group_ids` hands back for a batch whose numeric key
+    columns were born on the device: the columns themselves and their
+    validity arrays, for the keyed programs (`_KeyedAccumulator`), in
+    place of ids."""
+
+    cols: tuple
+    valids: tuple
+
+
+class _KeyedState(NamedTuple):
+    """A finished keyed accumulation: the reduced device state (groups
+    first) and how many groups it holds."""
+
+    state: tuple
+    n_groups: int
+
+
+class _KeyedAccumulator:
+    """Drives one scan's keyed accumulation (`_AggregateCore._keyed_*`).
+
+    Batches collect into groups of one shape class.  A group's count
+    launch goes out at once and its answer (4 B a group) is read one
+    flush later, so the host never waits on the device's queue in the
+    middle of a scan; the append launch that follows is sized by the
+    largest count (a power of two, at least `_MIN_WIDTH`).  The buffer
+    holds rows, not groups, so the host always knows how full it is:
+    where the next append would not fit, the reduce launch folds the
+    rows into groups (one more scalar read) and the buffer grows if
+    groups alone fill half of it.  The buffer is the ledger's (owner
+    `agg.keyed`) and grows only into what `LEDGER.fits`: a scan whose
+    groups find no room is refused (`_make_room`)."""
+
+    _MIN_WIDTH = 1024
+    # batches between flushes: how many probe outputs a scan holds
+    # while their counts travel
+    _FLUSH_BATCHES = 32
+
+    def __init__(self, rel: "AggregateRelation", core, params):
+        self.rel = rel
+        self.core = core
+        self.params = params
+        self.chunk: list = []
+        self.pending: list = []  # (group, aux, pull of its largest count)
+        self.state = None
+        self.capacity = 0
+        self.used = 0
+        self.offered = 0  # rows of the batches seen, kept or not
+
+    def add(self, data, validity, aux, num_rows, mask, keys: _DeviceKeys):
+        self.offered += int(num_rows)
+        self.chunk.append(
+            ((data, validity, num_rows, mask, keys.cols, keys.valids), aux))
+        if len(self.chunk) >= self._FLUSH_BATCHES:
+            self.flush()
+
+    def _groups(self):
+        """The chunk's batches by shape class (addition does not care
+        in which order batches arrive), each padded to its rung."""
+        from datafusion_tpu.exec.fused import (
+            entry_signature,
+            pad_group,
+            shared_signature,
+        )
+
+        classes: dict = {}
+        for entry, aux in self.chunk:
+            sig = (entry_signature(entry), shared_signature(aux))
+            classes.setdefault(sig, ([], aux))[0].append(entry)
+        self.chunk = []
+        for entries, aux in classes.values():
+            yield tuple(pad_group(
+                entries, lambda e: (e[0], e[1], np.int32(0), *e[3:]))), aux
+
+    def flush(self, drain: bool = False):
+        from datafusion_tpu.exec.batch import device_pull_start
+        from datafusion_tpu.exec.relation import device_scope
+        from datafusion_tpu.obs.stats import op_timer
+
+        # the groups counted by the flush before this one (at the end,
+        # all of them): their counts have long arrived
+        done = self.pending
+        self.pending = []
+        with op_timer(self.rel), device_scope(self.rel.device):
+            with METRICS.timer("aggregate.device_key_ids"):
+                for group, aux in self._groups():
+                    most = device_call(
+                        self.core.keyed_counts_jit, group, aux, self.params,
+                        _tag="agg.key_ids")
+                    self.pending.append(
+                        (group, aux, device_pull_start(most)))
+            if drain:
+                done, self.pending = done + self.pending, []
+            for group, aux, pull in done:
+                with METRICS.timer("aggregate.device_key_ids"):
+                    most = int(pull.finish())
+                if most:
+                    self._append(group, aux, most)
+
+    def _append(self, group, aux, most: int):
+        core = self.core
+        capacity = group[0][4][0].shape[0]
+        width = min(capacity, group_capacity(max(most, self._MIN_WIDTH)))
+        need = len(group) * width
+        if self.used + need > self.capacity:
+            if self.used:
+                state, self.used, _ = self._reduce()
+                self._hold(state)
+            self._make_room(self.used + need)
+        with METRICS.timer("execute.aggregate"):
+            rows = device_call(
+                core.keyed_rows_jit, width, group, aux, self.params,
+                _tag="agg.group")
+            self._hold(device_call(
+                core.keyed_store_jit, self.state, rows, np.int32(self.used),
+                _tag="agg.store"))
+        self.used += need
+
+    def _hold(self, state) -> None:
+        from datafusion_tpu.obs.device import LEDGER
+
+        # the query's own, gone with it: not a cached copy
+        self.state = LEDGER.adopt(state, owner="agg.keyed", cached=False)
+
+    def _make_room(self, rows: int) -> None:
+        """A buffer that holds `rows` rows: four times that where the
+        device has the room (appends between reduces), down to the
+        power of two that just holds them where it has not (every
+        append then reduces first); refused where that finds no room
+        either.  What is asked of the ledger is the new buffer and the
+        reduce's sorted copy of it."""
+        from datafusion_tpu.obs.device import LEDGER
+
+        if 2 * rows <= self.capacity:
+            return
+        core = self.core
+        # a buffer row: int64 + NULL flag a key, the count, the slots
+        row_bytes = len(core.key_cols) * 9 + 8 + sum(
+            np.dtype(sl.acc_dtype).itemsize for sl in core.slots)
+        want = group_capacity(4 * rows)
+        least = max(self.capacity, group_capacity(rows))
+        while want > least and not LEDGER.fits(2 * want * row_bytes):
+            want >>= 1
+        if want <= self.capacity:
+            return
+        if not LEDGER.fits(2 * want * row_bytes):
+            raise ExecutionError(
+                f"GROUP BY over join output: {rows:,} live rows and groups "
+                f"need {2 * want * row_bytes:,} bytes of device memory for "
+                f"their buffer, more than is free")
+        self._grow(want)
+
+    def _reduce(self):
+        """(the state reduced to groups, how many, the rows in them)."""
+        from datafusion_tpu.exec.batch import device_pull
+
+        with METRICS.timer("execute.aggregate"):
+            state, tally = device_call(
+                self.core.keyed_reduce_jit, self.state, _tag="agg.reduce")
+            groups, rows = (int(x) for x in device_pull(tally))
+            return state, groups, rows
+
+    def _grow(self, capacity: int):
+        """The buffer at `capacity` rows, the new ones empty."""
+        core = self.core
+        if self.state is None:
+            # from the smallest dense state (the core keeps that one)
+            self.capacity = group_capacity(1)
+            n_keys = len(core.key_cols)
+            self.state = ((jnp.zeros(self.capacity, jnp.int64),) * n_keys,
+                          (jnp.zeros(self.capacity, bool),) * n_keys,
+                          *core._init_state(self.capacity))
+        keys, knull, counts, accs = self.state
+        pad = capacity - self.capacity
+        self._hold((
+            tuple(jnp.concatenate([k, jnp.zeros(pad, k.dtype)]) for k in keys),
+            tuple(jnp.concatenate([n, jnp.zeros(pad, bool)]) for n in knull),
+            *core._grow_state((counts, accs), capacity)))
+        self.capacity = capacity
+
+    def finish(self) -> _KeyedState:
+        self.flush(drain=True)
+        if self.state is None:
+            self._grow(group_capacity(1))
+        state, groups, rows = self._reduce()
+        # of each kept row, the columns the step has to read: its keys
+        # and what the aggregates are made from
+        read = set(self.core.key_cols)
+        for spec in self.core.specs:
+            if not spec.count_star:
+                spec.arg.collect_columns(read)
+        schema = self.rel.child.schema
+        METRICS.add("aggregate.device_key.offered", self.offered)
+        METRICS.add("aggregate.device_key.rows", rows)
+        METRICS.add("aggregate.device_key.groups", groups)
+        METRICS.add("aggregate.device_key.input_bytes", rows * sum(
+            schema.field(i).data_type.np_dtype.itemsize for i in read))
+        return _KeyedState(state, groups)
 
 
 class AggregateRelation(Relation):
@@ -1253,11 +1638,19 @@ class AggregateRelation(Relation):
         _cost.store().observe(obs[0], obs[1], groups=self.encoder.num_groups)
 
     def accumulate(self):
-        """Run the scan, returning the partial-aggregate device state.
+        """Run the scan, returning the partial-aggregate device state
+        (counts, accumulators), indexed by the encoder's dense ids.
 
         Partitioned mode calls this per shard and combines states with
-        collectives; single-device mode finalizes it directly.
+        collectives; a worker takes it apart for the coordinator;
+        single-device mode (`batches`) finalizes its own scan directly.
         """
+        return self._scan(keyed=False)
+
+    def _scan(self, keyed: bool):
+        """`accumulate`; with `keyed`, a scan whose numeric group keys
+        are born on the device comes back as a `_KeyedState` instead
+        (`_device_key_columns`), for `_finalize_keyed` alone."""
         from datafusion_tpu.obs.stats import iter_stats
 
         # serving megabatch (serve.py): the cross-query fused launch
@@ -1270,7 +1663,7 @@ class AggregateRelation(Relation):
 
         self._adopt_source_state()
         return self._accumulate_core(
-            iter_stats(self.child), self.core, self._params
+            iter_stats(self.child), self.core, self._params, keyed=keyed
         )
 
     def _adopt_source_state(self) -> None:
@@ -1301,8 +1694,10 @@ class AggregateRelation(Relation):
         self._str_aux_cache = other._str_aux_cache
         self._ids_lock = other._ids_lock
 
-    def _accumulate_core(self, batches, core, params):
-        """The scan loop over one device core: stage, group, launch."""
+    def _accumulate_core(self, batches, core, params, keyed: bool = False):
+        """The scan loop over one device core: stage, group, launch.
+        `keyed`: whether batches that offer `_device_key_columns` go to
+        a `_KeyedAccumulator` (the result is then a `_KeyedState`)."""
         from datafusion_tpu.exec.prefetch import pipeline_enabled, staged_pipeline
         from datafusion_tpu.exec.relation import device_scope
         from datafusion_tpu.obs.stats import op_timer
@@ -1313,7 +1708,8 @@ class AggregateRelation(Relation):
             # consumer below dispatches batch N's kernel; results land
             # in batch.cache / relation caches and are re-read as hits
             def _stage(b):
-                self._group_ids(b)
+                if not keyed or self._device_key_columns(b) is None:
+                    self._group_ids(b)
                 # pin the aux tables computed NOW on the batch: global
                 # dictionaries keep growing while later batches parse,
                 # so a consumer-side recompute could see a bigger table
@@ -1344,6 +1740,7 @@ class AggregateRelation(Relation):
         state = None
         capacity = 0
         chunk: list = []
+        by_key: Optional[_KeyedAccumulator] = None
 
         def dispatch_chunk(state):
             if len(chunk) == 1:
@@ -1414,7 +1811,8 @@ class AggregateRelation(Relation):
             for idx in self.key_cols:
                 if batch.dicts[idx] is not None:
                     self._key_dicts[idx] = batch.dicts[idx]
-            ids = self._group_ids(batch)
+            keys = self._device_key_columns(batch) if keyed else None
+            ids = self._group_ids(batch) if keys is None else None
             staged = batch.cache.get("staged_aux")
             if staged is not None and staged[0] is core:
                 _, aux, str_aux = staged
@@ -1423,12 +1821,24 @@ class AggregateRelation(Relation):
                 str_aux = self._compute_str_aux(batch, core.slots)
             with device_scope(self.device):
                 data, validity, mask = self._device_inputs(batch, core)
+            if keys is not None:
+                if by_key is None:
+                    by_key = _KeyedAccumulator(self, core, params)
+                by_key.add(data, validity, tuple(aux),
+                           np.int32(batch.num_rows), mask, keys)
+                continue
             chunk.append(
                 (data, validity, tuple(aux), np.int32(batch.num_rows), mask,
                  ids, str_aux)
             )
             if len(chunk) >= fuse:
                 flush()
+        if by_key is not None:
+            if state is not None or chunk:
+                raise ExecutionError(
+                    "a scan handed the aggregate its numeric group keys "
+                    "both on the device and on the host")
+            return by_key.finish()
         flush()
         if state is None:
             state = core._init_state(group_capacity(1))
@@ -1522,6 +1932,16 @@ class AggregateRelation(Relation):
             if ids is not None:
                 batch.cache[self._ids_slot] = (self.encoder, ids)
                 return ids
+            pulled = [a for idx in self.key_cols
+                      for a in (batch.data[idx], batch.validity[idx])
+                      if a is not None and not isinstance(a, np.ndarray)]
+            if pulled:
+                # key columns born on the device that neither device
+                # path serves (`_device_key_columns`, `_device_group_ids`)
+                # come back to be encoded
+                nbytes = sum(int(a.nbytes) for a in pulled)
+                METRICS.add("aggregate.key_pull.bytes", nbytes)
+                METRICS.add("d2h.bytes", nbytes)
             key_cols = [np.asarray(batch.data[idx]) for idx in self.key_cols]
             key_valids = [
                 None if batch.validity[idx] is None else np.asarray(batch.validity[idx])
@@ -1557,6 +1977,25 @@ class AggregateRelation(Relation):
         )
         batch.cache[self._ids_slot] = (self.encoder, ids)
         return ids
+
+    def _device_key_columns(self, batch: RecordBatch):
+        """The key columns of a batch born on the device (a join's
+        probe output) where every key is an integer without a
+        dictionary, for the keyed programs (`_KeyedAccumulator`: no
+        column, id or mask of the batch crosses the link), else None.
+        String MIN / MAX ride dictionary ranks the keyed state does
+        not carry: such an aggregate is encoded on the host.  Only
+        `batches` asks (`_scan(keyed=True)`): a worker's fragment and
+        the mesh merge dense states by the encoder's ids."""
+        cols = tuple(batch.data[i] for i in self.key_cols)
+        if (not cols
+                or any(isinstance(c, np.ndarray) or c.dtype.kind not in "iub"
+                       for c in cols)
+                or any(batch.dicts[i] is not None for i in self.key_cols)
+                or any(sl.is_string for sl in self.slots)):
+            return None
+        return _DeviceKeys(cols, tuple(batch.validity[i]
+                                       for i in self.key_cols))
 
     def _device_group_ids(self, batch: RecordBatch):
         """What the kernels make group ids from (`_ids_of`) for a batch
@@ -1691,6 +2130,25 @@ class AggregateRelation(Relation):
         counts, accs = device_pull((counts, accs))
         return np.asarray(counts), [np.asarray(a) for a in accs]
 
+    def _finalize_keyed(self, keyed: _KeyedState) -> RecordBatch:
+        """The answer of a keyed accumulation, left on the device: one
+        launch turns the reduced state into output columns; the host
+        knows only how many rows they hold."""
+        in_schema = self.child.schema
+        key_dtypes = tuple(
+            np.dtype(in_schema.field(i).data_type.np_dtype).name
+            for i in self.key_cols)
+        # as many rows as a power of two holds the groups: the state's
+        # capacity is the buffer's, several times that
+        rows = min(keyed.state[2].shape[0], group_capacity(keyed.n_groups))
+        out_keys, out = device_call(
+            self.core.keyed_outputs_jit, keyed.state, key_dtypes, rows,
+            _tag="agg.outputs")
+        cols = [v for v, _ in out_keys] + [v for v, _ in out]
+        valids = [v for _, v in out_keys] + [v for _, v in out]
+        return RecordBatch(self._schema, cols, valids,
+                           num_rows=keyed.n_groups)
+
     def finalize(self, state) -> RecordBatch:
         self._cost_observe_done()
         counts, accs = self._pull_state(state)
@@ -1723,4 +2181,6 @@ class AggregateRelation(Relation):
         )
 
     def batches(self) -> Iterator[RecordBatch]:
-        yield self.finalize(self.accumulate())
+        state = self._scan(keyed=True)
+        yield (self._finalize_keyed(state) if isinstance(state, _KeyedState)
+               else self.finalize(state))

@@ -51,10 +51,12 @@ from datafusion_tpu.errors import NotSupportedError
 from datafusion_tpu.exec.batch import (
     RecordBatch,
     bucket_capacity,
+    device_pull,
     make_host_batch,
 )
 from datafusion_tpu.exec.materialize import compact_batch, iter_with_mask_prefetch
 from datafusion_tpu.exec.relation import Relation, device_scope as _device_scope
+from datafusion_tpu.exec.wordsort import key_words, lex_perm
 from datafusion_tpu.plan.expr import Column, SortExpr
 from datafusion_tpu.utils.metrics import METRICS
 from datafusion_tpu.utils.retry import device_call
@@ -520,8 +522,8 @@ class _TopKCore:
         """Merge one batch into the carried top-k state.
 
         state = (keys..., live bits, global row ids) each length k;
-        returns the same structure.  The sort carries ONLY the key
-        operands plus a permutation iota; the winning rows travel as
+        returns the same structure.  The sort sees ONLY the key
+        operands; the winning rows travel as
         global row ids and the HOST gathers payload values from the
         source batches afterwards — bit-exact f64 payloads (an
         emulated-f64 device round trip perturbs them ~1e-14), and no
@@ -545,14 +547,20 @@ class _TopKCore:
         # NULL-key rows tie with empty state slots and must still fill
         # a LIMIT larger than the non-null count
         ops.append(~live_col)
-        n_keys = len(ops)
-        ops.append(jnp.arange(k + capacity, dtype=jnp.int32))  # permutation
-        out = lax.sort(tuple(ops), num_keys=n_keys, is_stable=True)
-        perm = out[n_keys][:k]
-
-        new_keys = tuple(o[:k] for o in out[:n_keys - 1])  # drop tiebreak
+        # word by word: one sort over these operands compiles for
+        # minutes on a TPU (exec/wordsort.py)
+        perm = lex_perm([w for o in ops for w in key_words(o)])[:k]
+        new_keys = tuple(o[perm] for o in ops[:-1])  # drop tiebreak
         return new_keys, live_col[perm], rows_col[perm]
 
+
+
+def topk_take(arrays, idx):
+    """A TopK's winning rows of a source batch that lives on the device."""
+    return tuple(a[idx] for a in arrays)
+
+
+_TAKE_JIT = jax.jit(topk_take)
 
 
 class SortRelation(Relation):
@@ -923,8 +931,6 @@ class SortRelation(Relation):
             next_base += batch.capacity
             if len(chunk) >= fuse:
                 flush()
-        from datafusion_tpu.exec.batch import device_pull
-
         if state is None and not chunk:
             yield self._empty_result(in_schema, dicts)
             return
@@ -959,6 +965,32 @@ class SortRelation(Relation):
         base_arr = np.asarray(bases, dtype=np.int64)
         b_idx = np.searchsorted(base_arr, win, side="right") - 1
         local = win - base_arr[b_idx]
+        # a source batch born on the device (an aggregate's keyed
+        # output) hands over its winners alone: one gather launch and
+        # one pull a batch, never a whole column
+        host_rows: dict = {}
+        for b in np.unique(b_idx):
+            src = src_batches[b]
+            on_dev = [a for i in self._out_cols
+                      for a in (src.data[i], src.validity[i])
+                      if a is not None and not isinstance(a, np.ndarray)]
+            if on_dev:
+                idx = np.zeros(self.limit, np.int32)
+                rows = local[b_idx == b]
+                idx[: len(rows)] = rows
+                with _device_scope(self.device):
+                    taken = iter(device_pull(device_call(
+                        _TAKE_JIT, tuple(on_dev), idx, _tag="topk.gather")))
+                host_rows[b] = {
+                    id(a): np.asarray(next(taken))[: len(rows)]
+                    for a in on_dev}
+
+        def rows_of(b, a, m):
+            """Array `a` of source batch `b` at its winners' rows."""
+            if isinstance(a, np.ndarray):
+                return a[local[m]]
+            return host_rows[b][id(a)]
+
         out_cols = []
         out_valid = []
         for i in self._out_cols:
@@ -969,9 +1001,9 @@ class SortRelation(Relation):
             for b in np.unique(b_idx):
                 m = b_idx == b
                 src = src_batches[b]
-                vals_i[m] = np.asarray(src.data[i])[local[m]]
+                vals_i[m] = rows_of(b, src.data[i], m)
                 if src.validity[i] is not None:
-                    valid_i[m] = np.asarray(src.validity[i])[local[m]]
+                    valid_i[m] = rows_of(b, src.validity[i], m)
                     any_null = True
             out_cols.append(vals_i)
             out_valid.append(
@@ -1176,8 +1208,6 @@ class SortRelation(Relation):
         bits, so 3 planes): D2H bandwidth is the scarce resource and a
         permutation is incompressible, so shipping only its significant
         bytes is the available win."""
-        from datafusion_tpu.exec.batch import device_pull
-
         with _device_scope(self.device):
             planes = device_call(
                 _run_sort_planes, tuple(dev_ops), _tag="sort.run"
@@ -1488,7 +1518,7 @@ def run_topk_megabatch(rels: list["SortRelation"]) -> float:
     """
     import time as _time
 
-    from datafusion_tpu.exec.batch import device_inputs, device_pull
+    from datafusion_tpu.exec.batch import device_inputs
     from datafusion_tpu.exec.fused import (
         fuse_group_max,
         iter_groups,

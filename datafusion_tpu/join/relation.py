@@ -111,6 +111,35 @@ class JoinBuildArtifact:
         self.fingerprint = None
 
 
+def _int64_words(col: np.ndarray) -> Optional[tuple]:
+    """An int64 payload column as the 32-bit words the device keeps of
+    it, or None for any other type: the value itself as int32 where
+    every value fits (a date as a day number, a key of a smaller
+    table), else (low uint32, high int32).  A row gather of a 64-bit
+    table splits the whole table into halves in every launch (the key
+    column's cost in PERF.md section 6, PR 29); words are gathered as
+    q12's string codes are and put together after (`_take_column`)."""
+    if col.dtype != np.int64:
+        return None
+    i32 = np.iinfo(np.int32)
+    if len(col) == 0 or (i32.min <= col.min() and col.max() <= i32.max):
+        return (col.astype(np.int32),)
+    return (col.astype(np.uint32), (col >> 32).astype(np.int32))
+
+
+def _take_column(col, idx):
+    """`take_rows` of one resident payload column, an int64 column
+    (a tuple: `_int64_words`) put together from its words."""
+    import jax.numpy as jnp
+
+    if not isinstance(col, tuple):
+        return take_rows(col, idx)
+    low = take_rows(col[0], idx).astype(jnp.int64)
+    if len(col) == 1:
+        return low
+    return (take_rows(col[1], idx).astype(jnp.int64) << 32) | low
+
+
 @functools.lru_cache(maxsize=None)
 def _probe_fn_for(join_type: str, build_key_dtype: str):
     """One fused probe launch: slot lookup, hit mask, payload gather,
@@ -148,7 +177,7 @@ def _probe_fn_for(join_type: str, build_key_dtype: str):
         # only live build rows are in the slot table: a hit's build key
         # is not NULL, whatever the build column's validity array says
         kval = None if inner else hit
-        gath = tuple(take_rows(c, sb) for c in pcols)
+        gath = tuple(_take_column(c, sb) for c in pcols)
         # an INNER join masks its misses out: a build column without
         # NULLs stays without a validity array
         gval = tuple(
@@ -283,10 +312,12 @@ class HashJoinRelation(Relation):
             METRICS.add("join.build.rows", n)
             # the one size rule (module doc)
             slots = self._dense_slots(art)
-            if slots is not None and LEDGER.fits(
-                    self._placed_bytes(art, slots[1])
-                    + _unplaced_bytes(self.left, self.device)):
-                self._build_dense(art, *slots)
+            if slots is not None:
+                payload = self._payload(art)
+                if LEDGER.fits(
+                        self._placed_bytes(payload, slots[1])
+                        + _unplaced_bytes(self.left, self.device)):
+                    self._build_dense(art, *slots, payload)
             # a dense candidate that found no room is not kept: the
             # next query asks the ledger again
             art.keep = (art.dense if slots is not None
@@ -331,16 +362,25 @@ class HashJoinRelation(Relation):
             return None
         return kmin, num_slots
 
-    def _placed_bytes(self, art: JoinBuildArtifact, num_slots: int) -> int:
-        """HBM a dense build of `art` holds: the slot table and every
-        column but the key (module doc)."""
+    def _payload(self, art: JoinBuildArtifact) -> tuple:
+        """(columns, validity arrays) a dense build of `art` places:
+        every column but the key (module doc), an int64 column as the
+        tuple of its words (`_int64_words`), no validity as None."""
         ri = self.on[0][1]
-        key = art.cols[ri].nbytes + (
-            0 if art.valids[ri] is None else art.valids[ri].nbytes)
-        return art.nbytes - key + pad_rows(num_slots) * 4
+        cols = art.cols[:ri] + art.cols[ri + 1:]
+        return (tuple(_int64_words(c) or c for c in cols),
+                tuple(art.valids[:ri] + art.valids[ri + 1:]))
+
+    @staticmethod
+    def _placed_bytes(payload: tuple, num_slots: int) -> int:
+        """HBM a dense build holds: the slot table and its payload."""
+        import jax
+
+        return pad_rows(num_slots) * 4 + sum(
+            int(a.nbytes) for a in jax.tree.leaves(payload))
 
     def _build_dense(self, art: JoinBuildArtifact, kmin: int,
-                     num_slots: int) -> None:
+                     num_slots: int, payload: tuple) -> None:
         """Fill the device-resident slot table and payload columns."""
         ri = self.on[0][1]
         bkey, valid = art.cols[ri], art.valids[ri]
@@ -351,26 +391,22 @@ class HashJoinRelation(Relation):
         art.dense = True
         art.kmin, art.num_slots = kmin, num_slots
         # from here on the artifact's bytes are what it holds in HBM
-        art.nbytes = self._placed_bytes(art, num_slots)
+        art.nbytes = self._placed_bytes(payload, num_slots)
 
         # device residency: slot inputs + payload columns (every column
         # but the key) travel the compressed wire once, at build time;
         # warm probes reuse them.  Payload and slot table are padded to
         # whole `LANES`-wide rows (`take_rows`); no slot and no hit
         # points into the padding
+        import jax
+
         pad = pad_rows(art.n_rows) - art.n_rows
-        cols = art.cols[:ri] + art.cols[ri + 1:]
-        valids = art.valids[:ri] + art.valids[ri + 1:]
-        held = [v for v in valids if v is not None]
-        dev = put_compressed(
-            [pos, live] + [np.pad(a, (0, pad)) for a in cols + held],
+        arrays, layout = jax.tree.flatten(payload)
+        dev_pos, dev_live, *placed = put_compressed(
+            [pos, live] + [np.pad(a, (0, pad)) for a in arrays],
             self.device, owner="join.build",
         )
-        ncols = len(cols)
-        dev_valids = iter(dev[2 + ncols:])
-        payload = (tuple(dev[2:2 + ncols]),
-                   tuple(None if v is None else next(dev_valids)
-                         for v in valids))
+        payload = jax.tree.unflatten(layout, placed)
 
         # the stated engagement rule (exec/pallas): TPU batches and a
         # slot table within the kernel's window; operands are int32
@@ -387,7 +423,7 @@ class HashJoinRelation(Relation):
             device_call(
                 _build_jit(pad_rows(num_slots), use_pallas,
                            _pallas.interpret_mode()),
-                dev[0], dev[1], payload, _tag="join.build",
+                dev_pos, dev_live, payload, _tag="join.build",
             ), owner="join.build", device=self.device)
         # the probe's two scalars, placed once: a numpy scalar handed to
         # a jitted call is a transfer of its own in every launch

@@ -731,6 +731,10 @@ class PartitionedAggregateRelation(AggregateRelation):
             f"partitions={len(self.children)}, keys={len(self.key_cols)}]"
         )
 
+    def _scan(self, keyed: bool):
+        # shard states merge by the encoder's dense ids, keyed or not
+        return self.accumulate()
+
     # -- the partitioned scan loop --
     def accumulate(self):
         from datafusion_tpu.obs.stats import op_timer

@@ -233,10 +233,12 @@ class SqlToRel:
                 proj_nodes.append(p)
 
         aliases: dict[int, str] = {}
+        aliased: dict[str, ast.SqlNode] = {}
         exprs: list[Expr] = []
         for i, p in enumerate(proj_nodes):
             if isinstance(p, ast.SqlAliased):
                 aliases[i] = p.alias
+                aliased[p.alias] = p.expr
                 p = p.expr
             exprs.append(self.sql_to_rex(p, input_schema))
 
@@ -263,10 +265,16 @@ class SqlToRel:
                     plan,
                 )
             if sel.order_by:
+                # a key that names a select-list alias is that output
+                # column (`SUM(x) AS revenue ... ORDER BY revenue`), as
+                # on the projection path below
                 sort_exprs = [
                     SortExpr(
                         self._post_aggregate_rex(
-                            o.expr, input_schema, group_expr, aggr_expr
+                            aliased.get(o.expr.name, o.expr)
+                            if isinstance(o.expr, ast.SqlIdentifier)
+                            else o.expr,
+                            input_schema, group_expr, aggr_expr,
                         ),
                         o.asc,
                     )

@@ -93,6 +93,19 @@ def test_operator_stage(monkeypatch):
     assert sparse["device.launches.join.probe"] == 2
 
 
+def test_q3_stage(monkeypatch):
+    # 60,000 lines, 15,000 orders, 1,500 customers.  The cost store keys
+    # an in-memory table by its name: what the operator stage learned of
+    # its own `orders` would swap this join's sides at this size
+    monkeypatch.setenv("DATAFUSION_TPU_COST", "0")
+    out = chip_smoke.stage_q3("cpu", SF, batch_size=1 << 13)
+    assert out["q3_build"]["join.build.dense"] == 2
+    assert out["q3_pinned"]["join.build.reuse"] == 2
+    assert out["q3_pinned"]["aggregate.device_key.groups"] == out["groups"] > 50
+    assert out["q3_pinned"]["aggregate.key_pull.bytes"] == 0
+    assert out["q3_pinned"]["device.launches.join.probe"] == 2 * 8
+
+
 def test_mesh_stage_places_four_shards(resident_ctx, oracle):
     # conftest gives 8 virtual CPU devices; the stage takes four
     src = resident_ctx.datasources["lineitem"]

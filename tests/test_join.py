@@ -527,27 +527,34 @@ class TestFactToFactJoin:
         # the host probe compacts first: NULL keys stay in, masked rows out
         assert _delta(s1, s2, "join.host_probe.rows") == 5_000
 
-    def test_a_build_over_64_mb_is_pinned_and_probed_again(self):
-        """2.2 M keys, every fourth of 8.8 M: 35 MB of payload columns
-        and a 35 MB slot table; the 18 MB key column is not placed.  It
-        fits what is free, so the second query builds nothing."""
+    @pytest.mark.parametrize("wide,words", [(0, 1), (1 << 40, 2)])
+    def test_a_build_over_64_mb_is_pinned_and_probed_again(self, wide, words):
+        """2.2 M keys, every fourth of 8.8 M: two int64 payload columns
+        and a 35 MB slot table; the 18 MB key column is not placed.
+        Values that fit 32 bits are placed as one word each (53 MB in
+        all), values past them as both (70 MB).  It fits what is free,
+        so the second query builds nothing."""
         ctx = ExecutionContext(batch_size=512)
         okey = np.arange(2_200_000, dtype=np.int64) * 4 + 5
-        _mem_table(ctx, "o_big", {"ok": okey, "oval": okey * 3,
-                                  "oneg": -okey}, batch_rows=1 << 18)
+        _mem_table(ctx, f"o_big{words}", {"ok": okey, "oval": okey * 3 + wide,
+                                          "oneg": -okey - wide},
+                   batch_rows=1 << 18)
         lkey = np.array([5, 6, 8_800_001, 8_800_005, 9], np.int64)
-        _mem_table(ctx, "l_big", {"lk": lkey,
-                                  "lseq": np.arange(5, dtype=np.int64)})
-        sql = ("SELECT lseq, oval, oneg FROM l_big JOIN o_big "
-               "ON l_big.lk = o_big.ok")
-        want = [(0, 15, -5), (2, 26_400_003, -8_800_001), (4, 27, -9)]
+        _mem_table(ctx, f"l_big{words}",
+                   {"lk": lkey, "lseq": np.arange(5, dtype=np.int64)})
+        sql = (f"SELECT lseq, oval, oneg FROM l_big{words} JOIN o_big{words} "
+               f"ON l_big{words}.lk = o_big{words}.ok")
+        want = [(0, 15 + wide, -5 - wide),
+                (2, 26_400_003 + wide, -8_800_001 - wide),
+                (4, 27 + wide, -9 - wide)]
         s0 = _counts()
         assert _rows(ctx, sql) == want
         s1 = _counts()
         # what is placed: two payload columns and the slot table
-        assert _delta(s0, s1, "join.build.bytes") == (
-            2 * okey.nbytes + 4 * pad_rows(int(okey[-1] - okey[0]) + 1)
-        ) > 64 << 20
+        placed = (2 * words * okey.nbytes // 2
+                  + 4 * pad_rows(int(okey[-1] - okey[0]) + 1))
+        assert _delta(s0, s1, "join.build.bytes") == placed
+        assert (placed > 64 << 20) == (words == 2)
         assert _delta(s0, s1, "device.launches.join.build") == 1
         assert LEDGER.pinned_bytes() >= _delta(s0, s1, "join.build.bytes")
         assert _rows(ctx, sql + " WHERE lseq >= 0") == want
