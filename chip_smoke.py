@@ -347,12 +347,14 @@ def stage_operators(device: str, agg_rows: int = 1 << 21) -> dict:
     from datafusion_tpu.exec.datasource import MemoryDataSource
     from datafusion_tpu.exec.materialize import collect
     from datafusion_tpu.exec.pallas import hash_build
+    from datafusion_tpu.exec.rowgather import WINDOW_ROWS
 
     out: dict = {}
     extra = ("join.build.dense", "join.build.pallas_runs",
              "device.launches.sort.run", "device.launches.join.probe",
              "d2h.bytes", "join.probe.rows", "join.host_probe.rows",
-             "join.probe.gathers", "expr.cmp_lookups")
+             "join.probe.gathers", "join.probe.window.slot",
+             "join.probe.window.payload", "expr.cmp_lookups")
 
     def run(label, src_by_name, sql, want, batch_size=1 << 19):
         ctx = ExecutionContext(device=device, batch_size=batch_size,
@@ -502,6 +504,23 @@ def stage_operators(device: str, agg_rows: int = 1 << 21) -> dict:
             f"{ev['join.probe.gathers']} build arrays in "
             f"{ev['device.launches.join.probe']} launches; the build key "
             "comes from the probe key and `prio` alone is gathered")
+    # which launches read through the window, reckoned from the keys:
+    # 2^17 sorted keys of this side span half the 32,768-row slot table
+    # and half the payload's 8,192 rows, so none does
+    windows = [0, 0]
+    for lo in range(0, n_sort, 1 << 17):
+        part = slice(lo, lo + (1 << 17))
+        found = build_k[slot[part]] == probe_k[part]
+        for w, (rows, total) in enumerate((
+                ((probe_k[part] - 1) >> 7, 4 * n_build >> 7),
+                (slot[part][found] >> 7, n_build >> 7))):
+            windows[w] += bool(rows.max() - min(rows.min(), total - WINDOW_ROWS)
+                               < WINDOW_ROWS)
+    require([ev["join.probe.window.slot"], ev["join.probe.window.payload"]]
+            == windows,
+            f"join_sparse_4m_slots: {ev['join.probe.window.slot']} slot and "
+            f"{ev['join.probe.window.payload']} payload lookups took the "
+            f"window, the keys say {windows}")
     require(ev["expr.cmp_lookups"] == ev["device.launches.join.probe"],
             f"join_sparse_4m_slots: {ev['expr.cmp_lookups']} string-compare "
             "lookups handed to the device, one a probed batch expected")
@@ -542,6 +561,7 @@ def stage_q3(device: str, sf, batch_size: int = 1 << 17) -> dict:
     from datafusion_tpu.exec.context import ExecutionContext
     from datafusion_tpu.exec.datasource import MemoryDataSource
     from datafusion_tpu.exec.materialize import collect
+    from datafusion_tpu.exec.rowgather import WINDOW_ROWS
     from tpubench.spec import Spec
 
     spec = Spec(REPO)
@@ -568,12 +588,14 @@ def stage_q3(device: str, sf, batch_size: int = 1 << 17) -> dict:
             make_host_batch(schema, [a[lo: lo + batch_size] for a in arrays],
                             None, dicts)
             for lo in range(0, n, batch_size)]))
+    n_orders = len(made["tables"]["orders"]["o_orderkey"])
     params = {"segment": ds.SEGMENT, "date": ds.DATE}
     sql = spec.query("tpch_customer_orders_lineitem", "q3").format(
         **ds.bind("q3", params))
     want = made["oracle"].answer("q3", params)
     extra = ("join.build.dense", "join.build.reuse", "join.host_probe.rows",
              "device.launches.join.probe", "join.probe.gathers",
+             "join.probe.window.slot", "join.probe.window.payload",
              "aggregate.device_key.groups", "aggregate.device_key.rows",
              "aggregate.key_pull.bytes", "device.launches.agg.key_ids",
              "device.launches.topk.final", "d2h.bytes",
@@ -593,6 +615,20 @@ def stage_q3(device: str, sf, batch_size: int = 1 << 17) -> dict:
         require(ev["join.host_probe.rows"] == 0
                 and ev["aggregate.key_pull.bytes"] == 0,
                 f"{label}: the join or its group keys left the device: {ev}")
+        # lineitem is clustered by `l_orderkey` and orders stand in key
+        # order: every launch of the first probe reads its slot table
+        # (four slots an order) and its payload through the window,
+        # where they are past the window's rows (from SF-0.5); `o_custkey`
+        # is uniform over customer, so no launch of the second does
+        first = ev["device.launches.join.probe"] // 2
+        windows = [first if rows > WINDOW_ROWS else 0
+                   for rows in (4 * n_orders >> 7, n_orders >> 7)]
+        require([ev["join.probe.window.slot"],
+                 ev["join.probe.window.payload"]] == windows,
+                f"{label}: {ev['join.probe.window.slot']} slot and "
+                f"{ev['join.probe.window.payload']} payload lookups took the "
+                f"window; {windows} of the first probe's {first} launches "
+                "expected, and none of the second's")
         require(ev["aggregate.device_key.groups"] == made["oracle"].groups,
                 f"{label}: {ev['aggregate.device_key.groups']} groups on the "
                 f"device, the oracle keeps {made['oracle'].groups}")

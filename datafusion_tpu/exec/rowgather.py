@@ -1,4 +1,5 @@
-"""A lookup table read on the device as whole rows.
+"""A lookup table read on the device as whole rows, through a window
+where the indices in hand fit one.
 
 A table that a launch indexes by a batch of scattered positions stays
 on the device as `[n / 128, 128]` and is read as a gather of rows with
@@ -13,6 +14,27 @@ Two users: the join's device probe (`join/relation.py`: slot table and
 build payload) and the ordered string-vs-literal compare
 (`exec/expression.py`: the per-literal truth table over dictionary
 codes, `AuxSpec("cmp_table")`).
+
+**The window** (`take_rows_window`, the join's probe).  A row gather
+costs by where its table lies: 131,072 clustered indices read a 240 MB
+table from HBM in 2.02 ms and a 1 MB piece of it in 0.33 ms (PERF.md
+section 6, PR 28).  Traffic that is clustered by the lookup key
+(TPC-H's lineitem by `l_orderkey`: a batch's keys span 512 KB of the
+240 MB slot table, and the slots it finds 128 KB of each 60 MB payload
+column) is read through a window of `WINDOW_ROWS` rows that the launch
+picks from the batch's own indices: the smallest and the largest row
+among the indices that count, a start clamped inside the table, and,
+on the device, the choice: every such row inside the window, gather
+from the window; else gather from the whole table as `take_rows` does.
+Nothing but the indices in hand chooses, and a table no larger than
+the window compiles no choice at all.  On a v5e (PERF.md section 6,
+PR 33), 131,072 keys a launch: a gather through the window 0.237 ms
+and its lane select 0.110; the join's whole probe launch over TPC-H's
+orders (60 M slots, 15 M rows) with clustered keys 2.78 -> 0.73 ms
+with one payload column and 7.54 -> 1.31 ms with three, with uniform
+keys 1.78 -> 1.80 ms, and over a 1.5 M-row build with uniform keys
+0.633 -> 0.658 ms: the choice costs the side that does not take the
+window about 0.025 ms a launch.
 
 The truth table travels a bit a code, 32 to a word (`pack_bits`,
 `take_bits`): a row then holds 4,096 codes, and a table of one row,
@@ -32,6 +54,12 @@ LANE_BITS = 7
 LANES = 1 << LANE_BITS
 _WORD_SHIFT = 5
 WORD_BITS = 1 << _WORD_SHIFT
+# rows of the window `take_rows_window` reads through: 1 MB of int32.
+# 131,072 clustered indices, rows + lane select, on a v5e: 2.02 ms from
+# a 240 MB table, 0.33 ms from a 1 MB window of it (PERF.md section 6,
+# PR 28); inside the join's probe 0.35 ms, gather 0.237 + select 0.110
+# (PR 33)
+WINDOW_ROWS = 2_048
 
 
 def pad_rows(n: int) -> int:
@@ -52,6 +80,62 @@ def take_rows(table, idx):
     if table.dtype == jnp.bool_:
         return jnp.any(picked, axis=1)
     return jnp.sum(picked, axis=1, dtype=table.dtype)
+
+
+def take_rows_window(tables, idx, live):
+    """`take_rows(t, idx)` of every table of the pytree `tables`
+    (`[rows, LANES]` each, one `rows` for all), read through a window
+    of `WINDOW_ROWS` rows where the launch finds that the batch in
+    hand fits one.  `live` (bool, `idx`'s shape) says which indices
+    count: the window starts at the smallest live row, clamped so it
+    ends inside the table, and is taken, on the device, when the
+    largest live row lies in it too (no live index at all fits);
+    otherwise every table is read whole, as `take_rows` reads it.  An
+    index that is not live is pointed at the window's first entry and
+    its result is the caller's to mask.  Tables of at most
+    `WINDOW_ROWS` rows are read whole, by shape, with no conditional
+    in the program.
+
+    Returns `(values, took)`: the pytree of gathered arrays and a bool
+    scalar, whether the window was taken."""
+    import jax
+    import jax.numpy as jnp
+    from jax import lax
+
+    leaves = jax.tree.leaves(tables)
+    rows = leaves[0].shape[0] if leaves else 0
+    if rows <= WINDOW_ROWS:
+        safe = jnp.where(live, idx, 0)
+        return (jax.tree.map(lambda t: take_rows(t, safe), tables),
+                jnp.zeros((), jnp.bool_))
+    row = idx >> LANE_BITS
+    low = jnp.min(jnp.where(live, row, rows))
+    high = jnp.max(jnp.where(live, row, -1))
+    start = jnp.clip(low, 0, rows - WINDOW_ROWS).astype(jnp.int32)
+    took = high - start < WINDOW_ROWS
+    first = start << LANE_BITS
+    safe = jnp.where(live, idx, first)
+
+    # The windows are cut whatever the decision (1 MB a table) and held
+    # outside the conditional: XLA otherwise moves the cut into the
+    # branch, hands it the whole tables, and copies a table it had
+    # prefetched back out of fast memory there (60 MB a launch).  Each
+    # branch ends in a barrier too: without it the lane select, the
+    # same in both, moves out behind the conditional and the gathered
+    # rows and a 64 MB iota become its outputs (AOT for a v5e, PR 33).
+    windows = lax.optimization_barrier(jax.tree.map(
+        lambda t: lax.dynamic_slice(t, (start, jnp.int32(0)),
+                                    (WINDOW_ROWS, LANES)), tables))
+
+    def through_window(_, windows):
+        return lax.optimization_barrier(
+            jax.tree.map(lambda w: take_rows(w, safe - first), windows))
+
+    def whole(tables, _):
+        return lax.optimization_barrier(
+            jax.tree.map(lambda t: take_rows(t, safe), tables))
+
+    return lax.cond(took, through_window, whole, tables, windows), took
 
 
 def pack_bits(bits: np.ndarray, capacity: int) -> np.ndarray:

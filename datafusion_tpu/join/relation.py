@@ -16,14 +16,30 @@ left input).  The build side fully materializes once into a
   resident.  The slot table and the payload stay on the device as
   128-wide rows and are read as a row gather + lane select
   (`exec/rowgather.py`, shared with the string compare's truth
-  table).  The resident payload is the build's columns **other than
-  the key**: on a hit the build row's key IS the probe row's key, and
-  on a miss it is masked out (INNER) or NULL (LEFT OUTER), so the
-  join's output column for the build key is the probe batch's own key
-  (cast to the build column's dtype where the two differ) and no
-  launch reads the key column — at TPC-H's 15 M-row int64
-  `o_orderkey` its two gathers and the split into u32 halves were
-  2.4 of 3.84 device seconds a query (PERF.md section 6, PR 29).
+  table), each through a window of 2,048 rows (1 MB of int32) that
+  the launch picks from the batch in hand (`take_rows_window`): the
+  slot table by the keys that look something up (in range, not NULL,
+  selected), the payload, under one decision for all its arrays, by
+  the slots that were found.  A probe side clustered by the join key
+  (TPC-H's lineitem: a batch's 131,072 keys span 512 KB of the 240 MB
+  slot table and its hits 128 KB of each 60 MB payload column) reads
+  the windows; one that is not (Q3's `o_custkey` into customer) reads
+  the whole tables as before, launch by launch, by what the keys say.
+  On a v5e a probe launch over TPC-H's orders went 2.78 -> 0.73 ms
+  with one payload column (Q12) and 7.54 -> 1.31 ms with three (Q3),
+  and Q3's second probe 0.633 -> 0.658 ms (PERF.md section 6, PR 33).
+  ``join.probe.window.slot`` / ``.payload`` count the launches whose
+  lookup took the window (beside ``device.launches.join.probe``): the
+  flags stay on the device through the scan and are added up and read
+  once, 8 B, after its last batch.  The resident payload is the
+  build's columns **other than the key**: on a hit the build row's
+  key IS the probe row's key, and on a miss it is masked out (INNER)
+  or NULL (LEFT OUTER), so the join's output column for the build
+  key is the probe batch's own key (cast to the build column's dtype
+  where the two differ) and no launch reads the key column — at
+  TPC-H's 15 M-row int64 `o_orderkey` its two gathers and the split
+  into u32 halves were 2.4 of 3.84 device seconds a query (PERF.md
+  section 6, PR 29).
   ``join.probe.gathers`` counts, per launch, the build-side arrays
   (columns and validity arrays) the launch still gathers.
 - **host probe**: everything else (multi-key, strings, duplicate
@@ -66,11 +82,12 @@ from datafusion_tpu.exec import pallas as _pallas
 from datafusion_tpu.exec.batch import (
     RecordBatch,
     device_inputs,
+    device_pull,
     make_host_batch,
     put_compressed,
 )
 from datafusion_tpu.exec.relation import Relation
-from datafusion_tpu.exec.rowgather import LANES, pad_rows, take_rows
+from datafusion_tpu.exec.rowgather import LANES, pad_rows, take_rows_window
 from datafusion_tpu.join import core as _core
 from datafusion_tpu.obs.device import LEDGER, host_fits
 from datafusion_tpu.utils.metrics import METRICS
@@ -127,17 +144,17 @@ def _int64_words(col: np.ndarray) -> Optional[tuple]:
     return (col.astype(np.uint32), (col >> 32).astype(np.int32))
 
 
-def _take_column(col, idx):
-    """`take_rows` of one resident payload column, an int64 column
-    (a tuple: `_int64_words`) put together from its words."""
+def _int64_of(words):
+    """A gathered payload column: an int64 column (a tuple:
+    `_int64_words`) put together from its gathered words."""
     import jax.numpy as jnp
 
-    if not isinstance(col, tuple):
-        return take_rows(col, idx)
-    low = take_rows(col[0], idx).astype(jnp.int64)
-    if len(col) == 1:
+    if not isinstance(words, tuple):
+        return words
+    low = words[0].astype(jnp.int64)
+    if len(words) == 1:
         return low
-    return (take_rows(col[1], idx).astype(jnp.int64) << 32) | low
+    return (words[1].astype(jnp.int64) << 32) | low
 
 
 @functools.lru_cache(maxsize=None)
@@ -148,13 +165,18 @@ def _probe_fn_for(join_type: str, build_key_dtype: str):
     and the slot count are arguments, so every dense artifact of one
     shape class shares compiled probes.  The slot table and the
     payload (`pcols`, `pvalids`: the build's columns other than its
-    key) are `[rows, LANES]` (`take_rows`).
+    key) are `[rows, LANES]` and are read twice through
+    `take_rows_window`: the slot table by the keys that are in range,
+    not NULL and selected, and every payload array by the slots found,
+    under one decision.
 
-    Returns `(kcol, kval, gath, gval, out_mask)`: `kcol` / `kval` are
-    the join's output column for the build key, made from the probe
-    key — `kcol` None where the probe key already has
+    Returns `(kcol, kval, gath, gval, out_mask, windows)`: `kcol` /
+    `kval` are the join's output column for the build key, made from
+    the probe key — `kcol` None where the probe key already has
     `build_key_dtype` (the caller hands on the probe's own array),
-    else the cast; at miss rows its values are not observable."""
+    else the cast; at miss rows its values are not observable.
+    `windows` is int32[2]: whether the slot lookup and whether the
+    payload gather took the window."""
     import jax
     import jax.numpy as jnp
 
@@ -165,31 +187,49 @@ def _probe_fn_for(join_type: str, build_key_dtype: str):
         # range check in int64 BEFORE the int32 cast: a far-out-of-range
         # probe key must not wrap into a valid slot
         d = key.astype(jnp.int64) - kmin
-        inr = (d >= 0) & (d < num_slots)
+        live = (d >= 0) & (d < num_slots)
         if kvalid is not None:
-            inr = inr & kvalid
-        safe = jnp.where(inr, d, 0).astype(jnp.int32)
-        bidx = jnp.where(inr, take_rows(slot_row, safe), -1)
-        hit = bidx >= 0
-        sb = jnp.where(hit, bidx, 0)
+            live = live & kvalid
+        # a row the selection has dropped (a ragged tail's padding)
+        # looks nothing up: it must not stretch the batch's span
+        if mask is not None:
+            live = live & mask
+        found, slot_window = take_rows_window(
+            slot_row, d.astype(jnp.int32), live)
+        hit = live & (found >= 0)
         inner = join_type == "inner"
         kcol = None if key.dtype == key_dtype else key.astype(key_dtype)
         # only live build rows are in the slot table: a hit's build key
         # is not NULL, whatever the build column's validity array says
         kval = None if inner else hit
-        gath = tuple(_take_column(c, sb) for c in pcols)
+        # (a validity array that is None is no table: it stays None)
+        (gath, valid), payload_window = take_rows_window(
+            (pcols, pvalids), found, hit)
+        gath = tuple(_int64_of(c) for c in gath)
         # an INNER join masks its misses out: a build column without
         # NULLs stays without a validity array
         gval = tuple(
-            (None if inner else hit) if v is None else hit & take_rows(v, sb)
-            for v in pvalids)
-        if inner:
-            out_mask = hit if mask is None else mask & hit
-        else:
-            out_mask = mask
-        return kcol, kval, gath, gval, out_mask
+            (None if inner else hit) if v is None else hit & v
+            for v in valid)
+        # `hit` holds the selection mask already
+        out_mask = hit if inner else mask
+        windows = jnp.stack([slot_window, payload_window]).astype(jnp.int32)
+        return kcol, kval, gath, gval, out_mask, windows
 
     return jax.jit(join_probe)
+
+
+@functools.lru_cache(maxsize=None)
+def _window_counts_fn():
+    """The launches of one scan whose lookups took the window: the sum
+    of the probe launches' `windows`, as int32[2]."""
+    import jax
+    import jax.numpy as jnp
+
+    def window_counts(windows):
+        return jnp.sum(jnp.stack(windows), axis=0, dtype=jnp.int32)
+
+    return jax.jit(window_counts)
 
 
 def _unplaced_bytes(rel: Relation, device) -> int:
@@ -463,16 +503,22 @@ class HashJoinRelation(Relation):
         # key's, which the launch makes from the probe key (module doc)
         gathers = len(art.dev_cols) + sum(
             v is not None for v in art.dev_valids)
+        # each launch's two window flags stay on the device until the
+        # scan ends: one sum and one 8 B pull a scan, none in the loop
+        # (the sum belongs to the pull, as the pull seam's own packing
+        # does: it is no launch of a query's operators)
+        windows = []
         for batch in self.left.batches():
             with METRICS.timer("join.probe"):
                 data, validity, mask = device_inputs(batch, self.device)
-                kcol, kval, gath, gval, out_mask = device_call(
+                kcol, kval, gath, gval, out_mask, window = device_call(
                     probe_fn,
                     data[li], validity[li], mask, art.dev_kmin,
                     art.dev_num_slots, art.dev_slot_row, art.dev_cols,
                     art.dev_valids,
                     _tag="join.probe",
                 )
+            windows.append(window)
             METRICS.add("join.probe.rows", batch.num_rows)
             METRICS.add("join.probe.gathers", gathers)
             gath, gval = list(gath), list(gval)
@@ -486,6 +532,10 @@ class HashJoinRelation(Relation):
                 num_rows=batch.num_rows,
                 mask=out_mask,
             )
+        if windows:
+            slot, payload = device_pull(_window_counts_fn()(tuple(windows)))
+            METRICS.add("join.probe.window.slot", int(slot))
+            METRICS.add("join.probe.window.payload", int(payload))
 
     def _host_batches(self, art: JoinBuildArtifact):
         from datafusion_tpu.exec.materialize import (

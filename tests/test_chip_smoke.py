@@ -91,6 +91,9 @@ def test_operator_stage(monkeypatch):
     assert sparse["join.host_probe.rows"] == 0
     assert sparse["join.probe.rows"] == 1 << 18
     assert sparse["device.launches.join.probe"] == 2
+    # each batch's sorted keys span half of either table: no window
+    assert sparse["join.probe.window.slot"] == 0
+    assert sparse["join.probe.window.payload"] == 0
 
 
 def test_q3_stage(monkeypatch):
@@ -104,6 +107,9 @@ def test_q3_stage(monkeypatch):
     assert out["q3_pinned"]["aggregate.device_key.groups"] == out["groups"] > 50
     assert out["q3_pinned"]["aggregate.key_pull.bytes"] == 0
     assert out["q3_pinned"]["device.launches.join.probe"] == 2 * 8
+    # 469 rows of slots, 118 of payload: read whole, by shape
+    assert out["q3_pinned"]["join.probe.window.slot"] == 0
+    assert out["q3_pinned"]["join.probe.window.payload"] == 0
 
 
 def test_mesh_stage_places_four_shards(resident_ctx, oracle):

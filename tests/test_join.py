@@ -694,7 +694,8 @@ def test_probe_reading_rows_equals_numpy_reading_elements(how, keys):
     args = (jnp.asarray(key.astype(np.int64)), jnp.asarray(kvalid),
             jnp.asarray(mask), np.int64(kmin), np.int64(num_slots),
             rows(slot_row), (rows(pay),), (rows(pay_valid),))
-    kcol, kval, gath, gval, out_mask = jr._probe_fn_for(how, "int32")(*args)
+    kcol, kval, gath, gval, out_mask, windows = jr._probe_fn_for(
+        how, "int32")(*args)
     # a probe key of the build column's own dtype is handed on as it is
     assert jr._probe_fn_for(how, "int64")(*args)[0] is None
     d = key - kmin
@@ -703,6 +704,9 @@ def test_probe_reading_rows_equals_numpy_reading_elements(how, keys):
     hit = bidx >= 0
     assert np.array_equal(hit, kvalid & np.isin(d, pos))
     assert hit.sum() > (0 if keys != "no_live_row" else -1)
+    # a row the selection dropped looks nothing up: the launch's `hit`
+    # holds the mask, and what a miss row reads is not observable
+    hit &= mask
     sb = np.where(hit, bidx, 0)
     assert kcol.dtype == bkey.dtype
     assert np.array_equal(np.asarray(kcol)[hit], bkey[sb][hit])
@@ -711,14 +715,130 @@ def test_probe_reading_rows_equals_numpy_reading_elements(how, keys):
     assert ucol.dtype == np.uint32
     assert np.array_equal(np.asarray(ucol)[hit], bkey[sb][hit])
     assert len(gath) == len(gval) == 1
-    assert np.array_equal(np.asarray(gath[0]), pay[sb])
+    assert np.array_equal(np.asarray(gath[0])[hit], pay[sb][hit])
     assert np.array_equal(np.asarray(gval[0]), hit & pay_valid[sb])
     if how == "inner":
         assert kval is None
-        assert np.array_equal(np.asarray(out_mask), mask & hit)
+        assert np.array_equal(np.asarray(out_mask), hit)
     else:
         assert np.array_equal(np.asarray(kval), hit)
         assert np.array_equal(np.asarray(out_mask), mask)
+    # 60,000 slots are 469 rows of the 5,470-row slot table, and their
+    # build rows a few dozen of the payload's 313 (a table of at most
+    # `WINDOW_ROWS` rows is read whole, by shape)
+    assert windows.dtype == np.int32
+    assert np.asarray(windows).tolist() == [int(keys != "scattered"), 0]
+
+
+# -- the window a probe launch picks from its batch's own keys ----------
+_WINDOW_FEATURES = ["misses", "null_keys", "out_of_range_keys",
+                    "selection_mask", "ragged_last_batch",
+                    "int64_payload_of_two_words", "payload_with_validity"]
+
+
+def _window_tables(ctx, suffix, order, feature):
+    """`o<suffix>`: 300,000 keys, every fourth of 1.2 M (a slot table
+    of 9,375 rows, a payload of 2,344: both past `WINDOW_ROWS`), and
+    `l<suffix>` probing it in batches of 512, in key order (a batch
+    spans ~1,170 rows of slots and ~290 of payload) or shuffled (every
+    batch spans all of both).  Rows that look nothing up (a NULL key,
+    a key out of range, a row an earlier join dropped) carry keys from
+    both ends of the build.  Returns the SQL over them."""
+    rng = np.random.default_rng(len(feature))
+    okey = np.arange(300_000, dtype=np.int64) * 4 + 7
+    oval = okey * 3
+    if feature == "int64_payload_of_two_words":
+        oval = oval + (1 << 40)
+    if feature == "payload_with_validity":
+        oval = (oval, rng.random(len(okey)) > 0.2)
+    n = 4_000 if feature == "ragged_last_batch" else 4_096
+    lkey = rng.choice(okey, n)
+    if order == "key_order":
+        lkey.sort()
+    ends = np.where(np.arange(n) % 2 == 0, okey[0], okey[-1])
+    cols = {"lseq": np.arange(n, dtype=np.int64)}
+    if feature == "misses":
+        lkey[::5] += 1
+    if feature == "null_keys":
+        lvalid = rng.random(n) > 0.1
+        lkey = (np.where(lvalid, lkey, ends), lvalid)
+    if feature == "out_of_range_keys":
+        far = rng.choice(n, 300, replace=False)
+        # far below, far above, and one that is a slot once cut to 32 bits
+        lkey[far] = np.resize(
+            [-(1 << 40), 1 << 41, okey[0] - 1, okey[-1] + 1,
+             (1 << 32) + okey[5]], 300)
+    join = "JOIN f{0} ON l{0}.lf = f{0}.fid " if feature == "selection_mask" else ""
+    if feature == "selection_mask":
+        lf = rng.integers(0, 13, n)
+        lkey = np.where(lf < 10, lkey, ends)  # the first join drops lf >= 10
+        cols["lf"] = lf
+        _mem_table(ctx, "f" + suffix, {"fid": np.arange(10, dtype=np.int64)})
+    cols["lk"] = lkey
+    _mem_table(ctx, "o" + suffix, {"ok": okey, "oval": oval},
+               batch_rows=1 << 16)
+    _mem_table(ctx, "l" + suffix, cols)
+    return ("SELECT lseq, ok, oval FROM l{0} " + join
+            + "{1} o{0} ON l{0}.lk = o{0}.ok").format(suffix, "{0}")
+
+
+@pytest.mark.parametrize("feature", _WINDOW_FEATURES)
+@pytest.mark.parametrize("how", ["inner", "left"])
+@pytest.mark.parametrize("order", ["key_order", "shuffled"])
+def test_probe_window_engages_by_the_batchs_own_keys(
+        order, how, feature, monkeypatch):
+    """Every launch of an ordered probe side reads the slot table and
+    the payload through the window, no launch of a shuffled one does,
+    and the rows are the host index's either way."""
+    from datafusion_tpu.join import relation as jr
+
+    monkeypatch.setenv("DATAFUSION_TPU_COST", "0")
+    join = "JOIN" if how == "inner" else "LEFT JOIN"
+    suffix = f"_w_{order[0]}{how[0]}{_WINDOW_FEATURES.index(feature)}"
+    ctx = ExecutionContext(batch_size=512)
+    sql = _window_tables(ctx, suffix, order, feature).format(join)
+    pulls = []
+
+    def counted_pull(tree):
+        before = _counts().get("d2h.bytes", 0)
+        out = pull(tree)
+        pulls.append(_counts().get("d2h.bytes", 0) - before)
+        return out
+
+    pull = jr.device_pull
+    monkeypatch.setattr(jr, "device_pull", counted_pull)
+    s0 = _counts()
+    got = _rows(ctx, sql)
+    s1 = _counts()
+    joins = 2 if feature == "selection_mask" else 1
+    batches = 8
+    assert _delta(s0, s1, "join.build.dense") == joins
+    assert _delta(s0, s1, "device.launches.join.probe") == joins * batches
+    assert _delta(s0, s1, "join.host_probe.rows") == 0
+    # the ten-row table of the first join is read whole, by shape
+    taken = batches if order == "key_order" else 0
+    assert _delta(s0, s1, "join.probe.window.slot") == taken
+    assert _delta(s0, s1, "join.probe.window.payload") == taken
+    # one pull of at most 16 B a join, after its last batch (the sum it
+    # reads is the pull's own: no launch of the engine's is counted)
+    assert len(pulls) == joins and all(0 < b <= 16 for b in pulls)
+    tags = {k for k in s1 if k.startswith("device.launches.join")
+            and _delta(s0, s1, k)}
+    assert tags == {"device.launches.join.probe", "device.launches.join.build"}
+    arrays = 2 if feature == "payload_with_validity" else 1
+    assert _delta(s0, s1, "join.probe.gathers") == batches * arrays
+    monkeypatch.setenv("DATAFUSION_TPU_JOIN_DEVICE", "0")
+    host = ExecutionContext(batch_size=512)
+    want = _rows(host, _window_tables(host, suffix + "h", order, feature)
+                 .format(join))
+    s2 = _counts()
+    assert _delta(s1, s2, "join.host_probe.rows") > 0
+    assert _delta(s1, s2, "join.probe.window.slot") == 0
+    assert got == want and len(want) > 2_000
+    # a LEFT join keeps the rows that found nothing, as NULLs
+    assert any(r[2] is None for r in want) == (
+        how == "left" and feature in _WINDOW_FEATURES[:3]
+        or feature == "payload_with_validity")
 
 
 # -- the build key column is made from the probe key ------------------------

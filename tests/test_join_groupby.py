@@ -509,8 +509,8 @@ I64 = np.iinfo(np.int64)
     (I32.min, I32.max + 1, 2), (I32.min - 1, I32.max, 2),
     (I64.min, I64.max, 2), (0, 0, 1)])
 def test_int64_payload_words(lo, hi, n_words):
-    from datafusion_tpu.exec.rowgather import LANES, pad_rows
-    from datafusion_tpu.join.relation import _int64_words, _take_column
+    from datafusion_tpu.exec.rowgather import LANES, pad_rows, take_rows
+    from datafusion_tpu.join.relation import _int64_of, _int64_words
 
     rng = np.random.default_rng(47)
     col = np.concatenate([
@@ -524,7 +524,8 @@ def test_int64_payload_words(lo, hi, n_words):
     placed = tuple(
         jnp.asarray(np.pad(w, (0, pad_rows(1_000) - 1_000))
                     ).reshape(-1, LANES) for w in words)
-    got = jax.jit(_take_column)(placed, idx)
+    got = jax.jit(lambda words, i: _int64_of(
+        tuple(take_rows(w, i) for w in words)))(placed, idx)
     assert got.dtype == jnp.int64
     assert np.array_equal(np.asarray(got), col[idx])
 

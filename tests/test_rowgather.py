@@ -20,10 +20,12 @@ from datafusion_tpu.exec.expression import AuxSpec, compute_aux_values
 from datafusion_tpu.exec.materialize import collect
 from datafusion_tpu.exec.rowgather import (
     LANES,
+    WINDOW_ROWS,
     WORD_BITS,
     pack_bits,
     take_bits,
     take_rows,
+    take_rows_window,
 )
 from datafusion_tpu.utils.metrics import METRICS
 
@@ -64,6 +66,77 @@ def test_take_bits_is_flat_indexing_of_the_unpacked_table(cap):
     got = jax.jit(take_bits)(jnp.asarray(words), jnp.asarray(idx))
     assert got.dtype == jnp.bool_
     np.testing.assert_array_equal(np.asarray(got), padded[idx])
+
+# -- the window a launch picks from the batch's own indices -----------
+
+TABLE_ROWS = 3 * WINDOW_ROWS + 77
+
+
+def _window_case(case: str, rng):
+    """(table rows, indices, live, whether the window is taken)."""
+    n, rows = 5_000, TABLE_ROWS
+    live = np.ones(n, bool)
+    if case == "clustered":
+        idx = rng.integers(700 * LANES, (700 + WINDOW_ROWS) * LANES, n)
+        idx[:2] = [700 * LANES, (700 + WINDOW_ROWS) * LANES - 1]
+    elif case == "shuffled":
+        idx = rng.integers(0, rows * LANES, n)
+    elif case == "one_row_too_wide":
+        idx = rng.integers(700 * LANES, (700 + WINDOW_ROWS) * LANES, n)
+        idx[:2] = [700 * LANES, (700 + WINDOW_ROWS) * LANES]
+    elif case == "last_rows":
+        # the smallest live row lies less than a window before the
+        # table's end: the start is clamped, the window still holds all
+        idx = rng.integers((rows - 100) * LANES, rows * LANES, n)
+        idx[0] = rows * LANES - 1
+    elif case == "small_table":
+        rows = WINDOW_ROWS
+        idx = rng.integers(0, rows * LANES, n)
+    elif case == "no_live_index":
+        idx = rng.integers(0, rows * LANES, n)
+        live[:] = False
+    elif case == "dead_at_both_ends":
+        idx = rng.integers(900 * LANES, 1_000 * LANES, n)
+        live = rng.random(n) > 0.3
+        idx[~live] = np.where(rng.random((~live).sum()) > 0.5, 0,
+                              rows * LANES - 1)
+        live[:2], idx[:2] = False, [0, rows * LANES - 1]
+    took = case not in ("shuffled", "one_row_too_wide", "small_table")
+    return rows, idx.astype(np.int32), live, took
+
+
+_WINDOW_CASES = ["clustered", "shuffled", "one_row_too_wide", "last_rows",
+                 "small_table", "no_live_index", "dead_at_both_ends"]
+
+
+@pytest.mark.parametrize("case", _WINDOW_CASES)
+@pytest.mark.parametrize("dtype", ["int32", "uint32", "bool"])
+def test_take_rows_window_is_flat_indexing_of_the_live_indices(dtype, case):
+    rng = np.random.default_rng(len(case))
+    rows, idx, live, took = _window_case(case, rng)
+    flat = (rng.random(rows * LANES) > 0.5 if dtype == "bool"
+            else rng.integers(0, 1 << 31, rows * LANES).astype(dtype))
+    # a pytree of tables shares the one decision
+    tables = (jnp.asarray(flat.reshape(-1, LANES)),
+              {"twin": jnp.asarray(flat[::-1].reshape(-1, LANES))})
+    fn = jax.jit(take_rows_window)
+    (got, rest), flag = fn(tables, jnp.asarray(idx), jnp.asarray(live))
+    assert got.dtype == flat.dtype and flag.dtype == jnp.bool_
+    assert flag.shape == () and bool(flag) == took
+    np.testing.assert_array_equal(np.asarray(got)[live], flat[idx[live]])
+    np.testing.assert_array_equal(
+        np.asarray(rest["twin"])[live], flat[::-1][idx[live]])
+    # decided by shape: a table of at most `WINDOW_ROWS` rows compiles
+    # no conditional
+    jaxpr = str(jax.make_jaxpr(take_rows_window)(
+        tables, jnp.asarray(idx), jnp.asarray(live)))
+    assert ("cond[" in jaxpr) == (case != "small_table")
+
+
+def test_take_rows_window_of_no_table_gathers_nothing():
+    idx = jnp.zeros(8, jnp.int32)
+    got, flag = take_rows_window((), idx, idx == 0)
+    assert got == () and not bool(flag)
 
 
 def _unpack(words: np.ndarray) -> np.ndarray:
