@@ -24,8 +24,9 @@ process exits non-zero without printing a result:
           `tpubench`'s numpy oracle: two pinned builds, the second
           probed by a gathered column, three numeric group keys made
           into groups on the device, `ORDER BY` an alias, `LIMIT 10`
-5. mesh   only with >= 4 TPU devices: Q1 over lineitem split four ways
-          through `PartitionedDataSource` + `make_mesh(4)`
+5. mesh   only with >= 4 TPU devices: Q1 over lineitem registered through
+          `PartitionedContext.register_resident_parquet` on `make_mesh(4)`,
+          a new relation a pass; the second pass ships its masks alone
 
 Each stage prints the evidence that the device did the work (launches,
 H2D bytes, kernel engagement).  Without a TPU
@@ -643,45 +644,66 @@ def stage_q3(device: str, sf, batch_size: int = 1 << 17) -> dict:
     return out
 
 
-def stage_mesh(src, want_rows, n_devices: int = 4) -> dict:
-    """Q1 over the resident lineitem batches split `n_devices` ways."""
+def stage_mesh(sf, want_rows, n_devices: int = 4,
+               batch_size: int = 1 << 19) -> dict:
+    """Q1 over lineitem registered as a table that stays, its row groups
+    dealt to `n_devices` devices: a new relation a pass, and the second
+    pass ships its masks alone."""
     from benchmarks.suite import Q1
-    from datafusion_tpu.exec.datasource import MemoryDataSource
     from datafusion_tpu.exec.materialize import collect
+    from datafusion_tpu.io.readers import parquet_row_groups
     from datafusion_tpu.parallel.mesh import make_mesh
-    from datafusion_tpu.parallel.partition import (
-        PartitionedContext,
-        PartitionedDataSource,
-    )
+    from datafusion_tpu.parallel.partition import PartitionedContext
 
-    batches = list(src.batches())
-    require(len(batches) >= n_devices,
-            f"mesh: {len(batches)} batches cannot split {n_devices} ways")
-    parts = [MemoryDataSource(src.schema, batches[i::n_devices])
-             for i in range(n_devices)]
-    ctx = PartitionedContext(mesh=make_mesh(n_devices), batch_size=1 << 19)
-    ctx.register_datasource("lineitem", PartitionedDataSource(parts))
-    rel = ctx.sql(Q1)
-    before = _counts()
-    # two passes: a round's device stacks are kept from its second sighting
+    ctx = PartitionedContext(mesh=make_mesh(n_devices), batch_size=batch_size,
+                             result_cache=False)
+    path = lineitem_path(sf)
+    ctx.register_resident_parquet("lineitem", path)
+    shards = ctx.datasources["lineitem"].partitions
+    batches = [sum(1 for _ in p.batches()) for p in shards]
+    # a file of fewer row groups than devices (below SF-1) leaves shards empty
+    require(sum(map(bool, batches)) == min(n_devices, parquet_row_groups(path)),
+            f"mesh: the row groups were dealt as {batches}")
+    extra = ("h2d.resident_hits", "h2d.resident_misses", "mesh.rounds",
+             "device.launches.mesh.combine")
+    evs = []
     for i in range(2):
-        check_rows(collect(rel).to_rows(), want_rows, f"mesh Q1 pass {i}")
-    ev = evidence(before, _counts())
+        before = _counts()
+        check_rows(collect(ctx.sql(Q1)).to_rows(), want_rows,
+                   f"mesh Q1 pass {i}")
+        evs.append(evidence(before, _counts(), extra))
+    first, ev = evs
     require(ev["device.launches"] > 0, "mesh: no device launch")
+    require(ev["mesh.rounds"] == max(batches)
+            and ev["device.launches.mesh.combine"] == 1,
+            f"mesh: the second pass ran {ev}")
+    require(first["h2d.resident_misses"] == sum(batches)
+            and ev["h2d.resident_misses"] == 0
+            and ev["h2d.resident_hits"] == sum(batches),
+            f"mesh: the second pass placed columns again: {first} then {ev}")
+    rows = sum(b.num_rows for p in shards for b in p.batches())
+    require(ev["h2d.bytes"] <= rows // 4 + 64 * sum(batches),
+            f"mesh: the second pass shipped more than its masks: {ev}")
+    # where the column copies the queries left on the batches really
+    # sit: shard s's on mesh device s, and on no other
+    placed = [sorted({str(d) for b in p.batches()
+                      for a in _device_copies(b) for d in a.devices()})
+              for p in shards]
+    want = [[str(d)] if n else []
+            for d, n in zip(ctx.mesh.devices.flat, batches)]
+    require(placed == want,
+            f"mesh: the shards' columns sit on {placed}, the mesh is {want}")
+    return {"first": first, "evidence": ev, "devices": placed}
 
-    def walk(r):
-        yield r
-        for c in r.op_children():
-            yield from walk(c)
 
-    caches = [r._round_cache for r in walk(rel) if hasattr(r, "_round_cache")]
-    require(bool(caches) and bool(caches[0]),
-            "mesh: no PartitionedAggregateRelation round was kept")
-    _, put_cols = next(iter(caches[0].values()))[:2]
-    placed = {sh.device for sh in put_cols[0].addressable_shards}
-    require(len(placed) == n_devices,
-            f"mesh: stacked inputs sit on {len(placed)} devices: {placed}")
-    return {"evidence": ev, "devices": sorted(str(d) for d in placed)}
+def _device_copies(batch):
+    """The column copies `device_inputs` keeps on a batch and on the
+    views cached on it."""
+    for key, kept in batch.cache.items():
+        if hasattr(kept, "cache"):  # a projection's or a core's view
+            yield from _device_copies(kept)
+        elif key[0] == "device":
+            yield from kept[0]
 
 
 # -- driver -------------------------------------------------------------------
@@ -803,7 +825,7 @@ def main(argv=None) -> int:
     # Q3 at SF-1 at most: `tpubench`'s q3_sf10_join3 is the SF-10 run
     report["q3"] = timed("q3", stage_q3, "tpu", min(sf, 1))
     if device["count"] >= 4:
-        report["mesh"] = timed("mesh", stage_mesh, src, cold["rows"])
+        report["mesh"] = timed("mesh", stage_mesh, sf, cold["rows"])
     else:
         say("stage mesh: dormant (fewer than 4 TPU devices)")
 

@@ -1898,39 +1898,46 @@ class AggregateRelation(Relation):
         batch.cache["agg_inputs"] = (weakref.ref(self), core, out)
         return out
 
-    @property
-    def _ids_slot(self):
-        return ("group_ids", tuple(self.key_cols))
+    def _ids_slot(self, device):
+        # by device, as `device_inputs`' slot is: a batch scanned as a
+        # shard of a mesh after a single-device query (or before one)
+        # holds its ids where each of them wants them
+        return ("group_ids", tuple(self.key_cols),
+                None if device is None else repr(device))
 
-    def _group_ids(self, batch: RecordBatch):
+    def _group_ids(self, batch: RecordBatch, device=None):
         """Dense group ids for one batch, as the device array.  Cached
         on the batch (keyed by this relation's encoder) so re-scanned
         in-memory batches skip both the host encode and the H2D
-        transfer.
+        transfer.  `device`: where they go instead of `self.device`
+        (the mesh relation places a shard's batch on the shard's chip).
 
         Serialized by `_ids_lock`: the staging producer thread normally
         does all encoding, but a pin miss (another relation's encode
         overwrote the batch's slot) routes the consumer thread here
         concurrently, and GroupKeyEncoder mutation is not atomic."""
-        # one slot per batch and GROUP BY column set (a different
-        # encoder over the same keys overwrites it), so a long-lived
-        # batch holds one id array per key set, not one per query ever
-        # run; the entry pins the encoder so the identity check can't
-        # hit a recycled object
-        hit = batch.cache.get(self._ids_slot)
+        # one slot per batch, GROUP BY column set and device (a
+        # different encoder over the same keys overwrites it), so a
+        # long-lived batch holds one id array per key set, not one per
+        # query ever run; the entry pins the encoder so the identity
+        # check can't hit a recycled object
+        if device is None:
+            device = self.device
+        hit = batch.cache.get(self._ids_slot(device))
         if hit is not None and hit[0] is self.encoder:
             return hit[1]
         with self._ids_lock, METRICS.timer("aggregate.group_ids"):
-            return self._group_ids_locked(batch)
+            return self._group_ids_locked(batch, device)
 
-    def _group_ids_locked(self, batch: RecordBatch):
-        hit = batch.cache.get(self._ids_slot)
+    def _group_ids_locked(self, batch: RecordBatch, device):
+        slot = self._ids_slot(device)
+        hit = batch.cache.get(slot)
         if hit is not None and hit[0] is self.encoder:
             return hit[1]
         if self.key_cols:
             ids = self._device_group_ids(batch)
             if ids is not None:
-                batch.cache[self._ids_slot] = (self.encoder, ids)
+                batch.cache[slot] = (self.encoder, ids)
                 return ids
             pulled = [a for idx in self.key_cols
                       for a in (batch.data[idx], batch.validity[idx])
@@ -1958,7 +1965,7 @@ class AggregateRelation(Relation):
 
         wire = ids_np
         n_groups = self.encoder.num_groups
-        if _wire_enabled(self.device):
+        if _wire_enabled(device):
             if n_groups <= 127:
                 wire = ids_np.astype(np.int8)
             elif n_groups <= 32767:
@@ -1966,8 +1973,8 @@ class AggregateRelation(Relation):
         from datafusion_tpu.obs.device import LEDGER
 
         dev_wire = (
-            LEDGER.put(wire, self.device, owner="agg.ids")
-            if self.device is not None
+            LEDGER.put(wire, device, owner="agg.ids")
+            if device is not None
             else LEDGER.adopt(jnp.asarray(wire), owner="agg.ids")
         )
         ids = (
@@ -1975,7 +1982,7 @@ class AggregateRelation(Relation):
             if wire.dtype == np.int32
             else LEDGER.adopt(_WIDEN_IDS_JIT(dev_wire), owner="agg.ids")
         )
-        batch.cache[self._ids_slot] = (self.encoder, ids)
+        batch.cache[slot] = (self.encoder, ids)
         return ids
 
     def _device_key_columns(self, batch: RecordBatch):

@@ -159,12 +159,17 @@ class ParquetDataSource(DataSource):
         schema: Optional[Schema] = None,
         batch_size: int = DEFAULT_BATCH_SIZE,
         projection: Optional[Sequence[int]] = None,
+        row_groups: Optional[Sequence[int]] = None,
     ):
         self.path = path
         self.table_schema = schema if schema is not None else infer_parquet_schema(path)
         self.batch_size = batch_size
         self.projection = list(projection) if projection is not None else None
-        self._reader = ParquetReader(path, self.table_schema, batch_size, self.projection)
+        self.row_groups = None if row_groups is None else list(row_groups)
+        self._reader = ParquetReader(
+            path, self.table_schema, batch_size, self.projection,
+            self.row_groups,
+        )
 
     @property
     def schema(self) -> Schema:
@@ -175,16 +180,21 @@ class ParquetDataSource(DataSource):
 
     def with_projection(self, projection: Sequence[int]) -> "ParquetDataSource":
         return ParquetDataSource(
-            self.path, self.table_schema, self.batch_size, projection
+            self.path, self.table_schema, self.batch_size, projection,
+            self.row_groups,
         )
 
     def to_meta(self) -> dict:
-        # mirrors DataSourceMeta::ParquetFile (datasource.rs:79-84)
+        # mirrors DataSourceMeta::ParquetFile (datasource.rs:79-84); a
+        # source held to some of the file's row groups says which, so
+        # the worker that rebuilds it scans no more than it did
         return {
             "ParquetFile": {
                 "filename": self.path,
                 "schema": self.table_schema.to_json(),
                 "projection": self.projection,
+                **({} if self.row_groups is None
+                   else {"row_groups": self.row_groups}),
             }
         }
 

@@ -64,35 +64,52 @@ def _arrow_to_columns(
                         c = c.cast(pa.string())
                     enc = c.dictionary_encode()
                 idx = enc.indices
-                local = idx.fill_null(0).to_numpy(zero_copy_only=False)
-                merged = d.merge_codes(
-                    local.astype(np.int32), enc.dictionary.to_pylist()
-                )
-                isnull = idx.is_null().to_numpy(zero_copy_only=False)
-                merged[isnull] = 0
+                values = enc.dictionary.to_pylist()
+                if idx.null_count == 0:
+                    # no null in the chunk (the array knows): nothing
+                    # to fill, no mask to build
+                    merged = d.merge_codes(
+                        idx.to_numpy(zero_copy_only=False).astype(np.int32),
+                        values)
+                    isnull = None
+                else:
+                    local = idx.fill_null(0).to_numpy(zero_copy_only=False)
+                    merged = d.merge_codes(local.astype(np.int32), values)
+                    isnull = idx.is_null().to_numpy(zero_copy_only=False)
+                    merged[isnull] = 0
                 code_parts.append(merged)
                 null_parts.append(isnull)
             if not code_parts:
-                codes = np.empty(0, np.int32)
-                null_mask = np.empty(0, bool)
+                codes, null_mask = np.empty(0, np.int32), None
             elif len(code_parts) == 1:
                 codes, null_mask = code_parts[0], null_parts[0]
             else:
                 codes = np.concatenate(code_parts)
-                null_mask = np.concatenate(null_parts)
+                null_mask = None if all(m is None for m in null_parts) else (
+                    np.concatenate([
+                        np.zeros(len(c), bool) if m is None else m
+                        for c, m in zip(code_parts, null_parts)]))
             columns.append(codes)
-            validity.append(None if not null_mask.any() else ~null_mask)
+            validity.append(
+                None if null_mask is None or not null_mask.any()
+                else ~null_mask)
         else:
             import pyarrow as pa
 
-            null_mask = col.is_null().to_numpy(zero_copy_only=False)
-            fill = False if pa.types.is_boolean(col.type) else 0
-            vals = col.fill_null(fill).to_numpy(zero_copy_only=False)
+            if col.null_count == 0:
+                # the common case, and the array knows it: no mask to
+                # build and scan, no filled copy to make
+                null_mask = None
+                vals = col.to_numpy(zero_copy_only=False)
+            else:
+                null_mask = col.is_null().to_numpy(zero_copy_only=False)
+                fill = False if pa.types.is_boolean(col.type) else 0
+                vals = col.fill_null(fill).to_numpy(zero_copy_only=False)
             # copy=False: parquet f64 columns arrive already-typed; the
             # no-op astype would memcpy 48 MB per SF-1 numeric column
             vals = np.asarray(vals).astype(np_dtype, copy=False)
             columns.append(vals)
-            validity.append(None if not null_mask.any() else ~null_mask)
+            validity.append(None if null_mask is None else ~null_mask)
     return columns, validity
 
 
@@ -267,11 +284,15 @@ class ParquetReader:
         schema: Optional[Schema] = None,
         batch_size: int = DEFAULT_BATCH_SIZE,
         projection: Optional[Sequence[int]] = None,
+        row_groups: Optional[Sequence[int]] = None,
     ):
         self.path = path
         self.schema = schema if schema is not None else infer_parquet_schema(path)
         self.batch_size = batch_size
         self.projection = list(projection) if projection is not None else None
+        # the file's row groups this reader scans (None: all of them):
+        # how a table is dealt to the shards of a mesh
+        self.row_groups = None if row_groups is None else list(row_groups)
         self.out_schema = _project_schema(self.schema, projection)
         self.dicts: list[Optional[StringDictionary]] = [
             StringDictionary() if f.data_type == DataType.UTF8 else None
@@ -303,7 +324,9 @@ class ParquetReader:
         # read_dictionary only applies to string-physical columns; a
         # date/timestamp column (travels as ISO strings) keeps its type
         # and takes the cast path in _arrow_to_columns
-        for arrow_batch in pf.iter_batches(batch_size=self.batch_size, columns=names):
+        for arrow_batch in pf.iter_batches(
+                batch_size=self.batch_size, columns=names,
+                row_groups=self.row_groups):
             faults.check("io.read", path=self.path, format="parquet")
             cols = [arrow_batch.column(j) for j in range(arrow_batch.num_columns)]
             import pyarrow as pa
@@ -312,6 +335,17 @@ class ParquetReader:
             columns, validity = _arrow_to_columns(cols, self.out_schema, self.dicts)
             METRICS.add("scan.rows", arrow_batch.num_rows)
             yield make_host_batch(self.out_schema, columns, validity, list(self.dicts))
+
+
+def parquet_row_groups(path: str) -> int:
+    """How many row groups a Parquet file holds."""
+
+    def _count(p):
+        import pyarrow.parquet as pq
+
+        return pq.ParquetFile(p).metadata.num_row_groups
+
+    return run_on_io_thread(_count, path)
 
 
 def infer_parquet_schema(path: str) -> Schema:

@@ -10,7 +10,7 @@ against.  Three instruments, one module:
   through `LEDGER.put(...)` (the seam replacing raw ``jax.device_put``
   — lint rule DF006 keeps it load-bearing) or registers its outputs
   via `LEDGER.adopt(...)`.  Each tracked buffer records bytes, owner
-  tag (table scan, batch cache, mesh round-cache, sort image, ...),
+  tag (table scan, batch cache, mesh state, sort image, ...),
   the placing query's trace id, and its *lifetime* — a
   ``weakref.finalize`` fires when the buffer's Python handle dies, so
   live-bytes and the peak watermark are measured facts, not the
@@ -279,8 +279,8 @@ class DeviceLedger:
         return value
 
     def retag(self, value: Any, owner: str, cached: bool = True) -> None:
-        """Re-attribute already-tracked buffers (a mesh round admitted
-        into the round cache stops being transient)."""
+        """Re-attribute already-tracked buffers (a table's copies a
+        pin takes over stop being transient)."""
         if not _ENABLED:
             return
         import jax
@@ -668,26 +668,33 @@ class DeviceLedger:
             sum(e.nbytes for e in pins.values()),
         )
 
-    def headroom(self) -> Optional[int]:
+    def headroom(self, device=None) -> Optional[int]:
         """HBM bytes available before the measured capacity is reached
         (None when capacity is unknowable — admission then never sheds
-        on memory, matching the SLO's stay-dormant rule)."""
-        cap = hbm_capacity_bytes()
+        on memory, matching the SLO's stay-dormant rule).  With a
+        `device` (a jax Device): that chip's own capacity less what the
+        ledger holds on it — a mesh shard has to fit ITS chip, whatever
+        the others have free."""
+        if device is None:
+            cap = hbm_capacity_bytes()
+            return None if cap is None else cap - self.live_bytes()
+        cap = hbm_capacity_bytes(device)
         if cap is None:
             return None
-        return cap - self.live_bytes()
+        return cap - self.devices().get(_device_key(device), 0)
 
-    def fits(self, nbytes: int) -> bool:
+    def fits(self, nbytes: int, device=None) -> bool:
         """Whether `nbytes` of new residency fit what is free: the
         test admission applies to a cold table (`need <= headroom`),
         for whoever else places something that stays (a join's build
-        side).  `headroom` counts every live ledger buffer, pinned or
+        side; with `device`, a mesh shard on its own chip).
+        `headroom` counts every live ledger buffer, pinned or
         not, so a table's resident copies weigh in from the moment
         they are uploaded; what a caller knows is about to follow it
         adds to `nbytes` itself.  Where the capacity is unknowable the
         device is the host platform and residency is host memory
         (`host_fits`)."""
-        free = self.headroom()
+        free = self.headroom(device)
         if free is None:
             return host_fits(nbytes)
         return int(nbytes) <= free
@@ -731,7 +738,7 @@ def host_fits(nbytes: int) -> bool:
     return int(nbytes) <= free
 
 
-def hbm_capacity_bytes() -> Optional[int]:
+def hbm_capacity_bytes(device=None) -> Optional[int]:
     """Device memory capacity for the memory-pressure SLO
     (``DATAFUSION_TPU_SLO_*_HBM_FRAC``): the ``DATAFUSION_TPU_HBM_BYTES``
     override (TOTAL across local devices), else the sum of every local
@@ -740,21 +747,23 @@ def hbm_capacity_bytes() -> Optional[int]:
     dividing by one chip's capacity would over-report pressure N-fold
     on an N-device host.  Else None — an unknown capacity keeps the
     objective dormant rather than guessed (the exact anti-pattern the
-    ledger replaced in benchmarks/suite.py)."""
-    env = os.environ.get("DATAFUSION_TPU_HBM_BYTES")
-    if env:
-        try:
-            return int(float(env))
-        except (TypeError, ValueError):
-            return None
+    ledger replaced in benchmarks/suite.py).  With a `device`: that
+    one chip's capacity (the override's even share of the total)."""
     try:
         import jax
 
         devices = jax.devices()
     except Exception:  # noqa: BLE001 — capacity probing is best-effort by contract
         return None
+    env = os.environ.get("DATAFUSION_TPU_HBM_BYTES")
+    if env:
+        try:
+            total = int(float(env))
+        except (TypeError, ValueError):
+            return None
+        return total if device is None else total // max(len(devices), 1)
     total = 0
-    for d in devices:
+    for d in devices if device is None else [device]:
         # per-device guard: backends EXPOSE memory_stats but vary
         # wildly in what it returns — None, a partial dict without
         # bytes_limit (CPU/METAL do this), a non-dict, or a raise

@@ -7,10 +7,14 @@ combining them — `README.md:33-35`, `physicalplan.rs`,
 
 - a table is a list of partition files (`PartitionedDataSource`);
   partitions assign round-robin to mesh shards;
-- each round, every shard's next batch stacks into `[n_shards, cap]`
-  host arrays; one `shard_map`-ped jitted kernel runs the *same*
-  per-shard filter+aggregate update in parallel across devices
-  (partial aggregation = data parallelism over rows);
+- each round, every shard's next batch goes to its own mesh device
+  the way a batch goes to the one device of `ExecutionContext`
+  (`batch.device_inputs`: the column copies stay on the batch, a
+  resident table's query ships its mask alone); the per-device arrays
+  are assembled, without a copy, into one mesh-sharded array a column,
+  and one `shard_map`-ped jitted kernel runs the *same* per-shard
+  filter+aggregate update in parallel across devices (partial
+  aggregation = data parallelism over rows);
 - a second `shard_map` kernel combines partials with `psum` (SUM,
   COUNT, AVG) / all-gather + min/max over the mesh axis — the collective
   replaces the planned Arrow-IPC-over-HTTP partial exchange;
@@ -27,7 +31,9 @@ is needed until the final combine).
 
 from __future__ import annotations
 
-from typing import Iterator, Optional, Sequence
+import functools
+from concurrent.futures import ThreadPoolExecutor
+from typing import Iterable, Iterator, Optional, Sequence
 
 import jax
 import jax.numpy as jnp
@@ -43,11 +49,18 @@ from datafusion_tpu.exec.aggregate import (
     _AggregateCore as _AggCore,
     group_capacity,
 )
-from datafusion_tpu.exec.batch import RecordBatch, bucket_capacity
+from datafusion_tpu.exec.batch import (
+    RecordBatch,
+    bucket_capacity,
+    device_inputs,
+    make_host_batch,
+    pad_to,
+)
 from datafusion_tpu.exec.context import ExecutionContext
 from datafusion_tpu.exec.datasource import (
     CsvDataSource,
     DataSource,
+    MemoryDataSource,
     ParquetDataSource,
 )
 from datafusion_tpu.exec.expression import compute_aux_values
@@ -145,6 +158,71 @@ class PartitionedDataSource(DataSource):
         return {"Partitioned": [p.to_meta() for p in self.partitions]}
 
 
+# table batches a reader of `register_resident_parquet` parses at a time
+_READ_BATCHES = 8
+
+
+def _whole_batches(batches: Iterable[RecordBatch], schema: Schema,
+                   size: int) -> list[RecordBatch]:
+    """A scan's batches (a Parquet reader cuts one short at every row
+    group's end) as batches of exactly `size` rows, the last one what is
+    left: a shard's rounds then all have one shape, and none carries
+    padding to the device.  Streamed: each row is copied once, into the
+    batch that keeps it, while the reader parses the next."""
+    out: list[RecordBatch] = []
+    n_cols = len(schema.fields)
+    cols = valids = dicts = None
+    fill = 0
+
+    def emit():
+        out.append(make_host_batch(
+            schema,
+            [c[:fill] for c in cols],
+            [None if v is None else v[:fill] for v in valids],
+            list(dicts),
+        ))
+
+    for b in batches:
+        if dicts is None:
+            dicts = list(b.dicts)
+        data = [np.asarray(a) for a in b.data]
+        pos = 0
+        while pos < b.num_rows:
+            if cols is None:
+                cols = [np.empty(size, a.dtype) for a in data]
+                valids = [None] * n_cols
+                fill = 0
+            take = min(size - fill, b.num_rows - pos)
+            for i in range(n_cols):
+                cols[i][fill:fill + take] = data[i][pos:pos + take]
+                if b.validity[i] is not None:
+                    if valids[i] is None:
+                        valids[i] = np.ones(size, bool)
+                    valids[i][fill:fill + take] = np.asarray(
+                        b.validity[i])[pos:pos + take]
+            fill += take
+            pos += take
+            if fill == size:
+                emit()
+                cols = None
+    if cols is not None:
+        emit()
+    return out
+
+
+def _padded(batch: RecordBatch, cap: int) -> RecordBatch:
+    """`batch` with its host arrays zero-padded to capacity `cap`."""
+    return RecordBatch(
+        batch.schema,
+        [pad_to(np.asarray(a), cap) for a in batch.data],
+        [None if v is None else pad_to(np.asarray(v), cap)
+         for v in batch.validity],
+        list(batch.dicts),
+        num_rows=batch.num_rows,
+        mask=None if batch.mask is None else pad_to(np.asarray(batch.mask), cap),
+    )
+
+
 class _MeshStacker:
     """Builds `[n_shards, cap]` mesh-sharded device arrays by placing
     each shard's already-padded host column directly on its own mesh
@@ -187,10 +265,9 @@ class _MeshStacker:
     def put(self, shards: Sequence[np.ndarray], owner: str = "mesh.shard"):
         """One [n, cap] mesh-sharded array from n cap-length host
         arrays (shards[i] lands on mesh device i, no reshard).  The
-        per-shard transfers profile through the device ledger; the
-        assembled global array is what stays resident, adopted under
-        ``owner`` (re-tagged to ``mesh.round_cache`` when a warm round
-        admits it)."""
+        per-shard transfers profile through the device ledger and count
+        into `h2d.bytes`; the assembled global array is adopted under
+        ``owner`` and dies with its round."""
         from datafusion_tpu.obs.device import (
             LEDGER,
             enabled as _ledger_on,
@@ -211,9 +288,13 @@ class _MeshStacker:
             ]
             if synced:
                 jax.block_until_ready(put)
+        nbytes = sum(int(p.nbytes) for p in put)
+        METRICS.add("h2d.bytes", nbytes)
         if _ledger_on():
+            # one event for the batch of parallel transfers (it counts
+            # once in `device.h2d.transfers`)
             LEDGER.note_h2d(
-                sum(int(p.nbytes) for p in put),
+                nbytes,
                 span.wall_s,
                 self.devices[0],
                 synced=synced,
@@ -538,23 +619,26 @@ def _partitioned_jits(core, mesh):
     spec_sh = P(MESH_AXIS)  # leading axis = shard
     spec_rep = P()  # replicated
 
-    # per-round update: every input and the state carry a leading
-    # shard axis; each device runs the single-device kernel on its
-    # slice.  NOT donated: device_call may replay the dispatch on a
-    # transient failure, and a donated state buffer would already
-    # be consumed by the failed attempt.
+    # per-round update: each device runs the single-device kernel on
+    # its block.  The row inputs (columns, validity, mask, ids) are
+    # FLAT `[n_shards * cap]` arrays sharded over the mesh axis, so a
+    # device's block is the `[cap]` array `batch.device_inputs` left on
+    # it and a round assembles without a copy; the row counts and the
+    # state carry a leading shard axis.  NOT donated: device_call may
+    # replay the dispatch on a transient failure, and a donated state
+    # buffer would already be consumed by the failed attempt.
     def stacked_update(cols, valids, aux, num_rows, masks, ids, state,
                        str_aux, params):
         sq = lambda t: t[0]
         counts, accs = state
         local = (sq(counts), jax.tree.map(sq, accs))
         out = core._kernel(
-            [sq(c) for c in cols],
-            [None if v is None else sq(v) for v in valids],
+            list(cols),
+            list(valids),
             aux,
             sq(num_rows),
-            sq(masks),
-            sq(ids),
+            masks,
+            ids,
             local,
             str_aux,
             params,
@@ -610,11 +694,10 @@ def _partitioned_jits(core, mesh):
     stacked_jit = jax.jit(stacked_sm)
 
     # multi-ROUND fold (the PR 6 batch-group fold lifted to mesh
-    # rounds): consecutive warm rounds of one shape class — their
-    # padded shard stacks already device-resident in the round cache —
-    # fold through the shard_map'd update inside ONE jitted program,
-    # so a warm repeated mesh query pays one launch per shape class
-    # instead of one per round.
+    # rounds): consecutive rounds of one shape class fold through the
+    # shard_map'd update inside ONE jitted program, so a mesh query
+    # pays one launch per `_ROUND_FUSE_MAX` rounds instead of one per
+    # round.
     def multi_rounds(rounds, state, params):
         for (cols, valids, aux, num_rows, masks, ids, str_aux) in rounds:
             state = stacked_sm(cols, valids, aux, num_rows, masks, ids,
@@ -634,11 +717,58 @@ def _partitioned_jits(core, mesh):
     return hit
 
 
+# rounds folded into one launch.  The fold is unrolled (each round's
+# arrays are arguments of their own: stacking them would copy the
+# resident table every query), so the program grows with it; the
+# consumer's launches are not what sets a mesh query's pace.
+_ROUND_FUSE_MAX = 32
+
+
+@functools.lru_cache(maxsize=256)
+def _fill(device, cap: int, dtype: str, value):
+    """A `[cap]` constant on `device`: an absent shard's block, a mask
+    or validity array where one shard of a round has none."""
+    from datafusion_tpu.obs.device import LEDGER
+
+    return LEDGER.put(np.full(cap, value, np.dtype(dtype)), device,
+                      owner="mesh.fill")
+
+
+@functools.lru_cache(maxsize=None)
+def _stagers(n_shards: int) -> ThreadPoolExecutor:
+    """The threads that stage a round's shards side by side, one a
+    shard, for as long as the process lives (a pool a query would
+    start and end `n_shards` threads a query)."""
+    return ThreadPoolExecutor(n_shards, thread_name_prefix="df-tpu-mesh-stage")
+
+
+class _Round:
+    """One mesh round: shard s's next batch (None where the shard has
+    run out), all of one capacity.  `placed`, `aux` and `str_aux` are
+    what staging leaves for the consumer."""
+
+    __slots__ = ("batches", "cap", "placed", "aux", "str_aux")
+
+    def __init__(self, batches, cap):
+        self.batches = batches
+        self.cap = cap
+        self.placed = None
+
+
 class PartitionedAggregateRelation(AggregateRelation):
     """[Selection +] Aggregate over partitioned input on a device mesh.
 
     Reuses the single-device kernel (`AggregateRelation._kernel`) as the
-    per-shard body of a `shard_map`; adds the collective final combine.
+    per-shard body of a `shard_map` and the single-device scan loop's
+    parts around it: a round is staged on `staged_pipeline`'s producer
+    (each shard's batch through `batch.device_inputs` and `_group_ids`
+    onto that shard's device, the host predicate's mask bit-packed
+    through `put_compressed`), the consumer assembles the staged
+    per-device arrays into mesh-sharded ones and folds rounds into
+    launches; adds the collective final combine.  Residency is the
+    batch's (`device_inputs`' cache, the group-id slot): a table whose
+    shards hand out the same batches to every query
+    (`register_resident_parquet`) ships its columns once.
     """
 
     def __init__(
@@ -657,24 +787,14 @@ class PartitionedAggregateRelation(AggregateRelation):
         )
         self.children = children
         self.mesh = mesh
-        self.n_shards = int(np.prod(mesh.devices.shape))
-        # warm round-input cache: a re-collected relation (repeated
-        # query over in-memory partitions) reuses each round's padded +
-        # device-placed shard stacks instead of re-padding and
-        # re-transferring every column per run — the per-round host
-        # overhead was most of the r05 0.94x mesh-vs-single gap.
-        # Entries pin their round's batch objects, so the id()-keys
-        # stay valid; FIFO-bounded.
-        from collections import OrderedDict
-
-        self._round_cache: OrderedDict = OrderedDict()
-        self._round_cache_max = 64
-        # second-chance admission (mirrors SortRelation._run_seen): a
-        # round key must be SEEN twice before its device stacks are
-        # stored, so file-backed scans — fresh batch objects every run,
-        # their id()-keys can never repeat — pin no HBM at all
-        self._round_seen: OrderedDict = OrderedDict()
+        self._devices = list(mesh.devices.flat)
+        self.n_shards = len(self._devices)
+        self._sharding = NamedSharding(mesh, P(MESH_AXIS))
         self._init_stacked_cache: dict = {}
+        self._rows_cache: dict = {}
+        # how `_stage` goes over a round's shards: one after the other,
+        # or side by side where `accumulate` runs it under a producer
+        self._stage_map = map
         # the shard_map jits are keyed on the PROCESS-WIDE core (not
         # this relation): a fresh PartitionedContext per query would
         # otherwise rebuild `jax.jit(shard_map(...))` around new bound
@@ -684,10 +804,6 @@ class PartitionedAggregateRelation(AggregateRelation):
         self._stacked_jit, self._combine_jit, self._multi_jit = (
             _partitioned_jits(self.core, mesh)
         )
-        # cached zero-rows vector for dead-round padding (multi-round
-        # fold pads to the group-size ladder; a zero row count makes a
-        # round's every shard an identity contribution)
-        self._zero_rows = None
 
     # -- stacked state management --
     def _init_stacked_state(self, capacity: int):
@@ -706,9 +822,8 @@ class PartitionedAggregateRelation(AggregateRelation):
     def _shard_state(self, state):
         from datafusion_tpu.obs.device import LEDGER
 
-        sharding = NamedSharding(self.mesh, P(MESH_AXIS))
         return jax.tree.map(
-            lambda t: LEDGER.put(t, sharding, owner="mesh.state"), state
+            lambda t: LEDGER.put(t, self._sharding, owner="mesh.state"), state
         )
 
     def _grow_stacked_state(self, state, new_capacity: int):
@@ -735,261 +850,242 @@ class PartitionedAggregateRelation(AggregateRelation):
         # shard states merge by the encoder's dense ids, keyed or not
         return self.accumulate()
 
+    # -- a round: pulled, staged, assembled --
+    def _rounds(self) -> Iterator[_Round]:
+        """Shard s's next batch, round after round, until every shard
+        has run out; a round's batches share one capacity (a resident
+        table's already do, and are handed on as they are)."""
+        feeds = [
+            _ShardFeed(rels)
+            for rels in _round_robin(self.children, self.n_shards)
+        ]
+        while True:
+            batches = [f.next_batch() for f in feeds]
+            if all(b is None for b in batches):
+                return
+            cap = max(
+                bucket_capacity(1),
+                *(b.capacity for b in batches if b is not None),
+            )
+            yield _Round(
+                [b if b is None or b.capacity == cap else _padded(b, cap)
+                 for b in batches],
+                cap,
+            )
+
+    def _stage(self, r: _Round) -> None:
+        """The host's part of a round (the producer's, where there is
+        one): per shard batch its group ids (encoded and placed where
+        the key set is new to the batch), this query's host predicate,
+        and `device_inputs` onto the shard's device, which ships the
+        columns where the batch does not hold them there and the mask
+        bit-packed, alone, where it does.  Under the producer the
+        round's shards are staged side by side, a thread each
+        (`_stagers`): their numpy passes and puts release the GIL, and
+        each put goes down its own chip's link."""
+        with METRICS.timer("mesh.stage"):
+            live = None
+            for b in r.batches:
+                if b is None:
+                    continue
+                live = b
+                for idx in self.key_cols:
+                    if b.dicts[idx] is not None:
+                        self._key_dicts[idx] = b.dicts[idx]
+            r.placed = list(
+                self._stage_map(self._stage_shard, self._devices, r.batches))
+            # aux / rank tables derive from the (shared) dictionaries;
+            # computed after all shards' rows are encoded so versions
+            # are current
+            r.aux = tuple(
+                compute_aux_values(self._aux_specs, live, self._aux_cache)
+                if self._aux_specs else ()
+            )
+            r.str_aux = self._compute_str_aux(live)
+
+    def _stage_shard(self, dev, b: Optional[RecordBatch]):
+        """(cols, valids, mask, ids) of one shard's batch on `dev`;
+        None for a shard that has run out.  Shards share the encoder
+        (`_group_ids` serializes its misses) and the core's wire hints
+        (immutable entries, validated against every batch they are
+        tried on)."""
+        if b is None:
+            return None
+        ids = self._group_ids(b, dev)
+        return (
+            *device_inputs(
+                self._device_view(b), dev, self.core.wire_hints,
+                query_mask=self._query_mask(b),
+            ),
+            ids,
+        )
+
+    def _assemble(self, r: _Round, dtypes):
+        """(cols, valids, rows, mask, ids) of a staged round as
+        mesh-sharded arrays: each is the shards' own single-device
+        arrays seen as one `[n_shards * cap]` array — no copy, no put
+        (`_fill` constants stand in for an absent shard's block)."""
+        cap = r.cap
+
+        def glob(of_shard, dtype, value=0):
+            return jax.make_array_from_single_device_arrays(
+                (self.n_shards * cap,),
+                self._sharding,
+                [
+                    _fill(dev, cap, dtype, value) if a is None else a
+                    for dev, a in zip(
+                        self._devices,
+                        (None if p is None else of_shard(p)
+                         for p in r.placed),
+                    )
+                ],
+            )
+
+        # a validity array travels only for columns where some shard
+        # carries nulls this round (None otherwise: the all-valid
+        # common case never traces those bytes)
+        cols = tuple(
+            glob(lambda p, c=c: p[0][c], dt) for c, dt in enumerate(dtypes)
+        )
+        valids = tuple(
+            glob(lambda p, c=c: p[1][c], "bool", True)
+            if any(p is not None and p[1][c] is not None for p in r.placed)
+            else None
+            for c in range(len(dtypes))
+        )
+        rows_dev = self._rows(
+            tuple(0 if b is None else b.num_rows for b in r.batches))
+        # an absent shard's mask block is all True like a batch without
+        # a mask: its zero row count is what keeps it out
+        mask = glob(lambda p: p[2], "bool", True)
+        ids = glob(lambda p: p[3], "int32")
+        return cols, valids, rows_dev, mask, ids
+
+    def _rows(self, rows: tuple):
+        """A round's row counts, one a shard, on the mesh (a scan has
+        two or three distinct ones: whole batches, tails, a dead
+        round's zeros)."""
+        hit = self._rows_cache.get(rows)
+        if hit is None:
+            from datafusion_tpu.obs.device import LEDGER
+
+            hit = self._rows_cache[rows] = LEDGER.put(
+                np.asarray(rows, np.int32), self._sharding,
+                owner="mesh.rows", cached=False,
+            )
+        return hit
+
     # -- the partitioned scan loop --
     def accumulate(self):
+        from datafusion_tpu.exec.fused import (
+            entry_signature,
+            pad_group,
+            shared_signature,
+        )
+        from datafusion_tpu.exec.prefetch import (
+            pipeline_enabled,
+            staged_pipeline,
+        )
         from datafusion_tpu.obs.stats import op_timer
 
-        n = self.n_shards
-        feeds = [
-            _ShardFeed(rels) for rels in _round_robin(self.children, n)
-        ]
+        # a table whose shards hand out the same batches every query
+        # keeps ONE encoder a key set (`datasource.SharedScanState`):
+        # the ids a batch holds on its device replay for this query
+        self._adopt_source_state()
         in_schema = self.child.schema
+        dtypes = [
+            np.dtype(in_schema.field(i).data_type.np_dtype).name
+            for i in self.core.used_cols
+        ]
         state = None
         group_cap = 0
-
-        sub_cols = self.core.used_cols
-        sub_dtypes = [
-            in_schema.field(i).data_type.np_dtype for i in sub_cols
-        ]
-        stacker = _MeshStacker(self.mesh)
         # ambient per-query deadline: bounds every mesh round AND (via
         # the contextvar already being set) the device_call backoffs
         deadline = current_deadline()
 
-        # multi-round fold buffer: consecutive WARM rounds with one
-        # shape class collect here and dispatch as one launch through
-        # `self._multi_jit`; cold rounds, shape-class changes, and
-        # state growth flush first.
-        from datafusion_tpu.exec.fused import (
-            entry_signature,
-            fuse_group_max,
-            pad_group,
-            shared_signature,
-        )
+        rounds = self._rounds()
+        staged = pipeline_enabled(None)
+        self._stage_map = _stagers(self.n_shards).map if staged else map
+        if staged:
+            # one thread pulls rounds (a file-backed shard's parse), the
+            # producer stages them (a thread a shard under it), this
+            # thread launches: the single-device scan loop's pipeline,
+            # a round where it has a batch
+            rounds = staged_pipeline(rounds, self._stage)
 
-        round_fuse_max = fuse_group_max()
+        # consecutive rounds with one shape class collect here and
+        # dispatch as one launch through `self._multi_jit`; a shape-
+        # class change and state growth flush first
         round_buf: list = []
         round_sig = None
+        str_aux = None
+        shard_rows = np.zeros(self.n_shards, np.int64)
 
         def flush_rounds():
             nonlocal state
             if not round_buf:
                 return
             if len(round_buf) == 1:
-                (put_cols, put_valids, aux, rows_dev, put_mask, put_ids,
-                 str_aux) = round_buf[0]
+                (cols, valids, aux, rows_dev, mask, ids, s_aux) = round_buf[0]
                 with METRICS.timer("execute.partitioned_aggregate"), \
                         op_timer(self):
                     state = device_call(
-                        self._stacked_jit, put_cols, put_valids, aux,
-                        rows_dev, put_mask, put_ids, state, str_aux,
-                        self._params, _tag="mesh.stacked",
+                        self._stacked_jit, cols, valids, aux, rows_dev,
+                        mask, ids, state, s_aux, self._params,
+                        _tag="mesh.stacked",
                     )
                 round_buf.clear()
                 return
-            if self._zero_rows is None:
-                self._zero_rows = jnp.zeros(self.n_shards, jnp.int32)
-            zero = self._zero_rows
+            zero = self._rows((0,) * self.n_shards)
             group = pad_group(
                 list(round_buf),
-                # dead round: the live round's stacks with a zero row
+                # dead round: the live round's arrays with a zero row
                 # count — every shard contributes identity
                 lambda r: (r[0], r[1], r[2], zero, r[4], r[5], r[6]),
             )
-            rounds = tuple(group)
             METRICS.add("mesh.fused_round_launches")
             METRICS.add("mesh.fused_rounds", len(round_buf))
             with METRICS.timer("execute.partitioned_aggregate"), \
                     op_timer(self):
                 state = device_call(
-                    self._multi_jit, rounds, state, self._params,
+                    self._multi_jit, tuple(group), state, self._params,
                     _tag="mesh.multi",
                 )
             round_buf.clear()
 
-        while True:
+        for r in rounds:
             if deadline is not None:
                 deadline.check("partitioned aggregate round")
-            round_batches = [f.next_batch() for f in feeds]
-            if all(b is None for b in round_batches):
-                flush_rounds()
-                break
-            # one capacity for the whole round so shards stack
-            cap = max(
-                bucket_capacity(1),
-                *(b.capacity for b in round_batches if b is not None),
-            )
-            round_key = (
-                tuple(-1 if b is None else id(b) for b in round_batches),
-                cap,
-                tuple(
-                    tuple(
-                        d.version if d is not None else -1 for d in b.dicts
-                    )
-                    for b in round_batches
-                    if b is not None
-                ),
-            )
-            hit = self._round_cache.get(round_key)
-            if hit is not None:
-                # warm round: the padded shard stacks are already on
-                # their mesh devices (and the group ids this relation's
-                # encoder assigned are append-stable, so they replay
-                # exactly); only the state update kernel runs.
-                # Consecutive warm rounds of one shape class BUFFER and
-                # fold into one multi-round launch.
-                METRICS.add("mesh.round_cache_hits")
-                (_, put_cols, put_valids, aux, rows_dev, put_mask,
-                 put_ids, str_aux) = hit
-                needed = self._pick_capacity(group_cap)
-                if state is None:
-                    group_cap = needed
-                    state = self._init_stacked_state(group_cap)
-                elif needed > group_cap:
-                    flush_rounds()  # state is about to change shape
-                    state = self._grow_stacked_state(state, needed)
-                    group_cap = needed
-                entry = (put_cols, put_valids, aux, rows_dev, put_mask,
-                         put_ids, str_aux)
-                sig = (
-                    entry_signature((put_cols, put_valids, rows_dev,
-                                     put_mask, put_ids)),
-                    shared_signature((aux, str_aux)),
-                    group_cap,
-                )
-                if round_buf and (sig != round_sig
-                                  or len(round_buf) >= round_fuse_max):
-                    flush_rounds()
-                round_sig = sig
-                round_buf.append(entry)
-                continue
-            flush_rounds()  # cold round ahead: drain the warm buffer
-            views = [
-                None if b is None else self._device_view(b)
-                for b in round_batches
-            ]
-            # a validity plane ships only for columns where some shard
-            # actually carries nulls this round (None otherwise — the
-            # all-valid common case never moves or traces those bytes)
-            has_valid = [
-                any(v is not None and v.validity[c_i] is not None for v in views)
-                for c_i in range(len(sub_cols))
-            ]
-
-            col_shards: list[list[np.ndarray]] = [[] for _ in sub_cols]
-            valid_shards: list[list[np.ndarray]] = [[] for _ in sub_cols]
-            mask_shards: list[np.ndarray] = []
-            id_shards: list[np.ndarray] = []
-            rows_np = np.zeros((n,), np.int32)
-            live_batch = None
-
-            for s_i, (b, view) in enumerate(zip(round_batches, views)):
-                if b is None:
-                    for c_i, dt in enumerate(sub_dtypes):
-                        col_shards[c_i].append(stacker.fill(cap, dt))
-                        if has_valid[c_i]:
-                            valid_shards[c_i].append(stacker.fill(cap, bool, False))
-                    mask_shards.append(stacker.fill(cap, bool, False))
-                    id_shards.append(stacker.fill(cap, np.int32))
-                    continue
-                live_batch = b
-                rows_np[s_i] = b.num_rows
-                for c_i in range(len(sub_cols)):
-                    col_shards[c_i].append(stacker.pad(view.data[c_i], cap))
-                    if has_valid[c_i]:
-                        v = view.validity[c_i]
-                        valid_shards[c_i].append(
-                            stacker.fill(cap, bool, True)
-                            if v is None
-                            else stacker.pad(v, cap)
-                        )
-                # the host-evaluated predicate folds into the shard's
-                # mask plane on the host: the round's stacked copies are
-                # this relation's own (the round cache), not the batches'
-                mask = self._query_mask(b)
-                if view.mask is not None:
-                    up = np.asarray(view.mask)
-                    mask = up if mask is None else up & mask
-                mask_shards.append(
-                    stacker.fill(cap, bool, True)
-                    if mask is None
-                    else stacker.pad(mask, cap)
-                )
-                for idx in self.key_cols:
-                    if b.dicts[idx] is not None:
-                        self._key_dicts[idx] = b.dicts[idx]
-                if self.key_cols:
-                    key_cols = [np.asarray(b.data[i]) for i in self.key_cols]
-                    key_valids = [
-                        None if b.validity[i] is None else np.asarray(b.validity[i])
-                        for i in self.key_cols
-                    ]
-                    id_shards.append(
-                        stacker.pad(self.encoder.encode(key_cols, key_valids), cap)
-                    )
-                else:
-                    id_shards.append(stacker.fill(cap, np.int32))
-
+            if r.placed is None:
+                self._stage(r)  # no staging thread on this platform
+            METRICS.add("mesh.rounds")
+            shard_rows += [0 if b is None else b.num_rows for b in r.batches]
+            cols, valids, rows_dev, mask, ids = self._assemble(r, dtypes)
+            str_aux = r.str_aux
+            # capacity picked after the round's keys are encoded (the
+            # producer may be further ahead: a larger capacity holds
+            # every id of this round too)
             needed = self._pick_capacity(group_cap)
             if state is None:
                 group_cap = needed
                 state = self._init_stacked_state(group_cap)
             elif needed > group_cap:
+                flush_rounds()  # state is about to change shape
                 state = self._grow_stacked_state(state, needed)
                 group_cap = needed
-
-            # aux / rank tables derive from the (shared) dictionaries;
-            # compute after all shards' rows are encoded so versions are
-            # current
-            aux = (
-                compute_aux_values(self._aux_specs, live_batch, self._aux_cache)
-                if self._aux_specs
-                else []
+            sig = (
+                entry_signature((cols, valids, rows_dev, mask, ids)),
+                shared_signature((r.aux, str_aux)),
             )
-            str_aux = self._compute_str_aux(live_batch)
-            put_cols = tuple(stacker.put(s) for s in col_shards)
-            put_valids = tuple(
-                stacker.put(s) if has_valid[c_i] else None
-                for c_i, s in enumerate(valid_shards)
-            )
-            rows_dev = jnp.asarray(rows_np)
-            put_mask = stacker.put(mask_shards)
-            put_ids = stacker.put(id_shards)
-            if round_key in self._round_seen:
-                self._round_cache[round_key] = (
-                    tuple(round_batches), put_cols, put_valids, tuple(aux),
-                    rows_dev, put_mask, put_ids, str_aux,
-                )
-                # the admitted round's device stacks are now pinned by
-                # the cache: re-attribute them in the HBM ledger (and
-                # take them out of the leak sweep's transient set)
-                from datafusion_tpu.obs.device import LEDGER
-
-                LEDGER.retag(
-                    (put_cols, put_valids, put_mask, put_ids),
-                    "mesh.round_cache",
-                )
-                while len(self._round_cache) > self._round_cache_max:
-                    self._round_cache.popitem(last=False)
-            else:
-                self._round_seen[round_key] = True
-                while len(self._round_seen) > 4 * self._round_cache_max:
-                    self._round_seen.popitem(last=False)
-            with METRICS.timer("execute.partitioned_aggregate"), \
-                    op_timer(self):
-                state = device_call(
-                    self._stacked_jit,
-                    put_cols,
-                    put_valids,
-                    tuple(aux),
-                    rows_dev,
-                    put_mask,
-                    put_ids,
-                    state,
-                    str_aux,
-                    self._params,
-                    _tag="mesh.stacked",
-                )
+            if round_buf and (sig != round_sig
+                              or len(round_buf) >= _ROUND_FUSE_MAX):
+                flush_rounds()
+            round_sig = sig
+            round_buf.append((cols, valids, r.aux, rows_dev, mask, ids, str_aux))
+        flush_rounds()
+        METRICS.add("mesh.shards", self.n_shards)
+        METRICS.add("mesh.shard_rows.max", int(shard_rows.max()))
+        METRICS.add("mesh.shard_rows.total", int(shard_rows.sum()))
 
         if state is None:
             state = self._init_stacked_state(group_capacity(1))
@@ -1091,6 +1187,82 @@ class PartitionedContext(ExecutionContext):
                 [ParquetDataSource(p, schema, self.batch_size) for p in paths]
             ),
         )
+
+    def register_resident_parquet(
+        self, name: str, path: str, schema: Optional[Schema] = None
+    ) -> None:
+        """Register one Parquet file as a table that STAYS, sharded
+        over the mesh: the file is read once, row group g by the reader
+        of shard g mod n, the shards side by side on a thread each (one
+        dictionary set for all of them), each shard's rows are kept in memory as batches of `batch_size` rows
+        at one capacity (a `MemoryDataSource`: the SAME batch objects
+        for every query), and partition s is mesh device s's, as any
+        partitioned table's is.  A file with fewer row groups than
+        devices leaves shards empty.  Nothing is placed
+        here: the first query over the table ships each batch's columns
+        to its shard's device, where `batch.device_inputs` keeps them
+        on the batch, and every later query, whatever relation runs it,
+        ships its mask alone.  What a shard may come to hold there (its
+        columns' bytes) is asked of the ledger per device now, and a
+        table whose shard does not fit its chip is refused."""
+        from datafusion_tpu.exec.prefetch import staged_prefetch
+        from datafusion_tpu.io.readers import (
+            infer_parquet_schema,
+            parquet_row_groups,
+        )
+        from datafusion_tpu.obs.device import LEDGER
+
+        devices = list(self.mesh.devices.flat)
+        n = len(devices)
+        if schema is None:
+            schema = infer_parquet_schema(path)
+        n_groups = parquet_row_groups(path)
+        # the readers hand over `_READ_BATCHES` table batches at a time
+        # (a row group, if it is no longer): what a reader pays a batch
+        # (the chunk's dictionary merged, arrays made) it pays an eighth
+        # as often, and `_whole_batches` cuts the table's batches anyway
+        sources = [
+            ParquetDataSource(path, schema, _READ_BATCHES * self.batch_size,
+                              row_groups=list(range(s, n_groups, n)))
+            for s in range(n)
+        ]
+        # one dictionary set for all shards, grown under the
+        # dictionaries' own lock while the shards are read side by side
+        _share_dictionaries(sources)
+
+        def read(src):
+            # the reader parses on its IO thread (`io/io_thread.py`), a
+            # prefetch thread pulls ahead, this one re-cuts
+            return _whole_batches(
+                staged_prefetch(src.batches(), None,
+                                wait_timer="pipeline.scan_wait"),
+                src.schema, self.batch_size,
+            )
+
+        with ThreadPoolExecutor(n, thread_name_prefix="df-tpu-shard-read") as pool:
+            shards = list(pool.map(read, sources))
+        cap = max((b.capacity for bs in shards for b in bs), default=0)
+        parts = []
+        for s, (dev, batches) in enumerate(zip(devices, shards)):
+            batches = [b if b.capacity == cap else _padded(b, cap)
+                       for b in batches]
+            nbytes = sum(
+                int(a.nbytes) for b in batches
+                for a in (*b.data, *b.validity) if a is not None
+            )
+            if not LEDGER.fits(nbytes, dev):
+                raise ExecutionError(
+                    f"table {name!r}: shard {s} holds {nbytes} bytes of "
+                    f"columns and device {dev} has "
+                    f"{LEDGER.headroom(dev)} bytes free"
+                )
+            METRICS.add("mesh.resident.bytes", nbytes)
+            parts.append(MemoryDataSource(sources[0].schema, batches))
+        for p in parts[1:]:
+            # one table, one set of encoders: the ids a batch keeps on
+            # its device mean the same group on every shard
+            p._shared = parts[0]._shared
+        self.register_datasource(name, PartitionedDataSource(parts))
 
     def _execute_plan(self, plan: LogicalPlan) -> Relation:
         # wrap only the ROOT (execute recurses through self.execute for

@@ -171,7 +171,7 @@ class PlanFragment:
             m = meta["ParquetFile"]
             return ParquetDataSource(
                 m["filename"], Schema.from_json(m["schema"]), batch_size,
-                m.get("projection"),
+                m.get("projection"), m.get("row_groups"),
             )
         if "NdJsonFile" in meta:
             m = meta["NdJsonFile"]

@@ -112,11 +112,15 @@ def test_q3_stage(monkeypatch):
     assert out["q3_pinned"]["join.probe.window.payload"] == 0
 
 
-def test_mesh_stage_places_four_shards(resident_ctx, oracle):
+def test_mesh_stage_places_four_shards(oracle):
     # conftest gives 8 virtual CPU devices; the stage takes four
-    src = resident_ctx.datasources["lineitem"]
-    out = chip_smoke.stage_mesh(src, oracle.q1())
+    out = chip_smoke.stage_mesh(SF, oracle.q1(), batch_size=1 << 13)
     assert len(out["devices"]) == 4
+    assert out["first"]["h2d.resident_misses"] > 0
+    # a new relation: the table's batches held the copies
+    assert out["evidence"]["h2d.resident_misses"] == 0
+    assert out["evidence"]["h2d.resident_hits"] == \
+        out["first"]["h2d.resident_misses"]
 
 
 def test_wrong_answer_fails_the_stage(oracle):

@@ -86,19 +86,19 @@ class TestLedgerChurn:
         assert ledger.live_bytes() == x.nbytes
         assert list(ledger.owners()) == ["fragment.q1.replay"]
 
-    def test_retag_round_cache_owner(self, ledger):
-        # a mesh round admitted into the round cache stops being
-        # transient: retag moves it to the cache owner and out of the
-        # leak sweep's candidate set
+    def test_retag_moves_buffers_to_a_cache_owner(self, ledger):
+        # buffers a cache takes over stop being transient: retag moves
+        # them to the cache's owner and out of the leak sweep's
+        # candidate set
         import jax.numpy as jnp
 
         cols = (jnp.arange(512), jnp.arange(512, dtype=jnp.float32))
-        ledger.adopt(cols, owner="mesh.round", cached=False)
-        assert ledger.owners()["mesh.round"]["buffers"] == 2
-        ledger.retag(cols, "mesh.round_cache", cached=True)
+        ledger.adopt(cols, owner="scan", cached=False)
+        assert ledger.owners()["scan"]["buffers"] == 2
+        ledger.retag(cols, "pin.lineitem", cached=True)
         owners = ledger.owners()
-        assert "mesh.round" not in owners
-        assert owners["mesh.round_cache"]["buffers"] == 2
+        assert "scan" not in owners
+        assert owners["pin.lineitem"]["buffers"] == 2
         # cached entries never become leak candidates
         assert ledger.sweep(None, grace_s=0.0) == 0
         assert ledger.sweep(None, grace_s=0.0) == 0

@@ -1106,6 +1106,49 @@ class TestWirePolicy:
         assert np.array_equal(np.asarray(out2[1]).view(np.int64), col2b.view(np.int64))
         assert np.array_equal(np.asarray(out1[0]).view(np.int64), col1.view(np.int64))
 
+    @pytest.mark.parametrize("table", [
+        "quantities", "cents", "signs_and_nans", "full", "no_window"])
+    def test_dict_hint_finds_places_as_the_search_does(self, table):
+        """The hinted dict codec looks a row's place up through a 16-bit
+        window of its bit pattern where one tells the table's values
+        apart: the same places (wire bytes) as `searchsorted` gives, the
+        same refusal of a value the table lacks, and the search itself
+        where no window does."""
+        from datafusion_tpu.exec import batch as B
+
+        rng = np.random.default_rng(11)
+        values = {
+            "quantities": np.arange(1, 51, dtype=np.float64),
+            "cents": np.arange(0, 11) / 100.0,
+            "signs_and_nans": np.array(
+                [-0.0, 0.0, -1.5, 1.5, np.nan, -np.inf, np.inf, 1e-300, -2.5e200]),
+            "full": rng.standard_normal(B._DICT_MAX),
+            # 1.0, 1.0 with its lowest bit set, -1.0: bits 0 and 63 are
+            # never in one window
+            "no_window": (np.float64(1.0).view(np.int64) | np.array(
+                [0, 1, -(1 << 63)], np.int64)).view(np.float64),
+        }[table]
+        col = values[rng.integers(0, len(values), 4096)]
+        spec, wires = B._encode_wire(col)
+        assert spec == ("dict",)
+        hint = B._wire_hint_of(spec, wires)
+        assert (hint[2] is None) == (table == "no_window")
+        searched = B._encode_wire_hinted(col, (hint[0], hint[1], None))
+        windowed = B._encode_wire_hinted(col, hint)
+        for got, want in zip(windowed[1], searched[1]):
+            assert got.dtype == want.dtype
+            assert got.tobytes() == want.tobytes()
+        decoded = wires[1].view(np.int64)[windowed[1][0]]
+        assert np.array_equal(decoded, col.view(np.int64))
+        # a value the table lacks (it shares every window with one the
+        # table holds, or none): refused either way
+        other = col.copy()
+        other[7] = np.float64(12345.678)
+        assert B._encode_wire_hinted(other, hint) is None
+        near = col.view(np.int64).copy()
+        near[9] ^= 1 << 20
+        assert B._encode_wire_hinted(near.view(np.float64), hint) is None
+
     def test_wire_hint_miss_falls_back(self, monkeypatch):
         from datafusion_tpu.exec import batch as B
 
