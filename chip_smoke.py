@@ -26,7 +26,8 @@ process exits non-zero without printing a result:
           into groups on the device, `ORDER BY` an alias, `LIMIT 10`
 5. mesh   only with >= 4 TPU devices: Q1 over lineitem registered through
           `PartitionedContext.register_resident_parquet` on `make_mesh(4)`,
-          a new relation a pass; the second pass ships its masks alone
+          a new relation a pass; the second pass ships nothing but a
+          round's row counts (the predicate runs in the kernel)
 
 Each stage prints the evidence that the device did the work (launches,
 H2D bytes, kernel engagement).  Without a TPU
@@ -275,7 +276,29 @@ def stage_warm(ctx, oracle: Q1Oracle) -> dict:
             f"warm: {ev['kernel_cache.misses']} new kernels in warm passes")
     require(ev["device.h2d.transfers"] == 0,
             f"warm: {ev['device.h2d.transfers']} H2D transfers in warm passes")
-    return {"evidence": ev, "upload": ev_upload}
+    # a new relation a pass, as a client's `ctx.sql` makes one: over a
+    # table that stays the predicate is in the core, so it finds the
+    # column copies on the batches, looks its `cmp_table` up once a
+    # batch on the device and ships nothing
+    batches = sum(1 for _ in ctx.datasources["lineitem"].batches())
+    extra = ("h2d.resident_hits", "h2d.resident_misses", "expr.cmp_lookups")
+    fresh_before = _counts()
+    passes = 2
+    for i in range(passes):
+        fresh = ctx.sql(Q1)
+        require(fresh._host_pred_expr is None and fresh._core_pred is not None,
+                "warm: a resident table's predicate went to the host")
+        check_rows(collect(fresh).to_rows(), oracle.q1(),
+                   f"warm Q1, new relation {i}")
+    fresh_ev = evidence(fresh_before, _counts(), extra)
+    require(fresh_ev["device.h2d.transfers"] == 0
+            and fresh_ev["h2d.bytes"] == 0
+            and fresh_ev["h2d.resident_misses"] == 0
+            and fresh_ev["h2d.resident_hits"] == passes * batches
+            and fresh_ev["expr.cmp_lookups"] == passes * batches,
+            f"warm: {passes} new relations over {batches} resident batches "
+            f"moved or missed something: {fresh_ev}")
+    return {"evidence": ev, "upload": ev_upload, "new_relations": fresh_ev}
 
 
 def stage_serve(ctx, oracle: Q1Oracle, clients: int = 8,
@@ -648,7 +671,9 @@ def stage_mesh(sf, want_rows, n_devices: int = 4,
                batch_size: int = 1 << 19) -> dict:
     """Q1 over lineitem registered as a table that stays, its row groups
     dealt to `n_devices` devices: a new relation a pass, and the second
-    pass ships its masks alone."""
+    pass ships a round's row counts and the predicate's one table and
+    nothing else (the predicate is in the core: one `cmp_table` lookup
+    a round on every chip)."""
     from benchmarks.suite import Q1
     from datafusion_tpu.exec.materialize import collect
     from datafusion_tpu.io.readers import parquet_row_groups
@@ -665,7 +690,7 @@ def stage_mesh(sf, want_rows, n_devices: int = 4,
     require(sum(map(bool, batches)) == min(n_devices, parquet_row_groups(path)),
             f"mesh: the row groups were dealt as {batches}")
     extra = ("h2d.resident_hits", "h2d.resident_misses", "mesh.rounds",
-             "device.launches.mesh.combine")
+             "device.launches.mesh.combine", "expr.cmp_lookups")
     evs = []
     for i in range(2):
         before = _counts()
@@ -681,9 +706,13 @@ def stage_mesh(sf, want_rows, n_devices: int = 4,
             and ev["h2d.resident_misses"] == 0
             and ev["h2d.resident_hits"] == sum(batches),
             f"mesh: the second pass placed columns again: {first} then {ev}")
-    rows = sum(b.num_rows for p in shards for b in p.batches())
-    require(ev["h2d.bytes"] <= rows // 4 + 64 * sum(batches),
-            f"mesh: the second pass shipped more than its masks: {ev}")
+    # the row counts are a put a distinct round shape (and the dead
+    # rounds' zeros), the predicate's table one a query: `h2d.bytes`
+    # counts neither
+    require(ev["h2d.bytes"] == 0
+            and ev["device.h2d.transfers"] <= max(batches) + 2
+            and ev["expr.cmp_lookups"] == max(batches),
+            f"mesh: the second pass shipped more than its row counts: {ev}")
     # where the column copies the queries left on the batches really
     # sit: shard s's on mesh device s, and on no other
     placed = [sorted({str(d) for b in p.batches()

@@ -1436,27 +1436,33 @@ class AggregateRelation(Relation):
         from datafusion_tpu.exec.hostfn import host_evaluable
         from datafusion_tpu.exec.relation import _is_accelerator
 
-        # On accelerators a numpy-evaluable predicate runs on the host:
-        # its mask travels bit-packed, its input columns don't travel at
-        # all (the Q1 shipdate filter drops ~12 MB of dict codes per
-        # SF-1 scan to a 0.75 MB mask).  The predicate — literals and
-        # all — lives on THIS relation; the core is built as if there
-        # were no predicate, so every host-filtered query shape shares
-        # one device kernel regardless of literal values — and one set
-        # of device copies of the data columns: over a reusable source
-        # those are cached on the table's batches and do not travel
-        # again either, only this query's mask does (_device_inputs).
+        # Where the predicate is evaluated.  The host takes a numpy-
+        # evaluable predicate only where that saves a transfer: on an
+        # accelerator, over a source whose batches are streamed and die
+        # after the kernel read them.  There its input columns don't
+        # travel at all and its mask rides bit-packed with the columns
+        # the kernel does read (the Q1 shipdate filter drops ~12 MB of
+        # dict codes per SF-1 scan to a 0.75 MB mask), and the core is
+        # built as if there were no predicate, so every host-filtered
+        # query shape shares one device kernel whatever its literals.
+        # Over a source that hands the SAME batches to every query
+        # (`_keeps_batches`) there is nothing to save: the predicate's
+        # columns are shipped once with the others and found on the
+        # batch by every later query (`device_inputs`), while a host
+        # mask would be evaluated, put and decoded a batch a query for
+        # ever.  So there, as for a child whose batches are born on the
+        # device (a join's probe output: the host could only read them
+        # by pulling every batch back) and under `_FORCE_CORE_PRED`
+        # (serving), the predicate goes to the core and its literals
+        # travel as parameter slots and aux tables.
         # No function metas reach this ctor, so predicates containing
         # UDFs conservatively stay on device ({} finds no host_fn).
-        # A child whose batches are born on the device (a join's probe
-        # output) keeps its predicate in the core: the columns are
-        # there already, and the host could only read them by pulling
-        # every batch back.
         host_pred = (
             predicate is not None
             and _is_accelerator(device)
             and not _FORCE_CORE_PRED.get()
             and not getattr(child, "device_batches", False)
+            and not self._keeps_batches()
             and host_evaluable(predicate, {}, child.schema)
         )
         self._host_pred_expr = predicate if host_pred else None
@@ -1500,6 +1506,21 @@ class AggregateRelation(Relation):
         from datafusion_tpu.analysis import lockcheck
 
         self._ids_lock = lockcheck.make_lock("exec.aggregate_ids")
+
+    def _keeps_batches(self) -> bool:
+        """Whether every scan of the input (`op_children`: the child,
+        the mesh relation's one a partition) hands out the SAME batch
+        objects (`datasource.reusable_batches`: an in-memory table, a
+        resident pin and their projections, the ingest tables), so
+        what `device_inputs` and `_group_ids` leave on a batch is found
+        again by the next query.  Read from the scanned sources and
+        nothing else; a relation that is no plain scan (a pipeline, a
+        sort, a host-probed join) makes new batches a query."""
+        scans = self.op_children()
+        return bool(scans) and all(
+            getattr(getattr(c, "datasource", None), "reusable_batches", False)
+            for c in scans
+        )
 
     # -- delegates into the shared core (the partitioned subclass and
     # the multi-host coordinator call these by name) --
@@ -1673,12 +1694,13 @@ class AggregateRelation(Relation):
         append-only encoder shared by every query over these GROUP BY
         columns owns the per-batch group-id caches, so ids encoded (and
         uploaded) by ANY earlier query over the table replay for this
-        one.  Ids are encoded over all rows and do not depend on a
-        mask, so a host predicate is no obstacle; groups with no
-        surviving row stay out of the answer through the live counts."""
+        one.  Ids are encoded over all rows and do not depend on the
+        predicate (which such a source leaves in the core); groups with
+        no surviving row stay out of the answer through the live
+        counts."""
         ds = getattr(self.child, "datasource", None)
         owner = getattr(ds, "shared_state_for", None)
-        if owner is None or not getattr(ds, "reusable_batches", False):
+        if owner is None or not self._keeps_batches():
             return
         entry = owner(self.core)
         self.encoder = entry["encoder"]
@@ -1846,8 +1868,9 @@ class AggregateRelation(Relation):
 
     def _device_view(self, batch: RecordBatch, core=None) -> RecordBatch:
         """The batch's columns as the device kernel sees them: only
-        `used_cols` (group keys travel as dense ids, host-predicate
-        inputs not at all).  The view says nothing of any query's
+        `used_cols` (group keys travel as dense ids, the inputs of a
+        host-evaluated predicate not at all, those of a predicate in
+        the core like any other column).  The view says nothing of any query's
         literals: it is the batch itself or the `subset_view` cached on
         it, the SAME object for every relation over this batch, so the
         device copies `device_inputs` caches on it belong to the
@@ -1855,8 +1878,9 @@ class AggregateRelation(Relation):
         or concurrent query whatever its predicate.  A long-lived batch
         keeps one view (and its copies) per distinct used-column set —
         bounded by query-shape diversity; pin eviction clears the whole
-        cache when HBM needs the room.  The host-evaluated predicate is
-        not in the view: `_query_mask` / `_device_inputs`."""
+        cache when HBM needs the room.  A streamed scan's host-
+        evaluated predicate is not in the view: `_query_mask` /
+        `_device_inputs`."""
         from datafusion_tpu.exec.batch import subset_view
 
         core = self.core if core is None else core
@@ -1864,7 +1888,8 @@ class AggregateRelation(Relation):
 
     def _query_mask(self, batch: RecordBatch) -> Optional[np.ndarray]:
         """THIS query's host-evaluated predicate over one batch, as a
-        numpy bool array (None without one).  It carries the query's
+        numpy bool array (None without one: always over a source that
+        keeps its batches, `_keeps_batches`).  It carries the query's
         literals, so it belongs to the relation, never to the batch."""
         if self._host_pred_expr is None:
             return None
@@ -1875,17 +1900,18 @@ class AggregateRelation(Relation):
     def _device_inputs(self, batch: RecordBatch, core):
         """(data, validity, mask) on the device for one batch of this
         query.  Data and validity come from the literal-independent
-        `_device_view` (shipped once per long-lived batch); this
-        query's mask is the only thing that travels per query, and
-        `device_inputs` sends it alone or with the columns by what it
-        finds on the batch.  The result sits in ONE slot on the batch,
-        pinned by relation and core (the mask carries this query's
-        literals), so the consumer re-reads what the staging thread
-        placed and a long-lived batch holds one mask, not one per query
-        ever run; another relation's staging overwrites the slot and
-        this one then ships its mask again — correct, and rare.  The
-        pin is a weak reference: a resident batch must not keep every
-        last relation (and, through its scan, itself) alive."""
+        `_device_view`: shipped once per long-lived batch, after which
+        a query over it ships nothing (its predicate is in the core);
+        a streamed batch ships a query, its host-evaluated mask riding
+        in the columns' one `put_compressed` call.  The result sits in
+        ONE slot on the batch, pinned by relation and core (a streamed
+        batch's mask carries this query's literals), so the consumer
+        re-reads what the staging thread placed and a long-lived batch
+        holds one entry, not one per query ever run; another
+        relation's staging overwrites the slot and this one then asks
+        `device_inputs` again, which finds the copies.  The pin is a
+        weak reference: a resident batch must not keep every last
+        relation (and, through its scan, itself) alive."""
         from datafusion_tpu.exec.batch import device_inputs
 
         slot = batch.cache.get("agg_inputs")

@@ -981,23 +981,22 @@ def device_inputs(batch: RecordBatch, device=None, hints=None,
     and its copies die with it after the kernel consumed them.
 
     `query_mask` (a host bool array: one query's host-evaluated
-    predicate) belongs to the CALLER and is never cached here.  What
-    this function observes decides how it travels: where the column
-    copies are already on the batch it ships alone, bit-packed; where
-    they are not it rides in the columns' one `put_compressed` call
-    (one decode launch per batch either way).  The returned mask is
-    the query's AND the batch's own, combined on the device."""
+    predicate) belongs to the CALLER and is never cached here.  Only
+    the aggregate over a streamed scan hands one over (over a source
+    that keeps its batches its predicate is in the core:
+    `AggregateRelation._keeps_batches`), so a mask always rides in the
+    columns' one `put_compressed` call, one decode launch a batch.  A
+    batch that holds copies all the same (a pin that took hold between
+    the relation's construction and its scan) ships them again with
+    the mask: rare, and the same answer.  The returned mask is the
+    query's AND the batch's own, combined on the device."""
     from datafusion_tpu.utils.metrics import METRICS
 
     key = ("device", None if device is None else repr(device))
     hit = batch.cache.get(key)
-    if hit is not None:
+    if hit is not None and query_mask is None:
         METRICS.add("h2d.resident_hits")
-        if query_mask is None:
-            return hit
-        data, validity, mask = hit
-        qm = put_compressed([query_mask], device, owner="query.mask")[0]
-        return data, validity, _and_masks(qm, mask)
+        return hit
 
     # layout: data columns, then the present validity arrays, then the
     # batch's mask, then the query's

@@ -469,6 +469,10 @@ class PipelineRelation(Relation):
         # under host_scalar — the whole batch often never touches the
         # device.  Predicates containing host-only UDFs keep going to
         # the core so it raises its NotSupportedError contract.
+        # Unlike the aggregate's, this rule does not ask whether the
+        # source keeps its batches: the output returns to the host row
+        # for row, so a device predicate would ship columns only to
+        # pull a mask back.
         from datafusion_tpu.exec.aggregate import _FORCE_CORE_PRED
         from datafusion_tpu.exec.hostfn import contains_host_fn, host_evaluable
 

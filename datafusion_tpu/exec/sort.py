@@ -713,7 +713,9 @@ class SortRelation(Relation):
         """This query's fused predicate over one batch as a numpy bool
         mask (cached on the batch, pinned by relation — the predicate
         carries per-query literals).  Predicates reach here only when
-        host-evaluable (exec/fused.rewrite_sort's condition)."""
+        host-evaluable (exec/fused.rewrite_sort's condition); it stays
+        on the host over a resident table too (unlike the aggregate's):
+        the sorted rows return to the host, which holds the columns."""
         hit = batch.cache.get("sort_pred_mask")
         if hit is not None and hit[0] is self:
             return hit[1]

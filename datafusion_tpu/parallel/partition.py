@@ -10,7 +10,8 @@ combining them — `README.md:33-35`, `physicalplan.rs`,
 - each round, every shard's next batch goes to its own mesh device
   the way a batch goes to the one device of `ExecutionContext`
   (`batch.device_inputs`: the column copies stay on the batch, a
-  resident table's query ships its mask alone); the per-device arrays
+  resident table's query ships nothing: its predicate runs in the
+  kernel); the per-device arrays
   are assembled, without a copy, into one mesh-sharded array a column,
   and one `shard_map`-ped jitted kernel runs the *same* per-shard
   filter+aggregate update in parallel across devices (partial
@@ -762,13 +763,20 @@ class PartitionedAggregateRelation(AggregateRelation):
     per-shard body of a `shard_map` and the single-device scan loop's
     parts around it: a round is staged on `staged_pipeline`'s producer
     (each shard's batch through `batch.device_inputs` and `_group_ids`
-    onto that shard's device, the host predicate's mask bit-packed
-    through `put_compressed`), the consumer assembles the staged
+    onto that shard's device), the consumer assembles the staged
     per-device arrays into mesh-sharded ones and folds rounds into
     launches; adds the collective final combine.  Residency is the
     batch's (`device_inputs`' cache, the group-id slot): a table whose
     shards hand out the same batches to every query
     (`register_resident_parquet`) ships its columns once.
+
+    Where the predicate runs is `AggregateRelation`'s rule, read from
+    every partition's source: over shards that all keep their batches
+    it is in the core (a round's staging finds the copies and ships
+    nothing, the one `cmp_table` of the shared dictionaries serves
+    every chip); over streamed partitions, and over a mesh whose
+    shards disagree, the host evaluates it a shard batch and its mask
+    rides with that batch's columns.
     """
 
     def __init__(
@@ -781,17 +789,18 @@ class PartitionedAggregateRelation(AggregateRelation):
         predicate: Optional[Expr] = None,
         functions=None,
     ):
+        self.children = children  # the base's ctor asks every partition's source
         super().__init__(
             children[0], group_expr, aggr_expr, out_schema,
             predicate=predicate, functions=functions,
         )
-        self.children = children
         self.mesh = mesh
         self._devices = list(mesh.devices.flat)
         self.n_shards = len(self._devices)
         self._sharding = NamedSharding(mesh, P(MESH_AXIS))
         self._init_stacked_cache: dict = {}
         self._rows_cache: dict = {}
+        self._aux_on_mesh: dict = {}
         # how `_stage` goes over a round's shards: one after the other,
         # or side by side where `accumulate` runs it under a producer
         self._stage_map = map
@@ -876,10 +885,13 @@ class PartitionedAggregateRelation(AggregateRelation):
     def _stage(self, r: _Round) -> None:
         """The host's part of a round (the producer's, where there is
         one): per shard batch its group ids (encoded and placed where
-        the key set is new to the batch), this query's host predicate,
-        and `device_inputs` onto the shard's device, which ships the
-        columns where the batch does not hold them there and the mask
-        bit-packed, alone, where it does.  Under the producer the
+        the key set is new to the batch), this query's host predicate
+        where the partitions are streamed, and `device_inputs` onto the
+        shard's device, which ships the columns (that mask with them)
+        where the batch does not hold them there and nothing where it
+        does; then the round's aux tables (a predicate in the core
+        reads its `cmp_table` from them: one for all shards, the
+        dictionaries are one set).  Under the producer the
         round's shards are staged side by side, a thread each
         (`_stagers`): their numpy passes and puts release the GIL, and
         each put goes down its own chip's link."""
@@ -898,10 +910,27 @@ class PartitionedAggregateRelation(AggregateRelation):
             # computed after all shards' rows are encoded so versions
             # are current
             r.aux = tuple(
+                self._replicated(a) for a in
                 compute_aux_values(self._aux_specs, live, self._aux_cache)
-                if self._aux_specs else ()
-            )
+            ) if self._aux_specs else ()
             r.str_aux = self._compute_str_aux(live)
+
+    def _replicated(self, table):
+        """A round's aux table on every device of the mesh, put once a
+        query a distinct table (the shared `_aux_cache` hands every
+        round the same numpy object until a dictionary grows).  Left as
+        numpy it would travel to each chip again with every round slot
+        of every launch: 32 x 4 puts a folded launch (four chips,
+        PR 36: 14.8 ms a launch that way, 2.3 ms this way)."""
+        hit = self._aux_on_mesh.get(id(table))
+        if hit is None or hit[0] is not table:
+            from datafusion_tpu.obs.device import LEDGER
+
+            hit = self._aux_on_mesh[id(table)] = (table, LEDGER.put(
+                np.asarray(table), NamedSharding(self.mesh, P()),
+                owner="mesh.aux", cached=False,
+            ))
+        return hit[1]
 
     def _stage_shard(self, dev, b: Optional[RecordBatch]):
         """(cols, valids, mask, ids) of one shard's batch on `dev`;
@@ -1202,7 +1231,8 @@ class PartitionedContext(ExecutionContext):
         here: the first query over the table ships each batch's columns
         to its shard's device, where `batch.device_inputs` keeps them
         on the batch, and every later query, whatever relation runs it,
-        ships its mask alone.  What a shard may come to hold there (its
+        finds them there and ships nothing (its predicate is in the
+        core, over the copies).  What a shard may come to hold there (its
         columns' bytes) is asked of the ledger per device now, and a
         table whose shard does not fit its chip is refused."""
         from datafusion_tpu.exec.prefetch import staged_prefetch

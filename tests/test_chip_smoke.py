@@ -68,6 +68,10 @@ def test_warm_stage_is_compile_and_transfer_free(resident_ctx, oracle):
     out = chip_smoke.stage_warm(resident_ctx, oracle)
     assert out["evidence"]["kernel_cache.misses"] == 0
     assert out["evidence"]["device.h2d.transfers"] == 0
+    # two new relations: every batch found twice, looked up twice
+    fresh = out["new_relations"]
+    assert fresh["h2d.bytes"] == 0 and fresh["device.h2d.transfers"] == 0
+    assert fresh["h2d.resident_hits"] == fresh["expr.cmp_lookups"] > 0
 
 
 def test_serve_stage_megabatches(resident_ctx, oracle):
@@ -121,6 +125,9 @@ def test_mesh_stage_places_four_shards(oracle):
     assert out["evidence"]["h2d.resident_misses"] == 0
     assert out["evidence"]["h2d.resident_hits"] == \
         out["first"]["h2d.resident_misses"]
+    assert out["evidence"]["h2d.bytes"] == 0
+    assert out["evidence"]["expr.cmp_lookups"] == \
+        out["evidence"]["mesh.rounds"]
 
 
 def test_wrong_answer_fails_the_stage(oracle):

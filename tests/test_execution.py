@@ -1420,13 +1420,29 @@ class TestAggregateOverStreamedBatches:
         _approx_rows(got, want)
 
 
-class TestResidentTableShipsOnlyItsMask:
+@pytest.fixture
+def accel(monkeypatch):
+    """The accelerator's lowering on the CPU (as TestHostRouting forces
+    it) with the compressed wire (as TestWirePolicy does), and a kernel
+    registry of its own: cores built in this mode stay out of the
+    other tests' way."""
+    import datafusion_tpu.exec.kernels as kernels
+    import datafusion_tpu.exec.relation as relation
+
+    monkeypatch.setattr(relation, "_is_accelerator", lambda device: True)
+    monkeypatch.setenv("DATAFUSION_TPU_WIRE", "always")
+    monkeypatch.setattr(kernels, "_REGISTRY", type(kernels._REGISTRY)())
+
+
+class TestResidentTableShipsNothingAgain:
     """The `ctx.sql` door over a reusable source (the twin of
     tests/test_serve.py::test_warm_pinned_table_skips_h2d_entirely):
     device copies of columns and group ids are keyed by the table's
-    long-lived batch, so after the first query only a host-evaluated
-    predicate's bit-packed mask travels.  The accelerator lowering is
-    forced as in TestHostRouting, the wire as in TestWirePolicy."""
+    long-lived batch and the aggregate's predicate stays in the core
+    over them, so after the first query nothing travels; over a
+    streamed scan the host evaluates the predicate and its bit-packed
+    mask rides with the columns.  The accelerator lowering is forced
+    as in TestHostRouting, the wire as in TestWirePolicy."""
 
     ROWS, BATCHES = 2048, 4
     MASK_BYTES = ROWS // 8 * BATCHES  # one packed mask a batch
@@ -1437,19 +1453,6 @@ class TestResidentTableShipsOnlyItsMask:
     # the later two empty the ('N', 'F') group, whose rows all shipped
     # in August 1998
     LITERALS = ("1998-09-02", "1998-06-15", "1997-01-01")
-
-    @pytest.fixture
-    def accel(self, monkeypatch):
-        import datafusion_tpu.exec.kernels as kernels
-        import datafusion_tpu.exec.relation as relation
-
-        monkeypatch.setattr(relation, "_is_accelerator", lambda device: True)
-        monkeypatch.setenv("DATAFUSION_TPU_WIRE", "always")
-        saved = dict(kernels._REGISTRY)
-        kernels._REGISTRY.clear()
-        yield
-        kernels._REGISTRY.clear()
-        kernels._REGISTRY.update(saved)
 
     @classmethod
     def _batches(cls):
@@ -1545,37 +1548,79 @@ class TestResidentTableShipsOnlyItsMask:
                     assert va == vb
 
     @pytest.mark.parametrize("predicate", [True, False])
-    def test_later_queries_ship_the_mask_alone(self, accel, predicate):
+    def test_later_queries_ship_nothing(self, accel, predicate):
         from datafusion_tpu.exec.aggregate import AggregateRelation
+        from datafusion_tpu.utils.metrics import METRICS
 
         literals = self.LITERALS if predicate else (None,) * 3
         c = self._ctx()
         rel = c.sql(self._sql(literals[0]))
         assert isinstance(rel, AggregateRelation)
-        assert (rel._host_pred_expr is not None) == predicate
+        # the table keeps its batches: the predicate is the core's
+        assert rel._host_pred_expr is None
+        assert (rel._core_pred is not None) == predicate
+        names = ("device.h2d.transfers", "expr.cmp_lookups")
         moved = []
         for i, lit in enumerate(literals):
+            before = [METRICS.counts.get(n, 0) for n in names]
+            host_s = METRICS.timings.get("host.predicate", 0.0)
             rows, nbytes, hits, misses = self._run(c, self._sql(lit))
-            moved.append(nbytes)
+            puts, lookups = (METRICS.counts.get(n, 0) - b
+                             for n, b in zip(names, before))
+            moved.append((nbytes, puts))
             # one count a batch a query: found on the batch, or shipped
             assert (hits, misses) == ((0, self.BATCHES) if i == 0
                                       else (self.BATCHES, 0))
+            # one device lookup a batch, and the host evaluates nothing
+            assert lookups == (self.BATCHES if predicate else 0)
+            assert METRICS.timings.get("host.predicate", 0.0) == host_s
             # the unshared path: a table nobody has queried before
             self._same(rows, self._run(self._ctx(), self._sql(lit))[0])
             if lit == self.LITERALS[-1]:
                 assert ("N", "F") not in {r[:2] for r in rows}
                 assert len(rows) == 5
-        assert moved[0] > 4 * self.MASK_BYTES  # columns and the mask
-        assert moved[1:] == [self.MASK_BYTES if predicate else 0] * 2
+        assert moved[0][0] > 4 * self.MASK_BYTES and moved[0][1] > 0
+        # another literal reads the same copies: one view a used-column
+        # set on the table's batch
+        assert moved[1:] == [(0, 0)] * 2
+        for b in c.datasources["t"].batches():
+            assert sum(1 for k in b.cache if k[0] == "agg_subset") <= 1
 
-    def test_answers_match_the_predicate_in_the_core(self, accel):
+    @pytest.mark.parametrize("predicate", [True, False])
+    def test_a_streamed_scan_ships_its_mask_with_the_columns(
+            self, accel, predicate):
+        from datafusion_tpu.utils.metrics import METRICS
+
+        literals = self.LITERALS if predicate else (None,) * 3
+        c = self._ctx(reusable=False)
+        rel = c.sql(self._sql(literals[0]))
+        # batches that die with the scan: the host takes the predicate
+        assert (rel._host_pred_expr is not None) == predicate
+        assert rel._core_pred is None
+        moved = []
+        for lit in literals:
+            lookups = METRICS.counts.get("expr.cmp_lookups", 0)
+            rows, nbytes, hits, misses = self._run(c, self._sql(lit))
+            moved.append(nbytes)
+            assert (hits, misses) == (0, self.BATCHES)
+            assert METRICS.counts.get("expr.cmp_lookups", 0) == lookups
+            self._same(rows, self._run(self._ctx(), self._sql(lit))[0])
+        # every query ships its columns anew, and the mask with them
+        assert min(moved) > 4 * self.MASK_BYTES
+        assert moved[1:] == [moved[0]] * 2
+
+    def test_answers_match_wherever_the_predicate_runs(self, accel):
         from datafusion_tpu.exec.aggregate import force_core_predicate
 
-        shared, plain = self._ctx(), self._ctx()
+        resident, streamed, forced = (
+            self._ctx(), self._ctx(reusable=False), self._ctx(reusable=False))
         for lit in self.LITERALS:
             with force_core_predicate():
-                want = self._run(plain, self._sql(lit))[0]
-            self._same(self._run(shared, self._sql(lit))[0], want)
+                rel = forced.sql(self._sql(lit))
+                assert rel._host_pred_expr is None and rel._core_pred is not None
+                want = self._run(forced, self._sql(lit))[0]
+            self._same(self._run(resident, self._sql(lit))[0], want)
+            self._same(self._run(streamed, self._sql(lit))[0], want)
 
     def test_projected_query_ships_nothing_the_second_time(self, accel):
         c = self._ctx()
@@ -1622,17 +1667,21 @@ class TestResidentTableShipsOnlyItsMask:
 
         monkeypatch.setattr(batch_mod, "put_compressed", counting)
         c = self._ctx(reusable=reusable)
-        # the used columns, disc's validity, the mask
-        width = len(c.sql(self._sql(self.LITERALS[0])).core.used_cols) + 1 + 1
+        # the used columns and disc's validity: the ship date's codes
+        # where the table stays (the predicate is the core's), the
+        # host's mask in their place where it is streamed
+        used = c.sql(self._sql(self.LITERALS[0])).core.used_cols
+        assert (6 in used) == reusable  # shipdate is the table's column 6
+        width = len(used) + 1 + (0 if reusable else 1)
         for i, lit in enumerate(self.LITERALS):
             del calls[:]
             _, _, hits, misses = self._run(c, self._sql(lit))
             if reusable and i > 0:
-                # the copies are on the batch: the mask travels alone
-                assert calls == [1] * self.BATCHES
+                # the copies are on the batch: nothing travels
+                assert calls == []
                 assert (hits, misses) == (self.BATCHES, 0)
             else:
-                # columns and mask in ONE call, one decode launch
+                # columns (and mask) in ONE call, one decode launch
                 assert calls == [width] * self.BATCHES
                 assert (hits, misses) == (0, self.BATCHES)
 
@@ -1748,3 +1797,193 @@ class TestResidentTableShipsOnlyItsMask:
                 self._same(rows, want[lit])
         assert len(want[self.LITERALS[0]]) == 6
         assert len(want[self.LITERALS[2]]) == 5
+
+
+class TestWhereTheAggregatesPredicateRuns:
+    """The one rule (`AggregateRelation._keeps_batches`): on an
+    accelerator the host takes the aggregate's predicate over a
+    streamed scan, whose batches die after the kernel read them; over
+    a source that hands every query the same batches it stays in the
+    core.  Read from the child's source and nothing else; either way
+    the answer is the oracle's."""
+
+    ROWS, BATCH = 600, 64
+    SCHEMA = Schema([
+        Field("k", DataType.UTF8, False),
+        Field("d", DataType.UTF8, True),
+        Field("n", DataType.INT64, True),
+        Field("v", DataType.FLOAT64, False),
+    ])
+    SOURCES = {  # name -> does the source keep its batches
+        "memory": True, "pinned": True, "unpinned": False,
+        "parquet": False, "csv": False,
+    }
+    PREDICATES = {
+        # a string range compare over a column with NULLs
+        "string": ("d <= '1995-06-15'",
+                   lambda d, n: d is not None and d <= "1995-06-15"),
+        "numeric": ("n > 5", lambda d, n: n is not None and n > 5),
+        # TRUE OR NULL is TRUE, FALSE AND NULL is FALSE
+        "or": ("d > '1997-01-01' OR n < 3",
+               lambda d, n: (d is not None and d > "1997-01-01")
+               or (n is not None and n < 3)),
+        "is_null": ("d IS NULL AND n >= 0",
+                    lambda d, n: d is None and n is not None and n >= 0),
+        "keeps_no_row": ("d > '2999-01-01'", lambda d, n: False),
+    }
+
+    @pytest.fixture(scope="class")
+    def files(self, tmp_path_factory):
+        """(rows, Parquet file, CSV file): dates ascend with the row
+        number, so every batch brings the dictionary new strings."""
+        import pyarrow as pa
+        import pyarrow.csv as pacsv
+        import pyarrow.parquet as pq
+
+        rng = np.random.default_rng(36)
+        n = self.ROWS
+        k = rng.choice(["a", "b", "c"], n).tolist()
+        day = np.sort(rng.integers(0, 2500, n))
+        d = [None if rng.random() < 0.15 else
+             str(np.datetime64("1992-01-01") + int(x)) for x in day]
+        num = [None if rng.random() < 0.2 else int(x)
+               for x in rng.integers(0, 10, n)]
+        v = np.round(rng.uniform(0, 100, n), 2).tolist()
+        table = pa.table({
+            "k": pa.array(k, pa.string()), "d": pa.array(d, pa.string()),
+            "n": pa.array(num, pa.int64()), "v": pa.array(v, pa.float64())})
+        base = tmp_path_factory.mktemp("rule")
+        parquet, csv = str(base / "t.parquet"), str(base / "t.csv")
+        pq.write_table(table, parquet, row_group_size=200)
+        pacsv.write_csv(table, csv)
+        return list(zip(k, d, num, v)), parquet, csv
+
+    def _ctx(self, files, source):
+        from datafusion_tpu.exec.datasource import MemoryDataSource
+        from datafusion_tpu.serve import PinnedSource
+
+        _, parquet, csv = files
+        c = ExecutionContext(batch_size=self.BATCH, result_cache=False)
+        if source == "csv":
+            c.register_csv("t", csv, self.SCHEMA, has_header=True)
+            return c
+        c.register_parquet("t", parquet, self.SCHEMA)
+        scan = c.datasources["t"]
+        if source == "memory":
+            c.register_datasource(
+                "t", MemoryDataSource(scan.schema, list(scan.batches())))
+        elif source in ("pinned", "unpinned"):
+            pin = PinnedSource(scan, f"rule_{id(c)}")
+            if source == "pinned":
+                assert pin.ensure()
+            c.register_datasource("t", pin)
+        return c
+
+    @pytest.mark.parametrize("source", list(SOURCES))
+    def test_the_rule_reads_the_source(self, accel, files, source):
+        from datafusion_tpu.exec.aggregate import (
+            AggregateRelation,
+            force_core_predicate,
+        )
+        from datafusion_tpu.obs.device import LEDGER
+
+        c = self._ctx(files, source)
+        try:
+            grouped = c.sql(
+                "SELECT k, COUNT(1) FROM t WHERE n > 5 GROUP BY k")
+            projected = c.sql("SELECT SUM(v) FROM t WHERE d <= '1995-06-15'")
+            for rel in (grouped, projected):
+                assert isinstance(rel, AggregateRelation)
+                assert rel._keeps_batches() == self.SOURCES[source]
+                in_core = rel._core_pred is not None
+                assert in_core == self.SOURCES[source]
+                assert (rel._host_pred_expr is None) == in_core
+            # nothing to evaluate, nothing to place
+            bare = c.sql("SELECT k, COUNT(1) FROM t GROUP BY k")
+            assert bare._host_pred_expr is None and bare._core_pred is None
+            # the serving scope keeps the core whatever the source
+            with force_core_predicate():
+                served = c.sql("SELECT SUM(v) FROM t WHERE n > 5")
+            assert served._host_pred_expr is None
+            assert served._core_pred is not None
+        finally:
+            if source == "pinned":
+                LEDGER.unpin(c.datasources["t"].fingerprint)
+
+    def test_the_cpu_keeps_every_predicate_in_the_core(self, files):
+        for source in ("memory", "parquet"):
+            rel = self._ctx(files, source).sql(
+                "SELECT SUM(v) FROM t WHERE n > 5")
+            assert rel._host_pred_expr is None and rel._core_pred is not None
+
+    def test_a_child_that_is_no_scan_streams(self, accel, files):
+        """A limit (as a pipeline, a sort, a host-probed join) makes
+        new batches a query, whatever table feeds it."""
+        from datafusion_tpu.exec.aggregate import AggregateRelation
+        from datafusion_tpu.exec.sort import LimitRelation
+        from datafusion_tpu.plan.logical import Aggregate, Selection
+        from datafusion_tpu.sql.parser import parse_sql
+
+        c = self._ctx(files, "memory")
+        plan = c._plan(parse_sql("SELECT COUNT(1) FROM t WHERE n > 5"))
+        assert isinstance(plan, Aggregate)
+        assert isinstance(plan.input, Selection)
+        scan = c.execute(plan.input.input)
+        assert scan.datasource.reusable_batches
+
+        def over(child):
+            return AggregateRelation(
+                child, plan.group_expr, plan.aggr_expr, plan.schema,
+                predicate=plan.input.expr)
+
+        assert over(scan)._core_pred is not None
+        rel = over(LimitRelation(scan, 10 ** 6, scan.schema))
+        assert not rel._keeps_batches()
+        assert rel._host_pred_expr is not None and rel._core_pred is None
+
+    @pytest.mark.parametrize("source", list(SOURCES))
+    @pytest.mark.parametrize("predicate", list(PREDICATES))
+    def test_the_answer_is_the_oracles(self, accel, files, source, predicate):
+        from datafusion_tpu.exec.materialize import collect
+        from datafusion_tpu.obs.device import LEDGER
+
+        rows = files[0]
+        where, keep = self.PREDICATES[predicate]
+        kept = [r for r in rows if keep(r[1], r[2])]
+        c = self._ctx(files, source)
+        try:
+            for _ in range(2):  # the second finds what the first left
+                got = sorted(collect(c.sql(
+                    "SELECT k, COUNT(1), COUNT(n), SUM(v), MIN(d), MAX(n) "
+                    f"FROM t WHERE {where} GROUP BY k")).to_rows())
+                want = []
+                for key in sorted({r[0] for r in kept}):
+                    g = [r for r in kept if r[0] == key]
+                    ds = [r[1] for r in g if r[1] is not None]
+                    ns = [r[2] for r in g if r[2] is not None]
+                    want.append((key, len(g), len(ns), sum(r[3] for r in g),
+                                 min(ds) if ds else None,
+                                 max(ns) if ns else None))
+                assert [r[:3] + r[4:] for r in got] == [
+                    r[:3] + r[4:] for r in want]
+                np.testing.assert_allclose(
+                    [r[3] for r in got], [r[3] for r in want], rtol=1e-12)
+                (count, total), = collect(c.sql(
+                    f"SELECT COUNT(1), SUM(v) FROM t WHERE {where}")).to_rows()
+                assert count == len(kept)
+                if kept:
+                    np.testing.assert_allclose(
+                        total, sum(r[3] for r in kept), rtol=1e-12)
+                else:
+                    assert total is None
+        finally:
+            if source == "pinned":
+                LEDGER.unpin(c.datasources["t"].fingerprint)
+
+    def test_the_dictionary_grows_between_batches(self, files):
+        c = self._ctx(files, "memory")
+        sizes = []
+        for b in c.datasources["t"].batches():
+            codes = np.asarray(b.data[1])[:b.num_rows]
+            sizes.append(int(codes.max()) + 1)
+        assert sizes == sorted(sizes) and sizes[-1] > 2 * sizes[0]

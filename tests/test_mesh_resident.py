@@ -1,8 +1,9 @@
 """A table that stays, sharded over a mesh
 (`PartitionedContext.register_resident_parquet`): the file's row groups
 dealt to the mesh's devices, each shard's column copies kept on its
-batches on that device, a query shipping its mask alone; the round loop
-made of the single-device scan loop's parts.  On the suite's eight
+batches on that device, a later query shipping nothing (its predicate
+is in the core, over the copies); the round loop made of the
+single-device scan loop's parts.  On the suite's eight
 virtual CPU devices, against the data set's own numpy oracle."""
 
 import numpy as np
@@ -52,8 +53,9 @@ def delta_of(before: dict, snap: dict) -> dict:
 
 @pytest.fixture
 def as_on_the_chip(monkeypatch):
-    """The accelerator's lowering on the CPU mesh: the predicate on the
-    host, the compressed wire, the staging threads."""
+    """The accelerator's lowering on the CPU mesh: the predicate where
+    the sources say (the host's over streamed partitions, the core's
+    over shards that stay), the compressed wire, the staging threads."""
     import datafusion_tpu.exec.kernels as kernels
     import datafusion_tpu.exec.relation as relation
 
@@ -92,28 +94,61 @@ def test_the_sharded_table_answers_as_the_oracle_and_one_device_do(
                                    [r[2:] for r in want], rtol=1e-12)
 
 
-def test_a_second_query_ships_its_mask_alone(table, as_on_the_chip):
+def streamed_ctx(path: str, n: int) -> PartitionedContext:
+    """The same rows as `mesh_ctx`'s, shard s reading its row groups of
+    the file anew every query."""
+    from datafusion_tpu.exec.datasource import ParquetDataSource
+    from datafusion_tpu.parallel.partition import PartitionedDataSource
+
+    ctx = PartitionedContext(mesh=make_mesh(n), batch_size=BATCH,
+                             result_cache=False)
+    ctx.register_datasource("lineitem", PartitionedDataSource([
+        ParquetDataSource(path, None, BATCH,
+                          row_groups=list(range(s, ROWS // GROUP_ROWS, n)))
+        for s in range(n)]))
+    return ctx
+
+
+def test_a_second_query_ships_nothing(table, as_on_the_chip):
     """Under `ctx.sql` + `collect`, a new relation a query: the first
     places every batch's columns on its shard's device, the second (the
-    same literal) and the third (another) place none again."""
+    same literal) and the third (another) place none again, evaluate
+    nothing on the host and put a round's row counts and the
+    predicate's one table only."""
     path, _, oracle = table
     ctx = mesh_ctx(path, 4)
     batches = sum(1 for p in ctx.datasources["lineitem"].partitions
                   for _ in p.batches())
+    rounds = -(-2 * GROUP_ROWS // BATCH)  # the fullest shard: 2,000 rows
     seen = []
     for delta in (90, 90, 60):
+        rel = ctx.sql(q1(delta))
+        assert rel._host_pred_expr is None and rel._core_pred is not None
         before = dict(METRICS.counts)
-        got = collect(ctx.sql(q1(delta)))
+        host_s = METRICS.timings.get("host.predicate", 0.0)
+        got = collect(rel)
         assert oracle.check("q1", {"delta": delta}, got) is None
+        assert METRICS.timings.get("host.predicate", 0.0) == host_s
         seen.append(delta_of(before, METRICS.counts))
     first, *later = seen
     assert first["h2d.resident_misses"] == batches
     assert first["h2d.bytes"] > ROWS * 8  # at least one float column
+    for d in seen:
+        # one `cmp_table` a round for all four shards
+        assert d["expr.cmp_lookups"] == rounds
     for d in later:
         assert "h2d.resident_misses" not in d
         assert d["h2d.resident_hits"] == batches
-        # the masks alone, bit-packed: a bit a row of capacity (1,024)
-        assert 0 < d["h2d.bytes"] <= batches * (1024 // 8 + 16)
+        # the row counts (four int32 a distinct round shape and the
+        # dead rounds' zeros) and the predicate's one table, put on the
+        # mesh once a query: `h2d.bytes` counts neither
+        assert 0 < d["device.h2d.transfers"] <= rounds + 2
+        assert "h2d.bytes" not in d
+    # one view a used-column set on the batch: the other literal read
+    # the same copies
+    for p in ctx.datasources["lineitem"].partitions:
+        for b in p.batches():
+            assert sum(1 for k in b.cache if k[0] == "agg_subset") == 1
     # the copies sit on their shard's device (partition s on mesh
     # device s), on the table's batches
     for dev, p in zip(ctx.mesh.devices.flat,
@@ -309,33 +344,57 @@ def test_every_file_source_describes_itself(tmp_path, kind):
     assert sum(b.num_rows for b in rebuilt.batches()) == 3
 
 
+@pytest.mark.parametrize("resident", [True, False])
 def test_one_mesh_query_observes_every_timer_and_counter(
-        table, as_on_the_chip):
+        table, as_on_the_chip, resident):
     """The timers and counters the mesh path owes its layer, and those
-    of the single-device parts it is made of."""
+    of the single-device parts it is made of: over shards that stay,
+    the wire's and the put's in the query that ships the columns and
+    never the host predicate's; over streamed partitions all of them
+    in every query."""
     path, _, _ = table
     before = dict(METRICS.counts)
-    ctx = mesh_ctx(path, 4)
-    per_device = METRICS.counts["mesh.resident.bytes"] - before.get(
-        "mesh.resident.bytes", 0)
-    assert per_device >= ROWS * 44  # seven columns, 44 B a row, + padding
-    assert all(b.num_rows == BATCH for p in ctx.datasources["lineitem"].partitions
-               for b in list(p.batches())[:-1])
+    ctx = mesh_ctx(path, 4) if resident else streamed_ctx(path, 4)
+    if resident:
+        per_device = METRICS.counts["mesh.resident.bytes"] - before.get(
+            "mesh.resident.bytes", 0)
+        assert per_device >= ROWS * 44  # seven columns, 44 B a row, + padding
+        assert all(b.num_rows == BATCH
+                   for p in ctx.datasources["lineitem"].partitions
+                   for b in list(p.batches())[:-1])
+    snap = METRICS.snapshot()
+    t0 = dict(snap["timings_s"])
     collect(ctx.sql(q1()))  # the columns' trip
     snap = METRICS.snapshot()
+    shipped = delta_of(t0, snap["timings_s"])
     t0, c0 = dict(snap["timings_s"]), dict(snap["counts"])
     collect(ctx.sql(q1()))
     snap = METRICS.snapshot()
     timed = delta_of(t0, snap["timings_s"])
     counts = delta_of(c0, snap["counts"])
-    for name in ("mesh.stage", "execute.partitioned_aggregate",
-                 "execute.collective_combine", "pipeline.wait",
-                 "pipeline.stage", "d2h.wait", "h2d.encode",
-                 "h2d.dispatch", "host.predicate", "query"):
+    every_query = ("mesh.stage", "execute.partitioned_aggregate",
+                   "execute.collective_combine", "pipeline.wait",
+                   "pipeline.stage", "d2h.wait", "h2d.dispatch", "query")
+    the_wire = ("h2d.encode", "h2d.decode")
+    for name in every_query + the_wire:
+        assert shipped.get(name, 0) > 0, name
+    for name in every_query + (() if resident else the_wire):
         assert timed.get(name, 0) > 0, name
+    for d in (shipped, timed):
+        assert ("host.predicate" in d) == (not resident)
+    if resident:
+        assert not set(the_wire) & set(timed)
     assert "query.other" in snap["timings_s"]
+    if not resident:
+        # every batch of the file ships again, its mask with it
+        assert counts["h2d.resident_misses"] >= 12
+        assert counts["h2d.bytes"] > ROWS * 8
+        assert "h2d.resident_hits" not in counts
+        assert "expr.cmp_lookups" not in counts
+        return
     rounds = -(-2 * GROUP_ROWS // BATCH)  # the fullest shard: 2,000 rows
     assert counts["mesh.rounds"] == rounds
+    assert counts["expr.cmp_lookups"] == rounds
     assert counts["mesh.fused_round_launches"] == 1
     assert counts["mesh.fused_rounds"] == rounds
     assert counts["mesh.shards"] == 4
