@@ -1089,7 +1089,8 @@ class PartitionedAggregateRelation(AggregateRelation):
                 self._stage(r)  # no staging thread on this platform
             METRICS.add("mesh.rounds")
             shard_rows += [0 if b is None else b.num_rows for b in r.batches]
-            cols, valids, rows_dev, mask, ids = self._assemble(r, dtypes)
+            with METRICS.timer("mesh.assemble"):
+                cols, valids, rows_dev, mask, ids = self._assemble(r, dtypes)
             str_aux = r.str_aux
             # capacity picked after the round's keys are encoded (the
             # producer may be further ahead: a larger capacity holds

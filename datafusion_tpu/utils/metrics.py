@@ -14,6 +14,21 @@ the sampling profiler's stage, and is a `dftpu.<name>` span
 on `/host:CPU`, nested by thread and time, on the clock the device
 plane uses.  With no profile running no annotation is made (one
 `is_enabled()` call an entry; PERF.md section 6 has the cost).
+
+While a profile runs every interval also records the CPU seconds of
+the thread that ran it (`time.thread_time()`), as
+`timings[name + ".cpu"]`: wall less CPU is what the thread spent off the
+CPU inside the block, waiting for the interpreter lock, asleep on a
+queue or blocked in the runtime (a stager's `pipeline.wait.cpu` in a
+scan that waits for its files reads about 1 % of the wall).  It rides in
+`timings`, so every reader of the timers carries it with no edit.  Only
+while a profile runs, because the clock is a system call: 0.3 us in the
+sandbox, ~11 us on the TPU host, where two reads a span were 37 ms of a
+347 ms warm Q1 (PERF.md section 6, PR 37); its resolution there is a
+10 ms tick, so a sum over a window means something and one span's value
+does not.  What the seam itself costs while a profile runs (annotation
+and clock reads: 32-66 us a span there) accumulates as
+`timings["span.overhead"]` and is in no timer's self time.
 """
 
 from __future__ import annotations
@@ -99,12 +114,19 @@ _TRACE_ANNOTATION = None  # jax.profiler.TraceAnnotation, on first use
 class _Span:
     """One stage-timer interval (see the module docstring).  After the
     block, `wall_s` is its duration and `self_s` that less the stage
-    timers this thread ran inside it."""
+    timers this thread ran inside it; `cpu_s` is its thread's CPU
+    seconds and `stats` what the `_stats` callable gave for the span,
+    both None where no profile runs (the clock is then not read, the
+    callable not called).  While one runs, an enclosing timer's self
+    time also leaves out what the seam itself cost around this
+    interval, summed in `timings["span.overhead"]`: a traced
+    `query.other` stays the query's own dark time, not the tracing's."""
 
-    __slots__ = ("_metrics", "name", "_annotation", "_stage", "_t0",
-                 "_outer", "wall_s", "self_s")
+    __slots__ = ("_metrics", "name", "_annotation", "_stage", "_t0", "_c0",
+                 "_g0", "_outer", "wall_s", "cpu_s", "self_s", "stats")
 
-    def __init__(self, metrics: "Metrics", name: str, ids: dict):
+    def __init__(self, metrics: "Metrics", name: str, ids: dict,
+                 stats=None):
         global _TRACE_ANNOTATION
         annotation = _TRACE_ANNOTATION
         if annotation is None:
@@ -114,9 +136,18 @@ class _Span:
         self._metrics = metrics
         self.name = name
         # no profile running (one call into the profiler to ask): no
-        # annotation object, a quarter of an entry's cost
-        self._annotation = (annotation(SPAN_PREFIX + name, **ids)
-                            if annotation.is_enabled() else None)
+        # annotation object, a quarter of an entry's cost; the object
+        # opens the span as it is made, so `stats` runs before it
+        self.stats = self._annotation = self.cpu_s = None
+        if annotation.is_enabled():
+            # from here to the end of `__exit__` the interval and the
+            # seam's own work around it (the annotation, two reads of
+            # the CPU clock) are no enclosing timer's self time
+            self._g0 = time.perf_counter()
+            if stats is not None:
+                self.stats = stats()
+                ids = {**ids, **self.stats}
+            self._annotation = annotation(SPAN_PREFIX + name, **ids)
 
     def __enter__(self) -> "_Span":
         self._stage = stage_enter(self.name)
@@ -124,16 +155,23 @@ class _Span:
         _CHILDREN.s = 0.0
         if self._annotation is not None:
             self._annotation.__enter__()
+            self._c0 = time.thread_time()
         self._t0 = time.perf_counter()
         return self
 
     def __exit__(self, *exc) -> None:
         wall = self.wall_s = time.perf_counter() - self._t0
+        timings = self._metrics.timings
+        gross = wall
         if self._annotation is not None:
+            cpu = self.cpu_s = time.thread_time() - self._c0
+            timings[self.name + ".cpu"] += cpu
             self._annotation.__exit__(*exc)
+            gross = time.perf_counter() - self._g0
+            timings["span.overhead"] += gross - wall
         self.self_s = wall - _CHILDREN.s
-        _CHILDREN.s = self._outer + wall
-        self._metrics.timings[self.name] += wall
+        _CHILDREN.s = self._outer + gross
+        timings[self.name] += wall
         stage_exit(self._stage)
 
 
@@ -151,11 +189,14 @@ class Metrics:
         for name in self._declared:  # declared names survive resets
             self.counts[name] += 0
 
-    def timer(self, name: str, **ids) -> _Span:
+    def timer(self, name: str, _stats=None, **ids) -> _Span:
         """`with METRICS.timer(name):` times the block into
         `timings[name]` and spans it as `dftpu.<name>`; `ids` (`qid=`)
-        become the span's stats in a running profile."""
-        return _Span(self, name, ids)
+        become the span's stats in a running profile, and so does the
+        dict `_stats()` returns: called only while a profile runs and
+        before the span opens, for stats that cost something to make
+        (`utils/retry.device_call`'s census of a launch's arguments)."""
+        return _Span(self, name, ids, _stats)
 
     def add(self, name: str, n: int = 1):
         self.counts[name] += n

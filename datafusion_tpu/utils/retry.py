@@ -48,6 +48,8 @@ import os
 import random
 import time
 
+import numpy as np
+
 from datafusion_tpu.errors import QueryDeadlineError, classify_transient
 from datafusion_tpu.testing import faults
 from datafusion_tpu.utils.deadline import current_deadline
@@ -264,6 +266,28 @@ def is_transient(err: Exception) -> bool:
     return classify_transient(err) is not None
 
 
+# what a jit call puts on the device itself when it finds it among its
+# arguments: numpy arrays, numpy scalars, Python numbers
+_HOST_LEAF = (np.ndarray, np.generic, bool, int, float, complex)
+
+
+def _census(tag, args: tuple, kwargs: dict) -> dict:
+    """The stats of a launch's span: the pytree `leaves` it is handed,
+    the `host` values among them and the `bytes` of those (8 for a
+    Python number), under the launch's `tag`.  The call itself puts each
+    host value, outside the ledger seam, so `device.h2d.transfers` never
+    counts it.  A static Python number counts too (the census does not
+    know a program's static arguments); a string or a dtype is a leaf
+    and no host value."""
+    import jax
+
+    leaves = jax.tree_util.tree_leaves((args, kwargs))
+    host = [x for x in leaves if isinstance(x, _HOST_LEAF)]
+    stats = {"leaves": len(leaves), "host": len(host),
+             "bytes": sum(getattr(x, "nbytes", 8) for x in host)}
+    return stats if tag is None else {"tag": tag, **stats}
+
+
 def device_call(fn, /, *args, _tag=None, **kwargs):
     """Invoke a (pure) device computation, replaying on transient
     runtime failures with capped exponential backoff + full jitter,
@@ -291,8 +315,13 @@ def device_call(fn, /, *args, _tag=None, **kwargs):
 
             # the launch is a stage-timer interval (utils/metrics.py):
             # the "execute" slice of the phase breakdown, the sampling
-            # profiler's stage, and the `dftpu.device.dispatch` span
-            with METRICS.timer("device.dispatch") as span:
+            # profiler's stage, and the `dftpu.device.dispatch` span,
+            # whose stats are the census of what the launch is handed:
+            # taken only while a profile runs, and before the span
+            # opens (its own time is in no run's `device.dispatch`)
+            with METRICS.timer(
+                    "device.dispatch",
+                    _stats=lambda: _census(_tag, args, kwargs)) as span:
                 out = fn(*args, **kwargs)
                 if profile_sync_active():
                     # phase-profiled run (EXPLAIN ANALYZE, bench cold
@@ -310,6 +339,11 @@ def device_call(fn, /, *args, _tag=None, **kwargs):
             METRICS.add("device.launches")
             if _tag is not None:
                 METRICS.add(f"device.launches.{_tag}")
+            census = span.stats
+            if census is not None:
+                METRICS.add("device.dispatch.leaves", census["leaves"])
+                METRICS.add("device.dispatch.host_leaves", census["host"])
+                METRICS.add("device.dispatch.host_bytes", census["bytes"])
             from datafusion_tpu.obs.attribution import note_launch
             from datafusion_tpu.obs.recorder import record as flight_record
             from datafusion_tpu.obs.stats import record_launch
