@@ -254,7 +254,10 @@ def resident_lineitem(sf, batch_size: int = 1 << 19):
     ctx = ExecutionContext(device="cpu", batch_size=batch_size)
     ctx.register_parquet("lineitem", lineitem_path(sf))
     scan = ctx.datasources["lineitem"]
-    return MemoryDataSource(scan.schema, list(scan.batches()))
+    batches = list(scan.batches())
+    require(all(b.num_rows == batch_size for b in batches[:-1]),
+            "resident: the scan handed on a batch cut at a row group's end")
+    return MemoryDataSource(scan.schema, batches)
 
 
 def stage_warm(ctx, oracle: Q1Oracle) -> dict:

@@ -175,8 +175,9 @@ class ParquetDataSource(DataSource):
     def schema(self) -> Schema:
         return self._reader.out_schema
 
-    def batches(self) -> Iterator[RecordBatch]:
-        return self._reader.batches()
+    def batches(self, whole: bool = True) -> Iterator[RecordBatch]:
+        # `whole=False`: the file's own cut (`ParquetReader.batches`)
+        return self._reader.batches(whole)
 
     def with_projection(self, projection: Sequence[int]) -> "ParquetDataSource":
         return ParquetDataSource(

@@ -12,7 +12,8 @@ the host side, before any H2D transfer.
 from __future__ import annotations
 
 import json
-from typing import Iterator, Optional, Sequence
+from collections import deque
+from typing import Iterable, Iterator, Optional, Sequence
 
 import numpy as np
 
@@ -299,13 +300,20 @@ class ParquetReader:
             for f in self.out_schema.fields
         ]
 
-    def batches(self) -> Iterator[RecordBatch]:
-        # confined for the same reason as CsvReader.batches
-        yield from confined_iter(
-            METRICS.timed_iter("scan.parse", self._batches())
-        )
+    def batches(self, whole: bool = True) -> Iterator[RecordBatch]:
+        """The scan as batches of exactly `batch_size` rows, the last
+        one what is left; `whole=False` hands on the file's own cut
+        (pyarrow ends a batch at every row group's end where a column
+        is read dictionary-encoded) to a caller that re-cuts anyway."""
+        # confined for the same reason as CsvReader.batches; the re-cut
+        # runs on the parser thread too, inside scan.parse
+        pieces = self._pieces()
+        yield from confined_iter(METRICS.timed_iter(
+            "scan.parse",
+            whole_batches(pieces, self.batch_size) if whole else pieces,
+        ))
 
-    def _batches(self) -> Iterator[RecordBatch]:
+    def _pieces(self) -> Iterator[RecordBatch]:
         import pyarrow as pa
         import pyarrow.parquet as pq
 
@@ -328,13 +336,69 @@ class ParquetReader:
                 batch_size=self.batch_size, columns=names,
                 row_groups=self.row_groups):
             faults.check("io.read", path=self.path, format="parquet")
-            cols = [arrow_batch.column(j) for j in range(arrow_batch.num_columns)]
-            import pyarrow as pa
-
-            cols = [pa.chunked_array([c]) for c in cols]
+            cols = [pa.chunked_array([arrow_batch.column(j)])
+                    for j in range(arrow_batch.num_columns)]
             columns, validity = _arrow_to_columns(cols, self.out_schema, self.dicts)
             METRICS.add("scan.rows", arrow_batch.num_rows)
             yield make_host_batch(self.out_schema, columns, validity, list(self.dicts))
+
+
+def whole_batches(batches: Iterable[RecordBatch],
+                  size: int) -> Iterator[RecordBatch]:
+    """A scan's batches re-cut into batches of exactly `size` rows, the
+    last one what is left, rows in order.  A Parquet reader cuts the
+    batch that straddles a row group's end in two; a piece of another
+    capacity is a shape class of its own and ends the aggregate's run
+    of foldable batches (`exec/fused.iter_groups`), so the pieces are
+    joined here, before anything keeps them.  Streamed, at the numpy
+    level (string codes are global by now, no dictionary is unified):
+    a batch that arrives whole while nothing waits is handed on as it
+    is, every other row is copied once, into the batch that keeps it;
+    a validity array is made only where a piece brought one.  Longer
+    batches are cut down the same way (the mesh's readers hand over
+    several table batches at a time)."""
+    held: deque = deque()  # (batch, its first row not yet handed on)
+    have = 0
+
+    def take(n: int) -> RecordBatch:
+        nonlocal have
+        have -= n
+        parts = []
+        while n:
+            b, lo = held.popleft()
+            hi = min(b.num_rows, lo + n)
+            parts.append((b, lo, hi))
+            n -= hi - lo
+            if hi < b.num_rows:
+                held.appendleft((b, hi))
+        first = parts[0][0]
+        if parts == [(first, 0, first.num_rows)]:
+            return first  # the scan's short last batch, alone
+        if len(parts) > 1:
+            METRICS.add("scan.recut.pieces", len(parts))
+        columns, validity = [], []
+        for i in range(len(first.data)):
+            columns.append(np.concatenate(
+                [np.asarray(b.data[i])[lo:hi] for b, lo, hi in parts]))
+            validity.append(
+                None if all(b.validity[i] is None for b, _, _ in parts)
+                else np.concatenate([
+                    np.ones(hi - lo, bool) if b.validity[i] is None
+                    else np.asarray(b.validity[i])[lo:hi]
+                    for b, lo, hi in parts]))
+        return make_host_batch(first.schema, columns, validity,
+                               list(first.dicts))
+
+    for b in batches:
+        if not held and b.num_rows == size:
+            yield b
+        elif b.num_rows:
+            held.append((b, 0))
+            have += b.num_rows
+            while have >= size:
+                yield take(size)
+    if have:
+        yield take(have)
 
 
 def parquet_row_groups(path: str) -> int:

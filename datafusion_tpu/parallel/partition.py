@@ -34,7 +34,7 @@ from __future__ import annotations
 
 import functools
 from concurrent.futures import ThreadPoolExecutor
-from typing import Iterable, Iterator, Optional, Sequence
+from typing import Iterator, Optional, Sequence
 
 import jax
 import jax.numpy as jnp
@@ -54,7 +54,6 @@ from datafusion_tpu.exec.batch import (
     RecordBatch,
     bucket_capacity,
     device_inputs,
-    make_host_batch,
     pad_to,
 )
 from datafusion_tpu.exec.context import ExecutionContext
@@ -161,54 +160,6 @@ class PartitionedDataSource(DataSource):
 
 # table batches a reader of `register_resident_parquet` parses at a time
 _READ_BATCHES = 8
-
-
-def _whole_batches(batches: Iterable[RecordBatch], schema: Schema,
-                   size: int) -> list[RecordBatch]:
-    """A scan's batches (a Parquet reader cuts one short at every row
-    group's end) as batches of exactly `size` rows, the last one what is
-    left: a shard's rounds then all have one shape, and none carries
-    padding to the device.  Streamed: each row is copied once, into the
-    batch that keeps it, while the reader parses the next."""
-    out: list[RecordBatch] = []
-    n_cols = len(schema.fields)
-    cols = valids = dicts = None
-    fill = 0
-
-    def emit():
-        out.append(make_host_batch(
-            schema,
-            [c[:fill] for c in cols],
-            [None if v is None else v[:fill] for v in valids],
-            list(dicts),
-        ))
-
-    for b in batches:
-        if dicts is None:
-            dicts = list(b.dicts)
-        data = [np.asarray(a) for a in b.data]
-        pos = 0
-        while pos < b.num_rows:
-            if cols is None:
-                cols = [np.empty(size, a.dtype) for a in data]
-                valids = [None] * n_cols
-                fill = 0
-            take = min(size - fill, b.num_rows - pos)
-            for i in range(n_cols):
-                cols[i][fill:fill + take] = data[i][pos:pos + take]
-                if b.validity[i] is not None:
-                    if valids[i] is None:
-                        valids[i] = np.ones(size, bool)
-                    valids[i][fill:fill + take] = np.asarray(
-                        b.validity[i])[pos:pos + take]
-            fill += take
-            pos += take
-            if fill == size:
-                emit()
-                cols = None
-    if cols is not None:
-        emit()
-    return out
 
 
 def _padded(batch: RecordBatch, cap: int) -> RecordBatch:
@@ -1240,6 +1191,7 @@ class PartitionedContext(ExecutionContext):
         from datafusion_tpu.io.readers import (
             infer_parquet_schema,
             parquet_row_groups,
+            whole_batches,
         )
         from datafusion_tpu.obs.device import LEDGER
 
@@ -1251,7 +1203,7 @@ class PartitionedContext(ExecutionContext):
         # the readers hand over `_READ_BATCHES` table batches at a time
         # (a row group, if it is no longer): what a reader pays a batch
         # (the chunk's dictionary merged, arrays made) it pays an eighth
-        # as often, and `_whole_batches` cuts the table's batches anyway
+        # as often, and `whole_batches` cuts the table's batches anyway
         sources = [
             ParquetDataSource(path, schema, _READ_BATCHES * self.batch_size,
                               row_groups=list(range(s, n_groups, n)))
@@ -1262,13 +1214,17 @@ class PartitionedContext(ExecutionContext):
         _share_dictionaries(sources)
 
         def read(src):
-            # the reader parses on its IO thread (`io/io_thread.py`), a
-            # prefetch thread pulls ahead, this one re-cuts
-            return _whole_batches(
-                staged_prefetch(src.batches(), None,
+            # the reader parses on its IO thread (`io/io_thread.py`) and
+            # hands on the file's own cut (joining a row group's million
+            # rows to the next one's head first would copy every row
+            # twice), a prefetch thread pulls ahead, this one re-cuts:
+            # a shard's rounds then all have one shape, each row copied
+            # once, into the batch that keeps it
+            return list(whole_batches(
+                staged_prefetch(src.batches(whole=False), None,
                                 wait_timer="pipeline.scan_wait"),
-                src.schema, self.batch_size,
-            )
+                self.batch_size,
+            ))
 
         with ThreadPoolExecutor(n, thread_name_prefix="df-tpu-shard-read") as pool:
             shards = list(pool.map(read, sources))

@@ -200,12 +200,13 @@ def test_shards_read_side_by_side_end_with_one_dictionary_set(table):
 @pytest.mark.parametrize("pieces,size", [
     ([5, 3, 8], 4), ([3, 3], 8), ([4, 4], 4), ([1, 9, 2], 5), ([], 4)])
 def test_a_scan_is_recut_into_whole_batches(pieces, size):
-    """`_whole_batches`: every batch but the last holds `size` rows, the
+    """`whole_batches`, the reader's own re-cut, as the mesh's
+    registration calls it: every batch but the last holds `size` rows, the
     rows keep their order, and a validity array appears where a piece
     brought one (rows of the pieces that brought none are valid)."""
     from datafusion_tpu.datatypes import DataType, Field, Schema
     from datafusion_tpu.exec.batch import make_host_batch
-    from datafusion_tpu.parallel.partition import _whole_batches
+    from datafusion_tpu.io.readers import whole_batches
 
     schema = Schema([Field("x", DataType.INT64, True),
                      Field("y", DataType.FLOAT64, False)])
@@ -223,7 +224,7 @@ def test_a_scan_is_recut_into_whole_batches(pieces, size):
     want_valid = np.concatenate([
         valid[sum(pieces[:k]):sum(pieces[:k + 1])] if k % 2 == 0
         else np.ones(n, bool) for k, n in enumerate(pieces)] or [valid])
-    out = _whole_batches(iter(scan), schema, size)
+    out = list(whole_batches(iter(scan), size))
     assert [b.num_rows for b in out] == (
         [size] * (total // size) + ([total % size] if total % size else []))
     if not out:
@@ -237,6 +238,54 @@ def test_a_scan_is_recut_into_whole_batches(pieces, size):
     assert got_y.tolist() == (x * 0.5).tolist()
     assert got_v.tolist() == want_valid.tolist()
     assert all(b.validity[1] is None for b in out)
+
+
+def test_registration_recuts_the_files_own_cut_once(table, monkeypatch):
+    """The mesh's readers hand on the file's own cut (eight table
+    batches at a time, a row group if it is no longer) and the shard's
+    thread cuts that down to table batches, each row copied once: a
+    reader that joined first would copy every row twice.  A shard holds
+    its row groups' rows in the file's order, in batches of `BATCH`
+    rows but the last, every float as the file has it."""
+    import pyarrow.parquet as pq
+
+    from datafusion_tpu.io.readers import ParquetReader
+
+    path, _, _ = table
+    asked = []
+    real = ParquetReader.batches
+
+    def batches(self, whole=True):
+        asked.append((self.batch_size, whole))
+        return real(self, whole)
+
+    monkeypatch.setattr(ParquetReader, "batches", batches)
+    before = METRICS.counts.get("scan.recut.pieces", 0)
+    ctx = mesh_ctx(path, 4)
+    assert asked == [(8 * BATCH, False)] * 4
+    shards = ctx.datasources["lineitem"].partitions
+    pf = pq.ParquetFile(path)
+    col = shards[0].schema.names().index("l_extendedprice")
+    joined = 0
+    for s, p in enumerate(shards):
+        groups = list(range(s, ROWS // GROUP_ROWS, 4))
+        rows = len(groups) * GROUP_ROWS
+        assert [b.num_rows for b in p.batches()] == (
+            [BATCH] * (rows // BATCH) + ([rows % BATCH] if rows % BATCH else []))
+        # a table batch that straddles two of the shard's row groups is
+        # made of two pieces
+        joined += sum(
+            2 for lo in range(0, rows, BATCH)
+            if lo // GROUP_ROWS != (min(rows, lo + BATCH) - 1) // GROUP_ROWS)
+        if not groups:
+            continue
+        got = np.concatenate([
+            np.asarray(b.data[col])[:b.num_rows] for b in p.batches()])
+        want = np.concatenate([
+            pf.read_row_group(g, columns=["l_extendedprice"]).column(0)
+            .to_numpy() for g in groups])
+        assert got.tolist() == want.tolist()
+    assert METRICS.counts.get("scan.recut.pieces", 0) - before == joined
 
 
 def test_group_ids_are_kept_by_device(table):
