@@ -891,11 +891,13 @@ class _AggregateCore:
                 new_accs.append(val)
         return new_counts, tuple(new_accs)
 
-    def _fused_group(self, entries, state, aux, str_aux, params):
+    def _fused_group(self, entries, row_counts, state, aux, str_aux,
+                     params):
         """ONE device launch for a whole batch group (exec/fused.py).
 
-        entries: per-batch (cols, valids, num_rows, mask|None, ids)
-        pytrees with identical structure/shapes.  Dense-path
+        entries: per-batch (cols, valids, mask|None, ids) pytrees with
+        identical structure/shapes; row_counts: int32 [entries], their
+        row counts (0 for a dead entry).  Dense-path
         capacities fold with `lax.scan` — the per-batch
         kernel body traces once, not once per batch.  Sort-merge
         capacities instead concatenate every batch's contribution
@@ -911,18 +913,18 @@ class _AggregateCore:
             stacked = stack_entries(entries)
 
             def body(st, x):
-                cols, valids, num_rows, mask, ids = x
+                (cols, valids, mask, ids), num_rows = x
                 return self._kernel(
                     cols, valids, aux, num_rows, mask, ids, st, str_aux,
                     params,
                 ), None
 
-            state, _ = jax.lax.scan(body, state, stacked)
+            state, _ = jax.lax.scan(body, state, (stacked, row_counts))
             return state
 
         keys_l, contribs_l = [], []
         payload_of: dict[int, int] = {}
-        for cols, valids, num_rows, mask, ids in entries:
+        for (cols, valids, mask, ids), num_rows in zip(entries, row_counts):
             ids = _ids_of(ids)
             env = Env(cols, valids, aux, self.col_map, params)
             capacity = cols[0].shape[0] if cols else ids.shape[0]
@@ -950,14 +952,16 @@ class _AggregateCore:
             counts, accs, batch_keys, cat, payload_of, str_aux
         )
 
-    def _multi_fused_group(self, entries, states, aux, str_aux, params_list):
+    def _multi_fused_group(self, entries, row_counts, states, aux, str_aux,
+                           params_list):
         """ONE device launch for N queries × one batch group: the
         serving megabatch (serve.py).  Every query folds the SAME
-        stacked entries — XLA shares the input plumbing across the N
-        sub-folds — under its own literal params and accumulator state;
-        results de-multiplex per query as a tuple of states."""
+        stacked entries and row counts — XLA shares the input plumbing
+        across the N sub-folds — under its own literal params and
+        accumulator state; results de-multiplex per query as a tuple of
+        states."""
         return tuple(
-            self._fused_group(entries, st, aux, str_aux, ps)
+            self._fused_group(entries, row_counts, st, aux, str_aux, ps)
             for st, ps in zip(states, params_list)
         )
 
@@ -968,8 +972,9 @@ class _AggregateCore:
     # predicate keeps are compacted on the device and appended, key
     # tuple and contributions, to a keyed buffer; one sort + segmented
     # reduce turns the buffer into groups (`_KeyedAccumulator` drives
-    # the three programs).  An entry is (cols, valids, num_rows, mask,
-    # key cols, key valids); the state is (keys int64[B] a key, key
+    # the three programs).  An entry is (cols, valids, mask, key cols,
+    # key valids), its row count in the group's counts vector
+    # (exec/fused.pad_group); the state is (keys int64[B] a key, key
     # NULL flags bool[B] a key, counts int64[B], accumulators [B] a
     # slot); a row with count 0 is empty.
 
@@ -989,24 +994,25 @@ class _AggregateCore:
             mask = mask & pv
         return mask
 
-    def _keyed_counts(self, entries, aux, params):
+    def _keyed_counts(self, entries, row_counts, aux, params):
         """The most rows the predicate keeps of any one batch of a
         group: all the host learns of them (4 B; it sizes the append's
         compaction)."""
         from datafusion_tpu.exec.fused import stack_entries
 
         def body(x):
-            cols, valids, num_rows, mask, kcols, _ = x
+            (cols, valids, mask, kcols, _), num_rows = x
             live = self._live_rows(cols, valids, aux, num_rows, mask, kcols,
                                    params)
             return jnp.sum(live, dtype=jnp.int32)
 
-        return jnp.max(jax.lax.map(body, stack_entries(entries)))
+        return jnp.max(
+            jax.lax.map(body, (stack_entries(entries), row_counts)))
 
     def _slot_identities(self):
         return [jnp.asarray(self._slot_identity(sl)) for sl in self.slots]
 
-    def _keyed_rows(self, width, entries, aux, params):
+    def _keyed_rows(self, width, entries, row_counts, aux, params):
         """A batch group's kept rows as the keyed buffer holds them:
         of each batch the first `width` rows in kept-first order (a
         sort of row numbers; `width` holds every kept row of every
@@ -1018,7 +1024,7 @@ class _AggregateCore:
         idents = self._slot_identities()
 
         def body(x):
-            cols, valids, num_rows, mask, kcols, kvalids = x
+            (cols, valids, mask, kcols, kvalids), num_rows = x
             capacity = kcols[0].shape[0]
             live = self._live_rows(cols, valids, aux, num_rows, mask, kcols,
                                    params)
@@ -1056,8 +1062,9 @@ class _AggregateCore:
             return (tuple(keys), tuple(knull), live.astype(jnp.int64),
                     tuple(accs))
 
-        return jax.tree.map(lambda rows: rows.reshape(-1),
-                            jax.lax.map(body, stack_entries(entries)))
+        return jax.tree.map(
+            lambda rows: rows.reshape(-1),
+            jax.lax.map(body, (stack_entries(entries), row_counts)))
 
     @staticmethod
     def _keyed_store(state, rows, offset):
@@ -1404,7 +1411,8 @@ class _KeyedAccumulator:
         self.params = params
         self.reserve = reserve
         self.chunk: list = []
-        # (group, aux, its largest count: a pending pull, or the count)
+        # (group, counts, aux, its largest count: a pending pull, or
+        # the count)
         self.pending: list = []
         self.state = None
         self.capacity = 0
@@ -1415,15 +1423,16 @@ class _KeyedAccumulator:
     def add(self, data, validity, aux, num_rows, mask, keys: _DeviceKeys):
         if self.key_dtypes is None:
             self.key_dtypes = tuple(np.dtype(c.dtype) for c in keys.cols)
-        self.offered += int(num_rows)
+        self.offered += num_rows
         self.chunk.append(
-            ((data, validity, num_rows, mask, keys.cols, keys.valids), aux))
+            ((data, validity, mask, keys.cols, keys.valids), num_rows, aux))
         if len(self.chunk) >= self._FLUSH_BATCHES:
             self.flush()
 
     def _groups(self):
         """The chunk's batches by shape class (addition does not care
-        in which order batches arrive), each padded to its rung."""
+        in which order batches arrive), each padded to its rung:
+        (entries, counts, aux)."""
         from datafusion_tpu.exec.fused import (
             entry_signature,
             pad_group,
@@ -1431,13 +1440,14 @@ class _KeyedAccumulator:
         )
 
         classes: dict = {}
-        for entry, aux in self.chunk:
+        for entry, num_rows, aux in self.chunk:
             sig = (entry_signature(entry), shared_signature(aux))
-            classes.setdefault(sig, ([], aux))[0].append(entry)
+            entries, counts, _ = classes.setdefault(sig, ([], [], aux))
+            entries.append(entry)
+            counts.append(num_rows)
         self.chunk = []
-        for entries, aux in classes.values():
-            yield tuple(pad_group(
-                entries, lambda e: (e[0], e[1], np.int32(0), *e[3:]))), aux
+        for entries, counts, aux in classes.values():
+            yield (*pad_group(entries, counts), aux)
 
     def flush(self, drain: bool = False):
         from datafusion_tpu.exec.batch import device_pull_start
@@ -1450,31 +1460,31 @@ class _KeyedAccumulator:
         self.pending = []
         with op_timer(self.rel), device_scope(self.rel.device):
             with METRICS.timer("aggregate.device_key_ids"):
-                for group, aux in self._groups():
+                for group, counts, aux in self._groups():
                     if self.core._pred_fn is None and all(
-                            e[3] is None for e in group):
+                            e[2] is None for e in group):
                         # every row of a batch is kept: its count is
                         # its row count
                         self.pending.append(
-                            (group, aux, max(int(e[2]) for e in group)))
+                            (group, counts, aux, int(counts.max())))
                         continue
                     most = device_call(
-                        self.core.keyed_counts_jit, group, aux, self.params,
-                        _tag="agg.key_ids")
+                        self.core.keyed_counts_jit, group, counts, aux,
+                        self.params, _tag="agg.key_ids")
                     self.pending.append(
-                        (group, aux, device_pull_start(most)))
+                        (group, counts, aux, device_pull_start(most)))
             if drain:
                 done, self.pending = done + self.pending, []
-            for group, aux, pull in done:
+            for group, counts, aux, pull in done:
                 with METRICS.timer("aggregate.device_key_ids"):
                     most = pull if isinstance(pull, int) else int(
                         pull.finish())
                 if most:
-                    self._append(group, aux, most)
+                    self._append(group, counts, aux, most)
 
-    def _append(self, group, aux, most: int):
+    def _append(self, group, counts, aux, most: int):
         core = self.core
-        capacity = group[0][4][0].shape[0]
+        capacity = group[0][3][0].shape[0]
         width = min(capacity, group_capacity(max(most, self._MIN_WIDTH)))
         need = len(group) * width
         if self.state is None and self.reserve >= need:
@@ -1486,7 +1496,7 @@ class _KeyedAccumulator:
             self._make_room(self.used + need)
         with METRICS.timer("execute.aggregate"):
             rows = device_call(
-                core.keyed_rows_jit, width, group, aux, self.params,
+                core.keyed_rows_jit, width, group, counts, aux, self.params,
                 _tag="agg.group")
             self._hold(device_call(
                 core.keyed_store_jit, self.state, rows, np.int32(self.used),
@@ -2015,7 +2025,7 @@ class AggregateRelation(Relation):
             # one launch per shape-homogeneous batch group, padded to
             # the group-size ladder with zero-row (identity) entries so
             # scans of any length reuse a small set of compiled programs
-            entries = [(c[0], c[1], c[3], c[4], c[5]) for c in chunk]
+            entries = [(c[0], c[1], c[4], c[5]) for c in chunk]
             shareds = [(c[2], c[6]) for c in chunk]
             for idxs, (aux, str_aux) in iter_groups(entries, shareds):
                 if len(idxs) == 1:
@@ -2025,14 +2035,12 @@ class AggregateRelation(Relation):
                         state, c[6], params, _tag="agg",
                     )
                     continue
-                group = pad_group(
-                    [entries[i] for i in idxs],
-                    lambda e: (e[0], e[1], np.int32(0), e[3], e[4]),
-                )
+                group, counts = pad_group(
+                    [entries[i] for i in idxs], [chunk[i][3] for i in idxs])
                 METRICS.add("fused.groups")
                 METRICS.add("fused.group_batches", len(idxs))
                 state = device_call(
-                    core.group_jit, tuple(group), state, aux, str_aux,
+                    core.group_jit, group, counts, state, aux, str_aux,
                     params, _tag="agg.group",
                 )
             return state
@@ -2088,8 +2096,8 @@ class AggregateRelation(Relation):
                 if by_key is None:
                     by_key = _KeyedAccumulator(self, core, params,
                                                reserve=self._wide)
-                by_key.add(data, validity, tuple(aux),
-                           np.int32(batch.num_rows), mask, keys)
+                by_key.add(data, validity, tuple(aux), batch.num_rows, mask,
+                           keys)
                 continue
             chunk.append(
                 (data, validity, tuple(aux), np.int32(batch.num_rows), mask,

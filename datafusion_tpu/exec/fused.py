@@ -14,14 +14,19 @@ Two independent fusion layers:
   the per-batch device inputs of a whole scan collect host-side and
   dispatch as ONE jitted computation per *batch group* — a run of
   batches with identical (shape class, dtype tuple, aux identity).
-  State-carrying operators fold the group with `lax.scan` (dense
-  aggregate, TopK) or a concat + single sort-merge (high-cardinality
-  aggregate); the pipeline maps the group and returns per-batch
-  outputs.  Group sizes bucket to a short ladder and pad with
-  zero-row "dead" entries (identity contributions), so the compile
-  cache holds O(log n) group programs, keyed — like every core —
-  by (plan fingerprint, shape class, dtype tuple) through
-  `exec/kernels.cached_kernel` + jit's own shape cache.
+  A group is two things: its entries (each batch's device arrays,
+  stacked inside the program) and ONE int32 vector of their row
+  counts, a numpy array the launch puts once.  State-carrying
+  operators fold the group with `lax.scan` over (entries, counts)
+  (dense aggregate, TopK) or a concat + single sort-merge
+  (high-cardinality aggregate); the pipeline maps the group and
+  returns per-batch outputs.  Group sizes bucket to a short ladder:
+  `pad_group` repeats entry 0 and gives each pad a count of 0, a
+  "dead" entry whose rows are all masked off (an identity
+  contribution), so the compile cache holds O(log n) group programs,
+  keyed — like every core — by (plan fingerprint, shape class, dtype
+  tuple) through `exec/kernels.cached_kernel` + jit's own shape
+  cache.
 
 Why: one launch per batch group, not per batch, keeps the launch count
 of a scan a function of its shape classes, not of the table's size.
@@ -30,7 +35,7 @@ of a scan a function of its shape classes, not of the table's size.
 from __future__ import annotations
 
 import os
-from typing import Callable, Optional
+from typing import Optional
 
 import numpy as np
 
@@ -119,14 +124,17 @@ def iter_groups(entries, shareds):
         yield list(range(start, len(entries))), shareds[start]
 
 
-def pad_group(entries: list, dead_of: Callable):
-    """Pad a group to its ladder rung with dead entries (`dead_of`
-    returns a zero-row clone of an entry — identity contribution)."""
+def pad_group(entries: list, counts: list) -> tuple:
+    """A batch group as its launch takes it: (entries, counts).  The
+    entries are padded to their ladder rung with entry 0 again; counts
+    is one int32 array of the entries' row counts in which every pad
+    counts 0 — a dead entry, whose rows the kernel masks off by its
+    count, so it contributes the identity.  The counts travel as ONE
+    host value a launch, not one scalar an entry."""
     want = bucket_group(len(entries))
-    if want > len(entries):
-        dead = dead_of(entries[0])
-        entries = entries + [dead] * (want - len(entries))
-    return entries
+    rows = np.zeros(want, np.int32)
+    rows[:len(counts)] = counts
+    return tuple(entries) + (entries[0],) * (want - len(entries)), rows
 
 
 def stack_entries(entries):

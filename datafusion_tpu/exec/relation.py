@@ -286,29 +286,30 @@ class _PipelineCore:
         # stacked group; the shared input columns upload once
         self.multi_group_jit = jax.jit(self._multi_fused_group)
 
-    def _fused_group(self, entries, aux, params):
+    def _fused_group(self, entries, counts, aux, params):
         """ONE launch for a group of prepared batches: `lax.map` of the
-        fused kernel over the stacked group; outputs return per batch
-        (the unstacking slices fuse into the same program, so consumers
-        see ordinary per-batch arrays without extra dispatches)."""
+        fused kernel over the stacked group and its row counts; outputs
+        return per batch (the unstacking slices fuse into the same
+        program, so consumers see ordinary per-batch arrays without
+        extra dispatches)."""
         from datafusion_tpu.exec.fused import stack_entries
 
         stacked = stack_entries(entries)
 
         def body(x):
-            cols, valids, num_rows, mask = x
+            (cols, valids, mask), num_rows = x
             out_cols, out_valids, m = self._kernel(
                 cols, valids, aux, num_rows, mask, params
             )
             return tuple(out_cols), tuple(out_valids), m
 
-        ys = jax.lax.map(body, stacked)
+        ys = jax.lax.map(body, (stacked, counts))
         return tuple(
             jax.tree.map(lambda t, i=i: t[i], ys)
             for i in range(len(entries))
         )
 
-    def _multi_fused_group(self, entries, aux, params_list):
+    def _multi_fused_group(self, entries, counts, aux, params_list):
         """N queries over ONE stacked batch group in one launch (the
         serve-plane pipeline megabatch): the map body runs the kernel
         once per query against the same stacked inputs — per-query
@@ -321,7 +322,7 @@ class _PipelineCore:
         stacked = stack_entries(entries)
 
         def body(x):
-            cols, valids, num_rows, mask = x
+            (cols, valids, mask), num_rows = x
             outs = []
             for params in params_list:
                 out_cols, out_valids, m = self._kernel(
@@ -330,7 +331,7 @@ class _PipelineCore:
                 outs.append((tuple(out_cols), tuple(out_valids), m))
             return tuple(outs)
 
-        ys = jax.lax.map(body, stacked)
+        ys = jax.lax.map(body, (stacked, counts))
         return tuple(
             tuple(
                 jax.tree.map(lambda t, i=i: t[i], ys[q])
@@ -622,7 +623,7 @@ class PipelineRelation(Relation):
                 )
                 if self._host_pred_expr is not None:
                     mask_in = self._device_mask(batch)
-            return aux, (data, validity, np.int32(batch.num_rows), mask_in)
+            return aux, (data, validity, mask_in)
 
         def flush() -> list:
             if not buf:
@@ -632,18 +633,16 @@ class PipelineRelation(Relation):
                 if len(buf) == 1:
                     b, e, aux = buf[0]
                     outs = [device_call(
-                        core.jit, e[0], e[1], aux, e[2], e[3],
-                        self._params, _tag="pipeline",
+                        core.jit, e[0], e[1], aux, np.int32(b.num_rows),
+                        e[2], self._params, _tag="pipeline",
                     )]
                 else:
-                    group = pad_group(
-                        [e for _, e, _ in buf],
-                        lambda e: (e[0], e[1], np.int32(0), e[3]),
-                    )
+                    group, counts = pad_group(
+                        [e for _, e, _ in buf], [b.num_rows for b, _, _ in buf])
                     METRICS.add("fused.groups")
                     METRICS.add("fused.group_batches", len(buf))
                     outs = device_call(
-                        core.group_jit, tuple(group), buf[0][2],
+                        core.group_jit, group, counts, buf[0][2],
                         self._params, _tag="pipeline.group",
                     )
             emitted = [
@@ -847,16 +846,14 @@ def run_pipeline_megabatch(rels: list["PipelineRelation"]) -> float:
             return
         with METRICS.timer("execute.pipeline"), op_timer(leader), \
                 device_scope(leader.device):
-            group = pad_group(
-                [e for _, e, _ in buf],
-                lambda e: (e[0], e[1], np.int32(0), e[3]),
-            )
+            group, counts = pad_group(
+                [e for _, e, _ in buf], [b.num_rows for b, _, _ in buf])
             METRICS.add("fused.groups")
             METRICS.add("fused.group_batches", len(buf))
             METRICS.add("serve.megabatch_launches")
             METRICS.add("serve.megabatch_queries", n_live)
             outs = device_call(
-                core.multi_group_jit, tuple(group), buf[0][2],
+                core.multi_group_jit, group, counts, buf[0][2],
                 params_list, _tag="pipeline.mega",
             )
         for q, r in enumerate(rels):
@@ -879,7 +876,7 @@ def run_pipeline_megabatch(rels: list["PipelineRelation"]) -> float:
             data, validity, mask_in = device_inputs(
                 leader._subset_view(batch), leader.device, core.wire_hints
             )
-        entry = (data, validity, np.int32(batch.num_rows), mask_in)
+        entry = (data, validity, mask_in)
         sig = (entry_signature(entry), tuple(map(id, aux)))
         if buf and (sig != cur_sig or len(buf) >= group_max):
             flush()

@@ -215,20 +215,20 @@ class _TopKCore:
             [header, jnp.where(live, rows, jnp.int64(-1))]
         )
 
-    def _group_final(self, k, state, entries, rank_tables):
+    def _group_final(self, k, state, entries, counts, rank_tables):
         """The scan's LAST group fold fused with the result merge (see
         `_final_merge`) — one launch ends the pass."""
         return self._final_merge(
-            self._fused_group(k, state, entries, rank_tables)
+            self._fused_group(k, state, entries, counts, rank_tables)
         )
 
-    def _fused_group(self, k, state, entries, rank_tables):
+    def _fused_group(self, k, state, entries, counts, rank_tables):
         from datafusion_tpu.exec.fused import stack_entries
 
         stacked = stack_entries(entries)
 
         def body(st, x):
-            cols, valids, mask, num_rows, row_base, img = x
+            (cols, valids, mask, row_base, img), num_rows = x
             if self.single:
                 st = self._topk1_kernel(
                     k, st, cols, valids, mask, num_rows, row_base,
@@ -246,7 +246,7 @@ class _TopKCore:
                 )
             return st, None
 
-        state, _ = lax.scan(body, state, stacked)
+        state, _ = lax.scan(body, state, (stacked, counts))
         return state
 
     def _fold_batch(self, k, state, cols, valids, mask, num_rows,
@@ -267,7 +267,7 @@ class _TopKCore:
             rank_tables,
         )
 
-    def _multi_group(self, ks, states, entries, rank_tables):
+    def _multi_group(self, ks, states, entries, counts, rank_tables):
         """N queries' states folded over ONE stacked batch group (the
         serve-plane TopK megabatch): the scan body merges every
         query's state against the same batch operands, so a group
@@ -280,22 +280,23 @@ class _TopKCore:
         stacked = stack_entries(entries)
 
         def body(sts, x):
-            cols, valids, mask, num_rows, row_base, img = x
+            (cols, valids, mask, row_base, img), num_rows = x
             return tuple(
                 self._fold_batch(k, st, cols, valids, mask, num_rows,
                                  row_base, rank_tables, img)
                 for k, st in zip(ks, sts)
             ), None
 
-        states, _ = lax.scan(body, tuple(states), stacked)
+        states, _ = lax.scan(body, tuple(states), (stacked, counts))
         return states
 
-    def _multi_group_final(self, ks, states, entries, rank_tables):
+    def _multi_group_final(self, ks, states, entries, counts, rank_tables):
         """The megabatch's LAST group fold fused with every query's
         result merge — one launch ends the whole cross-query pass,
         and the outputs pack into one int64 array per query."""
         if entries:
-            states = self._multi_group(ks, states, entries, rank_tables)
+            states = self._multi_group(ks, states, entries, counts,
+                                       rank_tables)
         return tuple(self._final_merge(st) for st in states)
 
     @staticmethod
@@ -808,7 +809,7 @@ class SortRelation(Relation):
             # one launch per shape-homogeneous batch group (lax.scan
             # over the stacked group), padded to the ladder with
             # zero-row entries that merge as all-dead
-            entries = [(c[0], c[1], c[2], c[3], c[4], c[6]) for c in chunk]
+            entries = [(c[0], c[1], c[2], c[4], c[6]) for c in chunk]
             shareds = [c[5] for c in chunk]
             for idxs, ranks in iter_groups(entries, shareds):
                 if len(idxs) == 1:
@@ -818,14 +819,12 @@ class SortRelation(Relation):
                         args.append(c[6])
                     state = device_call(topk_jit, *args, _tag="topk")
                     continue
-                group = pad_group(
-                    [entries[i] for i in idxs],
-                    lambda e: (e[0], e[1], e[2], np.int32(0), e[4], e[5]),
-                )
+                group, counts = pad_group(
+                    [entries[i] for i in idxs], [chunk[i][3] for i in idxs])
                 METRICS.add("fused.groups")
                 METRICS.add("fused.group_batches", len(idxs))
                 state = device_call(
-                    core.group_jit, k, state, tuple(group), ranks,
+                    core.group_jit, k, state, group, counts, ranks,
                     _tag="topk.group",
                 )
             return state
@@ -1053,23 +1052,21 @@ class SortRelation(Relation):
             if not chunk:
                 return device_call(core.final_jit, state,
                                    _tag="topk.final")
-            entries = [(c[0], c[1], c[2], c[3], c[4], c[6]) for c in chunk]
+            entries = [(c[0], c[1], c[2], c[4], c[6]) for c in chunk]
             shareds = [c[5] for c in chunk]
             groups = list(iter_groups(entries, shareds))
             for gi, (idxs, ranks) in enumerate(groups):
-                group = pad_group(
-                    [entries[i] for i in idxs],
-                    lambda e: (e[0], e[1], e[2], np.int32(0), e[4], e[5]),
-                )
+                group, counts = pad_group(
+                    [entries[i] for i in idxs], [chunk[i][3] for i in idxs])
                 METRICS.add("fused.groups")
                 METRICS.add("fused.group_batches", len(idxs))
                 if gi == len(groups) - 1:
                     return device_call(
-                        core.group_final_jit, k, state, tuple(group),
+                        core.group_final_jit, k, state, group, counts,
                         ranks, _tag="topk.final",
                     )
                 state = device_call(
-                    core.group_jit, k, state, tuple(group), ranks,
+                    core.group_jit, k, state, group, counts, ranks,
                     _tag="topk.group",
                 )
 
@@ -1544,28 +1541,30 @@ def run_topk_megabatch(rels: list["SortRelation"]) -> float:
     next_base = 0
 
     def groups_of(chunk):
-        entries = [(c[0], c[1], c[2], c[3], c[4], c[6]) for c in chunk]
+        """The chunk's batch groups, each as its launch takes it:
+        [(entries, counts, rank tables, batches in it)]."""
+        entries = [(c[0], c[1], c[2], c[4], c[6]) for c in chunk]
         shareds = [c[5] for c in chunk]
-        return entries, list(iter_groups(entries, shareds))
+        return [
+            (*pad_group([entries[i] for i in idxs],
+                        [chunk[i][3] for i in idxs]), ranks, len(idxs))
+            for idxs, ranks in iter_groups(entries, shareds)
+        ]
 
     def flush():
         nonlocal states
         if not chunk:
             return
-        entries, groups = groups_of(chunk)
+        groups = groups_of(chunk)
         with METRICS.timer("execute.sort"), op_timer(leader), \
                 _device_scope(device):
-            for idxs, ranks in groups:
-                group = pad_group(
-                    [entries[i] for i in idxs],
-                    lambda e: (e[0], e[1], e[2], np.int32(0), e[4], e[5]),
-                )
+            for group, counts, ranks, n in groups:
                 METRICS.add("fused.groups")
-                METRICS.add("fused.group_batches", len(idxs))
+                METRICS.add("fused.group_batches", n)
                 METRICS.add("serve.megabatch_launches")
                 METRICS.add("serve.megabatch_queries", len(rels))
                 states = device_call(
-                    core.multi_group_jit, ks, states, tuple(group),
+                    core.multi_group_jit, ks, states, group, counts,
                     ranks, _tag="topk.mega",
                 )
         chunk.clear()
@@ -1573,31 +1572,27 @@ def run_topk_megabatch(rels: list["SortRelation"]) -> float:
     def final_flush():
         # mirrors SortRelation._final_flush: the tail group's fold
         # fuses with every query's result merge in one launch
-        entries, groups = groups_of(chunk)
+        groups = groups_of(chunk)
         with METRICS.timer("execute.sort"), op_timer(leader), \
                 _device_scope(device):
             st = states
             if not groups:
                 METRICS.add("serve.megabatch_launches")
                 METRICS.add("serve.megabatch_queries", len(rels))
-                return device_call(core.multi_final_jit, ks, st, (), (),
-                                   _tag="topk.mega.final")
-            for gi, (idxs, ranks) in enumerate(groups):
-                group = pad_group(
-                    [entries[i] for i in idxs],
-                    lambda e: (e[0], e[1], e[2], np.int32(0), e[4], e[5]),
-                )
+                return device_call(core.multi_final_jit, ks, st, (), None,
+                                   (), _tag="topk.mega.final")
+            for gi, (group, counts, ranks, n) in enumerate(groups):
                 METRICS.add("fused.groups")
-                METRICS.add("fused.group_batches", len(idxs))
+                METRICS.add("fused.group_batches", n)
                 METRICS.add("serve.megabatch_launches")
                 METRICS.add("serve.megabatch_queries", len(rels))
                 if gi == len(groups) - 1:
                     return device_call(
-                        core.multi_final_jit, ks, st, tuple(group),
+                        core.multi_final_jit, ks, st, group, counts,
                         ranks, _tag="topk.mega.final",
                     )
                 st = device_call(
-                    core.multi_group_jit, ks, st, tuple(group), ranks,
+                    core.multi_group_jit, ks, st, group, counts, ranks,
                     _tag="topk.mega",
                 )
 

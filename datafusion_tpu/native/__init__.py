@@ -77,16 +77,29 @@ def build_library() -> bool:
     ]
     if not srcs:
         return False
-    if os.path.exists(_LIB_PATH) and os.path.getmtime(_LIB_PATH) >= max(
-        os.path.getmtime(s) for s in srcs
-    ):
+
+    def built() -> bool:
+        return os.path.exists(_LIB_PATH) and os.path.getmtime(
+            _LIB_PATH) >= max(os.path.getmtime(s) for s in srcs)
+
+    if built():
         return True
+    import fcntl
+
     try:
-        subprocess.run(
-            ["make", "-C", _NATIVE_DIR], check=True,
-            capture_output=True, timeout=120,
-        )
-        return os.path.exists(_LIB_PATH)
+        # processes that ask at once (a test run's workers) take turns:
+        # one builds, the others wait and find the library made (the
+        # Makefile renames it into place whole, so a reader never loads
+        # a half-written one)
+        with open(os.path.join(_NATIVE_DIR, ".build.lock"), "w") as lock:
+            fcntl.flock(lock, fcntl.LOCK_EX)
+            if built():
+                return True
+            subprocess.run(
+                ["make", "-C", _NATIVE_DIR], check=True,
+                capture_output=True, timeout=120,
+            )
+            return os.path.exists(_LIB_PATH)
     except (OSError, subprocess.SubprocessError):
         # no make / compiler missing / build error or timeout: callers
         # fall back to the pure-Python front-end

@@ -958,8 +958,8 @@ class PartitionedAggregateRelation(AggregateRelation):
     # -- the partitioned scan loop --
     def accumulate(self):
         from datafusion_tpu.exec.fused import (
+            bucket_group,
             entry_signature,
-            pad_group,
             shared_signature,
         )
         from datafusion_tpu.exec.prefetch import (
@@ -1016,19 +1016,20 @@ class PartitionedAggregateRelation(AggregateRelation):
                     )
                 round_buf.clear()
                 return
-            zero = self._rows((0,) * self.n_shards)
-            group = pad_group(
-                list(round_buf),
-                # dead round: the live round's arrays with a zero row
-                # count — every shard contributes identity
-                lambda r: (r[0], r[1], r[2], zero, r[4], r[5], r[6]),
-            )
+            # padded to its ladder rung with dead rounds: the live
+            # round's arrays with a zero row count (a device array of
+            # the mesh's, like every round's) — every shard contributes
+            # identity
+            live = round_buf[0]
+            dead = (*live[:3], self._rows((0,) * self.n_shards), *live[4:])
+            group = tuple(round_buf) + (dead,) * (
+                bucket_group(len(round_buf)) - len(round_buf))
             METRICS.add("mesh.fused_round_launches")
             METRICS.add("mesh.fused_rounds", len(round_buf))
             with METRICS.timer("execute.partitioned_aggregate"), \
                     op_timer(self):
                 state = device_call(
-                    self._multi_jit, tuple(group), state, self._params,
+                    self._multi_jit, group, state, self._params,
                     _tag="mesh.multi",
                 )
             round_buf.clear()

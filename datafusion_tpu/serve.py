@@ -1230,18 +1230,16 @@ class Server:
             elif needed > capacity:
                 states = [core._grow_state(s, needed) for s in states]
                 capacity = needed
-            entries = [(c[0], c[1], c[3], c[4], c[5]) for c in chunk]
+            entries = [(c[0], c[1], c[4], c[5]) for c in chunk]
             shareds = [(c[2], c[6]) for c in chunk]
             for idxs, (aux, str_aux) in iter_groups(entries, shareds):
-                egroup = pad_group(
-                    [entries[i] for i in idxs],
-                    lambda e: (e[0], e[1], np.int32(0), e[3], e[4]),
-                )
+                egroup, counts = pad_group(
+                    [entries[i] for i in idxs], [chunk[i][3] for i in idxs])
                 st_in = tuple(states) + (states[0],) * (n_q - n_live)
                 with METRICS.timer("execute.serve_megabatch"), \
                         device_scope(device):
                     out = device_call(
-                        core.multi_group_jit, tuple(egroup), st_in, aux,
+                        core.multi_group_jit, egroup, counts, st_in, aux,
                         str_aux, params, _tag="serve.megabatch",
                     )
                 states = list(out[:n_live])
@@ -1269,9 +1267,8 @@ class Server:
                     str_aux = leader._compute_str_aux(batch, core.slots)
                 with device_scope(device):
                     data, validity, mask = leader._device_inputs(batch, core)
-                chunk.append((data, validity, aux,
-                              np.int32(batch.num_rows),
-                              mask, ids, str_aux))
+                chunk.append((data, validity, aux, batch.num_rows, mask,
+                              ids, str_aux))
                 if len(chunk) >= fuse:
                     flush()
             flush()

@@ -169,9 +169,9 @@ def test_cpu_seconds_tell_a_sleeping_thread_from_a_working_one(profiling):
     assert asleep.cpu_s < asleep.wall_s / 10
     assert m.timings["asleep.cpu"] == asleep.cpu_s
     # a busy loop is all CPU, on a machine that leaves it its core: the
-    # suite's workers share theirs, so the best of a few tries
+    # suite's workers share theirs, so the best of several tries
     shares = []
-    for _ in range(5):
+    for _ in range(20):
         with m.timer("busy") as busy:
             _busy(0.03)
         shares.append(busy.cpu_s / busy.wall_s)
