@@ -24,6 +24,9 @@ process exits non-zero without printing a result:
           `tpubench`'s numpy oracle: two pinned builds, the second
           probed by a gathered column, three numeric group keys made
           into groups on the device, `ORDER BY` an alias, `LIMIT 10`
+4c. q4    TPC-H Q4 over orders and lineitem at SF-1 against `tpubench`'s
+          numpy oracle: the correlated EXISTS a semi join whose build is
+          marked into a membership table on the device and looked up there
 5. mesh   only with >= 4 TPU devices: Q1 over lineitem registered through
           `PartitionedContext.register_resident_parquet` on `make_mesh(4)`,
           a new relation a pass; the second pass ships nothing but a
@@ -578,23 +581,14 @@ def stage_operators(device: str, agg_rows: int = 1 << 21) -> dict:
 Q3_SEED = 20260321
 
 
-def stage_q3(device: str, sf, batch_size: int = 1 << 17) -> dict:
-    """TPC-H Q3 over customer, orders and lineitem (`tpubench`'s data set
-    at 6 M lines an SF, and its numpy oracle): the first query builds and
-    pins both joins' build sides, the second finds them again; the three
-    numeric group keys become groups on the device and ten rows return."""
+def _resident_dataset(device: str, ds, made: dict, batch_size: int):
+    """A context over a `tpubench` data set's generated tables, each kept
+    in memory as the engine's batches (strings through one dictionary)."""
     from datafusion_tpu.datatypes import DataType, Field, Schema
     from datafusion_tpu.exec.batch import StringDictionary, make_host_batch
     from datafusion_tpu.exec.context import ExecutionContext
     from datafusion_tpu.exec.datasource import MemoryDataSource
-    from datafusion_tpu.exec.materialize import collect
-    from datafusion_tpu.exec.rowgather import WINDOW_ROWS
-    from tpubench.spec import Spec
 
-    spec = Spec(REPO)
-    ds = spec.dataset("tpch_customer_orders_lineitem")
-    made = ds.generate(Q3_SEED, int(6_000_000 * sf),
-                       threads=min(8, os.cpu_count() or 1))
     kinds = {"i64": DataType.INT64, "f64": DataType.FLOAT64,
              "str": DataType.UTF8}
     ctx = ExecutionContext(device=device, batch_size=batch_size,
@@ -615,6 +609,23 @@ def stage_q3(device: str, sf, batch_size: int = 1 << 17) -> dict:
             make_host_batch(schema, [a[lo: lo + batch_size] for a in arrays],
                             None, dicts)
             for lo in range(0, n, batch_size)]))
+    return ctx
+
+
+def stage_q3(device: str, sf, batch_size: int = 1 << 17) -> dict:
+    """TPC-H Q3 over customer, orders and lineitem (`tpubench`'s data set
+    at 6 M lines an SF, and its numpy oracle): the first query builds and
+    pins both joins' build sides, the second finds them again; the three
+    numeric group keys become groups on the device and ten rows return."""
+    from datafusion_tpu.exec.materialize import collect
+    from datafusion_tpu.exec.rowgather import WINDOW_ROWS
+    from tpubench.spec import Spec
+
+    spec = Spec(REPO)
+    ds = spec.dataset("tpch_customer_orders_lineitem")
+    made = ds.generate(Q3_SEED, int(6_000_000 * sf),
+                       threads=min(8, os.cpu_count() or 1))
+    ctx = _resident_dataset(device, ds, made, batch_size)
     n_orders = len(made["tables"]["orders"]["o_orderkey"])
     params = {"segment": ds.SEGMENT, "date": ds.DATE}
     sql = spec.query("tpch_customer_orders_lineitem", "q3").format(
@@ -667,6 +678,55 @@ def stage_q3(device: str, sf, batch_size: int = 1 << 17) -> dict:
     require(out["q3_pinned"]["h2d.resident_misses"] == 0
             and 0 < out["q3_pinned"]["d2h.bytes"] <= 4096,
             f"q3: the second query moved more than its answer: {out}")
+    return out
+
+
+Q4_DATE = "1993-07-01"
+
+
+def stage_q4(device: str, sf, batch_size: int = 1 << 17) -> dict:
+    """TPC-H Q4 over orders and lineitem (`tpubench`'s data set at 6 M
+    lines an SF, and its numpy oracle): the correlated EXISTS is a semi
+    join whose build, the lines with commit < receipt, is marked into a
+    membership table on the device from lineitem's resident batches, and
+    every order is looked up there; twice, the second finding every
+    column resident."""
+    from datafusion_tpu.exec.materialize import collect
+    from tpubench.spec import Spec
+
+    spec = Spec(REPO)
+    ds = spec.dataset("tpch_q4_orders_lineitem")
+    made = ds.generate(Q3_SEED, int(6_000_000 * sf),
+                       threads=min(8, os.cpu_count() or 1))
+    ctx = _resident_dataset(device, ds, made, batch_size)
+    params = {"date": Q4_DATE}
+    sql = spec.query("tpch_q4_orders_lineitem", "q4").format(
+        **ds.bind("q4", params))
+    want = made["oracle"].answer("q4", params)
+    extra = ("device.launches.join.semi_mark",
+             "device.launches.join.semi_lookup", "join.semi.mark_rows",
+             "join.semi.table_slots", "join.host_probe.rows", "d2h.bytes",
+             "h2d.resident_misses")
+    n_lines = len(made["tables"]["lineitem"]["l_orderkey"])
+    out = {}
+    for label in ("q4_first", "q4_resident"):
+        before = _counts()
+        got = collect(ctx.sql(sql))
+        ev = evidence(before, _counts(), extra)
+        bad = made["oracle"].check("q4", params, got)
+        require(bad is None, f"{label}: {bad}")
+        require_on_device(label, ev)
+        require(ev["device.launches.join.semi_mark"] > 0
+                and ev["join.host_probe.rows"] == 0,
+                f"{label}: the semi join was not marked on the device: {ev}")
+        require(ev["join.semi.mark_rows"] == n_lines,
+                f"{label}: {ev['join.semi.mark_rows']} lines offered to the "
+                f"marks, lineitem has {n_lines}")
+        out[label] = ev
+    out["counts"] = want
+    require(out["q4_resident"]["h2d.resident_misses"] == 0
+            and 0 < out["q4_resident"]["d2h.bytes"] <= 4096,
+            f"q4: the second query moved more than its answer: {out}")
     return out
 
 
@@ -856,6 +916,8 @@ def main(argv=None) -> int:
     report["operators"] = timed("operators", stage_operators, "tpu")
     # Q3 at SF-1 at most: `tpubench`'s q3_sf10_join3 is the SF-10 run
     report["q3"] = timed("q3", stage_q3, "tpu", min(sf, 1))
+    # Q4 at SF-1 at most: `tpubench`'s q4_sf10_exists is the SF-10 run
+    report["q4"] = timed("q4", stage_q4, "tpu", min(sf, 1))
     if device["count"] >= 4:
         report["mesh"] = timed("mesh", stage_mesh, sf, cold["rows"])
     else:

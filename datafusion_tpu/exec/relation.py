@@ -462,6 +462,7 @@ class PipelineRelation(Relation):
         self.projections = projections
         self._schema = out_schema if out_schema is not None else child.schema
         self.device = device
+        self.functions = functions
         self._metas = function_metas or {}
         host_scalar = _is_accelerator(device)
         # On accelerators a numpy-evaluable predicate runs on the host

@@ -46,10 +46,12 @@ left input).  The build side fully materializes once into a
   keys, a build that does not fit).  `core.HashIndex` CSR-expands
   matches per batch; its rows count as ``join.host_probe.rows``.
 
-- **semi probe** (`IN (subquery)`, type "semi": a probe row is kept
-  once if its key has a match, NULL matches nothing): the probe hands
-  on the left batch's own device columns and a mask, no build column.
-  A build of at most `_SEMI_DEVICE_MAX` live keys on one integer key
+- **semi probe** (`IN (subquery)`, a decorrelated `EXISTS`; type
+  "semi": a probe row is kept once if its key has a match, NULL
+  matches nothing): the probe hands on the left batch's own device
+  columns and a mask, no build column.  Three builds, by the semi
+  join's size rule below.
+  A build of at most `_SEMI_DEVICE_MAX` rows on one integer key
   goes to the device as it is, its keys in an array and its padding
   and NULLs marked invalid, and every probe batch is ONE launch
   (``device.launches.join.semi``, program ``jit_semi_probe``) that
@@ -63,11 +65,40 @@ left input).  The build side fully materializes once into a
   is probed where it lies, its live rows (below its row count, valid,
   selected) made in one launch (``device.launches.join.semi_build``):
   neither side crosses the link but the selection mask of a filter run
-  on the host.  A semi
-  build is a query's own result and is never pinned.  A larger one,
-  or a string key, takes the host index (`HashIndex.contains`).
-  Timer ``join.semi_probe``; counters ``join.semi.build_rows``,
-  ``join.semi.probe_rows``.
+  on the host.  A larger build that is a scan of a table that keeps
+  its batches (`reusable_batches`), filtered by the subquery's WHERE
+  and cut to its key (TPC-H Q4's `l_commitdate < l_receiptdate` over
+  lineitem: ~38 M of 60 M rows kept, ~14 M distinct order keys), is
+  marked into a **membership table**: a byte a slot over the build
+  key's span (`kmin` .. the greatest key), `[rows, LANES]` on the
+  device, zeroed once a query.  Each of the scan's batches is one mark
+  launch (``device.launches.join.semi_mark``, program
+  ``jit_semi_mark``) that evaluates the subquery's predicate over the
+  batch's resident columns (the predicate the pipeline would have
+  evaluated on the host goes into the launch: no mask a batch is made
+  or put) and sets the slots of its live keys, one scatter through a
+  window of 1 MB where the batch's keys fit one (lineitem is clustered
+  by `l_orderkey`: a batch's keys span ~128 KB of the 60 MB table),
+  the table donated so it is marked in place.  Each probe batch is
+  then one lookup launch (``device.launches.join.semi_lookup``,
+  program ``jit_semi_lookup``): a row gather and lane select of the
+  table through the same 1 MB window (orders: a batch of 131,072 keys
+  spans 512 KB of it).  Neither side crosses the link; the build's
+  batches are iterated, never listed or compacted.  A byte, not a
+  bit: marking is one scatter of True, right whatever the duplicates,
+  where a bit table would need an OR of words that XLA's scatter has
+  no combiner for; at TPC-H SF-10 the byte table is 60 MB of 16 GB.
+  A semi build is a query's own result and is never pinned.
+  Anything else (a string key, a build that is no such scan, or too
+  sparse for a table) takes the host index (`HashIndex.contains`).
+  Timers ``join.semi_probe`` (a probe batch, either launch),
+  ``join.semi_mark`` (a build batch's mark launch); counters
+  ``join.semi.build_rows`` (the compare probe's and the host's build),
+  ``join.semi.probe_rows``, ``join.semi.mark_rows`` (build rows
+  offered to the marks), ``join.semi.mark_bytes`` (what the marks read
+  and write: the key and predicate columns over those rows, and the
+  table once) and ``join.semi.table_slots`` (the table's slots, once a
+  query).
 
 **The one size rule.**  A unique-integer-key build goes to the device
 when its direct-address table is no sparser than a hash table would
@@ -83,6 +114,22 @@ this).  It is pinned under the same test.  A dimension table and a
 (strings, duplicate keys, too sparse) holds no HBM: it is pinned when
 its host arrays fit the host's free memory.  One that only found no
 room is not pinned, so the next query asks the ledger again.
+
+**The semi join's size rule.**  A build of at most `_SEMI_DEVICE_MAX`
+rows takes the compare probe: a probe key is compared with the build
+keys of its batch's range, and the build costs what its rows do, not
+what its span does (Q18's ~620 order keys over a 60 M-key span: 5 KB,
+where a table would be 60 MB).  Past that, where the build is a scan
+that can be marked (above), it takes the membership table when the
+table is no sparser than the same 32 B a build row that the dense rule
+allows — at most `_SEMI_SLOTS_PER_ROW` one-byte slots a row offered to
+the marks — and it fits the ledger less what either side has yet to
+upload.  The compare probe would cost every probe row a pass over the
+build a block of 128 keys at a time (Q4: 14 M keys, a hundred thousand
+blocks), and the host index would pull the build over the link each
+query (Q4: 300 MB of keys); the table costs a byte a slot, zeroed once
+and written at the build's keys.  What the rule refuses takes the host
+index.
 
 Artifacts pin in the device ledger under the build subtree's query
 fingerprint (``join:<fp>``): a warm query probing the same build side
@@ -111,7 +158,13 @@ from datafusion_tpu.exec.batch import (
     subset_view,
 )
 from datafusion_tpu.exec.relation import Relation
-from datafusion_tpu.exec.rowgather import LANES, pad_rows, take_rows_window
+from datafusion_tpu.exec.rowgather import (
+    LANES,
+    WINDOW_ROWS,
+    pad_rows,
+    set_rows_window,
+    take_rows_window,
+)
 from datafusion_tpu.join import core as _core
 from datafusion_tpu.obs.device import LEDGER, host_fits
 from datafusion_tpu.utils.metrics import METRICS
@@ -122,11 +175,17 @@ from datafusion_tpu.utils.retry import device_call
 _MAX_SLOTS = (1 << 31) - 1
 # at most this many slots a live build row (module doc)
 _SLOTS_PER_BUILD_ROW = 8
-# the most live keys a semi join's build may hold to be probed on the
-# device; each launch compares a probe key with each of them (module doc)
+# the most rows a semi join's build may hold to take the compare probe;
+# each launch compares a probe key with each of them (module doc)
 _SEMI_DEVICE_MAX = 4096
 # build keys one step of the semi probe compares against at once
 _SEMI_BLOCK = 128
+# the most one-byte slots of a membership table a build row offered to
+# the marks may cost: the dense rule's 32 B a row (module doc)
+_SEMI_SLOTS_PER_ROW = 32
+# rows of the window the membership table is marked and read through:
+# 1 MB of bytes, as `WINDOW_ROWS` rows of int32 are
+_SEMI_WINDOW_ROWS = 4 * WINDOW_ROWS
 
 
 def _device_path_enabled() -> bool:
@@ -314,6 +373,74 @@ def _semi_probe_fn():
 
 
 @functools.lru_cache(maxsize=None)
+def _semi_mark_fn(core, key_at: int, pred_at: tuple):
+    """One mark launch (`jit_semi_mark`): the membership table with the
+    slots of a build batch's live keys set.  A key is live below the
+    batch's row count, selected by its mask, valid, inside the table
+    and, where `core` (a `_PipelineCore` of the subquery's predicate,
+    no projection) is given, where the predicate holds (NULL drops the
+    row, as a WHERE does).  The batch's view holds the key at `key_at`
+    and the predicate's columns at `pred_at`, in `core.used_cols`
+    order.  The table is donated: marked in place."""
+    import jax
+    import jax.numpy as jnp
+
+    def semi_mark(table, kmin, num_slots, data, validity, aux, num_rows,
+                  mask, params):
+        key, kvalid = data[key_at], validity[key_at]
+        if core is None:
+            live = jnp.arange(key.shape[0], dtype=jnp.int32) < num_rows
+            if mask is not None:
+                live = live & mask
+        else:
+            live = core._kernel([data[i] for i in pred_at],
+                                [validity[i] for i in pred_at], aux,
+                                num_rows, mask, params)[2]
+        if kvalid is not None:
+            live = live & kvalid
+        d = key.astype(jnp.int64) - kmin
+        live = live & (d >= 0) & (d < num_slots)
+        return set_rows_window(table, d.astype(jnp.int32), live,
+                               _SEMI_WINDOW_ROWS)
+
+    return jax.jit(semi_mark, donate_argnums=0)
+
+
+@functools.lru_cache(maxsize=None)
+def _semi_lookup_fn():
+    """One lookup launch (`jit_semi_lookup`): whether each probe key's
+    slot of the membership table is set, AND the key's validity and
+    the batch's mask; the table is read through a window of
+    `_SEMI_WINDOW_ROWS` rows where the batch's keys fit one."""
+    import jax
+    import jax.numpy as jnp
+
+    def semi_lookup(key, kvalid, mask, table, kmin, num_slots):
+        d = key.astype(jnp.int64) - kmin
+        live = (d >= 0) & (d < num_slots)
+        for m in (kvalid, mask):
+            if m is not None:
+                live = live & m
+        found, _ = take_rows_window(table, d.astype(jnp.int32), live,
+                                    _SEMI_WINDOW_ROWS)
+        return found & live
+
+    return jax.jit(semi_lookup)
+
+
+@functools.lru_cache(maxsize=None)
+def _semi_table_fn(rows: int):
+    """An empty membership table of `rows` rows of `LANES` bytes."""
+    import jax
+    import jax.numpy as jnp
+
+    def semi_table():
+        return jnp.zeros((rows, LANES), jnp.bool_)
+
+    return jax.jit(semi_table)
+
+
+@functools.lru_cache(maxsize=None)
 def _live_rows_fn():
     """A column's live rows as one bool array: below `num_rows`, valid
     (None: all) and selected by the mask (None: all)."""
@@ -341,6 +468,29 @@ def _window_counts_fn():
         return jnp.sum(jnp.stack(windows), axis=0, dtype=jnp.int32)
 
     return jax.jit(window_counts)
+
+
+def _device_scalar(x, device):
+    """`x` placed once, for launches that take it as an argument: a
+    numpy scalar handed to a jitted call is a transfer of its own in
+    every launch."""
+    if device is not None:
+        return LEDGER.put(x, device, owner="join.build")
+    import jax.numpy as jnp
+
+    return LEDGER.adopt(jnp.asarray(x), owner="join.build")
+
+
+def _rows_on_device(batch: RecordBatch, device):
+    """`batch`'s row count as a device int32, placed once and kept on
+    the batch (a batch of a table that keeps its batches is marked
+    again by every query)."""
+    key = ("num_rows", None if device is None else repr(device))
+    hit = batch.cache.get(key)
+    if hit is None:
+        hit = batch.cache[key] = _device_scalar(np.int32(batch.num_rows),
+                                                device)
+    return hit
 
 
 def _unplaced_bytes(rel: Relation, device) -> int:
@@ -576,14 +726,9 @@ class HashJoinRelation(Relation):
                            _pallas.interpret_mode()),
                 dev_pos, dev_live, payload, _tag="join.build",
             ), owner="join.build", device=self.device)
-        # the probe's two scalars, placed once: a numpy scalar handed to
-        # a jitted call is a transfer of its own in every launch
-        import jax.numpy as jnp
-
+        # the probe's two scalars, placed once
         art.dev_kmin, art.dev_num_slots = (
-            LEDGER.put(np.int64(x), self.device, owner="join.build")
-            if self.device is not None
-            else LEDGER.adopt(jnp.asarray(np.int64(x)), owner="join.build")
+            _device_scalar(np.int64(x), self.device)
             for x in (kmin, num_slots))
         METRICS.add("join.build.dense")
 
@@ -725,21 +870,119 @@ class HashJoinRelation(Relation):
             return None, _core.HashIndex([cols[ri]], [valids[ri]],
                                          [dicts[ri]])
 
+    def _mark_source(self) -> Optional[tuple]:
+        """(scan relation, key column of the scan, the pipeline over it or
+        None) where the build can be marked into a membership table
+        (module doc): one integer key, and the build a scan of a table
+        that keeps its batches, under at most a pipeline that filters it
+        by a predicate the device can evaluate and cuts it to columns.
+        None otherwise."""
+        from datafusion_tpu.exec.hostfn import contains_host_fn
+        from datafusion_tpu.exec.relation import PipelineRelation
+        from datafusion_tpu.plan.expr import Column
+
+        if not self.device_batches:
+            return None
+        rel, key, pipe = self.right, self.on[0][1], None
+        if isinstance(rel, PipelineRelation):
+            if rel.projections is not None:
+                if not isinstance(rel.projections[key], Column):
+                    return None
+                key = rel.projections[key].index
+            if rel.predicate is not None and contains_host_fn(
+                    rel.predicate, rel._metas):
+                return None
+            pipe, rel = rel, rel.child
+        if not getattr(getattr(rel, "datasource", None), "reusable_batches",
+                       False):
+            return None
+        return rel, key, pipe
+
+    def _semi_mark(self, scan, key: int, pipe) -> Optional[tuple]:
+        """(table, kmin, slot count) of the membership table marked from
+        `scan`'s batches by the predicate of `pipe` (None: every row),
+        the last two as device scalars; None where the size rule (module
+        doc) gives the build another probe."""
+        from datafusion_tpu.exec.aggregate import _key_bounds
+        from datafusion_tpu.exec.expression import compute_aux_values
+        from datafusion_tpu.exec.kernels import parameterize_exprs
+        from datafusion_tpu.exec.relation import _PipelineCore, device_scope
+
+        with METRICS.timer("join.build"):
+            rows, bounds = 0, []
+            for b in scan.datasource.batches():
+                rows += b.num_rows
+                kb = _key_bounds(b, key)
+                if kb is not None:
+                    bounds.append(kb)
+            if rows <= _SEMI_DEVICE_MAX:
+                return None
+            kmin = min((lo for lo, _ in bounds), default=0)
+            num_slots = max((hi for _, hi in bounds), default=kmin) - kmin + 1
+            if num_slots > min(_MAX_SLOTS, _SEMI_SLOTS_PER_ROW * rows):
+                return None
+            table_rows = pad_rows(num_slots) // LANES
+            if not LEDGER.fits(table_rows * LANES
+                               + _unplaced_bytes(self, self.device)):
+                return None
+            core = params = None
+            if pipe is not None and pipe.predicate is not None:
+                core = _PipelineCore.build(scan.schema, pipe.predicate, None,
+                                           pipe.functions, pipe._metas)
+                params = parameterize_exprs(_PipelineCore.param_exprs(
+                    pipe.predicate, None, pipe._metas))[2]
+            used = [] if core is None else core.used_cols
+            cols = sorted({key, *used})
+            mark_fn = _semi_mark_fn(core, cols.index(key),
+                                    tuple(cols.index(c) for c in used))
+            with device_scope(self.device):
+                table = LEDGER.adopt(
+                    device_call(_semi_table_fn(table_rows),
+                                _tag="join.semi_table"),
+                    owner="join.semi", cached=False)
+            kmin_dev, slots_dev = (_device_scalar(np.int64(x), self.device)
+                                   for x in (kmin, num_slots))
+        METRICS.add("join.semi.table_slots", num_slots)
+        aux_cache: dict = {}
+        for batch in scan.batches():
+            with METRICS.timer("join.semi_mark"):
+                view = subset_view(batch, cols)
+                data, validity, mask = device_inputs(
+                    view, self.device, None if core is None else core.wire_hints)
+                aux = () if core is None else tuple(
+                    compute_aux_values(core.aux_specs, batch, aux_cache))
+                table = device_call(
+                    mark_fn, table, kmin_dev, slots_dev, data, validity, aux,
+                    _rows_on_device(view, self.device), mask, params,
+                    _tag="join.semi_mark")
+            METRICS.add("join.semi.mark_rows", batch.num_rows)
+            METRICS.add("join.semi.mark_bytes", batch.num_rows * sum(
+                a.dtype.itemsize for a in (*view.data, *view.validity)
+                if a is not None))
+        METRICS.add("join.semi.mark_bytes", table_rows * LANES)
+        return (LEDGER.adopt(table, owner="join.semi", cached=False),
+                kmin_dev, slots_dev)
+
     def _semi_batches(self):
         from datafusion_tpu.exec.materialize import (
             compact_batch,
             iter_with_mask_prefetch,
         )
 
-        build, index = self._semi_build()
         li = self.on[0][0]
+        source = self._mark_source()
+        build = self._semi_mark(*source) if source is not None else None
         if build is not None:
-            probe_fn = _semi_probe_fn()
+            probe_fn, tag = _semi_lookup_fn(), "join.semi_lookup"
+        else:
+            build, index = self._semi_build()
+            probe_fn, tag = _semi_probe_fn(), "join.semi"
+        if build is not None:
             for batch in self.left.batches():
                 with METRICS.timer("join.semi_probe"):
                     data, validity, mask = device_inputs(batch, self.device)
                     hit = device_call(probe_fn, data[li], validity[li], mask,
-                                      *build, _tag="join.semi")
+                                      *build, _tag=tag)
                 METRICS.add("join.semi.probe_rows", batch.num_rows)
                 yield RecordBatch(self._schema, list(data), list(validity),
                                   list(batch.dicts), num_rows=batch.num_rows,

@@ -107,6 +107,15 @@ class SqlInSubquery(SqlNode):
 
 
 @dataclass
+class SqlExists(SqlNode):
+    """`[NOT] EXISTS (SELECT ...)`: whether the subquery has a row (the
+    planner decorrelates it into a semi join)."""
+
+    subquery: "SqlSelect"
+    negated: bool = False
+
+
+@dataclass
 class SqlNested(SqlNode):
     """Parenthesized expression."""
 

@@ -14,8 +14,8 @@ the CREATE EXTERNAL TABLE extension, `src/dfparser.rs:101-208`):
 Expression grammar with precedence climbing:
     OR < AND < NOT < comparison (= != <> < <= > >=) < + - < * / %
 with postfix IS [NOT] NULL, `[NOT] IN (SELECT ...)` (an uncorrelated
-subquery of one column), CAST(expr AS TYPE), function calls, unary +/-,
-parenthesized expressions.
+subquery of one column), prefix `[NOT] EXISTS (SELECT ...)`, CAST(expr
+AS TYPE), function calls, unary +/-, parenthesized expressions.
 """
 
 from __future__ import annotations
@@ -56,8 +56,9 @@ _RESERVED_STOP = {
 # false positive ('JOIN' inside a string literal) is harmless — the
 # Python parser implements the full grammar.
 _HAS_JOIN = re.compile(r"\bJOIN\b", re.IGNORECASE)
-# `IN (SELECT ...)` is Python-front-end grammar too
-_HAS_IN_SELECT = re.compile(r"\bIN\s*\(\s*SELECT\b", re.IGNORECASE)
+# `IN (SELECT ...)` and `EXISTS (SELECT ...)` are Python-front-end
+# grammar too
+_HAS_SUBQUERY = re.compile(r"\b(?:IN|EXISTS)\s*\(\s*SELECT\b", re.IGNORECASE)
 
 _TYPE_WORDS = {
     "BOOLEAN": ast.SqlType.Boolean,
@@ -347,6 +348,17 @@ class Parser:
             return ast.SqlInSubquery(left, sub, negated)
         raise ParserError(f"Unexpected infix token {t} in {self.sql!r}")
 
+    def _parse_exists(self, negated: bool) -> ast.SqlExists:
+        """`( SELECT ... )` after [NOT] EXISTS."""
+        self.expect_op("(")
+        if not self.parse_keyword("SELECT"):
+            raise ParserError(
+                f"Expected SELECT after EXISTS (, found {self.peek()} in "
+                f"{self.sql!r}")
+        sub = self._parse_select(nested=True)
+        self.expect_op(")")
+        return ast.SqlExists(sub, negated)
+
     def parse_prefix(self) -> ast.SqlNode:
         t = self.next()
         if t.kind == NUMBER:
@@ -375,6 +387,10 @@ class Parser:
             return ast.SqlBooleanLiteral(False)
         if w == "NULL":
             return ast.SqlNullLiteral()
+        if w == "NOT" and self.parse_keyword("EXISTS"):
+            return self._parse_exists(negated=True)
+        if w == "EXISTS" and self.peek().kind == OP and self.peek().value == "(":
+            return self._parse_exists(negated=False)
         if w == "NOT":
             return ast.SqlUnary("NOT", self.parse_expr(_PREC_NOT))
         if w == "CAST":
@@ -444,7 +460,7 @@ def parse_sql(sql: str) -> ast.SqlNode:
                 "CREATE MATERIALIZED VIEW requires AS SELECT ...")
         return ast.SqlCreateMaterializedView(m.group(1), query, query_sql)
     # multi-relation FROM (JOIN) is Python-front-end-only grammar
-    if _HAS_JOIN.search(sql) or _HAS_IN_SELECT.search(sql):
+    if _HAS_JOIN.search(sql) or _HAS_SUBQUERY.search(sql):
         return Parser(sql).parse_statement()
     node = native_parse_sql(sql)
     if node is not None:

@@ -29,6 +29,16 @@ each).  It is placed on the left spine of the join tree, as deep as the
 key column (or a column an inner join's ON equates with it) reaches:
 TPC-H Q18's `o_orderkey IN (...)` filters lineitem by `l_orderkey`
 before either join probes.
+
+`EXISTS (SELECT ... WHERE inner_col = outer_col AND ...)`, a conjunct of
+WHERE, is decorrelated into the same semi join: the one equality between
+a column of the outer query and a column of the subquery's FROM is the
+key pair, and the subquery's other conjuncts stay inside it as a
+Selection over its FROM (TPC-H Q4: `orders ⋉ lineitem` on
+`o_orderkey = l_orderkey`, `l_commitdate < l_receiptdate` inside the
+build).  Any other correlation is refused by name: NOT EXISTS (an anti
+join), EXISTS under OR, a correlated conjunct that is not such an
+equality, more than one correlated column, and an uncorrelated EXISTS.
 """
 
 from __future__ import annotations
@@ -110,6 +120,14 @@ def _conjuncts(node: ast.SqlNode) -> list[ast.SqlNode]:
     if isinstance(node, ast.SqlBinaryExpr) and node.op == "AND":
         return _conjuncts(node.left) + _conjuncts(node.right)
     return [node]
+
+
+def _conjoin(nodes: list) -> Optional[ast.SqlNode]:
+    """The AND of AST conjuncts, None for none."""
+    out = None
+    for c in nodes:
+        out = c if out is None else ast.SqlBinaryExpr(out, "AND", c)
+    return out
 
 
 def _aggregates_in(e: Expr, out: list) -> list:
@@ -295,6 +313,71 @@ class SqlToRel:
             raise PlanError(f"IN (subquery) compares {lt!r} with {rt!r}")
         return self._semi_join(plan, key.index, sub)
 
+    def _plan_exists(self, node: ast.SqlExists,
+                     plan: LogicalPlan) -> LogicalPlan:
+        """`plan` semi-joined against the decorrelated subquery of
+        `node` (module doc).  A name the subquery's FROM has is the
+        subquery's, as SQL scopes it; one only `plan` has is the outer
+        query's."""
+        if node.negated:
+            raise NotSupportedError(
+                "NOT EXISTS (subquery) is not supported (it is an anti join)")
+        inner = node.subquery
+        if inner.relation is None:
+            raise NotSupportedError("EXISTS (subquery) needs a FROM clause")
+        if inner.group_by or inner.having is not None or inner.limit is not None:
+            raise NotSupportedError(
+                "EXISTS over a grouped or limited subquery is not supported")
+        own = self.sql_to_rel(inner.relation).schema
+        own_names = {f.name for f in own.fields} | {
+            f.name.split(".")[-1] for f in own.fields}
+        outer_names = ({f.name for f in plan.schema.fields} | {
+            f.name.split(".")[-1] for f in plan.schema.fields}) - own_names
+
+        def outer_in(n) -> set:
+            return _names_in(n, set()) & outer_names
+
+        named = outer_in([inner.projection, [o.expr for o in inner.order_by]])
+        if named:
+            raise NotSupportedError(
+                "correlated subquery: EXISTS (SELECT ...) names "
+                f"{sorted(named)} of the outer query outside its WHERE")
+        keep, pairs = [], []
+        for c in (_conjuncts(inner.selection)
+                  if inner.selection is not None else []):
+            if not outer_in(c):
+                keep.append(c)
+                continue
+            sides = (c.left, c.right) if isinstance(
+                c, ast.SqlBinaryExpr) and c.op == "=" else ()
+            idents = [s for s in sides if isinstance(
+                s, (ast.SqlIdentifier, ast.SqlCompoundIdentifier))]
+            outer = [s for s in idents if outer_in(s)]
+            if len(idents) != 2 or len(outer) != 1:
+                raise NotSupportedError(
+                    "correlated subquery: a non-equality correlation in "
+                    f"EXISTS ({c!r}); only one outer column = one subquery "
+                    "column is supported")
+            pairs.append((outer[0], next(s for s in idents if s is not outer[0])))
+        if not pairs:
+            raise NotSupportedError(
+                "an uncorrelated EXISTS (subquery) is not supported: it "
+                "needs one equality with a column of the outer query")
+        if len(pairs) > 1:
+            raise NotSupportedError(
+                "correlated subquery: more than one correlated column in "
+                f"EXISTS ({len(pairs)} equalities with the outer query)")
+        (outer_col, inner_col), = pairs
+        sub = self._plan_select(ast.SqlSelect(
+            projection=[inner_col], relation=inner.relation,
+            selection=_conjoin(keep)))
+        key = self.sql_to_rex(outer_col, plan.schema)
+        lt = plan.schema.field(key.index).data_type
+        rt = sub.schema.field(0).data_type
+        if lt != rt and get_supertype(lt, rt) is None:
+            raise PlanError(f"EXISTS correlates {lt!r} with {rt!r}")
+        return self._semi_join(plan, key.index, sub)
+
     def _semi_join(self, plan: LogicalPlan, col: int,
                    sub: LogicalPlan) -> LogicalPlan:
         """The semi join of `plan`'s column `col` against `sub`'s one
@@ -322,19 +405,19 @@ class SqlToRel:
         else:
             input_plan = EmptyRelation(Schema([]))
 
-        # IN (subquery) conjuncts of WHERE become semi joins of the FROM
-        # plan, the rest a Selection over it
+        # IN (subquery) and EXISTS conjuncts of WHERE become semi joins of
+        # the FROM plan, the rest a Selection over it
         where = sel.selection
         if where is not None:
             parts = _conjuncts(where)
-            rest = [c for c in parts if not isinstance(c, ast.SqlInSubquery)]
+            subqueries = (ast.SqlInSubquery, ast.SqlExists)
+            rest = [c for c in parts if not isinstance(c, subqueries)]
             for c in parts:
                 if isinstance(c, ast.SqlInSubquery):
                     input_plan = self._plan_in_subquery(c, input_plan)
-            where = None
-            for c in rest:
-                where = c if where is None else ast.SqlBinaryExpr(
-                    where, "AND", c)
+                elif isinstance(c, ast.SqlExists):
+                    input_plan = self._plan_exists(c, input_plan)
+            where = _conjoin(rest)
         input_schema = input_plan.schema
 
         # WHERE first (reference sqlplanner.rs:68-74)
@@ -590,6 +673,10 @@ class SqlToRel:
         if isinstance(node, ast.SqlInSubquery):
             raise NotSupportedError(
                 "IN (subquery) is supported as a conjunct of WHERE only")
+        if isinstance(node, ast.SqlExists):
+            raise NotSupportedError(
+                "EXISTS (subquery) is supported as a conjunct of WHERE only, "
+                "not under OR or NOT")
         raise NotSupportedError(f"Unsupported expression {node!r}")
 
     @staticmethod

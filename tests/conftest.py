@@ -41,7 +41,8 @@ def test_data_dir():
 # have to go.  What else the first two tests assert is asserted again,
 # by name, in `tests/tpubench/test_tpubench_launch_metrics.py`; what
 # else the third does, in `tests/test_parquet_recut.py`; what else the
-# last two do, in `tests/tpubench/test_tpubench_q18.py`.
+# two launch-metric tests do, in `tests/tpubench/test_tpubench_q18.py`;
+# what else its own two do, in `tests/tpubench/test_tpubench_q4.py`.
 SUPERSEDED = {
     "test_tpubench_resident_hit_share.py::"
     "test_resident_hit_share_is_a_counter_of_the_h2d_layer_in_every_cell":
@@ -69,6 +70,17 @@ SUPERSEDED = {
         "line 212 takes workloads[-1] and configs[-1] to be the four-chip "
         "cell's, and PR 39's cell and configuration go to the end of the "
         "lists: a benchmark PR makes it find them by name",
+    # the Q4 cell appends a cell, its configuration and two per-layer
+    # entries
+    "test_tpubench_q18.py::"
+    "test_the_launch_five_then_this_cells_four_close_per_layer":
+        "line 328 holds q18's four metrics to be the last entries of "
+        "per_layer, and the Q4 cell's two go to the end of the list: a "
+        "benchmark PR makes it find them by name",
+    "test_tpubench_q18.py::test_the_four_chip_cell_by_name":
+        "line 346 holds q18_sf10_highcard to be the last name of "
+        "resident_hit_share.workloads, and q4_sf10_exists goes to the end of "
+        "the list: a benchmark PR makes it find the cells by name",
 }
 
 

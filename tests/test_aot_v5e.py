@@ -53,3 +53,56 @@ def test_the_semi_builds_live_rows_compile_for_a_v5e(one_chip, masked):
 
     _live_rows_fn().lower(shape(jnp.int64), np.int32(621), shape(jnp.bool_),
                           shape(jnp.bool_) if masked else None).compile()
+
+
+def _lineitem_core():
+    """The pipeline core of Q4's subquery predicate, `l_commitdate <
+    l_receiptdate`, over (l_orderkey, l_commitdate, l_receiptdate)."""
+    from datafusion_tpu.datatypes import DataType, Field, Schema
+    from datafusion_tpu.exec.relation import _PipelineCore
+    from datafusion_tpu.plan.expr import BinaryExpr, Column, Operator
+
+    schema = Schema([Field(n, DataType.INT64, False) for n in (
+        "l_orderkey", "l_commitdate", "l_receiptdate")])
+    pred = BinaryExpr(Column(1), Operator.Lt, Column(2))
+    return _PipelineCore.build(schema, pred, None, {}, {})
+
+
+@pytest.mark.parametrize("masked", [False, True])
+def test_the_semi_mark_compiles_for_a_v5e(one_chip, masked):
+    """At TPC-H SF-10's size: 131,072 lineitem rows a batch into a
+    table of 60 M one-byte slots, donated."""
+    import jax
+    import jax.numpy as jnp
+
+    from datafusion_tpu.join.relation import _semi_mark_fn
+
+    def shape(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    rows = 131_072
+    core = _lineitem_core()
+    col = shape((rows,), jnp.int64)
+    mask = shape((rows,), jnp.bool_) if masked else None
+    _semi_mark_fn(core, 0, (1, 2)).lower(
+        shape((468_750, 128), jnp.bool_), shape((), jnp.int64),
+        shape((), jnp.int64), (col, col, col), (None, None, None), (),
+        shape((), jnp.int32), mask, ()).compile()
+
+
+@pytest.mark.parametrize("masked", [False, True])
+def test_the_semi_lookup_compiles_for_a_v5e(one_chip, masked):
+    import jax
+    import jax.numpy as jnp
+
+    from datafusion_tpu.join.relation import _semi_lookup_fn
+
+    def shape(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    rows = 131_072
+    flags = shape((rows,), jnp.bool_) if masked else None
+    _semi_lookup_fn().lower(
+        shape((rows,), jnp.int64), flags, flags,
+        shape((468_750, 128), jnp.bool_), shape((), jnp.int64),
+        shape((), jnp.int64)).compile()

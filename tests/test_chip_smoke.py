@@ -116,6 +116,17 @@ def test_q3_stage(monkeypatch):
     assert out["q3_pinned"]["join.probe.window.payload"] == 0
 
 
+def test_q4_stage():
+    # 60,000 lines in batches of 8,192, 15,000 orders
+    out = chip_smoke.stage_q4("cpu", SF, batch_size=1 << 13)
+    for label in ("q4_first", "q4_resident"):
+        assert out[label]["device.launches.join.semi_mark"] == 8
+        assert out[label]["device.launches.join.semi_lookup"] == 2
+        assert out[label]["join.semi.mark_rows"] == 60_000
+        assert out[label]["join.host_probe.rows"] == 0
+    assert len(out["counts"]) == 5
+
+
 def test_mesh_stage_places_four_shards(oracle):
     # conftest gives 8 virtual CPU devices; the stage takes four
     out = chip_smoke.stage_mesh(SF, oracle.q1(), batch_size=1 << 13)

@@ -51,12 +51,17 @@ SEMI_CASES = {
 }
 
 
-@pytest.mark.parametrize("path", ["device", "host"])
+@pytest.mark.parametrize("path", ["device", "host", "table"])
 @pytest.mark.parametrize("case", list(SEMI_CASES))
 def test_semi_join_against_python(sctx, monkeypatch, case, path):
+    """`device`: the compare probe; `table`: past the compare probe's
+    size, the membership table marked from `s`'s batches; `host`: past
+    it, with the table refused too."""
     where, kept = SEMI_CASES[case]
-    if path == "host":
+    if path != "device":
         monkeypatch.setattr(join_rel, "_SEMI_DEVICE_MAX", -1)
+    if path == "host":
+        monkeypatch.setattr(join_rel, "_SEMI_SLOTS_PER_ROW", 0)
     d = sctx._data
     build = set(d["sk"][kept(d)].tolist())
     want = sorted((int(k), v) for v, (k, ok) in enumerate(
@@ -68,14 +73,18 @@ def test_semi_join_against_python(sctx, monkeypatch, case, path):
     # each probe row once, whatever the build's duplicates; NULL on
     # either side matches nothing
     assert got == want
-    if path == "device":
-        assert _delta(s0, s1, "device.launches.join.semi") == -(-1_000 // 256)
-        assert _delta(s0, s1, "join.host_probe.rows") == 0
-    else:
-        assert _delta(s0, s1, "device.launches.join.semi") == 0
+    probe_tag = {"device": "device.launches.join.semi",
+                 "table": "device.launches.join.semi_lookup"}
+    for p, tag in probe_tag.items():
+        assert _delta(s0, s1, tag) == (-(-1_000 // 256) if p == path else 0)
+    assert _delta(s0, s1, "device.launches.join.semi_mark") == (
+        -(-65 // 32) if path == "table" else 0)
+    if path == "host":
         assert _delta(s0, s1, "join.host_probe.rows") > 0
+    else:
+        assert _delta(s0, s1, "join.host_probe.rows") == 0
     assert _delta(s0, s1, "join.semi.probe_rows") == (
-        1_000 if path == "device" else 0)
+        0 if path == "host" else 1_000)
 
 
 def test_the_semi_join_goes_under_the_inner_joins(sctx):
