@@ -12,8 +12,9 @@ owner of a table lays it out once, on the host or where it is built.
 
 Three users: the join's device probe (`join/relation.py`: slot table and
 build payload), the semi join's membership table (a byte a slot, marked
-by `set_rows_window` and read through a window of as many bytes as the
-int32 tables' window holds) and the ordered string-vs-literal compare
+by `set_rows_window`, by tile where the keys are clustered, and read
+through a window of as many bytes as the int32 tables' window holds)
+and the ordered string-vs-literal compare
 (`exec/expression.py`: the per-literal truth table over dictionary
 codes, `AuxSpec("cmp_table")`).
 
@@ -37,6 +38,28 @@ with one payload column and 7.54 -> 1.31 ms with three, with uniform
 keys 1.78 -> 1.80 ms, and over a 1.5 M-row build with uniform keys
 0.633 -> 0.658 ms: the choice costs the side that does not take the
 window about 0.025 ms a launch.
+
+**The mark by tile** (`set_rows_window`, the semi join's membership
+table).  An element scatter costs a v5e ~6 ns an index whatever the
+sort of the indices or the size of the target: 131,072 clustered keys a
+launch into a 60 M-slot table took 0.81 ms through the window and 1.04
+whole (PERF.md section 6).  Keys clustered as TPC-H's lineitem
+is by `l_orderkey` keep each block of `MARK_BLOCK` consecutive entries
+inside a few table rows, so the launch cuts its entries into blocks,
+takes each block's smallest live row as its base, makes the block's
+`[MARK_TILE_ROWS, LANES]` tile densely (entry `s` of the tile is set
+where a live entry of the block lies `s` slots past the base: a compare
+and an `any` over the block), and places the tiles from their bases
+with one row scatter whose update is `max`, an OR: 4,096 rows of 128
+lanes where the element scatter wrote 131,072 single slots, right
+whatever the earlier marks and the tiles of neighbouring blocks that
+share a row.  The launch goes by tile where every block's live rows
+span at most `MARK_TILE_ROWS` and the window is taken (a table no
+larger than the window is its own), and scatters as before where not:
+the indices in hand choose, in the program.  On a v5e, 131,072
+clustered keys a launch, 458 launches into a 60 M-slot table: 0.814 ->
+0.120 ms a mark; shuffled keys take the scatter, 1.141 ms a mark on
+either side (PERF.md section 6).
 
 The truth table travels a bit a code, 32 to a word (`pack_bits`,
 `take_bits`): a row then holds 4,096 codes, and a table of one row,
@@ -62,6 +85,17 @@ WORD_BITS = 1 << _WORD_SHIFT
 # PR 28); inside the join's probe 0.35 ms, gather 0.237 + select 0.110
 # (PR 33)
 WINDOW_ROWS = 2_048
+# entries of a block that `set_rows_window` marks as one tile, and the
+# rows of the tile (module doc).  TPC-H's lineitem (1..7 lines an
+# order, 8 of every 32 order keys) keeps a block of 128 lines' keys
+# inside at most 3 rows of the membership table, and one of 256 inside
+# 4; 4 rows leave blocks of 128 a row to spare.  131,072 clustered keys
+# a mark on a v5e, in a first form of the program: blocks of 128 or 256
+# with 4 rows 0.148 / 0.146 ms, 128 with 3 rows 0.360 (a compare 384
+# wide), tiles as a one-hot contraction 0.172-0.193; the element
+# scatter 0.814 (PERF.md section 6)
+MARK_BLOCK = 128
+MARK_TILE_ROWS = 4
 
 
 def pad_rows(n: int) -> int:
@@ -150,36 +184,93 @@ def take_rows_window(tables, idx, live, window_rows: int = WINDOW_ROWS):
     return lax.cond(took, through_window, whole, tables, windows), took
 
 
+def _scatter_rows(table, idx, live, first_row):
+    """`table` with entry `idx - first_row * LANES` set wherever `live`:
+    one element scatter of True.  An index that is not live points past
+    the end and is dropped."""
+    import jax.numpy as jnp
+
+    r = jnp.where(live, (idx >> LANE_BITS) - first_row, table.shape[0])
+    return table.at[r, idx & (LANES - 1)].set(True, mode="drop")
+
+
+def _mark_blocks(idx, live, rows: int):
+    """`idx` and `live` cut into blocks of `MARK_BLOCK` (the last padded
+    with entries that are not live), and each block's smallest and
+    largest live row (`rows` and -1 where none is live)."""
+    import jax.numpy as jnp
+
+    pad = -idx.shape[0] % MARK_BLOCK
+    if pad:
+        idx = jnp.pad(idx, (0, pad))
+        live = jnp.pad(live, (0, pad))
+    idx, live = (a.reshape(-1, MARK_BLOCK) for a in (idx, live))
+    row = idx >> LANE_BITS
+    low = jnp.min(jnp.where(live, row, rows), axis=1)
+    high = jnp.max(jnp.where(live, row, -1), axis=1)
+    return idx, live, low, high
+
+
+def _tile_rows(table, idx, live, low, first_row):
+    """`table` with the blocks' entries set, a tile a block (module doc):
+    `[MARK_TILE_ROWS, LANES]` bools made densely from the block's live
+    entries, placed from its smallest live row `low` by a row scatter
+    that ORs.  Every live entry of a block lies in its tile's rows; the
+    tile of a block with none is placed past the end and dropped."""
+    import jax.numpy as jnp
+
+    off = jnp.where(live, idx - (low << LANE_BITS)[:, None], -1)
+    slots = jnp.arange(MARK_TILE_ROWS * LANES, dtype=jnp.int32)
+    tiles = jnp.any(off[:, :, None] == slots, axis=1).reshape(-1, LANES)
+    at = ((low - first_row)[:, None]
+          + jnp.arange(MARK_TILE_ROWS, dtype=jnp.int32)).reshape(-1)
+    return table.at[at].max(tiles, mode="drop")
+
+
 def set_rows_window(table, idx, live, window_rows: int):
     """`table` (bool `[rows, LANES]`) with entry `idx` set wherever
-    `live`: one scatter of True, through a window of `window_rows` rows
-    where the live indices fit one (`take_rows_window`'s rule), else
-    into the whole table.  Duplicate indices set the same value.  On a
-    v5e 131,072 clustered indices into a 60 M-entry table took 0.81 ms
-    through a window and 1.04 ms whole: the scatter's own cost, about
-    6 ns an index, whatever the sort of the indices or the size of the
-    target (PERF.md section 6).  Donate `table` to the launch:
-    the update is then made in place, the conditional included."""
+    `live`, and whether the launch marked it by tile (a bool scalar).
+    By tile (module doc) where every block of `MARK_BLOCK` entries
+    keeps its live entries inside `MARK_TILE_ROWS` rows, through a
+    window of `window_rows` rows where the live indices fit one
+    (`take_rows_window`'s rule); else one element scatter of True, as
+    before, through the window where they fit it and into the whole
+    table where not.  A table of at most `window_rows` rows is its own
+    window, by shape.  Duplicate indices set the same value, whichever
+    way.  Donate `table` to the launch: the update is then made in
+    place, every branch included."""
     import jax.numpy as jnp
     from jax import lax
 
     rows = table.shape[0]
-
-    def scatter(t, first_row):
-        # an index that is not live points past the end and is dropped
-        r = jnp.where(live, (idx >> LANE_BITS) - first_row, t.shape[0])
-        return t.at[r, idx & (LANES - 1)].set(True, mode="drop")
-
+    bidx, blive, low, high = _mark_blocks(idx, live, rows)
+    fits = jnp.all(high - low < MARK_TILE_ROWS)
     if rows <= window_rows:
-        return scatter(table, 0)
+        return lax.cond(
+            fits, lambda t: _tile_rows(t, bidx, blive, low, 0),
+            lambda t: _scatter_rows(t, idx, live, 0), table), fits
     start, took = _window_start(rows, idx, live, window_rows)
+    at = (start, jnp.int32(0))
+    # the window is cut outside the conditional and every branch ends
+    # in a barrier, as `take_rows_window` does: the donated table is
+    # then written in place whichever branch runs (AOT for a v5e)
+    window = lax.optimization_barrier(
+        lax.dynamic_slice(table, at, (window_rows, LANES)))
 
-    def through_window(t):
-        w = lax.dynamic_slice(t, (start, jnp.int32(0)), (window_rows, LANES))
-        return lax.dynamic_update_slice(t, scatter(w, start),
-                                        (start, jnp.int32(0)))
+    def tiled(t, w):
+        return lax.optimization_barrier(lax.dynamic_update_slice(
+            t, _tile_rows(w, bidx, blive, low, start), at))
 
-    return lax.cond(took, through_window, lambda t: scatter(t, 0), table)
+    def through_window(t, w):
+        return lax.optimization_barrier(lax.dynamic_update_slice(
+            t, _scatter_rows(w, idx, live, start), at))
+
+    def whole(t, _):
+        return lax.optimization_barrier(_scatter_rows(t, idx, live, 0))
+
+    branch = jnp.where(took, jnp.where(fits, 0, 1), 2)
+    return (lax.switch(branch, (tiled, through_window, whole), table, window),
+            took & fits)
 
 
 def pack_bits(bits: np.ndarray, capacity: int) -> np.ndarray:

@@ -76,18 +76,27 @@ left input).  The build side fully materializes once into a
   ``jit_semi_mark``) that evaluates the subquery's predicate over the
   batch's resident columns (the predicate the pipeline would have
   evaluated on the host goes into the launch: no mask a batch is made
-  or put) and sets the slots of its live keys, one scatter through a
-  window of 1 MB where the batch's keys fit one (lineitem is clustered
-  by `l_orderkey`: a batch's keys span ~128 KB of the 60 MB table),
-  the table donated so it is marked in place.  Each probe batch is
+  or put) and sets the slots of its live keys through a window of 1 MB
+  where the batch's keys fit one (lineitem is clustered by
+  `l_orderkey`: a batch's keys span ~128 KB of the 60 MB table), by
+  tile where every block of 128 keys keeps its live ones inside 4 table
+  rows (a dense tile a block, placed by one row scatter that ORs:
+  `exec/rowgather.py`; every mark of Q4, 0.81 -> 0.12 ms on a v5e,
+  PERF.md section 6), else by one element scatter.  The table
+  and a running int32, the count of the marks that went by tile, are
+  donated, so both are updated in place; the count is read once a
+  query, 4 B, after the probe's last lookup is enqueued
+  (``join.semi.mark.tiled``, beside
+  ``device.launches.join.semi_mark``).  Each probe batch is
   then one lookup launch (``device.launches.join.semi_lookup``,
   program ``jit_semi_lookup``): a row gather and lane select of the
   table through the same 1 MB window (orders: a batch of 131,072 keys
   spans 512 KB of it).  Neither side crosses the link; the build's
   batches are iterated, never listed or compacted.  A byte, not a
-  bit: marking is one scatter of True, right whatever the duplicates,
-  where a bit table would need an OR of words that XLA's scatter has
-  no combiner for; at TPC-H SF-10 the byte table is 60 MB of 16 GB.
+  bit: marking is a scatter of True, or of tiles under `max`, right
+  whatever the duplicates, where a bit table would need an OR of words
+  that XLA's scatter has no combiner for; at TPC-H SF-10 the byte
+  table is 60 MB of 16 GB.
   A semi build is a query's own result and is never pinned.
   Anything else (a string key, a build that is no such scan, or too
   sparse for a table) takes the host index (`HashIndex.contains`).
@@ -97,8 +106,8 @@ left input).  The build side fully materializes once into a
   ``join.semi.probe_rows``, ``join.semi.mark_rows`` (build rows
   offered to the marks), ``join.semi.mark_bytes`` (what the marks read
   and write: the key and predicate columns over those rows, and the
-  table once) and ``join.semi.table_slots`` (the table's slots, once a
-  query).
+  table once), ``join.semi.mark.tiled`` (the marks that went by tile)
+  and ``join.semi.table_slots`` (the table's slots, once a query).
 
 **The one size rule.**  A unique-integer-key build goes to the device
 when its direct-address table is no sparser than a hash table would
@@ -375,18 +384,20 @@ def _semi_probe_fn():
 @functools.lru_cache(maxsize=None)
 def _semi_mark_fn(core, key_at: int, pred_at: tuple):
     """One mark launch (`jit_semi_mark`): the membership table with the
-    slots of a build batch's live keys set.  A key is live below the
-    batch's row count, selected by its mask, valid, inside the table
-    and, where `core` (a `_PipelineCore` of the subquery's predicate,
-    no projection) is given, where the predicate holds (NULL drops the
-    row, as a WHERE does).  The batch's view holds the key at `key_at`
-    and the predicate's columns at `pred_at`, in `core.used_cols`
-    order.  The table is donated: marked in place."""
+    slots of a build batch's live keys set, and the count of the launches
+    that marked by tile (`set_rows_window`) with this one added.  A key
+    is live below the batch's row count, selected by its mask, valid,
+    inside the table and, where `core` (a `_PipelineCore` of the
+    subquery's predicate, no projection) is given, where the predicate
+    holds (NULL drops the row, as a WHERE does).  The batch's view holds
+    the key at `key_at` and the predicate's columns at `pred_at`, in
+    `core.used_cols` order.  The table and the count are donated: both
+    are updated in place."""
     import jax
     import jax.numpy as jnp
 
-    def semi_mark(table, kmin, num_slots, data, validity, aux, num_rows,
-                  mask, params):
+    def semi_mark(table, tiled, kmin, num_slots, data, validity, aux,
+                  num_rows, mask, params):
         key, kvalid = data[key_at], validity[key_at]
         if core is None:
             live = jnp.arange(key.shape[0], dtype=jnp.int32) < num_rows
@@ -400,10 +411,11 @@ def _semi_mark_fn(core, key_at: int, pred_at: tuple):
             live = live & kvalid
         d = key.astype(jnp.int64) - kmin
         live = live & (d >= 0) & (d < num_slots)
-        return set_rows_window(table, d.astype(jnp.int32), live,
-                               _SEMI_WINDOW_ROWS)
+        table, by_tile = set_rows_window(table, d.astype(jnp.int32), live,
+                                         _SEMI_WINDOW_ROWS)
+        return table, tiled + by_tile.astype(jnp.int32)
 
-    return jax.jit(semi_mark, donate_argnums=0)
+    return jax.jit(semi_mark, donate_argnums=(0, 1))
 
 
 @functools.lru_cache(maxsize=None)
@@ -430,12 +442,13 @@ def _semi_lookup_fn():
 
 @functools.lru_cache(maxsize=None)
 def _semi_table_fn(rows: int):
-    """An empty membership table of `rows` rows of `LANES` bytes."""
+    """An empty membership table of `rows` rows of `LANES` bytes, and a
+    zero int32: the count of the marks that go by tile."""
     import jax
     import jax.numpy as jnp
 
     def semi_table():
-        return jnp.zeros((rows, LANES), jnp.bool_)
+        return jnp.zeros((rows, LANES), jnp.bool_), jnp.zeros((), jnp.int32)
 
     return jax.jit(semi_table)
 
@@ -899,9 +912,10 @@ class HashJoinRelation(Relation):
         return rel, key, pipe
 
     def _semi_mark(self, scan, key: int, pipe) -> Optional[tuple]:
-        """(table, kmin, slot count) of the membership table marked from
-        `scan`'s batches by the predicate of `pipe` (None: every row),
-        the last two as device scalars; None where the size rule (module
+        """((table, kmin, slot count), tiled) of the membership table
+        marked from `scan`'s batches by the predicate of `pipe` (None:
+        every row), all device arrays, `tiled` the int32 count of the
+        mark launches that went by tile; None where the size rule (module
         doc) gives the build another probe."""
         from datafusion_tpu.exec.aggregate import _key_bounds
         from datafusion_tpu.exec.expression import compute_aux_values
@@ -936,7 +950,7 @@ class HashJoinRelation(Relation):
             mark_fn = _semi_mark_fn(core, cols.index(key),
                                     tuple(cols.index(c) for c in used))
             with device_scope(self.device):
-                table = LEDGER.adopt(
+                table, tiled = LEDGER.adopt(
                     device_call(_semi_table_fn(table_rows),
                                 _tag="join.semi_table"),
                     owner="join.semi", cached=False)
@@ -951,8 +965,9 @@ class HashJoinRelation(Relation):
                     view, self.device, None if core is None else core.wire_hints)
                 aux = () if core is None else tuple(
                     compute_aux_values(core.aux_specs, batch, aux_cache))
-                table = device_call(
-                    mark_fn, table, kmin_dev, slots_dev, data, validity, aux,
+                table, tiled = device_call(
+                    mark_fn, table, tiled, kmin_dev, slots_dev, data,
+                    validity, aux,
                     _rows_on_device(view, self.device), mask, params,
                     _tag="join.semi_mark")
             METRICS.add("join.semi.mark_rows", batch.num_rows)
@@ -961,7 +976,7 @@ class HashJoinRelation(Relation):
                 if a is not None))
         METRICS.add("join.semi.mark_bytes", table_rows * LANES)
         return (LEDGER.adopt(table, owner="join.semi", cached=False),
-                kmin_dev, slots_dev)
+                kmin_dev, slots_dev), tiled
 
     def _semi_batches(self):
         from datafusion_tpu.exec.materialize import (
@@ -971,8 +986,9 @@ class HashJoinRelation(Relation):
 
         li = self.on[0][0]
         source = self._mark_source()
-        build = self._semi_mark(*source) if source is not None else None
-        if build is not None:
+        marked = self._semi_mark(*source) if source is not None else None
+        if marked is not None:
+            build, tiled = marked
             probe_fn, tag = _semi_lookup_fn(), "join.semi_lookup"
         else:
             build, index = self._semi_build()
@@ -987,6 +1003,10 @@ class HashJoinRelation(Relation):
                 yield RecordBatch(self._schema, list(data), list(validity),
                                   list(batch.dicts), num_rows=batch.num_rows,
                                   mask=hit)
+            if marked is not None:
+                # the marks' count stays on the device until the last
+                # lookup is enqueued: one 4 B pull a query
+                METRICS.add("join.semi.mark.tiled", int(device_pull(tiled)))
             return
         for batch in iter_with_mask_prefetch(self.left.batches()):
             with METRICS.timer("join.host_probe"):

@@ -121,6 +121,7 @@ def test_q4_stage():
     out = chip_smoke.stage_q4("cpu", SF, batch_size=1 << 13)
     for label in ("q4_first", "q4_resident"):
         assert out[label]["device.launches.join.semi_mark"] == 8
+        assert out[label]["join.semi.mark.tiled"] == 8
         assert out[label]["device.launches.join.semi_lookup"] == 2
         assert out[label]["join.semi.mark_rows"] == 60_000
         assert out[label]["join.host_probe.rows"] == 0

@@ -141,6 +141,22 @@ def test_q4_shape_against_python(made, case):
     assert got == want
 
 
+def test_a_clustered_build_marks_by_tile(made):
+    """Every mark of a build in key order goes by tile
+    (`join.semi.mark.tiled`, counted on the device and read once a
+    query); a shuffled build's marks scatter; a small build marks
+    nothing."""
+    d, ctx, large = made
+    s0 = _counts()
+    collect(ctx.sql(_q4("AND lc < lr")))
+    s1 = _counts()
+    marks = _delta(s0, s1, "device.launches.join.semi_mark")
+    assert marks == (-(-len(d["lk"]) // BATCH) if large else 0)
+    clustered = bool(np.all(np.diff(d["lk"]) >= 0))
+    assert _delta(s0, s1, "join.semi.mark.tiled") == (
+        marks if clustered else 0)
+
+
 def test_an_empty_build():
     d = _tables(8_000, True)
     ctx = _context(d)
