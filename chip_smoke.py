@@ -703,7 +703,8 @@ def stage_q4(device: str, sf, batch_size: int = 1 << 17) -> dict:
     sql = spec.query("tpch_q4_orders_lineitem", "q4").format(
         **ds.bind("q4", params))
     want = made["oracle"].answer("q4", params)
-    extra = ("device.launches.join.semi_mark", "join.semi.mark.tiled",
+    extra = ("device.launches.join.semi_mark", "join.semi.mark_batches",
+             "join.semi.mark.tiled",
              "device.launches.join.semi_lookup", "join.semi.mark_rows",
              "join.semi.table_slots", "join.host_probe.rows", "d2h.bytes",
              "h2d.resident_misses")
@@ -719,11 +720,15 @@ def stage_q4(device: str, sf, batch_size: int = 1 << 17) -> dict:
         require(ev["device.launches.join.semi_mark"] > 0
                 and ev["join.host_probe.rows"] == 0,
                 f"{label}: the semi join was not marked on the device: {ev}")
-        # lineitem is clustered by its order key: every mark by tile
-        require(ev["join.semi.mark.tiled"]
-                == ev["device.launches.join.semi_mark"],
+        # lineitem is clustered by its order key: every batch by tile
+        require(ev["join.semi.mark.tiled"] == ev["join.semi.mark_batches"],
                 f"{label}: {ev['join.semi.mark.tiled']} of "
-                f"{ev['device.launches.join.semi_mark']} marks by tile")
+                f"{ev['join.semi.mark_batches']} batches marked by tile")
+        # a launch marks a group of batches
+        require(ev["device.launches.join.semi_mark"]
+                < ev["join.semi.mark_batches"],
+                f"{label}: {ev['device.launches.join.semi_mark']} mark "
+                f"launches for {ev['join.semi.mark_batches']} batches")
         require(ev["join.semi.mark_rows"] == n_lines,
                 f"{label}: {ev['join.semi.mark_rows']} lines offered to the "
                 f"marks, lineitem has {n_lines}")

@@ -71,23 +71,31 @@ left input).  The build side fully materializes once into a
   lineitem: ~38 M of 60 M rows kept, ~14 M distinct order keys), is
   marked into a **membership table**: a byte a slot over the build
   key's span (`kmin` .. the greatest key), `[rows, LANES]` on the
-  device, zeroed once a query.  Each of the scan's batches is one mark
-  launch (``device.launches.join.semi_mark``, program
-  ``jit_semi_mark``) that evaluates the subquery's predicate over the
-  batch's resident columns (the predicate the pipeline would have
-  evaluated on the host goes into the launch: no mask a batch is made
-  or put) and sets the slots of its live keys through a window of 1 MB
+  device, zeroed once a query.  The scan's batches are marked a group
+  a launch (``device.launches.join.semi_mark``, program
+  ``jit_semi_mark``), as the dense aggregate folds a resident table:
+  collected by shape class (marking is an OR, so order does not
+  matter), at most `fuse_group_max()` a group, each group padded to
+  its rung by `exec/fused.pad_group` (a pad counts 0 rows and its
+  step marks nothing), stacked in the program and marked batch after
+  batch by a `lax.scan` whose carry is the table (TPC-H SF-10's 458
+  batches of lineitem: two launches where there were 458 launch calls
+  at 0.27 ms of host CPU each, PERF.md section 6).  Each step
+  evaluates the subquery's predicate over its batch's resident columns
+  (the predicate the pipeline would have evaluated on the host goes
+  into the launch: no mask a batch is made or put) and sets the slots
+  of the batch's live keys through a window of 1 MB
   where the batch's keys fit one (lineitem is clustered by
   `l_orderkey`: a batch's keys span ~128 KB of the 60 MB table), by
   tile where every block of 128 keys keeps its live ones inside 4 table
   rows (a dense tile a block, placed by one row scatter that ORs:
   `exec/rowgather.py`; every mark of Q4, 0.81 -> 0.12 ms on a v5e,
   PERF.md section 6), else by one element scatter.  The table
-  and a running int32, the count of the marks that went by tile, are
-  donated, so both are updated in place; the count is read once a
-  query, 4 B, after the probe's last lookup is enqueued
-  (``join.semi.mark.tiled``, beside
-  ``device.launches.join.semi_mark``).  Each probe batch is
+  and a running int32, the count of the batches marked by tile, are
+  donated, so both are updated in place, step after step; the count
+  is read once a query, 4 B, after the probe's last lookup is enqueued
+  (``join.semi.mark.tiled``, beside ``join.semi.mark_batches``, the
+  batches marked).  Each probe batch is
   then one lookup launch (``device.launches.join.semi_lookup``,
   program ``jit_semi_lookup``): a row gather and lane select of the
   table through the same 1 MB window (orders: a batch of 131,072 keys
@@ -101,13 +109,16 @@ left input).  The build side fully materializes once into a
   Anything else (a string key, a build that is no such scan, or too
   sparse for a table) takes the host index (`HashIndex.contains`).
   Timers ``join.semi_probe`` (a probe batch, either launch),
-  ``join.semi_mark`` (a build batch's mark launch); counters
+  ``join.semi_mark`` (a build batch's preparation, and a group's
+  launch); counters
   ``join.semi.build_rows`` (the compare probe's and the host's build),
   ``join.semi.probe_rows``, ``join.semi.mark_rows`` (build rows
   offered to the marks), ``join.semi.mark_bytes`` (what the marks read
   and write: the key and predicate columns over those rows, and the
-  table once), ``join.semi.mark.tiled`` (the marks that went by tile)
-  and ``join.semi.table_slots`` (the table's slots, once a query).
+  table once), ``join.semi.mark_batches`` (the batches marked, pads
+  not counted: beside ``device.launches.join.semi_mark`` the batches a
+  launch), ``join.semi.mark.tiled`` (the batches marked by tile) and
+  ``join.semi.table_slots`` (the table's slots, once a query).
 
 **The one size rule.**  A unique-integer-key build goes to the device
 when its direct-address table is no sparser than a hash table would
@@ -383,21 +394,29 @@ def _semi_probe_fn():
 
 @functools.lru_cache(maxsize=None)
 def _semi_mark_fn(core, key_at: int, pred_at: tuple):
-    """One mark launch (`jit_semi_mark`): the membership table with the
-    slots of a build batch's live keys set, and the count of the launches
-    that marked by tile (`set_rows_window`) with this one added.  A key
-    is live below the batch's row count, selected by its mask, valid,
+    """One mark launch (`jit_semi_mark`) over a group of build batches
+    (`exec/fused.pad_group`: entries `(data, validity, mask)` of one
+    shape class, and their row counts as one int32 array, 0 for a pad):
+    the membership table with the slots of every batch's live keys set,
+    and the count of the batches that marked by tile (`set_rows_window`)
+    with the group's added.  The entries are stacked in the program and
+    marked one after another by a `lax.scan` that carries the table and
+    the count; a pad's step marks nothing and counts nothing.  A key is
+    live below its batch's row count, selected by its mask, valid,
     inside the table and, where `core` (a `_PipelineCore` of the
     subquery's predicate, no projection) is given, where the predicate
-    holds (NULL drops the row, as a WHERE does).  The batch's view holds
-    the key at `key_at` and the predicate's columns at `pred_at`, in
+    holds (NULL drops the row, as a WHERE does).  An entry holds the key
+    at `key_at` and the predicate's columns at `pred_at`, in
     `core.used_cols` order.  The table and the count are donated: both
-    are updated in place."""
+    are updated in place, step after step."""
     import jax
     import jax.numpy as jnp
 
-    def semi_mark(table, tiled, kmin, num_slots, data, validity, aux,
-                  num_rows, mask, params):
+    from datafusion_tpu.exec.fused import stack_entries
+
+    def mark(carry, entry, num_rows, kmin, num_slots, aux, params):
+        table, tiled = carry
+        data, validity, mask = entry
         key, kvalid = data[key_at], validity[key_at]
         if core is None:
             live = jnp.arange(key.shape[0], dtype=jnp.int32) < num_rows
@@ -414,6 +433,33 @@ def _semi_mark_fn(core, key_at: int, pred_at: tuple):
         table, by_tile = set_rows_window(table, d.astype(jnp.int32), live,
                                          _SEMI_WINDOW_ROWS)
         return table, tiled + by_tile.astype(jnp.int32)
+
+    def semi_mark(table, tiled, kmin, num_slots, entries, counts, aux,
+                  params):
+        def step(carry, x):
+            entry, num_rows = x
+            entry = jax.tree.map(lambda a: a.reshape(-1), entry)
+            return jax.lax.cond(
+                num_rows > 0,
+                lambda c: mark(c, entry, num_rows, kmin, num_slots, aux,
+                               params),
+                lambda c: c, carry), None
+
+        # each array stacked `[n, capacity]`, then laid out as rows of
+        # `LANES`: a step's slice of the stack is then whole (8, 128)
+        # tiles of the TPU's layout, where one row of `[n, capacity]` is
+        # 512 B of each of capacity / 128 tiles, read apart (a batch
+        # 0.189 -> 0.163 ms inside a group of 256 on a v5e, PERF.md
+        # section 6).  The barrier keeps the stack one concatenate a
+        # leaf: stacked straight into rows, XLA writes it by one update
+        # an entry and leaf, 1,536 programs at rung 256, and a set-up
+        # that finds them in the compile cache took ~8 s longer
+        stacked = jax.tree.map(
+            lambda a: a.reshape(a.shape[0], -1, LANES)
+            if a.shape[1] % LANES == 0 else a,
+            jax.lax.optimization_barrier(stack_entries(entries)))
+        carry, _ = jax.lax.scan(step, (table, tiled), (stacked, counts))
+        return carry
 
     return jax.jit(semi_mark, donate_argnums=(0, 1))
 
@@ -492,18 +538,6 @@ def _device_scalar(x, device):
     import jax.numpy as jnp
 
     return LEDGER.adopt(jnp.asarray(x), owner="join.build")
-
-
-def _rows_on_device(batch: RecordBatch, device):
-    """`batch`'s row count as a device int32, placed once and kept on
-    the batch (a batch of a table that keeps its batches is marked
-    again by every query)."""
-    key = ("num_rows", None if device is None else repr(device))
-    hit = batch.cache.get(key)
-    if hit is None:
-        hit = batch.cache[key] = _device_scalar(np.int32(batch.num_rows),
-                                                device)
-    return hit
 
 
 def _unplaced_bytes(rel: Relation, device) -> int:
@@ -915,10 +949,19 @@ class HashJoinRelation(Relation):
         """((table, kmin, slot count), tiled) of the membership table
         marked from `scan`'s batches by the predicate of `pipe` (None:
         every row), all device arrays, `tiled` the int32 count of the
-        mark launches that went by tile; None where the size rule (module
-        doc) gives the build another probe."""
+        batches that were marked by tile; None where the size rule (module
+        doc) gives the build another probe.  The batches are marked a
+        group a launch: collected by shape class (marking is an OR, so
+        their order does not matter), a group launched when it holds
+        `fuse_group_max()` of them and the rest after the scan's last."""
         from datafusion_tpu.exec.aggregate import _key_bounds
         from datafusion_tpu.exec.expression import compute_aux_values
+        from datafusion_tpu.exec.fused import (
+            entry_signature,
+            fuse_group_max,
+            pad_group,
+            shared_signature,
+        )
         from datafusion_tpu.exec.kernels import parameterize_exprs
         from datafusion_tpu.exec.relation import _PipelineCore, device_scope
 
@@ -957,23 +1000,39 @@ class HashJoinRelation(Relation):
             kmin_dev, slots_dev = (_device_scalar(np.int64(x), self.device)
                                    for x in (kmin, num_slots))
         METRICS.add("join.semi.table_slots", num_slots)
+
+        def launch(entries, counts, aux):
+            nonlocal table, tiled
+            group, counts = pad_group(entries, counts)
+            table, tiled = device_call(
+                mark_fn, table, tiled, kmin_dev, slots_dev, group, counts,
+                aux, params, _tag="join.semi_mark")
+            METRICS.add("join.semi.mark_batches", len(entries))
+
+        fuse = fuse_group_max()
         aux_cache: dict = {}
+        # shape class -> (entries, row counts, aux) not yet launched
+        classes: dict = {}
         for batch in scan.batches():
             with METRICS.timer("join.semi_mark"):
                 view = subset_view(batch, cols)
-                data, validity, mask = device_inputs(
+                entry = device_inputs(
                     view, self.device, None if core is None else core.wire_hints)
                 aux = () if core is None else tuple(
                     compute_aux_values(core.aux_specs, batch, aux_cache))
-                table, tiled = device_call(
-                    mark_fn, table, tiled, kmin_dev, slots_dev, data,
-                    validity, aux,
-                    _rows_on_device(view, self.device), mask, params,
-                    _tag="join.semi_mark")
+                sig = (entry_signature(entry), shared_signature(aux))
+                entries, counts, _ = classes.setdefault(sig, ([], [], aux))
+                entries.append(entry)
+                counts.append(batch.num_rows)
+                if len(entries) >= fuse:
+                    launch(*classes.pop(sig))
             METRICS.add("join.semi.mark_rows", batch.num_rows)
             METRICS.add("join.semi.mark_bytes", batch.num_rows * sum(
                 a.dtype.itemsize for a in (*view.data, *view.validity)
                 if a is not None))
+        with METRICS.timer("join.semi_mark"):
+            for group in classes.values():
+                launch(*group)
         METRICS.add("join.semi.mark_bytes", table_rows * LANES)
         return (LEDGER.adopt(table, owner="join.semi", cached=False),
                 kmin_dev, slots_dev), tiled

@@ -68,15 +68,13 @@ def _lineitem_core():
     return _PipelineCore.build(schema, pred, None, {}, {})
 
 
-@pytest.mark.parametrize("masked", [False, True])
-def test_the_semi_mark_compiles_for_a_v5e(one_chip, masked):
-    """At TPC-H SF-10's size: 131,072 lineitem rows a batch into a
-    table of 60 M one-byte slots, donated with the count of the marks by
-    tile.  Every branch (by tile, scatter through the window, scatter
-    into the whole table) writes the table in place: the program's
-    temporaries stay far below the table's 60 MB."""
+def _compiled_mark(one_chip, masked, rung):
+    """The mark launch over a group of `rung` batches at TPC-H SF-10's
+    size: 131,072 lineitem rows a batch into a table of 60 M one-byte
+    slots, donated with the count of the batches by tile."""
     import jax
     import jax.numpy as jnp
+    import numpy as np
 
     from datafusion_tpu.join.relation import _semi_mark_fn
 
@@ -84,17 +82,46 @@ def test_the_semi_mark_compiles_for_a_v5e(one_chip, masked):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
 
     rows = 131_072
-    core = _lineitem_core()
     col = shape((rows,), jnp.int64)
     mask = shape((rows,), jnp.bool_) if masked else None
-    table = (468_750, 128)
-    compiled = _semi_mark_fn(core, 0, (1, 2)).lower(
-        shape(table, jnp.bool_), shape((), jnp.int32), shape((), jnp.int64),
-        shape((), jnp.int64), (col, col, col), (None, None, None), (),
-        shape((), jnp.int32), mask, ()).compile()
-    memory = compiled.memory_analysis()
+    entries = (((col, col, col), (None, None, None), mask),) * rung
+    return _semi_mark_fn(_lineitem_core(), 0, (1, 2)).lower(
+        shape(TABLE, jnp.bool_), shape((), jnp.int32), shape((), jnp.int64),
+        shape((), jnp.int64), entries, np.zeros(rung, np.int32), (),
+        ()).compile()
+
+
+# the membership table of TPC-H SF-10's 60 M order keys
+TABLE = (468_750, 128)
+
+
+@pytest.mark.parametrize("masked", [False, True])
+def test_the_semi_mark_compiles_for_a_v5e(one_chip, masked):
+    """A group of one batch.  Every branch (by tile, scatter through the
+    window, scatter into the whole table) writes the table in place: the
+    program's temporaries stay far below the table's 60 MB."""
+    memory = _compiled_mark(one_chip, masked, 1).memory_analysis()
     if memory is not None:
-        assert memory.temp_size_in_bytes < table[0] * table[1] // 8
+        assert memory.temp_size_in_bytes < TABLE[0] * TABLE[1] // 8
+
+
+@pytest.mark.parametrize("masked", [False, True])
+def test_a_group_of_256_marks_compiles_for_a_v5e(one_chip, masked):
+    """The largest rung a scan's group takes (`fuse_group_max()`): the
+    donated table is the program's output too (aliased, not copied),
+    and the scan's steps update it in place: the program's peak is its
+    arguments, the stacked entries (24 B a row, a byte more masked), one
+    stacked 32-bit word being laid out as rows (4 B a row) and less than
+    an eighth of the table besides, where a copy of the table a step, or
+    one kept beside the carry, would add all 60 MB of it."""
+    memory = _compiled_mark(one_chip, masked, 256).memory_analysis()
+    if memory is not None:
+        table = TABLE[0] * TABLE[1]
+        stacked = 256 * 131_072 * (24 + masked)
+        relaid = 256 * 131_072 * 4
+        assert memory.alias_size_in_bytes >= table
+        assert (memory.peak_memory_in_bytes - memory.argument_size_in_bytes
+                < stacked + relaid + table // 8)
 
 
 @pytest.mark.parametrize("masked", [False, True])

@@ -7,13 +7,16 @@ matching nothing, an empty build, a predicate that keeps nothing), which
 build the size rule gives each shape (a membership table marked and looked
 up on the device past 4,096 rows, the compare probe for a small sparse
 build as TPC-H Q18's, the host index for a large sparse one), the same
-under an accelerator's rules, and the refusals by name."""
+under an accelerator's rules, and the refusals by name; TPC-H Q4 through
+the entry and the harness of the benchmark's cell at 400,000 lines, whose
+batches are marked a group of one shape class a launch."""
 
 import numpy as np
 import pytest
 
 from datafusion_tpu.errors import NotSupportedError
 from datafusion_tpu.exec.context import ExecutionContext
+from datafusion_tpu.exec.fused import fuse_group_max
 from datafusion_tpu.exec.materialize import collect
 from datafusion_tpu.plan.logical import Join, Projection, Selection
 from datafusion_tpu.sql import ast
@@ -58,6 +61,12 @@ def _tables(n_lines, clustered, seed=7):
         "lk": lk, "lk_valid": rng.random(n) > 0.03,
         "lc": rng.integers(0, 60, n), "lr": rng.integers(0, 60, n),
     }
+
+
+def _groups(batches):
+    """Mark launches of `batches` batches of one shape class: a group
+    holds at most `fuse_group_max()` of them."""
+    return -(-batches // fuse_group_max())
 
 
 def _context(d):
@@ -110,8 +119,10 @@ def test_each_order_once_against_python(made, case):
     assert _delta(s0, s1, "join.host_probe.rows") == 0
     probes = -(-len(d["ok"]) // BATCH)
     marks = -(-len(d["lk"]) // BATCH)
+    # the lines' batches are one shape class: one group a launch
     assert _delta(s0, s1, "device.launches.join.semi_mark") == (
-        marks if large else 0)
+        _groups(marks) if large else 0)
+    assert _delta(s0, s1, "join.semi.mark_batches") == (marks if large else 0)
     assert _delta(s0, s1, "device.launches.join.semi_lookup") == (
         probes if large else 0)
     assert _delta(s0, s1, "device.launches.join.semi") == (
@@ -142,19 +153,21 @@ def test_q4_shape_against_python(made, case):
 
 
 def test_a_clustered_build_marks_by_tile(made):
-    """Every mark of a build in key order goes by tile
+    """Every batch of a build in key order is marked by tile
     (`join.semi.mark.tiled`, counted on the device and read once a
-    query); a shuffled build's marks scatter; a small build marks
-    nothing."""
+    query, beside `join.semi.mark_batches`); a shuffled build's batches
+    scatter; a small build marks nothing."""
     d, ctx, large = made
     s0 = _counts()
     collect(ctx.sql(_q4("AND lc < lr")))
     s1 = _counts()
-    marks = _delta(s0, s1, "device.launches.join.semi_mark")
-    assert marks == (-(-len(d["lk"]) // BATCH) if large else 0)
+    batches = _delta(s0, s1, "join.semi.mark_batches")
+    assert batches == (-(-len(d["lk"]) // BATCH) if large else 0)
+    assert _delta(s0, s1, "device.launches.join.semi_mark") == (
+        _groups(batches) if large else 0)
     clustered = bool(np.all(np.diff(d["lk"]) >= 0))
     assert _delta(s0, s1, "join.semi.mark.tiled") == (
-        marks if clustered else 0)
+        batches if clustered else 0)
 
 
 def test_an_empty_build():
@@ -176,7 +189,8 @@ def test_warm_queries_mark_again_and_upload_nothing():
     s0 = _counts()
     assert collect(ctx.sql(sql)).to_rows() == first
     s1 = _counts()
-    assert _delta(s0, s1, "device.launches.join.semi_mark") == 20
+    assert _delta(s0, s1, "device.launches.join.semi_mark") == 1
+    assert _delta(s0, s1, "join.semi.mark_batches") == 20
     assert _delta(s0, s1, "device.launches.join.semi_table") == 1
     assert _delta(s0, s1, "h2d.resident_misses") == 0
     # the two scalars of the table, and the final sort's few rows
@@ -201,9 +215,134 @@ def test_under_an_accelerators_rules(monkeypatch):
     counts = np.bincount([d["op"][i] for i in _exists_by_python(
         d, d["lc"] < d["lr"]) if inside[i]], minlength=len(PRIOS))
     assert got == [(p, int(n)) for p, n in zip(PRIOS, counts) if n]
-    assert _delta(s0, s1, "device.launches.join.semi_mark") == 20
+    assert _delta(s0, s1, "device.launches.join.semi_mark") == 1
+    assert _delta(s0, s1, "join.semi.mark_batches") == 20
     assert _delta(s0, s1, "join.host_probe.rows") == 0
     assert _delta(s0, s1, "h2d.bytes") < 16 * 1024
+
+
+# TPC-H Q4 through the entry of the benchmark's cell `q4_sf10_exists`, at
+# 400,000 lineitem rows: Parquet files of its data set, registered and
+# kept resident as the cell keeps them
+Q4_CELL = "q4_sf10_exists"
+Q4_ROWS = 400_000
+Q4_SEED = 2147483659
+
+
+@pytest.fixture(scope="module")
+def q4_engine(tmp_path_factory):
+    import os
+
+    from tpubench import data as tdata
+    from tpubench.entries import Spans
+    from tpubench.spec import Spec
+
+    spec = Spec(os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+    ds = spec.dataset("tpch_q4_orders_lineitem")
+    made = ds.generate(Q4_SEED, Q4_ROWS, threads=4)
+    d = tmp_path_factory.mktemp("q4")
+    paths = {}
+    for table, cols in made["tables"].items():
+        paths[table] = str(d / f"{table}.parquet")
+        tdata.write_parquet(cols, paths[table], 100_000)
+    entry = spec.entry(spec.traffic(spec.cell(Q4_CELL)["traffic"])["entry"])
+    sql = spec.query("tpch_q4_orders_lineitem", "q4").format(
+        **ds.bind("q4", {"date": "1993-07-01"}))
+    return (entry("cpu", {"result_cache": False}, paths, Spans()), sql,
+            made, spec.dataset("tpch_orders_lineitem"))
+
+
+@pytest.mark.parametrize("rules", ["cpu", "accelerator"])
+def test_each_q4_request_marks_its_batches_a_group_a_launch(
+        q4_engine, monkeypatch, rules):
+    """Two requests in a row, under a CPU's rules and an accelerator's:
+    each marks every batch of lineitem into the membership table, a
+    group of one shape class a launch (three batches of 131,072 lines
+    and one of 6,784, a class of its own), looks every batch of orders
+    up on the device, reads the lines' bytes and the table's once, and
+    sends nothing to the host index; the second request finds every
+    column resident."""
+    import types
+
+    from datafusion_tpu.exec import relation as exec_rel
+
+    engine, sql, made, sibling = q4_engine
+    if rules == "accelerator":
+        monkeypatch.setattr(exec_rel, "_is_accelerator", lambda d: True)
+    lines, orders = -(-Q4_ROWS // 131_072), -(-(Q4_ROWS // 4) // 131_072)
+    for _ in range(2):
+        s0 = _counts()
+        got = engine.query(types.SimpleNamespace(sql=sql),
+                           types.SimpleNamespace(rid=0))
+        s1 = _counts()
+        assert made["oracle"].check("q4", {"date": "1993-07-01"}, got) is None
+        assert _delta(s0, s1, "join.semi.mark_batches") == lines
+        assert _delta(s0, s1, "device.launches.join.semi_mark") == 2
+        assert _delta(s0, s1, "join.semi.mark.tiled") == lines
+        assert _delta(s0, s1, "device.launches.join.semi_lookup") == orders
+        assert _delta(s0, s1, "join.semi.mark_rows") == Q4_ROWS
+        assert _delta(s0, s1, "join.semi.probe_rows") == Q4_ROWS // 4
+        # keys 1 .. the last order's, the table in rows of 128
+        slots = int(sibling.order_keys(Q4_ROWS // 4 - 1, Q4_ROWS // 4)[0])
+        assert _delta(s0, s1, "join.semi.table_slots") == slots
+        assert _delta(s0, s1, "join.semi.mark_bytes") == (
+            Q4_ROWS * 24 + -(-slots // 128) * 128)
+        assert _delta(s0, s1, "join.host_probe.rows") == 0
+        assert _delta(s0, s1, "device.launches.join.semi") == 0
+        assert _delta(s0, s1, "aggregate.key_pull.bytes") == 0
+        assert _delta(s0, s1, "d2h.bytes") < 16 * 1024
+    assert _delta(s0, s1, "h2d.resident_misses") == 0
+
+
+@pytest.fixture
+def jax_cache_threshold_as_found():
+    """A harness run sets JAX's persistent-cache threshold for its
+    process; put back, so a later test on the same worker
+    (`test_compile_cache.py` reads JAX's own default) finds JAX as it
+    was."""
+    import jax
+
+    name = "jax_persistent_cache_min_compile_time_secs"
+    before = getattr(jax.config, name)
+    yield
+    jax.config.update(name, before)
+
+
+def test_the_q4_cells_rehearsal_marks_a_group_a_launch(
+        capsys, tmp_path, jax_cache_threshold_as_found):
+    """The cell's harness at 400,000 rows on the CPU (counts only): the
+    answer `correct`, every column resident, nothing compiled in the
+    window, and a request's launches two mark groups, the one lookup of
+    orders' batch, the table, the aggregate and the sort of its five
+    rows."""
+    import json
+    import os
+    import shutil
+
+    from tpubench.harness import main
+
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    root = str(tmp_path / "root")
+    os.makedirs(root)
+    shutil.copy(os.path.join(repo, "BENCHMARK.json"), root)
+    shutil.copytree(os.path.join(repo, "tpubench"),
+                    os.path.join(root, "tpubench"),
+                    ignore=shutil.ignore_patterns("__pycache__"))
+    capsys.readouterr()
+    code = main(["--workload", Q4_CELL, "--seed", str(Q4_SEED), "--seconds",
+                 "0.3", "--trace", "1", "--rehearse-rows", str(Q4_ROWS)],
+                root=root)
+    line = json.loads(capsys.readouterr().out.strip().splitlines()[-1])
+    assert code == 0 and line["correct"] is True and line["failed"] == 0
+    m = line["metrics"]
+    assert m["resident_hit_share"]["value"] == 100
+    assert m["d2h_kb_per_query"]["value"] < 16
+    assert m["h2d_mb_per_query"]["value"] < 0.1
+    assert m["compiles_in_window"]["value"] == 0
+    assert m["launches_per_query"]["value"] == 2 + 1 + 1 + 1 + 1
+    # no device time in a rehearsal
+    for name in ("semi_mark_ms_per_query", "semi_mark_roofline"):
+        assert name not in m
 
 
 @pytest.mark.parametrize("shape", ["grouped", "scan"])

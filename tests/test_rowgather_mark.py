@@ -2,9 +2,10 @@
 `jit_semi_mark` program's write): for every input the table it leaves is
 the one the element scatter leaves and the one a numpy set of the live
 keys gives, bit for bit, whichever way the launch went; clustered keys go
-by tile, shuffled and sparse ones fall back to the scatter; the count of
-the launches by tile travels with the table and reaches
-`join.semi.mark.tiled` once a query."""
+by tile, shuffled and sparse ones fall back to the scatter; a launch
+marks a group of batches, padded to its rung with entries that mark and
+count nothing; the count of the batches by tile travels with the table
+and reaches `join.semi.mark.tiled` once a query."""
 
 import jax
 import jax.numpy as jnp
@@ -117,20 +118,28 @@ def test_the_table_is_the_scatters_and_the_live_keys(name):
     np.testing.assert_array_equal(np.asarray(got), np.asarray(scattered))
 
 
-def _mark_launch(core, key, kvalid, num_rows, mask, kmin, slots, rows,
-                 cols=(), table=None, tiled=None):
+def _mark_launch(core, batches, kmin, slots, rows, table=None, tiled=None):
+    """One `jit_semi_mark` launch over a group of `batches`, each (key,
+    key validity, row count, mask, predicate columns), padded to its
+    rung as the join pads it."""
+    from datafusion_tpu.exec.fused import pad_group
     from datafusion_tpu.join.relation import _semi_mark_fn
 
     if table is None:
         table = jnp.zeros((rows, LANES), jnp.bool_)
         tiled = jnp.zeros((), jnp.int32)
-    data = (jnp.asarray(key), *(jnp.asarray(c) for c in cols))
-    validity = (None if kvalid is None else jnp.asarray(kvalid),
-                *(None for _ in cols))
-    pred_at = () if core is None else tuple(range(1, 1 + len(cols)))
+    entries = []
+    for key, kvalid, _, mask, cols in batches:
+        data = (jnp.asarray(key), *(jnp.asarray(c) for c in cols))
+        validity = (None if kvalid is None else jnp.asarray(kvalid),
+                    *(None for _ in cols))
+        entries.append((data, validity,
+                        None if mask is None else jnp.asarray(mask)))
+    group, counts = pad_group(entries, [b[2] for b in batches])
+    pred_at = () if core is None else tuple(range(1, 1 + len(batches[0][4])))
     return _semi_mark_fn(core, 0, pred_at)(
-        table, tiled, jnp.int64(kmin), jnp.int64(slots), data, validity, (),
-        jnp.int32(num_rows), None if mask is None else jnp.asarray(mask), ())
+        table, tiled, jnp.int64(kmin), jnp.int64(slots), group, counts, (),
+        ())
 
 
 def _late_core():
@@ -152,10 +161,11 @@ LAUNCH_CASES = ["nulls", "rows_past_num_rows", "keys_outside_the_table",
 @pytest.mark.parametrize("name", LAUNCH_CASES)
 def test_a_mark_launch_against_the_live_keys(name):
     """Through `jit_semi_mark` itself, three batches into a table past
-    the window of 8,192 rows: which keys are live (NULL, past the row
-    count, unselected, outside `[kmin, kmin + slots)`, dropped by the
-    predicate) is the launch's, and the count of launches by tile runs
-    on with the table."""
+    the window of 8,192 rows, two in one launch and the third in the
+    next: which keys are live (NULL, past the row count, unselected,
+    outside `[kmin, kmin + slots)`, dropped by the predicate) is the
+    launch's, and the count of batches by tile runs on with the table,
+    step after step and launch after launch."""
     from datafusion_tpu.join.relation import _SEMI_WINDOW_ROWS
 
     rng = np.random.default_rng(100 + LAUNCH_CASES.index(name))
@@ -173,23 +183,24 @@ def test_a_mark_launch_against_the_live_keys(name):
     if name == "predicate_keeps_nothing":
         c2 = np.minimum(c1, c2)
     core = _late_core() if name.startswith("predicate") else None
-    table = tiled = None
     want = np.zeros(rows * LANES, bool)
+    batches = []
     for b in range(3):
         at = slice(b * n, (b + 1) * n)
         key = keys[at]
         kvalid = rng.random(n) > 0.1 if name == "nulls" else None
         mask = rng.random(n) < 0.5 if name == "mask" else None
         num_rows = n - 1_000 if name == "rows_past_num_rows" and b == 2 else n
-        table, tiled = _mark_launch(
-            core, key, kvalid, num_rows, mask, kmin, slots, rows,
-            cols=(c1[at], c2[at]) if core is not None else (),
-            table=table, tiled=tiled)
+        batches.append((key, kvalid, num_rows, mask,
+                        (c1[at], c2[at]) if core is not None else ()))
         live = (np.arange(n) < num_rows) & (key >= kmin) & (key < kmin + slots)
         for m in (kvalid, mask, None if core is None else c1[at] < c2[at]):
             if m is not None:
                 live &= m
         want[key[live] - kmin] = True
+    table, tiled = _mark_launch(core, batches[:2], kmin, slots, rows)
+    table, tiled = _mark_launch(core, batches[2:], kmin, slots, rows,
+                                table=table, tiled=tiled)
     np.testing.assert_array_equal(np.asarray(table).reshape(-1), want)
     d = np.where(want)[0]
     scattered = _scatter_rows(jnp.zeros((rows, LANES), jnp.bool_),
@@ -208,10 +219,11 @@ def _ctx(lk, ok):
 
 @pytest.mark.parametrize("order", ["clustered", "shuffled", "sparse"])
 def test_the_query_counts_the_marks_by_tile(order):
-    """`join.semi.mark.tiled` beside `device.launches.join.semi_mark`:
-    every mark of a clustered build, none of a shuffled or a sparse one
-    (20 slots a line: a block spans 20 rows), the answer right either
-    way, and the count read back once, 4 B."""
+    """`join.semi.mark.tiled` beside `join.semi.mark_batches`: every
+    batch of a clustered build, none of a shuffled or a sparse one (20
+    slots a line: a block spans 20 rows), the answer right either way,
+    the ten batches of one shape class one launch, and the count read
+    back once, 4 B."""
     rng = np.random.default_rng(7)
     lk = _lines(rng, 20_000, 1)
     if order == "shuffled":
@@ -226,8 +238,53 @@ def test_the_query_counts_the_marks_by_tile(order):
     s1 = _counts()
     assert sorted(r[0] for r in got.to_rows()) == sorted(
         set(ok.tolist()) & set(lk.tolist()))
-    marks = _delta(s0, s1, "device.launches.join.semi_mark")
-    assert marks == -(-len(lk) // 2_048)
+    batches = _delta(s0, s1, "join.semi.mark_batches")
+    assert batches == -(-len(lk) // 2_048)
+    assert _delta(s0, s1, "device.launches.join.semi_mark") == 1
     assert _delta(s0, s1, "join.semi.mark.tiled") == (
-        marks if order == "clustered" else 0)
+        batches if order == "clustered" else 0)
     assert _delta(s0, s1, "join.host_probe.rows") == 0
+
+
+# name -> (lines, whether in key order, `fuse_group_max()`, the mark
+# launches: batches of 2,048 lines, one of at most 1,024 a shape class
+# of its own)
+GROUPED = {
+    # five batches, padded to rung 6 with a dead entry
+    "padded": (5 * 2_048, True, 256, 1),
+    "padded_shuffled": (5 * 2_048, False, 256, 1),
+    # six batches of 2,048 and one of 300: two classes, two groups
+    "two_shapes": (6 * 2_048 + 300, True, 256, 2),
+    # eleven batches, four at most a group: 4 + 4 + 3
+    "past_the_group_max": (11 * 2_048, True, 4, 3),
+}
+
+
+@pytest.mark.parametrize("name", list(GROUPED))
+def test_a_grouped_mark_leaves_the_live_keys_table(name, monkeypatch):
+    """The semi join's own mark loop (`HashJoinRelation._semi_mark`): the
+    batches collected by shape class, a group a launch, each padded to
+    its rung; the table it leaves is numpy's set of the build's keys, a
+    pad marks and counts nothing (a dead entry would read as by tile),
+    and the counters say batches and launches."""
+    n, clustered, group_max, launches = GROUPED[name]
+    monkeypatch.setenv("DATAFUSION_TPU_FUSE_GROUP", str(group_max))
+    rng = np.random.default_rng(list(GROUPED).index(name))
+    lk = _lines(rng, n, 1)
+    if not clustered:
+        lk = rng.permutation(lk)
+    ctx = _ctx(lk, np.arange(1, lk.max() + 50, 3, dtype=np.int64))
+    join = ctx.sql(
+        "SELECT ok FROM o WHERE EXISTS (SELECT * FROM l WHERE lk = ok)").child
+    s0 = _counts()
+    (table, kmin, slots), tiled = join._semi_mark(*join._mark_source())
+    s1 = _counts()
+    want = np.zeros(np.asarray(table).size, bool)
+    want[lk - int(kmin)] = True
+    assert int(kmin) == lk.min() and int(slots) == lk.max() - lk.min() + 1
+    np.testing.assert_array_equal(np.asarray(table).reshape(-1), want)
+    batches = -(-n // 2_048)
+    assert _delta(s0, s1, "device.launches.join.semi_mark") == launches
+    assert _delta(s0, s1, "join.semi.mark_batches") == batches
+    assert _delta(s0, s1, "join.semi.mark_rows") == n
+    assert int(tiled) == (batches if clustered else 0)

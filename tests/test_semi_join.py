@@ -77,7 +77,10 @@ def test_semi_join_against_python(sctx, monkeypatch, case, path):
                  "table": "device.launches.join.semi_lookup"}
     for p, tag in probe_tag.items():
         assert _delta(s0, s1, tag) == (-(-1_000 // 256) if p == path else 0)
+    # `s`'s three batches are one shape class: one group, one launch
     assert _delta(s0, s1, "device.launches.join.semi_mark") == (
+        1 if path == "table" else 0)
+    assert _delta(s0, s1, "join.semi.mark_batches") == (
         -(-65 // 32) if path == "table" else 0)
     if path == "host":
         assert _delta(s0, s1, "join.host_probe.rows") > 0
