@@ -1,7 +1,5 @@
-"""The five mandated benchmark configs (BASELINE.md).
-
-The reference's own bench list is commented out and publishes no
-numbers (`/root/reference/Cargo.toml:50-68`, `.travis.yml:30-33`), so
-the baseline for every config is this engine's own single-thread CPU
-path on identical inputs, and `vs_baseline` is the TPU speedup over it.
+"""Helpers that outlived the first benchmark harness: the reference's
+own micro-benches (`reference_benches`), the serving load generator
+(`serve_load`) and seeded inputs for the smoke scripts (`data`).  The
+timed benchmark is `tpubench/`.
 """

@@ -1,10 +1,8 @@
-"""Benchmark input generation, cached on disk under test/data/bench/.
+"""Seeded inputs for the smoke scripts and the reference benches.
 
-TPC-H lineitem (the Q1 column subset) is generated at a given scale
-factor and written as Parquet — the input BASELINE.md config 3
-mandates; the reference never got a Parquet reader (`README.md:22`).
-Generation is seeded and chunked so SF-10 (~60M rows) streams through
-a bounded footprint.
+A scaled-up uk_cities CSV (cached on disk under test/data/bench/) and
+two in-memory tables: a group-by table and a sort table.  The timed
+benchmark's data sets are `tpubench/datasets/`.
 """
 
 from __future__ import annotations
@@ -17,71 +15,6 @@ BENCH_DIR = os.path.join(
     os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
     "test", "data", "bench",
 )
-
-LINEITEM_ROWS_PER_SF = 6_000_000
-_CHUNK = 1_000_000
-
-
-def lineitem_parquet(sf: float) -> str:
-    """Path to the cached lineitem Parquet for scale factor `sf`;
-    generates it on first use."""
-    import pyarrow as pa
-    import pyarrow.parquet as pq
-
-    os.makedirs(BENCH_DIR, exist_ok=True)
-    tag = str(sf).replace(".", "_")
-    path = os.path.join(BENCH_DIR, f"lineitem_sf{tag}.parquet")
-    if os.path.exists(path):
-        return path
-
-    rows = int(LINEITEM_ROWS_PER_SF * sf)
-    rng = np.random.default_rng(42)
-    base = np.datetime64("1992-01-02")
-    n_dates = 2526  # 1992-01-02 .. 1998-12-01, receiptdate horizon
-    date_strs = pa.array(
-        [str(base + np.timedelta64(i, "D")) for i in range(n_dates)]
-    )
-    flags = pa.array(["A", "N", "R"])
-    statuses = pa.array(["F", "O"])
-
-    schema = pa.schema(
-        [
-            ("l_returnflag", pa.string()),
-            ("l_linestatus", pa.string()),
-            ("l_quantity", pa.float64()),
-            ("l_extendedprice", pa.float64()),
-            ("l_discount", pa.float64()),
-            ("l_tax", pa.float64()),
-            ("l_shipdate", pa.string()),
-        ]
-    )
-    tmp = path + ".tmp"
-    writer = pq.ParquetWriter(tmp, schema)
-    try:
-        for start in range(0, rows, _CHUNK):
-            n = min(_CHUNK, rows - start)
-            ship = rng.integers(0, n_dates, n).astype(np.int64)
-            # returnflag correlates with shipdate in TPC-H (returns only
-            # for old orders); keep the same flavor of skew
-            old = ship < (n_dates // 2)
-            flag = np.where(
-                old, rng.integers(0, 2, n) * 2, np.int64(1)
-            )  # old -> A/R, recent -> N
-            status = (ship >= (n_dates * 5 // 8)).astype(np.int64)  # F then O
-            cols = [
-                pa.DictionaryArray.from_arrays(pa.array(flag, pa.int32()), flags).cast(pa.string()),
-                pa.DictionaryArray.from_arrays(pa.array(status, pa.int32()), statuses).cast(pa.string()),
-                pa.array(np.floor(rng.uniform(1, 51, n))),
-                pa.array(np.round(rng.uniform(900.0, 104950.0, n), 2)),
-                pa.array(rng.integers(0, 11, n) / 100.0),
-                pa.array(rng.integers(0, 9, n) / 100.0),
-                pa.DictionaryArray.from_arrays(pa.array(ship, pa.int32()), date_strs).cast(pa.string()),
-            ]
-            writer.write_table(pa.Table.from_arrays(cols, schema=schema))
-    finally:
-        writer.close()
-    os.replace(tmp, path)
-    return path
 
 
 def cities_csv(rows: int) -> str:
@@ -110,7 +43,7 @@ def cities_csv(rows: int) -> str:
 
 
 def groupby_batches(rows: int, groups: int, batch_rows: int, seed: int = 3):
-    """In-memory table for config 2: int64 key of `groups` cardinality +
+    """In-memory group-by table: int64 key of `groups` cardinality +
     three value columns.  Returns (schema, MemoryDataSource)."""
     from datafusion_tpu.datatypes import DataType, Field, Schema
     from datafusion_tpu.exec.batch import make_host_batch
@@ -139,7 +72,7 @@ def groupby_batches(rows: int, groups: int, batch_rows: int, seed: int = 3):
 
 
 def sort_batches(rows: int, batch_rows: int):
-    """In-memory table for config 4: two sort keys + payload."""
+    """In-memory sort table: two sort keys + payload."""
     from datafusion_tpu.datatypes import DataType, Field, Schema
     from datafusion_tpu.exec.batch import make_host_batch
     from datafusion_tpu.exec.datasource import MemoryDataSource
@@ -164,85 +97,3 @@ def sort_batches(rows: int, batch_rows: int):
         ]
         batches.append(make_host_batch(schema, cols, [None] * 4, [None] * 4))
     return schema, MemoryDataSource(schema, batches)
-
-
-def tpch_join_csvs(sf: float = 0.01):
-    """TPC-H-lite star-schema CSVs for the join configs (Q3/Q5/Q10/Q12
-    shapes): nation/customer/orders/lineitem at roughly `sf` times the
-    spec's cardinalities, seeded, cached on disk.  Returns
-    {table: (path, schema)} plus enough skew (dangling orders, repeated
-    customers) that LEFT OUTER and dedup paths do real work."""
-    from datafusion_tpu.datatypes import DataType, Field, Schema
-
-    os.makedirs(BENCH_DIR, exist_ok=True)
-    n_cust = max(200, int(150_000 * sf))
-    n_orders = max(2_000, int(1_500_000 * sf))
-    n_line = max(8_000, int(6_000_000 * sf))
-    n_nation = 25
-    tag = f"sf{sf:g}"
-    rng = np.random.default_rng(19)
-
-    def write(name, header, rows):
-        path = os.path.join(BENCH_DIR, f"join_{name}_{tag}.csv")
-        if os.path.exists(path):
-            return path
-        tmp = path + ".tmp"
-        with open(tmp, "w") as f:
-            f.write(header + "\n")
-            for r in rows:
-                f.write(",".join(str(v) for v in r) + "\n")
-        os.replace(tmp, path)
-        return path
-
-    nation = [(i, f"NATION_{i:02d}") for i in range(n_nation)]
-    cust = [
-        (i, int(rng.integers(0, n_nation)), int(rng.integers(0, 5)),
-         round(float(rng.uniform(-999, 9999)), 2))
-        for i in range(n_cust)
-    ]
-    # ~2% of orders reference customers past the table (dangling keys)
-    orders = [
-        (i, int(rng.integers(0, int(n_cust * 1.02))),
-         f"1995-{rng.integers(1, 13):02d}-{rng.integers(1, 29):02d}",
-         int(rng.integers(0, 3)))
-        for i in range(n_orders)
-    ]
-    line = [
-        (int(rng.integers(0, n_orders)), int(rng.integers(1, 51)),
-         round(float(rng.uniform(900, 105000)), 2),
-         round(float(rng.uniform(0, 0.1)), 2), int(rng.integers(0, 7)))
-        for _ in range(n_line)
-    ]
-    I64, F64, U8 = DataType.INT64, DataType.FLOAT64, DataType.UTF8
-    return {
-        "nation": (
-            write("nation", "n_nationkey,n_name", nation),
-            Schema([Field("n_nationkey", I64, False),
-                    Field("n_name", U8, False)]),
-        ),
-        "customer": (
-            write("customer", "c_custkey,c_nationkey,c_mktsegment,c_acctbal",
-                  cust),
-            Schema([Field("c_custkey", I64, False),
-                    Field("c_nationkey", I64, False),
-                    Field("c_mktsegment", I64, False),
-                    Field("c_acctbal", F64, False)]),
-        ),
-        "orders": (
-            write("orders", "o_orderkey,o_custkey,o_orderdate,o_shippriority",
-                  orders),
-            Schema([Field("o_orderkey", I64, False),
-                    Field("o_custkey", I64, False),
-                    Field("o_orderdate", U8, False),
-                    Field("o_shippriority", I64, False)]),
-        ),
-        "lineitem": (
-            write("lineitem", "l_orderkey,l_quantity,l_extendedprice,"
-                  "l_discount,l_shipmode", line),
-            Schema([Field("l_orderkey", I64, False),
-                    Field("l_quantity", I64, False),
-                    Field("l_extendedprice", F64, False),
-                    Field("l_discount", F64, False),
-                    Field("l_shipmode", I64, False)]),
-        ),
-    }

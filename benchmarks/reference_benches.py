@@ -10,8 +10,8 @@ input), micro-scale so they run anywhere in seconds:
 
     python -m benchmarks.reference_benches
 
-Prints one JSON object with p50 micro-timings per target.  The macro
-perf suite is bench.py (the five BASELINE.md configs).
+Prints one JSON object with p50 micro-timings per target.  The timed
+benchmark is `python3 -m tpubench` (`tpubench/README.md`).
 """
 
 from __future__ import annotations

@@ -1,9 +1,9 @@
 """Shared closed-loop load generator for the serving front door.
 
-Both consumers of the serving benchmark protocol — the `concurrency`
-bench config (`benchmarks/suite.config_concurrency`) and the CI gate
-(`scripts/serve_smoke.py`) — drive the same harness pieces from here,
-so the measurement methodology cannot drift between them:
+Its consumers — the CI gates (`scripts/serve_smoke.py`,
+`scripts/qos_smoke.py`) and `chip_smoke.py`'s serve stage — drive the
+same harness pieces from here, so the methodology cannot drift between
+them:
 
 - `launch_floor_plan(ms)`: the injected per-launch latency floor (a
   seeded `device.call` delay rule).  Host-CPU dispatch models no

@@ -4,8 +4,9 @@
 One process — the only one that imports JAX, so the only holder of the
 chip — drives `ExecutionContext(device="tpu")` -> `register_parquet` ->
 `ctx.sql(Q1)` -> `collect`, and the same table behind `ctx.serve()`,
-over TPC-H lineitem at SF-10 (60 M rows; `benchmarks/data.py`, seeded),
-and checks every answer against an independent pyarrow + numpy oracle.
+over TPC-H lineitem at SF-10 (60 M rows: `tpubench`'s `tpch_lineitem` data
+set, the one `q1_sf10_warm` runs on, seeded), and checks every answer
+against an independent pyarrow + numpy oracle.
 
 Stages, each checked, none caught — a failed check raises and the
 process exits non-zero without printing a result:
@@ -16,8 +17,8 @@ process exits non-zero without printing a result:
 3. serve  a few dozen concurrent `submit`s of Q1's aggregates under
           `l_quantity < x` (distinct x) through the megabatcher
 4. ops    GROUP BY at ~8 k and 100 k groups, int64 ORDER BY over 2^18
-          rows, TopK, a dense-int join at the Pallas hash-build kernel's
-          window edge (8,192 slots), and a join on sparse keys over 2^22
+          rows, TopK, a dense-int join over 8,192 slots, and a join on
+          sparse keys over 2^22
           slots grouped by a build-side string (the device probe that
           `tpubench`'s q12_sf10_join measures)
 4b. q3    TPC-H Q3 over customer, orders and lineitem at SF-1 against
@@ -33,15 +34,15 @@ process exits non-zero without printing a result:
           round's row counts (the predicate runs in the kernel)
 
 Each stage prints the evidence that the device did the work (launches,
-H2D bytes, kernel engagement).  Without a TPU
+H2D bytes, the paths the joins took).  Without a TPU
 (`jax.devices()[0].platform != "tpu"`) the script exits 1 before
 touching data; it sets no `JAX_PLATFORMS` itself.  It writes only under
 `chiprun_out/` and the git-ignored `test/data/bench/`.  The last line
 of stdout is one JSON object: {"ok": true, "device": {...}}.
 
-Sums and averages compare at rtol 1e-9 (`suite._assert_tables_match`'s
-tolerance): f64 is f32-pair software on this chip, and the device's
-reduction order differs from numpy's; group keys and counts are exact.
+Sums and averages compare at rtol 1e-9 (`tpubench/check.py`'s tolerance):
+f64 is f32-pair software on this chip, and the device's reduction order
+differs from numpy's; group keys and counts are exact.
 """
 
 from __future__ import annotations
@@ -62,6 +63,9 @@ RTOL = 1e-9
 # the contract allows 1200 s, compilation included: past this the
 # watchdog dumps every thread's stack to stderr and exits non-zero
 WATCHDOG_S = 1150
+SEED = 20260321
+# Q1 at the spec's validation DELTA = 90: 1998-12-01 less 90 days
+Q1_DELTA = 90
 CUTOFF = "1998-09-02"
 
 _AGGS = (
@@ -227,20 +231,38 @@ def require_on_device(stage: str, ev: dict) -> None:
 
 
 def lineitem_path(sf) -> str:
-    from benchmarks import data as bdata
+    """The Parquet file of `tpubench`'s `tpch_lineitem` data set at 6 M
+    rows an SF, written as `q1_sf10_warm`'s configuration writes it (once
+    per rows and seed, found again by later runs)."""
+    from tpubench import data as tdata
+    from tpubench.spec import Spec
 
-    return bdata.lineitem_parquet(sf)
+    spec = Spec(REPO)
+    made = tdata.prepare(spec.dataset("tpch_lineitem"), "tpch_lineitem",
+                         SEED, round(sf * 6_000_000), REPO,
+                         threads=min(8, os.cpu_count() or 1))
+    paths, _ = tdata.parquet_files(
+        made, spec.config("tpch_lineitem_sf10")["row_group_rows"])
+    return paths["lineitem"]
+
+
+def q1_sql() -> str:
+    """`tpubench`'s Q1 text, bound at `Q1_DELTA`."""
+    from tpubench.spec import Spec
+
+    spec = Spec(REPO)
+    return spec.query("tpch_lineitem", "q1").format(
+        **spec.dataset("tpch_lineitem").bind("q1", {"delta": Q1_DELTA}))
 
 
 def stage_cold(device: str, sf, oracle: Q1Oracle) -> dict:
-    from benchmarks.suite import Q1
     from datafusion_tpu.exec.context import ExecutionContext
     from datafusion_tpu.exec.materialize import collect
 
     before = _counts()
     ctx = ExecutionContext(device=device, batch_size=1 << 19)
     ctx.register_parquet("lineitem", lineitem_path(sf))
-    rows = collect(ctx.sql(Q1)).to_rows()
+    rows = collect(ctx.sql(q1_sql())).to_rows()
     ev = evidence(before, _counts())
     check_rows(rows, oracle.q1(), "cold Q1")
     require_on_device("cold", ev)
@@ -249,8 +271,8 @@ def stage_cold(device: str, sf, oracle: Q1Oracle) -> dict:
 
 
 def resident_lineitem(sf, batch_size: int = 1 << 19):
-    """The lineitem rows as a resident in-memory table, built as
-    `benchmarks/suite.config3_tpch_q1` builds its warm leg."""
+    """The lineitem rows as a resident in-memory table: the engine's
+    Parquet reader's batches, kept."""
     from datafusion_tpu.exec.context import ExecutionContext
     from datafusion_tpu.exec.datasource import MemoryDataSource
 
@@ -265,10 +287,10 @@ def resident_lineitem(sf, batch_size: int = 1 << 19):
 
 def stage_warm(ctx, oracle: Q1Oracle) -> dict:
     """`ctx` holds the resident table as `lineitem` on its device."""
-    from benchmarks.suite import Q1
     from datafusion_tpu.exec.materialize import collect
 
-    rel = ctx.sql(Q1)
+    sql = q1_sql()
+    rel = ctx.sql(sql)
     before = _counts()
     check_rows(collect(rel).to_rows(), oracle.q1(), "warm Q1 (upload pass)")
     uploaded = _counts()
@@ -291,7 +313,7 @@ def stage_warm(ctx, oracle: Q1Oracle) -> dict:
     fresh_before = _counts()
     passes = 2
     for i in range(passes):
-        fresh = ctx.sql(Q1)
+        fresh = ctx.sql(sql)
         require(fresh._host_pred_expr is None and fresh._core_pred is not None,
                 "warm: a resident table's predicate went to the host")
         check_rows(collect(fresh).to_rows(), oracle.q1(),
@@ -364,26 +386,20 @@ def _columns(src) -> list:
 
 
 def stage_operators(device: str, agg_rows: int = 1 << 21) -> dict:
-    """Operator coverage at the largest shapes the (former and present)
-    Pallas windows admit, plus the stock high-cardinality paths.
-    In-memory tables, numpy oracles."""
-    import jax
-
+    """Operator coverage: group-bys at small and high cardinality, a
+    sort, a TopK and two joins.  In-memory tables, numpy oracles."""
     from benchmarks import data as bdata
     from datafusion_tpu.datatypes import DataType, Field, Schema
-    from datafusion_tpu.exec import pallas
     from datafusion_tpu.exec.batch import make_host_batch
     from datafusion_tpu.exec.context import ExecutionContext
     from datafusion_tpu.exec.datasource import MemoryDataSource
     from datafusion_tpu.exec.materialize import collect
-    from datafusion_tpu.exec.pallas import hash_build
     from datafusion_tpu.exec.rowgather import WINDOW_ROWS
 
     out: dict = {}
-    extra = ("join.build.dense", "join.build.pallas_runs",
-             "device.launches.sort.run", "device.launches.join.probe",
-             "d2h.bytes", "join.probe.rows", "join.host_probe.rows",
-             "join.probe.gathers", "join.probe.window.slot",
+    extra = ("join.build.dense", "device.launches.sort.run",
+             "device.launches.join.probe", "d2h.bytes", "join.probe.rows",
+             "join.host_probe.rows", "join.probe.gathers", "join.probe.window.slot",
              "join.probe.window.payload", "expr.cmp_lookups")
 
     def run(label, src_by_name, sql, want, batch_size=1 << 19):
@@ -440,9 +456,9 @@ def stage_operators(device: str, agg_rows: int = 1 << 21) -> dict:
     require_on_device("topk", ev)
     out["topk"] = ev
 
-    # dense-int-key join: 8,192 build slots, the hash-build kernel's
-    # window edge; ~2.5 % of probe keys dangle past the build side
-    slots = pallas.BUILD_MAX_SLOTS
+    # dense-int-key join: 8,192 build slots; ~2.5 % of probe keys dangle
+    # past the build side
+    slots = 8192
     rng = np.random.default_rng(23)
     dim_schema = Schema([Field("k", DataType.INT64, False),
                          Field("grp", DataType.INT64, False)])
@@ -469,17 +485,11 @@ def stage_operators(device: str, agg_rows: int = 1 << 21) -> dict:
     require(ev["device.launches"] > 0, "join_8k_slots: no device launch")
     require(ev["join.build.dense"] == 1,
             "join_8k_slots: the dense device build did not engage")
-    # the kernel engages by its stated rule (exec/pallas): TPU batches
-    # and a slot table within BUILD_MAX_SLOTS — never probe-and-carry-on
-    engaged = ev["join.build.pallas_runs"] == 1
-    require(engaged == pallas.enabled_for(jax.devices(device)[0]),
-            f"join_8k_slots: hash_build engaged={engaged}, the rule says "
-            f"{not engaged}")
-    out["join_8k_slots"] = {**ev, "hash_build_engaged": engaged}
+    out["join_8k_slots"] = ev
 
     # a fact-to-fact join as `tpubench`'s q12_sf10_join measures it, at a
     # sixteenth: 2^20 build rows on TPC-H's sparse keys (the first 8 of
-    # every 32: 2^22 slots, past the old 2^20 cap and the kernel's window),
+    # every 32: 2^22 slots, past the old 2^20 cap),
     # probed by clustered keys of which a fifth dangle, filtered by a
     # range compare on a build-side string (its truth table read on
     # the device: the join's output is born there) and grouped by that
@@ -520,8 +530,8 @@ def stage_operators(device: str, agg_rows: int = 1 << 21) -> dict:
     )
     require(sorted(got) == want, "join_sparse_4m_slots: rows differ from numpy")
     require_on_device("join_sparse_4m_slots", ev)
-    require(ev["join.build.dense"] == 1 and ev["join.build.pallas_runs"] == 0,
-            "join_sparse_4m_slots: not the device build by the XLA scatter")
+    require(ev["join.build.dense"] == 1,
+            "join_sparse_4m_slots: the dense device build did not engage")
     require(ev["join.host_probe.rows"] == 0
             and ev["join.probe.rows"] == n_sort
             and ev["device.launches.join.probe"] == n_sort >> 17,
@@ -559,26 +569,7 @@ def stage_operators(device: str, agg_rows: int = 1 << 21) -> dict:
             f"({ev['d2h.bytes']} B): group ids not made on the device")
     out["join_sparse_4m_slots"] = ev
 
-    # the kernel alone against its numpy oracle at the same shape
-    if engaged:
-        pos = dim_k.astype(np.int32)
-        live = rng.random(slots) > 0.1
-        got_kernel = jax.jit(
-            lambda p, l: hash_build.build_slot_table(
-                p, l, slots, interpret=pallas.interpret_mode())
-        )(pos, live)
-        want_kernel = hash_build.build_slot_table_numpy(pos, live, slots)
-        for g, w in zip(got_kernel, want_kernel):
-            require(bool((np.asarray(g) == w).all()),
-                    "hash_build kernel differs from build_slot_table_numpy")
-        out["hash_build_kernel"] = (
-            f"compiled and matched build_slot_table_numpy at "
-            f"slots={slots} rows={slots}"
-        )
     return out
-
-
-Q3_SEED = 20260321
 
 
 def _resident_dataset(device: str, ds, made: dict, batch_size: int):
@@ -623,7 +614,7 @@ def stage_q3(device: str, sf, batch_size: int = 1 << 17) -> dict:
 
     spec = Spec(REPO)
     ds = spec.dataset("tpch_customer_orders_lineitem")
-    made = ds.generate(Q3_SEED, int(6_000_000 * sf),
+    made = ds.generate(SEED, int(6_000_000 * sf),
                        threads=min(8, os.cpu_count() or 1))
     ctx = _resident_dataset(device, ds, made, batch_size)
     n_orders = len(made["tables"]["orders"]["o_orderkey"])
@@ -696,7 +687,7 @@ def stage_q4(device: str, sf, batch_size: int = 1 << 17) -> dict:
 
     spec = Spec(REPO)
     ds = spec.dataset("tpch_q4_orders_lineitem")
-    made = ds.generate(Q3_SEED, int(6_000_000 * sf),
+    made = ds.generate(SEED, int(6_000_000 * sf),
                        threads=min(8, os.cpu_count() or 1))
     ctx = _resident_dataset(device, ds, made, batch_size)
     params = {"date": Q4_DATE}
@@ -747,7 +738,6 @@ def stage_mesh(sf, want_rows, n_devices: int = 4,
     pass ships a round's row counts and the predicate's one table and
     nothing else (the predicate is in the core: one `cmp_table` lookup
     a round on every chip)."""
-    from benchmarks.suite import Q1
     from datafusion_tpu.exec.materialize import collect
     from datafusion_tpu.io.readers import parquet_row_groups
     from datafusion_tpu.parallel.mesh import make_mesh
@@ -767,7 +757,7 @@ def stage_mesh(sf, want_rows, n_devices: int = 4,
     evs = []
     for i in range(2):
         before = _counts()
-        check_rows(collect(ctx.sql(Q1)).to_rows(), want_rows,
+        check_rows(collect(ctx.sql(q1_sql())).to_rows(), want_rows,
                    f"mesh Q1 pass {i}")
         evs.append(evidence(before, _counts(), extra))
     first, ev = evs

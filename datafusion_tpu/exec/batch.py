@@ -876,9 +876,7 @@ def put_compressed(host_arrays, device=None, hints=None, owner="h2d"):
     Every placement goes through the device ledger (obs/device.py):
     the wires record as profiled *transient* transfers, and the
     decoded resident outputs are adopted under ``owner`` so HBM
-    residency is accounted per owner tag.  With
-    DATAFUSION_TPU_DEVICE_LEDGER=0 the seam degrades to bare
-    device_puts — byte-identical behavior, zero tracking.
+    residency is accounted per owner tag.
 
     `hints` is an optional caller-owned mutable dict {position: hint}
     remembering each column's codec across batches of a scan (cores are

@@ -3,9 +3,8 @@
 Two physical strategies behind one `HashJoinRelation` (relation.py):
 
 - **dense-int device path**: a single integer key whose build-side
-  range is small direct-addresses a slot table built on device (Pallas
-  kernel `exec/pallas/hash_build.py` when it engages, stock-XLA
-  scatter otherwise) and probed inside one fused launch per batch —
+  range is small direct-addresses a slot table built on device (one
+  stock-XLA scatter launch) and probed inside one fused launch per batch —
   payload gather, validity, selection mask all in the same launch.
 - **host path**: the general fallback (multi-key, strings, duplicate
   keys) — a `HashIndex` (core.py) over the build rows, probed with

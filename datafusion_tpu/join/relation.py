@@ -7,8 +7,8 @@ left input).  The build side fully materializes once into a
 
 - **dense-int device probe**: single integer key, unique on the build
   side — the build fills a direct-address slot table on device
-  (`exec/pallas/hash_build` kernel when it engages, stock-XLA scatter
-  otherwise; both launch under ``device.launches.join.build``) and
+  (`build_slot_table_xla`, one launch under
+  ``device.launches.join.build``) and
   every probe batch runs ONE fused launch
   (``device.launches.join.probe``, program ``jit_join_probe``)
   computing hit mask + payload gather at probe capacity — no host
@@ -168,7 +168,6 @@ from typing import Optional
 import numpy as np
 
 from datafusion_tpu.datatypes import Schema
-from datafusion_tpu.exec import pallas as _pallas
 from datafusion_tpu.exec.batch import (
     RecordBatch,
     device_inputs,
@@ -756,21 +755,12 @@ class HashJoinRelation(Relation):
         )
         payload = jax.tree.unflatten(layout, placed)
 
-        # the stated engagement rule (exec/pallas): TPU batches and a
-        # slot table within the kernel's window; operands are int32
-        use_pallas = (
-            _pallas.enabled_for(self.device)
-            and num_slots <= _pallas.BUILD_MAX_SLOTS
-        )
-        if use_pallas:
-            METRICS.add("join.build.pallas_runs")
         # one launch fills the slot table and lays it and the payload
         # out as rows; these outputs are what stays resident (the
         # flat uploads go with this frame)
         art.dev_slot_row, (art.dev_cols, art.dev_valids) = LEDGER.adopt(
             device_call(
-                _build_jit(pad_rows(num_slots), use_pallas,
-                           _pallas.interpret_mode()),
+                _build_jit(pad_rows(num_slots)),
                 dev_pos, dev_live, payload, _tag="join.build",
             ), owner="join.build", device=self.device)
         # the probe's two scalars, placed once
@@ -1105,25 +1095,49 @@ def _concat_compacted(parts: list, schema: Schema) -> tuple:
 _BUILD_JITS: dict = {}
 
 
-def _build_jit(num_slots: int, use_pallas: bool, interpret: bool):
-    """Jitted build, one per (slots, kernel-choice): the slot table
-    filled, and it and the payload laid out as `LANES`-wide rows."""
-    key = (num_slots, use_pallas, interpret)
-    hit = _BUILD_JITS.get(key)
+def build_slot_table_xla(pos, live, num_slots: int):
+    """Direct-address build: per slot in [0, num_slots), the max build
+    row index whose key maps there (-1 = empty) and the number of build
+    rows sharing it.  `pos` is int32 slot positions (key - kmin), `live`
+    masks rows out.  Two scatters, traceable — call under jit."""
+    import jax.numpy as jnp
+
+    n = pos.shape[0]
+    rows = jnp.arange(n, dtype=jnp.int32)
+    safe = jnp.where(live, pos, num_slots)  # dead rows land off-table
+    slot_row = jnp.full(num_slots + 1, -1, jnp.int32).at[safe].max(
+        jnp.where(live, rows, -1)
+    )
+    slot_count = jnp.zeros(num_slots + 1, jnp.int32).at[safe].add(
+        live.astype(jnp.int32)
+    )
+    return slot_row[:num_slots], slot_count[:num_slots]
+
+
+def build_slot_table_numpy(pos, live, num_slots: int):
+    """Numpy parity oracle for `build_slot_table_xla`."""
+    pos = np.asarray(pos)
+    live = np.asarray(live, bool)
+    sel = live & (pos >= 0) & (pos < num_slots)
+    slot_row = np.full(num_slots, -1, np.int32)
+    slot_count = np.zeros(num_slots, np.int32)
+    rows = np.arange(pos.shape[0], dtype=np.int32)
+    np.maximum.at(slot_row, pos[sel], rows[sel])
+    np.add.at(slot_count, pos[sel], 1)
+    return slot_row, slot_count
+
+
+def _build_jit(num_slots: int):
+    """Jitted build, one per slot count: the slot table filled, and it
+    and the payload laid out as `LANES`-wide rows."""
+    hit = _BUILD_JITS.get(num_slots)
     if hit is None:
         import jax
 
-        from datafusion_tpu.exec.pallas import hash_build
-
         def join_build(pos, live, payload):
-            if use_pallas:
-                slot_row = hash_build.build_slot_table(
-                    pos, live, num_slots, interpret=interpret)[0]
-            else:
-                slot_row = hash_build.build_slot_table_xla(
-                    pos, live, num_slots)[0]
+            slot_row = build_slot_table_xla(pos, live, num_slots)[0]
             return jax.tree.map(lambda a: a.reshape(-1, LANES),
                                 (slot_row, payload))
 
-        hit = _BUILD_JITS[key] = jax.jit(join_build)
+        hit = _BUILD_JITS[num_slots] = jax.jit(join_build)
     return hit

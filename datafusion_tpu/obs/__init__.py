@@ -33,8 +33,8 @@ fragment, per worker:
   sliding windows, burn-rate gauges, flight-recorder dump on breach.
 - `obs.profiler` — host-side wall-clock sampling profiler (stdlib
   only): collapsed stacks / speedscope output with per-phase and
-  per-trace attribution; scoped captures under EXPLAIN ANALYZE and the
-  bench cold legs, continuous mode via `DATAFUSION_TPU_PROFILE_HZ`.
+  per-trace attribution; scoped captures under EXPLAIN ANALYZE,
+  continuous mode via `DATAFUSION_TPU_PROFILE_HZ`.
 - `obs.httpd` — the unified debug HTTP plane (`/debug/metrics`,
   `/debug/flights`, `/debug/hbm`, `/debug/top`, `/debug/profile`,
   `/debug/bundle`) served on `DATAFUSION_TPU_DEBUG_PORT` by workers

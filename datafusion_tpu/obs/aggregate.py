@@ -314,14 +314,10 @@ def node_snapshot() -> dict:
     # the exact sum (correcting any lock-free-writer drift) and rewrites
     # device.hbm.live_bytes/peak_bytes, so the piggybacked snapshot —
     # and every fleet.hbm.* sum derived from it — reports measured
-    # residency, not the last put's running estimate.  A ledger-off
-    # node publishes NO hbm gauges at all: a zero from a node that
-    # measures nothing would sum into fleet.hbm.* looking like a
-    # measured empty device
-    from datafusion_tpu.obs import device as _device
+    # residency, not the last put's running estimate
+    from datafusion_tpu.obs.device import LEDGER
 
-    if _device.enabled():
-        _device.LEDGER.live_bytes()
+    LEDGER.live_bytes()
     # host-resource gauges (RSS, peak RSS, open FDs) refresh the same
     # way: measured at snapshot time, absent when the platform hides
     # them — the fleet sums only measured values
@@ -334,11 +330,6 @@ def node_snapshot() -> dict:
     attribution.refresh_tenant_gauges()
     snap = METRICS.snapshot()
     gauges = snap["gauges"]
-    if not _device.enabled():
-        gauges = {
-            k: v for k, v in gauges.items()
-            if not k.startswith("device.hbm.")
-        }
     return {
         "ts": time.time(),
         "histograms": {k: h.snapshot() for k, h in HISTOGRAMS.items()},

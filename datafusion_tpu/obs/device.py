@@ -13,8 +13,8 @@ against.  Three instruments, one module:
   tag (table scan, batch cache, mesh state, sort image, ...),
   the placing query's trace id, and its *lifetime* — a
   ``weakref.finalize`` fires when the buffer's Python handle dies, so
-  live-bytes and the peak watermark are measured facts, not the
-  estimated-peak formula ``benchmarks/suite.py`` used before.  Gauges
+  live-bytes and the peak watermark are measured facts, not an
+  estimated-peak formula.  Gauges
   ``device.hbm.live_bytes`` / ``device.hbm.peak_bytes`` ride every
   scrape, `\\hbm` renders the per-owner breakdown, and a leak sweep at
   query completion flags non-cache buffers that outlive their query
@@ -31,9 +31,7 @@ against.  Three instruments, one module:
 - **The phase breakdown**: per-query deltas of the engine's stage
   timers decompose a cold run into decode (parse+encode) -> H2D ->
   compile -> execute -> D2H -> other, rendered as a one-line bar in
-  EXPLAIN ANALYZE and recorded as ``cold_phase_ms`` per bench config —
-  ROADMAP item 3's "cold >= 2x CPU" target becomes a measured,
-  decomposed gap instead of folklore.
+  EXPLAIN ANALYZE and carried on the ``query.done`` flight event.
 
 Cost model: like the flight recorder, the put/adopt/release path is
 LOCK-FREE — dict stores, int adds, one ``weakref.finalize``
@@ -42,11 +40,8 @@ other subsystems' critical sections (lint rule DF005 and the lockcheck
 soak enforce it).  The running live-bytes counter tolerates the
 occasional lost increment under concurrent writers (the statsd trade);
 ``live_bytes()`` recomputes the exact sum from the entry table on
-every read (scrape paths), correcting any drift.
-
-``DATAFUSION_TPU_DEVICE_LEDGER=0`` disables everything: the seam
-degrades to a bare ``jax.device_put`` and the hot paths are
-byte-identical to the unledgered engine.
+every read (scrape paths), correcting any drift.  The ledger is always
+on: admission (``fits`` / ``headroom``) and the H2D counters read it.
 """
 
 from __future__ import annotations
@@ -59,13 +54,11 @@ import time
 import weakref
 from typing import Any, Optional
 
-from datafusion_tpu.obs.recorder import _env_flag
 from datafusion_tpu.obs.recorder import record as _flight_record
 from datafusion_tpu.obs.trace import _current_trace
 from datafusion_tpu.utils.metrics import METRICS
 
 
-_ENABLED = _env_flag("DATAFUSION_TPU_DEVICE_LEDGER", True)
 # buffers that are not cache-owned and survive this long past their
 # query's completion are reported as leaks (two sweeps must see them:
 # one marks, a later one past the grace reports)
@@ -74,18 +67,14 @@ _LEAK_GRACE_S = float(
 )
 
 
-def enabled() -> bool:
-    return _ENABLED
-
-
 # -- profiling-sync mode ----------------------------------------------
 # Jitted launches return after DISPATCH on accelerators; the device
 # keeps computing while the host moves on, and the wall lands in
 # whichever timer blocks next (d2h.wait).  Always blocking launches
 # would serialize real host/device overlap the engine relies on (mesh
 # rounds, merge prep), so phase-accurate launch timing is opt-in: the
-# phase-breakdown consumers (EXPLAIN ANALYZE, bench cold legs) run
-# their query under `profile_sync()`, and `utils/retry.device_call`
+# phase-breakdown consumer (EXPLAIN ANALYZE) runs its query under
+# `profile_sync()`, and `utils/retry.device_call`
 # blocks each launch on completion only inside it — the "execute"
 # slice then measures device wall, not dispatch, and "d2h" shrinks to
 # the true transfer.  Contextvar-scoped so one traced query never
@@ -107,15 +96,12 @@ def profile_sync():
 
 
 def profile_sync_active() -> bool:
-    return _ENABLED and _profile_sync_depth.get() > 0
+    return _profile_sync_depth.get() > 0
 
 
-def configure(enabled: Optional[bool] = None,
-              leak_grace_s: Optional[float] = None) -> None:
-    """Test/embedding override of the env-derived knobs."""
-    global _ENABLED, _LEAK_GRACE_S
-    if enabled is not None:
-        _ENABLED = bool(enabled)
+def configure(leak_grace_s: Optional[float] = None) -> None:
+    """Test/embedding override of the env-derived knob."""
+    global _LEAK_GRACE_S
     if leak_grace_s is not None:
         _LEAK_GRACE_S = float(leak_grace_s)
 
@@ -198,24 +184,21 @@ class DeviceLedger:
         jax Device, a Sharding, or None for the default), record the
         transfer, and track the resulting buffer's residency under
         ``owner``.  ``cached=False`` marks buffers that should die with
-        their query — the leak sweep only ever flags those.  Disabled
-        (``DATAFUSION_TPU_DEVICE_LEDGER=0``) this is a bare device_put.
+        their query — the leak sweep only ever flags those.
 
         Timing: ``jax.device_put`` is asynchronous on accelerators, so
         ordinary puts record the *dispatch* wall only (events marked
         ``dispatch_only``, no GB/s claimed) and the engine keeps its
         transfer/host-work overlap: parse of batch N+1 proceeds while
         batch N's DMA is in flight.  Under ``profile_sync()`` (EXPLAIN
-        ANALYZE, bench cold legs, i.e. the phase-breakdown consumers)
-        the put blocks on completion and the event carries true
-        achieved GB/s vs the link baseline.  Call sites that dispatch a
+        ANALYZE, the phase-breakdown consumer) the put blocks on
+        completion and the event carries true achieved GB/s vs the link
+        baseline.  Call sites that dispatch a
         *batch* of transfers to distinct devices use
         ``transfer(..., profile=False)`` + one ``note_h2d`` so parallel
         links stay parallel."""
         import jax
 
-        if not _ENABLED:
-            return jax.device_put(arr, device)
         if _is_device_array(arr):
             # already device-resident: this is a reshard/placement
             # (e.g. mesh state distribution), not a host->device
@@ -250,8 +233,6 @@ class DeviceLedger:
         on the batch and records one combined ``note_h2d``."""
         import jax
 
-        if not _ENABLED:
-            return jax.device_put(arr, device)
         if not profile:
             return jax.device_put(arr, device)
         synced = profile_sync_active()
@@ -269,8 +250,6 @@ class DeviceLedger:
         resident buffer under ``owner`` — for buffers the engine did
         not place directly: decode-kernel outputs, mesh-stacked global
         arrays.  Returns ``value`` unchanged."""
-        if not _ENABLED:
-            return value
         import jax
 
         for leaf in jax.tree.leaves(value):
@@ -281,8 +260,6 @@ class DeviceLedger:
     def retag(self, value: Any, owner: str, cached: bool = True) -> None:
         """Re-attribute already-tracked buffers (a table's copies a
         pin takes over stop being transient)."""
-        if not _ENABLED:
-            return
         import jax
 
         for leaf in jax.tree.leaves(value):
@@ -410,8 +387,8 @@ class DeviceLedger:
         return self._peak
 
     def begin_peak_window(self) -> int:
-        """Start a per-run watermark (EXPLAIN ANALYZE, bench cold
-        legs): `window_peak_bytes` then reports the high-water mark
+        """Start a per-run watermark (EXPLAIN ANALYZE):
+        `window_peak_bytes` then reports the high-water mark
         since this call, WITHOUT disturbing the process-wide
         `device.hbm.peak_bytes` gauge monitoring relies on.  One
         window at a time — a new begin re-arms it (concurrent queries
@@ -476,8 +453,6 @@ class DeviceLedger:
         running long concurrent untraced queries should enable tracing
         (buffers then scope to their query) or raise
         ``DATAFUSION_TPU_LEDGER_LEAK_GRACE_S``."""
-        if not _ENABLED:
-            return 0
         grace = _LEAK_GRACE_S if grace_s is None else grace_s
         now = time.monotonic()
         leaks = 0
@@ -707,7 +682,6 @@ class DeviceLedger:
             f"Device ledger: {snap['buffers']} buffer(s), "
             f"live {_fmt_bytes(snap['live_bytes'])}, "
             f"peak {_fmt_bytes(snap['peak_bytes'])}"
-            + ("" if _ENABLED else "  [DISABLED]")
         ]
         for dev, nbytes in sorted(snap["devices"].items()):
             lines.append(f"  device {dev}: {_fmt_bytes(nbytes)}")
@@ -746,8 +720,7 @@ def hbm_capacity_bytes(device=None) -> Optional[int]:
     bytes span all local devices (the mesh path shards across them), so
     dividing by one chip's capacity would over-report pressure N-fold
     on an N-device host.  Else None — an unknown capacity keeps the
-    objective dormant rather than guessed (the exact anti-pattern the
-    ledger replaced in benchmarks/suite.py).  With a `device`: that
+    objective dormant rather than guessed.  With a `device`: that
     one chip's capacity (the override's even share of the total)."""
     try:
         import jax
@@ -816,8 +789,6 @@ def record_d2h(nbytes: int, seconds: float) -> None:
     """One device->host pull completed (materialize's blocking wait):
     flight event + per-operator transfer time.  The ``d2h.wait`` stage
     timer is the caller's (no double count here)."""
-    if not _ENABLED:
-        return
     from datafusion_tpu.obs.stats import record_d2h_time
 
     record_d2h_time(seconds)
@@ -857,14 +828,7 @@ def phase_snapshot() -> dict[str, float]:
     """Current values of every timer a phase derives from — capture
     before a query, feed to ``phase_breakdown`` after.  Timers are
     process-global: with concurrent queries in flight the breakdown is
-    approximate (attributed to whichever root completes).  With the
-    ledger disabled the ``h2d.dispatch`` timer never accrues (the seam
-    degrades to a bare device_put), so rather than render a bar that
-    silently folds H2D into "other" — misleading exactly the
-    decode-vs-H2D tuning the bar exists for — both phase functions
-    return empty and the consumers skip rendering."""
-    if not _ENABLED:
-        return {}
+    approximate (attributed to whichever root completes)."""
     timings = METRICS.timings
     return {
         t: timings.get(t, 0.0)
@@ -876,10 +840,7 @@ def phase_snapshot() -> dict[str, float]:
 def phase_breakdown(before: Optional[dict], wall_s: float,
                     ) -> dict[str, float]:
     """Per-phase seconds for one query from the timer deltas since
-    ``before`` (None/{} = since process start) and the query wall.
-    Empty when the ledger is disabled (see ``phase_snapshot``)."""
-    if not _ENABLED:
-        return {}
+    ``before`` (None/{} = since process start) and the query wall."""
     before = before or {}
     cur = phase_snapshot()
     phases: dict[str, float] = {}
@@ -895,8 +856,8 @@ def phase_breakdown(before: Optional[dict], wall_s: float,
 
 
 def phase_ms(phases: dict[str, float]) -> dict[str, float]:
-    """Milliseconds form for JSON artifacts (BENCH ``cold_phase_ms``,
-    flight-dump ``query.phases``)."""
+    """Milliseconds form for JSON artifacts (flight-dump
+    ``query.phases``)."""
     return {k: round(v * 1e3, 2) for k, v in phases.items()}
 
 

@@ -317,7 +317,7 @@ def explain_analyze(ctx, plan,
     phases = phase_breakdown(phase_before, wall)
     hbm = {"peak_bytes": LEDGER.window_peak_bytes(),
            "live_bytes": LEDGER.live_bytes(),
-           "buffers": LEDGER.entries} if _device.enabled() else {}
+           "buffers": LEDGER.entries}
     counters = {
         k: METRICS.counts.get(k, 0) - before.get(k, 0) for k in _WATCHED
     }

@@ -106,7 +106,6 @@ def build_bundle(*, label: Optional[str] = None,
     """The one-stop debug artifact (see module doc).  ``profile_seconds``
     > 0 captures a fresh on-demand profile (bounded); the continuous
     profiler's rolling report rides along when it is running."""
-    from datafusion_tpu.obs import device as _device
     from datafusion_tpu.obs import profiler, recorder, slo
     from datafusion_tpu.obs.aggregate import refresh_host_gauges
     from datafusion_tpu.obs.device import LEDGER
@@ -130,10 +129,7 @@ def build_bundle(*, label: Optional[str] = None,
             "events_emitted": recorder.emitted(),
             "events": recorder.events(trace_id),
         },
-        "hbm": (
-            {"enabled": True, **LEDGER.snapshot()}
-            if _device.enabled() else {"enabled": False}
-        ),
+        "hbm": {"enabled": True, **LEDGER.snapshot()},
         "slo": slo.WATCHDOG.evaluate() if slo.WATCHDOG.armed() else [],
     }
     try:
@@ -369,12 +365,9 @@ def _route_request(srv: "DebugServer", path: str, q: dict):
             "events": recorder.events(q.get("trace_id") or None),
         })
     if path == "/debug/hbm":
-        from datafusion_tpu.obs import device as _device
         from datafusion_tpu.obs.device import LEDGER
 
-        if _device.enabled():
-            return _json_body({"enabled": True, **LEDGER.snapshot()})
-        return _json_body({"enabled": False})
+        return _json_body({"enabled": True, **LEDGER.snapshot()})
     if path == "/debug/serve":
         from datafusion_tpu.obs.aggregate import HISTOGRAMS
         from datafusion_tpu.obs.device import LEDGER

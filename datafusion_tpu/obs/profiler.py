@@ -37,7 +37,7 @@ caller's thread at report time.
 Modes:
 
 - **Scoped** (``with profile() as cap: ...; cap.report()``): EXPLAIN
-  ANALYZE, the bench cold legs, and ``/debug/profile?seconds=N`` run
+  ANALYZE and ``/debug/profile?seconds=N`` run
   under one of these.  The sampler thread exists only while a capture
   is active — default-off means zero threads.
 - **Continuous** (``DATAFUSION_TPU_PROFILE_HZ=<hz>``, default 0=off):
@@ -465,8 +465,8 @@ def stop_continuous() -> Optional[ProfileReport]:
 
 class profile:
     """``with profile() as cap: ...`` — scoped capture; read
-    ``cap.report()`` after the block (EXPLAIN ANALYZE, the bench cold
-    legs, ``/debug/profile``).  ``hz=0``/``enabled=False`` degrades to
+    ``cap.report()`` after the block (EXPLAIN ANALYZE,
+    ``/debug/profile``).  ``hz=0``/``enabled=False`` degrades to
     a no-op scope yielding None (callers need no branching)."""
 
     __slots__ = ("_hz", "_name", "_cap", "_enabled")

@@ -139,14 +139,10 @@ class SloWatchdog:
     def _hbm_burn(self, obj: Objective) -> dict:
         """Memory-pressure burn: measured live-HBM fraction over the
         allowance, read fresh from the device ledger.  Unknown device
-        capacity OR a disabled ledger = dormant (burn 0, samples 0),
-        never a guess — with DATAFUSION_TPU_DEVICE_LEDGER=0 nothing
-        registers, so live_bytes()=0 would read as a confidently
-        healthy device while HBM might be exhausted."""
-        from datafusion_tpu.obs import device as _device
+        capacity = dormant (burn 0, samples 0), never a guess."""
         from datafusion_tpu.obs.device import LEDGER, hbm_capacity_bytes
 
-        cap = hbm_capacity_bytes() if _device.enabled() else None
+        cap = hbm_capacity_bytes()
         value = LEDGER.live_bytes() / cap if cap else 0.0
         burn = value / obj.threshold
         return {

@@ -220,11 +220,7 @@ class _MeshStacker:
         per-shard transfers profile through the device ledger and count
         into `h2d.bytes`; the assembled global array is adopted under
         ``owner`` and dies with its round."""
-        from datafusion_tpu.obs.device import (
-            LEDGER,
-            enabled as _ledger_on,
-            profile_sync_active,
-        )
+        from datafusion_tpu.obs.device import LEDGER, profile_sync_active
 
         # dispatch every shard without per-transfer blocking (the n
         # device links genuinely run in parallel), then — only under
@@ -242,15 +238,9 @@ class _MeshStacker:
                 jax.block_until_ready(put)
         nbytes = sum(int(p.nbytes) for p in put)
         METRICS.add("h2d.bytes", nbytes)
-        if _ledger_on():
-            # one event for the batch of parallel transfers (it counts
-            # once in `device.h2d.transfers`)
-            LEDGER.note_h2d(
-                nbytes,
-                span.wall_s,
-                self.devices[0],
-                synced=synced,
-            )
+        # one event for the batch of parallel transfers (it counts once
+        # in `device.h2d.transfers`)
+        LEDGER.note_h2d(nbytes, span.wall_s, self.devices[0], synced=synced)
         return LEDGER.adopt(
             jax.make_array_from_single_device_arrays(
                 (self.n,) + np.asarray(shards[0]).shape,

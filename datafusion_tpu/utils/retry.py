@@ -301,7 +301,7 @@ def device_call(fn, /, *args, _tag=None, **kwargs):
     stage timer (the "execute" slice of the cold-path phase breakdown;
     XLA compile inside a traced first call is split back out via the
     ``compile.xla`` listener).  Under ``obs/device.profile_sync()``
-    (EXPLAIN ANALYZE, bench cold legs) the launch blocks on completion
+    (EXPLAIN ANALYZE) the launch blocks on completion
     so that wall is device execution, not async dispatch; elsewhere it
     is dispatch-only and launches stay asynchronous."""
     attempt = 0
@@ -324,10 +324,10 @@ def device_call(fn, /, *args, _tag=None, **kwargs):
                     _stats=lambda: _census(_tag, args, kwargs)) as span:
                 out = fn(*args, **kwargs)
                 if profile_sync_active():
-                    # phase-profiled run (EXPLAIN ANALYZE, bench cold
-                    # legs): block so the "execute" slice measures device
-                    # wall, not async dispatch — production launches stay
-                    # async (see obs/device.profile_sync)
+                    # phase-profiled run (EXPLAIN ANALYZE): block so the
+                    # "execute" slice measures device wall, not async
+                    # dispatch — production launches stay async (see
+                    # obs/device.profile_sync)
                     import jax
 
                     jax.block_until_ready(out)

@@ -1,8 +1,10 @@
 """One-shot cold-path profiler: per-phase wall timeline for TPC-H Q1.
 
 Run:  python scripts/profile_cold.py [sf]
-Prints a per-batch timeline (parse / encode / h2d / dispatch) plus the
-final blocking wait, and a raw link-bandwidth measurement.
+Lineitem is `tpubench`'s `tpch_lineitem` data set (as `chip_smoke.py`
+writes it), the query `tpubench`'s Q1 at DELTA 90.  Prints a per-batch
+timeline (parse / encode / h2d / dispatch) plus the final blocking wait,
+and a raw link-bandwidth measurement.
 """
 
 import os
@@ -33,12 +35,12 @@ def main():
     print(f"H2D 64MB: {t1-t0:.3f}s ({64/(t1-t0):.0f} MB/s)   "
           f"D2H 64MB: {t2-t1:.3f}s ({64/(t2-t1):.0f} MB/s)", flush=True)
 
-    from benchmarks import data as bdata
-    from benchmarks.suite import Q1
+    from chip_smoke import lineitem_path, q1_sql
     from datafusion_tpu.exec.context import ExecutionContext
     from datafusion_tpu.exec.materialize import collect
 
-    path = bdata.lineitem_parquet(sf)
+    path = lineitem_path(sf)
+    Q1 = q1_sql()
 
     def cold():
         ctx = ExecutionContext(batch_size=1 << 19)
@@ -58,9 +60,9 @@ def main():
 
     real_device_inputs = batch_mod.device_inputs
 
-    def timed_device_inputs(b, device=None):
+    def timed_device_inputs(*args, **kwargs):
         t = time.perf_counter()
-        out = real_device_inputs(b, device)
+        out = real_device_inputs(*args, **kwargs)
         events.append(("device_inputs", t, time.perf_counter()))
         return out
 
@@ -69,9 +71,9 @@ def main():
 
     real_group_ids = agg.AggregateRelation._group_ids
 
-    def timed_group_ids(self, b):
+    def timed_group_ids(self, *args, **kwargs):
         t = time.perf_counter()
-        out = real_group_ids(self, b)
+        out = real_group_ids(self, *args, **kwargs)
         events.append(("group_ids", t, time.perf_counter()))
         return out
 
@@ -100,10 +102,10 @@ def main():
     # wrap the parquet reader batch iterator
     import datafusion_tpu.io.readers as readers
 
-    real_batches = readers.ParquetReader._batches
+    real_batches = readers.ParquetReader.batches
 
-    def timed_batches(self):
-        it = real_batches(self)
+    def timed_batches(self, *args, **kwargs):
+        it = real_batches(self, *args, **kwargs)
         while True:
             t = time.perf_counter()
             try:
@@ -113,7 +115,7 @@ def main():
             events.append(("parse", t, time.perf_counter()))
             yield b
 
-    readers.ParquetReader._batches = timed_batches
+    readers.ParquetReader.batches = timed_batches
 
     # wrap the jitted aggregate kernel dispatch
     from datafusion_tpu.utils import retry

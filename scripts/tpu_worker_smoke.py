@@ -5,9 +5,7 @@ parity with the single-process CPU engine.  Prints one JSON line.
 A chip belongs to one process.  Run standalone, this script is the
 coordinator: it pins itself to the CPU *before* anything imports JAX,
 so the `python -m datafusion_tpu.worker --device tpu` child it starts
-is the only process that initialises the TPU backend.  `bench.py`
-already holds the chip, so its worker leg calls `run_parity` against a
-worker served from a thread of its own process instead.
+is the only process that initialises the TPU backend.
 
 Run:  python scripts/tpu_worker_smoke.py
 (Equivalent pytest: DATAFUSION_TPU_TEST_TPU_WORKER=1

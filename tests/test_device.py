@@ -3,8 +3,7 @@ HBM residency-ledger semantics under churn (release on buffer death,
 no double-count on re-adopt, owner re-tagging), the leak detector's
 two-sweep confirmation, the cold-path phase breakdown, per-table scan
 histograms at the datasource boundary, lint rule DF006, and the
-EXPLAIN ANALYZE phase-bar/HBM rendering — plus the
-``DATAFUSION_TPU_DEVICE_LEDGER=0`` escape hatch."""
+EXPLAIN ANALYZE phase-bar/HBM rendering."""
 
 from __future__ import annotations
 
@@ -242,18 +241,6 @@ class TestLedgerChurn:
             e for e in recorder.events() if e["kind"] == "device.h2d"
         ]
 
-    def test_disabled_ledger_is_a_bare_device_put(self, ledger):
-        saved = device._ENABLED
-        device.configure(enabled=False)
-        try:
-            out = ledger.put(np.arange(128), None, owner="x")
-            assert hasattr(out, "copy_to_host_async")
-            assert ledger.entries == 0
-            assert ledger.adopt(out, owner="x") is out
-            assert ledger.sweep(None) == 0
-        finally:
-            device.configure(enabled=saved)
-
     def test_report_text_renders(self, ledger):
         held = ledger.put(
             np.arange(1000, dtype=np.float64), None, owner="scan.t"
@@ -310,24 +297,6 @@ class TestQueryIntegration:
         report = res.report()
         assert "Phases: " in report
         assert "HBM: peak " in report
-
-    def test_explain_analyze_disabled_ledger_skips_device_lines(
-            self, tmp_path):
-        path = _write_csv(tmp_path / "t.csv")
-        ctx = ExecutionContext()
-        ctx.register_csv("t", path, SCHEMA, has_header=True)
-        saved = device._ENABLED
-        device.configure(enabled=False)
-        try:
-            res = ctx.sql_collect(
-                "EXPLAIN ANALYZE SELECT k, SUM(v) FROM t GROUP BY k"
-            )
-        finally:
-            device.configure(enabled=saved)
-        assert res.phases == {} and res.hbm == {}
-        report = res.report()
-        assert "Phases: " not in report
-        assert "HBM: peak " not in report
 
     def test_metrics_text_exposes_hbm_and_scan_histograms(self, tmp_path):
         from datafusion_tpu.obs.device import LEDGER
@@ -405,8 +374,8 @@ class TestScanHistograms:
 class TestPhaseBreakdown:
     def test_profile_sync_scopes_and_launch_works_inside(self):
         # profile-sync is the opt-in "block launches for phase-accurate
-        # execute timing" mode used by EXPLAIN ANALYZE and bench cold
-        # legs; it must nest, scope, and leave device_call functional
+        # execute timing" mode used by EXPLAIN ANALYZE; it must nest,
+        # scope, and leave device_call functional
         import jax.numpy as jnp
 
         from datafusion_tpu.utils.retry import device_call
@@ -420,14 +389,6 @@ class TestPhaseBreakdown:
                 assert int(out[3]) == 6
             assert device.profile_sync_active()
         assert not device.profile_sync_active()
-        # disabled ledger keeps the mode off even inside the context
-        saved = device._ENABLED
-        device.configure(enabled=False)
-        try:
-            with device.profile_sync():
-                assert not device.profile_sync_active()
-        finally:
-            device.configure(enabled=saved)
 
     def test_breakdown_math(self):
         before = phase_snapshot()
@@ -460,19 +421,6 @@ class TestPhaseBreakdown:
     def test_bar_empty(self):
         assert phase_bar({}, 1.0) == "(no phases recorded)"
 
-    def test_disabled_ledger_yields_no_phases(self):
-        # with the ledger off, h2d.dispatch never accrues — a rendered
-        # bar would silently fold H2D into "other", so the phase
-        # functions return empty and consumers skip the line
-        saved = device._ENABLED
-        device.configure(enabled=False)
-        try:
-            assert phase_snapshot() == {}
-            assert phase_breakdown(None, 1.0) == {}
-        finally:
-            device.configure(enabled=saved)
-
-
 class TestHbmPressureSlo:
     def test_hbm_frac_burn_and_breach(self, monkeypatch):
         from datafusion_tpu.obs import slo
@@ -493,28 +441,6 @@ class TestHbmPressureSlo:
         assert row["breached"] and row["burn_rate"] >= 1.0
         assert held is not None and held2 is not None
         LEDGER.clear()
-
-    def test_disabled_ledger_keeps_hbm_objective_dormant(self, monkeypatch):
-        # with DATAFUSION_TPU_DEVICE_LEDGER=0 nothing registers, so
-        # live_bytes()=0 must not read as a confidently healthy device
-        from datafusion_tpu.obs import slo
-
-        monkeypatch.setenv("DATAFUSION_TPU_HBM_BYTES", str(1 << 20))
-        saved = device._ENABLED
-        device.configure(enabled=False)
-        try:
-            wd = slo.SloWatchdog(capture_on_breach=False)
-            wd.add(slo.Objective("pressure", "hbm_frac", 0.5))
-            row = wd.evaluate()[0]
-            assert row["samples"] == 0 and not row["breached"]
-            # ...and a ledger-off node publishes NO hbm gauges for the
-            # fleet to sum as measured zeros
-            snap = aggregate.node_snapshot()
-            assert not any(
-                k.startswith("device.hbm.") for k in snap["gauges"]
-            )
-        finally:
-            device.configure(enabled=saved)
 
     def test_capacity_sums_local_devices(self, monkeypatch):
         # ledger live bytes span ALL local devices (the mesh shards

@@ -12,8 +12,6 @@ and the shuffle partition/dedup units distributed joins build on.
 
 from __future__ import annotations
 
-import os
-
 import numpy as np
 import pandas as pd
 import pytest
@@ -98,18 +96,6 @@ class TestJoinParity:
         assert _delta(s0, s1, "device.launches.join.build") == 1
         n_batches = -(-600 // 256)
         assert _delta(s0, s1, "device.launches.join.probe") == n_batches
-
-    def test_dense_build_under_interpret_kernel(self, jctx, monkeypatch):
-        # DATAFUSION_TPU_PALLAS=interpret routes the slot-table build
-        # through the Pallas hash-build kernel (counted), same rows
-        sql = "SELECT seq, name FROM fact JOIN dim ON fact.k = dim.k"
-        monkeypatch.setenv("DATAFUSION_TPU_PALLAS", "interpret")
-        s0 = _counts()
-        got = _rows(jctx, sql)
-        s1 = _counts()
-        assert _delta(s0, s1, "join.build.pallas_runs") == 1
-        assert got == _pd_rows(jctx._fact.merge(jctx._dim, on="k"),
-                               ["seq", "name"])
 
     def test_left_outer(self, jctx):
         got = _rows(jctx, "SELECT seq, name FROM fact "

@@ -2,9 +2,7 @@
 
 Three layers, all on the CPU tier-1 backend:
 
-- The Pallas hash-build kernel against its numpy oracle through the
-  Pallas interpreter (`interpret=True` — same kernel code path the TPU
-  runs, minus Mosaic lowering).
+- The join's slot-table build against its numpy oracle.
 - The engine's fused passes against numpy / Python oracles: right
   answers, one launch a batch group, plan-chain collapse in effect.
 - Sort semantics that must survive any backend/kernel swap: stability,
@@ -57,57 +55,35 @@ def _rows(ctx, sql):
     return collect(ctx.sql(sql)).to_rows()
 
 
-# ---------------------------------------------------------------- pallas
+# ------------------------------------------------------------ join build
 
 
-class TestPallasKernelParity:
-    """The one Pallas kernel (exec/pallas/hash_build.py) against its
-    numpy oracle through the interpreter, at the 2-D (8, 128)-aligned
-    block shapes the chip compiles."""
+class TestHashBuildParity:
+    """The join's slot-table build (join/relation.py) against its numpy
+    oracle."""
 
     @pytest.mark.parametrize("n,slots", [
-        (8192, 8192),   # window edge: BUILD_MAX_SLOTS slots, one row each
-        (5000, 8192),   # sparse table, ragged row padding
+        (8192, 8192),   # full table, one row each
+        (5000, 8192),   # sparse table
         (700, 300),     # duplicates: counts > 1, max row index wins
         (1, 1),
     ])
     def test_hash_build_parity(self, n, slots):
         import jax
 
-        from datafusion_tpu.exec.pallas import hash_build
+        from datafusion_tpu.join.relation import (
+            build_slot_table_numpy,
+            build_slot_table_xla,
+        )
 
         rng = np.random.default_rng(7)
         pos = (rng.permutation(max(n, slots))[:n] % slots).astype(np.int32)
         live = rng.random(n) > 0.15
-        want = hash_build.build_slot_table_numpy(pos, live, slots)
-        got = jax.jit(lambda p, l: hash_build.build_slot_table(
-            p, l, slots, interpret=True))(pos, live)
-        xla = jax.jit(lambda p, l: hash_build.build_slot_table_xla(
+        want = build_slot_table_numpy(pos, live, slots)
+        got = jax.jit(lambda p, l: build_slot_table_xla(
             p, l, slots))(pos, live)
-        for g, x, w in zip(got, xla, want):
+        for g, w in zip(got, want):
             np.testing.assert_array_equal(np.asarray(g), w)
-            np.testing.assert_array_equal(np.asarray(x), w)
-
-    def test_hash_build_blocks_are_tile_aligned(self):
-        from datafusion_tpu.exec import pallas
-        from datafusion_tpu.exec.pallas import hash_build
-
-        assert hash_build.BLOCK_R % 8 == 0 and hash_build.TILE_S % 128 == 0
-        assert pallas.BUILD_MAX_SLOTS % hash_build.TILE_S == 0
-
-    def test_engagement_is_a_stated_rule(self, monkeypatch):
-        # no probe, no try/except: mode + device platform decide
-        import jax
-
-        from datafusion_tpu.exec import pallas
-
-        cpu = jax.devices("cpu")[0]
-        monkeypatch.delenv("DATAFUSION_TPU_PALLAS", raising=False)
-        assert not pallas.enabled_for(cpu)
-        monkeypatch.setenv("DATAFUSION_TPU_PALLAS", "interpret")
-        assert pallas.enabled_for(cpu) and pallas.interpret_mode()
-        monkeypatch.setenv("DATAFUSION_TPU_PALLAS", "0")
-        assert not pallas.enabled_for(cpu)
 
 
 # ------------------------------------------------------------ fused pass
